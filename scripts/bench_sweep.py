@@ -31,6 +31,17 @@ def run(batch, remat, steps=10, seq=2048, policy="none", quant=None,
     from shellac_tpu import get_model_config
     from shellac_tpu.config import TrainConfig
     from shellac_tpu.training import init_train_state, make_train_step
+    from shellac_tpu.utils.compile_cache import enable_compile_cache
+    from shellac_tpu.utils.metrics import (
+        device_info,
+        peak_bf16_flops,
+        train_flops_per_token,
+    )
+
+    enable_compile_cache()
+    device = device_info()
+    # Resolved before any work: an unknown device_kind is an error.
+    peak = device["count"] * peak_bf16_flops(device["kind"])
 
     cfg = get_model_config("shellac-1b").replace(
         remat=bool(remat), remat_policy=policy
@@ -51,7 +62,7 @@ def run(batch, remat, steps=10, seq=2048, policy="none", quant=None,
         data["segment_ids"] = jnp.asarray(seg)
 
     state, metrics = step(state, data)
-    float(metrics["loss"])  # sync
+    jax.block_until_ready(metrics["loss"])
 
     t0 = time.perf_counter()
     for _ in range(steps):
@@ -60,10 +71,6 @@ def run(batch, remat, steps=10, seq=2048, policy="none", quant=None,
     dt = (time.perf_counter() - t0) / steps
 
     from shellac_tpu.models.transformer import num_params
-    from shellac_tpu.utils.metrics import (
-        TPU_V5E_BF16_PEAK_FLOPS,
-        train_flops_per_token,
-    )
 
     n = num_params(state.params)
     flops_tok = train_flops_per_token(n, cfg.n_layers, cfg.d_model, seq)
@@ -72,8 +79,9 @@ def run(batch, remat, steps=10, seq=2048, policy="none", quant=None,
         "batch": batch, "remat": bool(remat), "policy": policy,
         "quant": quant, "packed": bool(packed), "fused": fused,
         "tok_s": round(tok_s, 1), "step_s": round(dt, 4),
-        "mfu": round(tok_s * flops_tok / TPU_V5E_BF16_PEAK_FLOPS, 4),
+        "mfu": round(tok_s * flops_tok / peak, 4),
         "loss": round(loss, 3),
+        "device": device,
     }))
 
 

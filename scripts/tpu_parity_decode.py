@@ -12,8 +12,8 @@ parity assertions with interpret=False on the real chip:
   - training flash attention: forward + backward grads vs reference
 
 Exits 0 and prints one JSON line {"ok": true, ...} on success; any
-mismatch raises. Driven by tests/test_tpu_parity.py (subprocess, skipped
-off-TPU) and by the verify skill.
+mismatch raises; without a TPU it exits 2 and checks nothing. Driven by
+chip_smoke.py's kernels phase (in-process, through run_all).
 """
 
 from __future__ import annotations
@@ -29,13 +29,61 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _normal(key, shape, dtype=jnp.float32):
+    """Standard-normal test data made on the host from a jax key: on
+    the chip jax.random.normal compiles a program per shape (seconds
+    each), which is set-up, not what this gate checks."""
+    seed = np.asarray(jax.random.key_data(key)).ravel().tolist()
+    return jnp.asarray(
+        np.random.default_rng(seed).standard_normal(shape, np.float32), dtype
+    )
+
+
+def _jitted(fn):
+    """fn as ONE compiled program per call: array arguments are traced,
+    everything else (window sizes, impl names, flags) is closed over.
+    Called eagerly, every jnp op inside a dispatcher or a reference is
+    its own compile, and on the chip those add up to minutes."""
+    def call(*args, **kw):
+        def is_array(v):
+            return isinstance(v, (jax.Array, np.ndarray))
+
+        where = [is_array(a) for a in args]
+        traced_kw = {k: v for k, v in kw.items() if is_array(v)}
+        static_kw = {k: v for k, v in kw.items() if k not in traced_kw}
+
+        def program(arrays, arrays_kw):
+            it = iter(arrays)
+            full = [next(it) if w else a for w, a in zip(where, args)]
+            return fn(*full, **arrays_kw, **static_kw)
+
+        return jax.jit(program)(
+            [a for w, a in zip(where, args) if w], traced_kw
+        )
+
+    return call
+
+
+def _host(x):
+    """fp32 on the host (no device-side convert to compile)."""
+    return np.asarray(x).astype(np.float32)
+
+
 def check(name, got, want, atol, checks, rtol=None):
-    got, want = np.asarray(got), np.asarray(want)
     np.testing.assert_allclose(
-        got, want, atol=atol, rtol=rtol if rtol is not None else atol,
-        err_msg=name,
+        _host(got), _host(want), atol=atol,
+        rtol=rtol if rtol is not None else atol, err_msg=name,
     )
     checks.append(name)
+
+
+def check_grads(label, got, want, checks, atol=3e-2):
+    """dq/dk/dv against the reference's, each scaled by the reference's
+    largest entry so one tolerance fits all three."""
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        a, b = _host(a), _host(b)
+        scale = max(1.0, float(np.abs(b).max()))
+        check(f"{label} {name}", a / scale, b / scale, atol, checks)
 
 
 def dense_decode_cases(checks):
@@ -44,17 +92,17 @@ def dense_decode_cases(checks):
     B, L, H, HKV, D = 4, 1024, 16, 8, 128
     for s, window in [(1, None), (1, 200), (4, None), (4, 200)]:
         ks = jax.random.split(jax.random.PRNGKey(s * 13 + (window or 1)), 3)
-        q = jax.random.normal(ks[0], (B, s, H, D), jnp.bfloat16)
-        ck = jax.random.normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
-        cv = jax.random.normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
+        q = _normal(ks[0], (B, s, H, D), jnp.bfloat16)
+        ck = _normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
+        cv = _normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
         index = jnp.array([0, 37, 519, L - s], jnp.int32)
-        out = decode_attention(
+        out = _jitted(decode_attention)(
             q, ck, cv, index, window=window, impl="flash", interpret=False
         )
-        ref = _decode_ref(q, ck, cv, index, window, D ** -0.5)
+        ref = _jitted(_decode_ref)(q, ck, cv, index, window, D ** -0.5)
         check(
             f"dense s={s} window={window}",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            out, ref,
             atol=2e-2, checks=checks,
         )
 
@@ -76,9 +124,9 @@ def paged_decode_cases(checks):
         max_blocks = L // bs
         n_blocks = B * max_blocks + 1
         ks = jax.random.split(jax.random.PRNGKey(s * 11 + (window or 1)), 3)
-        q = jax.random.normal(ks[0], (B, s, H, D), jnp.bfloat16)
-        dense_k = jax.random.normal(ks[1], (B, L, HKV, D), jnp.bfloat16)
-        dense_v = jax.random.normal(ks[2], (B, L, HKV, D), jnp.bfloat16)
+        q = _normal(ks[0], (B, s, H, D), jnp.bfloat16)
+        dense_k = _normal(ks[1], (B, L, HKV, D), jnp.bfloat16)
+        dense_v = _normal(ks[2], (B, L, HKV, D), jnp.bfloat16)
         index = jnp.array([0, 37, 519, L - s], jnp.int32)
 
         rng = np.random.default_rng(s)
@@ -95,19 +143,19 @@ def paged_decode_cases(checks):
                 pool_k[tables[b, j]] = dk[b, :, j * bs:(j + 1) * bs]
                 pool_v[tables[b, j]] = dv[b, :, j * bs:(j + 1) * bs]
 
-        out = paged_decode_attention(
+        out = _jitted(paged_decode_attention)(
             q, jnp.asarray(pool_k, jnp.bfloat16),
             jnp.asarray(pool_v, jnp.bfloat16),
             jnp.asarray(tables, jnp.int32), index,
             window=window, impl="flash", interpret=False,
         )
-        ref = _decode_ref(
+        ref = _jitted(_decode_ref)(
             q, dense_k.transpose(0, 2, 1, 3), dense_v.transpose(0, 2, 1, 3),
             index, window, D ** -0.5,
         )
         check(
             f"paged s={s} window={window} bs={bs} shuffled-table",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            out, ref,
             atol=2e-2, checks=checks,
         )
 
@@ -119,32 +167,36 @@ def quant_cache_cases(checks):
 
     B, L, H, HKV, D = 4, 1024, 16, 8, 128
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, D), jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (B, L, HKV, D), jnp.float32)
-    vf = jax.random.normal(ks[2], (B, L, HKV, D), jnp.float32)
-    kq, ksc = quantize_kv(kf)
-    vq, vsc = quantize_kv(vf)
+    q = _normal(ks[0], (B, 1, H, D), jnp.bfloat16)
+    kf = _normal(ks[1], (B, L, HKV, D), jnp.float32)
+    vf = _normal(ks[2], (B, L, HKV, D), jnp.float32)
+    kq, ksc = _jitted(quantize_kv)(kf)
+    vq, vsc = _jitted(quantize_kv)(vf)
     ck, cv = kq.transpose(0, 2, 1, 3), vq.transpose(0, 2, 1, 3)
     kscale, vscale = ksc.transpose(0, 2, 1), vsc.transpose(0, 2, 1)
     index = jnp.array([0, 37, 519, L - 1], jnp.int32)
     for window in (None, 200):
-        out = decode_attention(
+        out = _jitted(decode_attention)(
             q, ck, cv, index, window=window, impl="flash", interpret=False,
             k_scale=kscale, v_scale=vscale,
         )
-        ref = _decode_ref(
+        ref = _jitted(_decode_ref)(
             q, ck, cv, index, window, D ** -0.5,
             k_scale=kscale, v_scale=vscale,
         )
         check(
             f"dense int8-kv window={window}",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            out, ref,
             atol=2e-2, checks=checks,
         )
 
 
 def quant_paged_cases(checks):
     """int8 paged pool: grouped-gather kernel with scale pages, compiled."""
+    from shellac_tpu.inference.cache.paged import (
+        INT8_BLOCK_SIZE_DEFAULT,
+        INT8_BLOCK_SIZES_RECOMMENDED,
+    )
     from shellac_tpu.inference.kvcache import (
         paged_gather_layer,
         paged_gather_scales,
@@ -155,16 +207,24 @@ def quant_paged_cases(checks):
         paged_decode_attention,
     )
 
-    B, H, HKV, D = 4, 16, 8, 128
-    for s, window, bs, mb in [(1, None, 32, 32), (1, 200, 32, 32),
-                              (1, None, 64, 16), (2, None, 64, 16)]:
+    H, HKV, D = 16, 8, 128
+    dflt = INT8_BLOCK_SIZE_DEFAULT
+    cases = [(4, 1, None, dflt, 1024), (4, 1, 200, dflt, 1024),
+             (4, 2, None, dflt, 1024)]
+    # Every other page size the engine's error message recommends, and
+    # the default at the serve shape (8 slots x ctx 2048).
+    cases += [(4, 1, None, bs, 1024)
+              for bs in INT8_BLOCK_SIZES_RECOMMENDED if bs != dflt]
+    cases += [(8, 1, None, dflt, 2048)]
+    for B, s, window, bs, L in cases:
+        mb = L // bs
         n_blocks = B * mb + 1
         ks = jax.random.split(jax.random.PRNGKey(s * 7 + (window or 1)), 3)
-        q = jax.random.normal(ks[0], (B, s, H, D), jnp.bfloat16)
-        kf = jax.random.normal(ks[1], (n_blocks, bs, HKV, D), jnp.float32)
-        vf = jax.random.normal(ks[2], (n_blocks, bs, HKV, D), jnp.float32)
-        kq, ksc = quantize_kv(kf)
-        vq, vsc = quantize_kv(vf)
+        q = _normal(ks[0], (B, s, H, D), jnp.bfloat16)
+        kf = _normal(ks[1], (n_blocks, bs, HKV, D), jnp.float32)
+        vf = _normal(ks[2], (n_blocks, bs, HKV, D), jnp.float32)
+        kq, ksc = _jitted(quantize_kv)(kf)
+        vq, vsc = _jitted(quantize_kv)(vf)
         pool_k = kq.transpose(0, 2, 1, 3)
         pool_v = vq.transpose(0, 2, 1, 3)
         pks = ksc.transpose(0, 2, 1)
@@ -173,21 +233,21 @@ def quant_paged_cases(checks):
         tables = jnp.asarray(
             (rng.permutation(n_blocks - 1) + 1).reshape(B, mb), jnp.int32
         )
-        L = mb * bs
-        index = jnp.array([0, 37, 519, L - s], jnp.int32)
-        out = paged_decode_attention(
+        index = jnp.asarray(([0, 37, 519, L - s] * 2)[:B], jnp.int32)
+        out = _jitted(paged_decode_attention)(
             q, pool_k, pool_v, tables, index, window=window,
             impl="flash", interpret=False, k_scale=pks, v_scale=pvs,
         )
-        k_all, v_all = paged_gather_layer(pool_k, pool_v, tables)
-        ref = _decode_ref(
+        k_all, v_all = _jitted(paged_gather_layer)(pool_k, pool_v, tables)
+        ref = _jitted(_decode_ref)(
             q, k_all, v_all, index, window, D ** -0.5,
-            k_scale=paged_gather_scales(pks, tables),
-            v_scale=paged_gather_scales(pvs, tables),
+            k_scale=_jitted(paged_gather_scales)(pks, tables),
+            v_scale=_jitted(paged_gather_scales)(pvs, tables),
         )
         check(
-            f"paged int8 s={s} window={window} bs={bs} shuffled-table",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            f"paged int8 B={B} L={L} s={s} window={window} bs={bs} "
+            "shuffled-table",
+            out, ref,
             atol=2e-2, checks=checks,
         )
 
@@ -198,9 +258,9 @@ def flash_train_cases(checks):
 
     B, S, H, HKV, D = 2, 2048, 8, 4, 128
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (B, S, HKV, D), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (B, S, HKV, D), jnp.bfloat16)
+    q = _normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k = _normal(ks[1], (B, S, HKV, D), jnp.bfloat16)
+    v = _normal(ks[2], (B, S, HKV, D), jnp.bfloat16)
     # Ragged packed documents, boundaries off block edges.
     seg = jnp.asarray(
         np.concatenate([
@@ -232,28 +292,22 @@ def flash_train_cases(checks):
                 ) ** 2
             )
 
-        out = flash_attention(
+        out = _jitted(flash_attention)(
             q, k, v, causal=causal, window=window, segments=segments,
             interpret=False,
         )
-        ref = attention_ref(
+        ref = _jitted(attention_ref)(
             q, k, v, causal=causal, window=window,
             q_segments=segments, kv_segments=segments,
         )
         check(
             f"flash fwd {label}",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            out, ref,
             atol=2e-2, checks=checks,
         )
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for name, a, b in zip("dq dk dv".split(), gf, gr):
-            scale = max(1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
-            check(
-                f"flash bwd {label} {name}",
-                a.astype(jnp.float32) / scale, b.astype(jnp.float32) / scale,
-                atol=3e-2, checks=checks,
-            )
+        gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+        gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+        check_grads(f"flash bwd {label}", gf, gr, checks)
 
 
 def head_dim_64_cases(checks):
@@ -264,46 +318,40 @@ def head_dim_64_cases(checks):
 
     B, L, H, HKV, D = 2, 512, 8, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, D), jnp.bfloat16)
-    ck = jax.random.normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
-    cv = jax.random.normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
+    q = _normal(ks[0], (B, 1, H, D), jnp.bfloat16)
+    ck = _normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
+    cv = _normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
     index = jnp.array([33, L - 1], jnp.int32)
-    out = decode_attention(q, ck, cv, index, impl="flash", interpret=False)
-    ref = _decode_ref(q, ck, cv, index, None, D ** -0.5)
+    out = _jitted(decode_attention)(q, ck, cv, index, impl="flash", interpret=False)
+    ref = _jitted(_decode_ref)(q, ck, cv, index, None, D ** -0.5)
     check(
         "dense dh=64",
-        out.astype(jnp.float32), ref.astype(jnp.float32),
+        out, ref,
         atol=2e-2, checks=checks,
     )
 
     S = 1024
-    qf = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (B, S, HKV, D), jnp.bfloat16)
-    vf = jax.random.normal(ks[2], (B, S, HKV, D), jnp.bfloat16)
-    out = flash_attention(qf, kf, vf, causal=True, interpret=False)
-    ref = attention_ref(qf, kf, vf, causal=True)
+    qf = _normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    kf = _normal(ks[1], (B, S, HKV, D), jnp.bfloat16)
+    vf = _normal(ks[2], (B, S, HKV, D), jnp.bfloat16)
+    out = _jitted(flash_attention)(qf, kf, vf, causal=True, interpret=False)
+    ref = _jitted(attention_ref)(qf, kf, vf, causal=True)
     check(
         "flash fwd dh=64",
-        out.astype(jnp.float32), ref.astype(jnp.float32),
+        out, ref,
         atol=2e-2, checks=checks,
     )
-    gf = jax.grad(
+    gf = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(
             flash_attention(q, k, v, causal=True, interpret=False) ** 2
         ),
         argnums=(0, 1, 2),
-    )(qf, kf, vf)
-    gr = jax.grad(
+    ))(qf, kf, vf)
+    gr = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(attention_ref(q, k, v, causal=True) ** 2),
         argnums=(0, 1, 2),
-    )(qf, kf, vf)
-    for name, a, b in zip("dq dk dv".split(), gf, gr):
-        scale = max(1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
-        check(
-            f"flash bwd dh=64 {name}",
-            a.astype(jnp.float32) / scale, b.astype(jnp.float32) / scale,
-            atol=3e-2, checks=checks,
-        )
+    ))(qf, kf, vf)
+    check_grads("flash bwd dh=64", gf, gr, checks)
 
 
 def mla_shape_cases(checks):
@@ -316,33 +364,27 @@ def mla_shape_cases(checks):
 
     B, L, H, D = 2, 1024, 16, 576  # latent width kv_rank 512 + rope 64
     ks = jax.random.split(jax.random.PRNGKey(13), 2)
-    q = jax.random.normal(ks[0], (B, 1, H, D), jnp.bfloat16)
-    lat = jax.random.normal(ks[1], (B, 1, L, D), jnp.bfloat16)
+    q = _normal(ks[0], (B, 1, H, D), jnp.bfloat16)
+    lat = _normal(ks[1], (B, 1, L, D), jnp.bfloat16)
     index = jnp.array([43, L - 1], jnp.int32)
-    out = decode_attention(q, lat, lat, index, impl="flash",
+    out = _jitted(decode_attention)(q, lat, lat, index, impl="flash",
                            scale=192 ** -0.5, interpret=False)
-    ref = _decode_ref(q, lat, lat, index, None, 192 ** -0.5)
-    check("mla latent decode d=576", out.astype(jnp.float32),
-          ref.astype(jnp.float32), atol=2e-2, checks=checks)
+    ref = _jitted(_decode_ref)(q, lat, lat, index, None, 192 ** -0.5)
+    check("mla latent decode d=576", out, ref, atol=2e-2, checks=checks)
 
     S, HKV, DQ = 1024, 8, 192
     ks = jax.random.split(jax.random.PRNGKey(14), 3)
-    qf = jax.random.normal(ks[0], (B, S, HKV, DQ), jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (B, S, HKV, DQ), jnp.bfloat16)
-    vf = jax.random.normal(ks[2], (B, S, HKV, DQ), jnp.bfloat16)
-    out = flash_attention(qf, kf, vf, causal=True, interpret=False)
-    ref = attention_ref(qf, kf, vf, causal=True)
-    check("mla flash fwd d=192", out.astype(jnp.float32),
-          ref.astype(jnp.float32), atol=2e-2, checks=checks)
-    gf = jax.grad(lambda a, b, c: jnp.sum(flash_attention(
-        a, b, c, causal=True, interpret=False) ** 2), (0, 1, 2))(qf, kf, vf)
-    gr = jax.grad(lambda a, b, c: jnp.sum(attention_ref(
-        a, b, c, causal=True) ** 2), (0, 1, 2))(qf, kf, vf)
-    for name, a, b in zip("dq dk dv".split(), gf, gr):
-        sc = max(1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
-        check(f"mla flash bwd d=192 {name}",
-              a.astype(jnp.float32) / sc, b.astype(jnp.float32) / sc,
-              atol=3e-2, checks=checks)
+    qf = _normal(ks[0], (B, S, HKV, DQ), jnp.bfloat16)
+    kf = _normal(ks[1], (B, S, HKV, DQ), jnp.bfloat16)
+    vf = _normal(ks[2], (B, S, HKV, DQ), jnp.bfloat16)
+    out = _jitted(flash_attention)(qf, kf, vf, causal=True, interpret=False)
+    ref = _jitted(attention_ref)(qf, kf, vf, causal=True)
+    check("mla flash fwd d=192", out, ref, atol=2e-2, checks=checks)
+    gf = jax.jit(jax.grad(lambda a, b, c: jnp.sum(flash_attention(
+        a, b, c, causal=True, interpret=False) ** 2), (0, 1, 2)))(qf, kf, vf)
+    gr = jax.jit(jax.grad(lambda a, b, c: jnp.sum(attention_ref(
+        a, b, c, causal=True) ** 2), (0, 1, 2)))(qf, kf, vf)
+    check_grads("mla flash bwd d=192", gf, gr, checks)
 
 
 
@@ -357,32 +399,31 @@ def sink_cases(checks):
 
     B, L, H, HKV, D = 4, 1024, 16, 8, 128
     ks = jax.random.split(jax.random.PRNGKey(99), 4)
-    sinks = jax.random.normal(ks[3], (H,), jnp.float32) * 2.0
-    q = jax.random.normal(ks[0], (B, 1, H, D), jnp.bfloat16)
-    ck = jax.random.normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
-    cv = jax.random.normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
+    sinks = _normal(ks[3], (H,), jnp.float32) * 2.0
+    q = _normal(ks[0], (B, 1, H, D), jnp.bfloat16)
+    ck = _normal(ks[1], (B, HKV, L, D), jnp.bfloat16)
+    cv = _normal(ks[2], (B, HKV, L, D), jnp.bfloat16)
     index = jnp.array([0, 37, 519, L - 1], jnp.int32)
     for window in (None, 200):
-        out = decode_attention(
+        out = _jitted(decode_attention)(
             q, ck, cv, index, window=window, sinks=sinks, impl="flash",
             interpret=False,
         )
-        ref = _decode_ref(q, ck, cv, index, window, D ** -0.5, sinks=sinks)
+        ref = _jitted(_decode_ref)(q, ck, cv, index, window, D ** -0.5, sinks=sinks)
         check(
             f"dense sinks window={window}",
-            out.astype(jnp.float32), ref.astype(jnp.float32),
+            out, ref,
             atol=2e-2, checks=checks,
         )
 
     S = 512
-    qf = jax.random.normal(ks[0], (2, S, H, D), jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (2, S, HKV, D), jnp.bfloat16)
-    vf = jax.random.normal(ks[2], (2, S, HKV, D), jnp.bfloat16)
-    out = flash_attention(qf, kf, vf, causal=True, sinks=sinks,
+    qf = _normal(ks[0], (2, S, H, D), jnp.bfloat16)
+    kf = _normal(ks[1], (2, S, HKV, D), jnp.bfloat16)
+    vf = _normal(ks[2], (2, S, HKV, D), jnp.bfloat16)
+    out = _jitted(flash_attention)(qf, kf, vf, causal=True, sinks=sinks,
                           interpret=False)
-    ref = attention_ref(qf, kf, vf, causal=True, sinks=sinks)
-    check("flash fwd sinks", out.astype(jnp.float32),
-          ref.astype(jnp.float32), atol=2e-2, checks=checks)
+    ref = _jitted(attention_ref)(qf, kf, vf, causal=True, sinks=sinks)
+    check("flash fwd sinks", out, ref, atol=2e-2, checks=checks)
 
     def loss_flash(q, k, v, s):
         return (flash_attention(
@@ -394,18 +435,18 @@ def sink_cases(checks):
             q, k, v, causal=True, sinks=s
         ).astype(jnp.float32) ** 2).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(qf, kf, vf, sinks)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(qf, kf, vf, sinks)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2, 3)))(
+        qf, kf, vf, sinks)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2, 3)))(
+        qf, kf, vf, sinks)
     for name, a, b in zip(("dq", "dk", "dv", "dsink"), gf, gr):
-        check(f"flash bwd sinks {name}", a.astype(jnp.float32),
-              b.astype(jnp.float32), atol=1.5e-1, checks=checks)
+        check(f"flash bwd sinks {name}", a, b, atol=1.5e-1, checks=checks)
 
 
-def main():
-    backend = jax.default_backend()
-    if backend != "tpu":
-        print(json.dumps({"ok": False, "error": f"backend={backend}, need tpu"}))
-        sys.exit(2)
+def run_all():
+    """Every compiled parity check; returns the list of check names.
+    Raises on the first mismatch. The caller has made sure a TPU is
+    the default backend."""
     checks = []
     dense_decode_cases(checks)
     paged_decode_cases(checks)
@@ -415,7 +456,21 @@ def main():
     head_dim_64_cases(checks)
     mla_shape_cases(checks)
     sink_cases(checks)
-    print(json.dumps({"ok": True, "backend": backend, "checks": checks}))
+    return checks
+
+
+def main():
+    from shellac_tpu.utils.compile_cache import enable_compile_cache
+    from shellac_tpu.utils.metrics import device_info
+
+    enable_compile_cache()
+    device = device_info()
+    if device["platform"] != "tpu":
+        print(json.dumps({"ok": False, "device": device,
+                          "error": "need a tpu"}))
+        sys.exit(2)
+    checks = run_all()
+    print(json.dumps({"ok": True, "device": device, "checks": checks}))
 
 
 if __name__ == "__main__":

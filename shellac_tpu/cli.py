@@ -141,8 +141,14 @@ def _load_native(native_dir):
     return cfg.validate(), params
 
 
-def _restore_params(args, cfg, train_cfg=None):
+def _restore_params(args, cfg, train_cfg=None, mesh=None):
     """Params from --ckpt-dir (latest step), or a fresh random init.
+
+    With `mesh`, the params are created (or restored) directly in the
+    mesh's shardings, as the trainer does: a whole model built on the
+    default device and sharded afterwards leaves a model-sized
+    transient on one chip, and cannot work at all once the model is
+    larger than that chip.
 
     With --ema (eval/generate on a checkpoint trained with
     TrainConfig.ema_decay), returns the averaged weights instead."""
@@ -166,7 +172,10 @@ def _restore_params(args, cfg, train_cfg=None):
         abstract = jax.eval_shape(
             lambda: init_train_state(cfg, tcfg, jax.random.PRNGKey(0))
         )
-        state = ckpt.restore(abstract_state=abstract)
+        state = ckpt.restore(
+            abstract_state=abstract, mesh=mesh,
+            model_cfg=cfg if mesh is not None else None,
+        )
         if use_ema:
             if state.ema_params is None:
                 raise SystemExit(
@@ -175,7 +184,15 @@ def _restore_params(args, cfg, train_cfg=None):
                 )
             return state.ema_params
         return state.params
-    return transformer.init_params(cfg, jax.random.PRNGKey(args.seed))
+    key = jax.random.PRNGKey(args.seed)
+    if mesh is None:
+        return transformer.init_params(cfg, key)
+    from shellac_tpu.parallel.sharding import make_shardings
+
+    return jax.jit(
+        lambda k: transformer.init_params(cfg, k),
+        out_shardings=make_shardings(mesh, transformer.logical_axes(cfg)),
+    )(key)
 
 
 def _resume_skip(args) -> int:
@@ -288,7 +305,11 @@ def cmd_train(args):
     _dump_metrics(args)
     import jax
 
-    print(json.dumps({"final_step": int(jax.device_get(state.step))}))
+    from shellac_tpu.utils.metrics import device_info, device_memory
+
+    print(json.dumps({"final_step": int(jax.device_get(state.step)),
+                      "device": device_info(),
+                      "memory": device_memory()}))
     return 0
 
 
@@ -770,11 +791,13 @@ def cmd_batch(args):
     except ValueError as e:
         raise SystemExit(str(e))
     cfg = _model_config(args)
-    params = _apply_lora(args, cfg, _restore_params(args, cfg))
     mesh = _mesh_from(args)
+    params = _apply_lora(args, cfg, _restore_params(args, cfg, mesh=mesh))
     if mesh is not None:
         from shellac_tpu.inference.engine import shard_params
 
+        # Already in the mesh's shardings unless adapters were merged
+        # in; then this re-places the merged leaves.
         params = shard_params(cfg, params, mesh)
     tok = get_tokenizer(args.tokenizer)
     eng = engine_class(backend_name)(
@@ -945,11 +968,6 @@ def cmd_serve(args):
             "multiplying out to the GLOBAL device count"
         )
     cfg = _model_config(args)
-    params = _apply_lora(args, cfg, _restore_params(args, cfg))
-    if args.quantize:
-        from shellac_tpu.ops.quant import quantize_params
-
-        params = quantize_params(cfg, params)
     mesh = None
     if args.mesh:
         from shellac_tpu.inference.engine import shard_params
@@ -980,6 +998,15 @@ def cmd_serve(args):
                 "tp=8); dp/fsdp would split the slot batch across hosts"
             )
         mesh = global_mesh(pcfg)
+    # With a mesh the params are born in its shardings (no whole-model
+    # transient on the first chip).
+    params = _apply_lora(args, cfg, _restore_params(args, cfg, mesh=mesh))
+    if args.quantize:
+        from shellac_tpu.ops.quant import quantize_params
+
+        params = quantize_params(cfg, params)
+    if mesh is not None:
+        # Re-places only what adapters or quantization rebuilt.
         params = shard_params(cfg, params, mesh)
     # Engine construction is wrapped in a zero-arg closure wherever an
     # engine is built here: the serving supervisor's auto-recovery
@@ -994,8 +1021,8 @@ def cmd_serve(args):
     paged_extra = {}
     if paged:
         # block_size=None lets the engine resolve the backend's own
-        # default (the 32-aligned 64 for int8 pools, 16 for bf16) —
-        # ONE source of truth for page geometry.
+        # default (128 for int8 pools, 16 for bf16) — ONE source of
+        # truth for page geometry.
         paged_extra = {
             "prefix_cache": args.prefix_cache,
             "block_size": args.block_size,
@@ -1601,7 +1628,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "windows, and paged pools)")
     s.add_argument("--block-size", type=int, default=None, dest="block_size",
                    help="paged pool page size (default 16; int8 pools "
-                        "need a multiple of 32 and default to 64)")
+                        "need a multiple of 128 and default to 128)")
     s.add_argument("--prefix-cache", action="store_true", dest="prefix_cache",
                    help="reuse cached KV blocks across prompts sharing a "
                         "prefix (requires a paged backend)")
@@ -2141,6 +2168,14 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# Commands that never compile (stdlib-only router and dashboards, pure
+# text tools): they stay importable without jax, so main() does not
+# place the compile cache for them.
+_HOST_ONLY_COMMANDS = frozenset(
+    {"serve-tier", "top", "trace-report", "tokenize"}
+)
+
+
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv[:1] == ["lint"]:
@@ -2150,6 +2185,10 @@ def main(argv=None) -> int:
 
         return lint_main(argv[1:])
     args = build_parser().parse_args(argv)
+    if args.command not in _HOST_ONLY_COMMANDS:
+        from shellac_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     return args.fn(args)
 
 

@@ -1,12 +1,13 @@
 """Startup auto-tuning of the decode window length and the prefill
 chunk size, and the simulated host-latency harness that lets CPU CI
-reproduce the relay-bound regime.
+imitate a host-bound decode loop.
 
-BENCH_DECODE measured the serving engine at ~88 ms/tick with ~2 ms of
-device work: the tick is host-RPC-bound, so `decode_ticks` (K decode
-steps per host sync) is the highest-leverage knob — and its best value
-depends entirely on where the host sits relative to the device (local
-CPU: 1-2; a relay-attached TPU: 8+). TACCL's lesson (PAPERS.md) applies:
+When a decode tick costs the host more than it costs the device,
+`decode_ticks` (K decode steps per host sync) is the highest-leverage
+knob — and its best value depends on how expensive a host sync is
+relative to a device tick, which no attached-chip run has measured yet
+(ROADMAP D3 decides from measurements whether the sweep stays).
+TACCL's lesson (PAPERS.md) applies:
 treat the schedule parameter as a first-class searchable object, not a
 constant. `autotune_decode_ticks` runs the bench_decode sweep's core —
 probe requests through the LIVE engine at each candidate K, measured
@@ -27,9 +28,9 @@ results become available `device_s` after dispatch, whose prefill
 results become available `prefill_s` after theirs, and whose dispatch
 RPC blocks the host for `dispatch_s`, using the engine's window and
 prefill hooks — the real pipeline runs underneath, only the clock is
-shaped. With it, overlapped dispatch (decode AND prefill) shows the
-same ~max(host, device) vs host+device win on a laptop CPU that it
-shows against the relay.
+shaped. With it, overlapped dispatch (decode AND prefill) shows a
+~max(host, device) vs host+device win on a laptop CPU — a ratio of
+injected sleeps, not a speed.
 """
 
 from __future__ import annotations

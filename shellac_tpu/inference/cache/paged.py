@@ -42,6 +42,17 @@ from shellac_tpu.inference.cache.layout import (
 )
 
 
+#: Page geometry of int8 pools. The grouped-gather decode kernel DMAs
+#: each page's fp32 scales out of an (n_blocks, Hkv, block_size) pool in
+#: HBM, and Mosaic refuses any slice of an HBM ref whose lane (last) dim
+#: is narrower than the 128-lane tiling — so a page holds at least 128
+#: tokens. The chipless compile gate (tests/test_aot_compile.py)
+#: compiles the kernel at every size named here.
+INT8_BLOCK_ALIGN = 128
+INT8_BLOCK_SIZES_RECOMMENDED = (INT8_BLOCK_ALIGN, 2 * INT8_BLOCK_ALIGN)
+INT8_BLOCK_SIZE_DEFAULT = INT8_BLOCK_ALIGN
+
+
 class PagedBackend(CacheBackend):
     name = "paged"
     is_paged = True
@@ -53,22 +64,24 @@ class PagedBackend(CacheBackend):
         super().__init__(cfg, n_slots, max_len, kv_quant=kv_quant,
                          chunk_slack=chunk_slack)
         if kv_quant == "int8":
-            if block_size % 32:
-                # The int8 grouped-gather kernel lands each page at
-                # sublane offset g*bs of its VMEM tile; int8's native
-                # (32, 128) tiling makes 32 the alignment unit. An
-                # engine knob, so an error beats a per-tick fallback
+            if block_size % INT8_BLOCK_ALIGN:
+                # An engine knob, so an error beats a per-tick fallback
                 # warning.
                 raise ValueError(
-                    f"kv_quant='int8' paged pools need block_size % 32 "
-                    f"== 0 (got {block_size}); use 32 or 64"
+                    f"kv_quant='int8' paged pools need block_size % "
+                    f"{INT8_BLOCK_ALIGN} == 0 (got {block_size}); use "
+                    + " or ".join(map(str, INT8_BLOCK_SIZES_RECOMMENDED))
                 )
             self.name = "paged-int8"
         self.block_size = block_size
         self.prefix_cache = prefix_cache
         self.max_blocks_per_slot = -(-max_len // block_size)
         if pool_tokens is None:
-            pool_tokens = n_slots * max_len // 2
+            # Half the dense footprint, but never fewer pages than
+            # slots: with coarse pages (int8 pools: 128 tokens) and a
+            # short max_len the halving would leave slots that can
+            # never be admitted while another holds the only page.
+            pool_tokens = max(n_slots * max_len // 2, n_slots * block_size)
         self.n_blocks = max(
             -(-pool_tokens // block_size), self.max_blocks_per_slot
         ) + 1
@@ -480,7 +493,8 @@ class QuantPagedBackend(PagedBackend):
     name = "paged-int8"
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int, *,
-                 kv_quant: Optional[str] = "int8", block_size: int = 64,
+                 kv_quant: Optional[str] = "int8",
+                 block_size: int = INT8_BLOCK_SIZE_DEFAULT,
                  pool_tokens: Optional[int] = None,
                  prefix_cache: bool = False, chunk_slack: int = 1):
         if kv_quant != "int8":

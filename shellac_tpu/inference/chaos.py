@@ -241,7 +241,13 @@ class ReplicaProc:
     Binds port 0 and reports the actual address from the CLI's
     `{"serving": ...}` startup line, so parallel replicas never
     collide. `kill()` is SIGKILL — no drain, no goodbye — and
-    `drain()` posts the graceful path for contrast."""
+    `drain()` posts the graceful path for contrast.
+
+    A CPU-only harness: replicas start with JAX_PLATFORMS=cpu unless
+    the caller's environment says otherwise, so several can run beside
+    a parent that holds jax. Nothing measured through it is a device
+    number, and on a chip machine its replicas would not see the chip
+    (one process per chip) — do not build a benchmark on it."""
 
     def __init__(self, *, model: str = "tiny",
                  config_path: Optional[str] = None, seed: int = 0,

@@ -20,6 +20,7 @@ from typing import Optional
 import flax.struct
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from shellac_tpu.config import ModelConfig
 from shellac_tpu.inference.kvcache import init_cache_for
@@ -321,8 +322,10 @@ class Engine:
 
 #: Junk-beam score: a beam forced onto a constraint-masked candidate
 #: (fewer legal continuations than beams) carries this; the host-side
-#: BEAM_INVALID filter drops it from the returned set.
-BEAM_NEG = jnp.float32(-1e30)
+#: BEAM_INVALID filter drops it from the returned set. A numpy scalar:
+#: a jnp one is a device array, and making it at import initialises the
+#: backend before a multi-host worker can call initialize().
+BEAM_NEG = np.float32(-1e30)
 BEAM_INVALID = -1e20  # host-side validity threshold on final scores
 
 
@@ -498,8 +501,6 @@ def truncate_at_stop(tokens, stop, prompt_outputs=None):
     the same contract with true early exit (its submit(..., stop=...));
     this helper keeps the single-request API consistent.
     """
-    import numpy as np
-
     rows = np.asarray(tokens)
     seqs = [list(map(int, s)) for s in stop]
     if any(len(s) == 0 for s in seqs):

@@ -1107,7 +1107,7 @@ class InferenceServer:
             self._g.thread.start()
 
     def _watchdog(self) -> None:
-        """Detect a wedged engine step (lost follower, dead relay) from
+        """Detect a wedged engine step (lost follower, hung device) from
         outside the scheduler thread. One watchdog follows the
         supervisor across generations for the server's lifetime; it
         exits when the server closes or goes fatal."""
@@ -3745,12 +3745,22 @@ def make_http_server(server: InferenceServer, host: str = "127.0.0.1",
 
 def serve(cfg: ModelConfig, params, *, host="127.0.0.1", port=8000,
           tokenizer=None, **engine_kw):
-    """Blocking entry point used by the CLI."""
+    """Blocking entry point used by the CLI. The start-up line names
+    the device the engine runs on and what it holds once the engine is
+    placed; SIGINT stops the server cleanly."""
+    from shellac_tpu.utils.metrics import device_info, device_memory
+
     srv = InferenceServer(cfg, params, tokenizer=tokenizer, **engine_kw)
     httpd = make_http_server(srv, host, port)
-    print(json.dumps({"serving": f"http://{host}:{httpd.server_address[1]}"}),
-          flush=True)
+    print(json.dumps({
+        "serving": f"http://{host}:{httpd.server_address[1]}",
+        "device": device_info(),
+        "memory": device_memory(),
+    }), flush=True)
     try:
         httpd.serve_forever()
+    except KeyboardInterrupt:
+        pass
     finally:
+        httpd.server_close()
         srv.close()

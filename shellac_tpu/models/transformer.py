@@ -456,7 +456,7 @@ def _block(
         return quant_dot(xin, materialize(w, cdt), cfg.quant_training)
 
     # --- attention ---
-    hx = rms_norm(x, lp["attn_norm"], cfg.norm_eps).astype(cdt)
+    hx = rms_norm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     if cfg.mla is not None:
         o, new_cache = _mla_attention(
             cfg, mesh, attn_impl, hx, lp, cos, sin, cache,
@@ -465,7 +465,7 @@ def _block(
         )
         o = pdot(o, lp["wo"])
         if cfg.post_norms:
-            o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps).astype(cdt)
+            o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
         x = x + constrain(o, mesh, ("batch", "seq", None))
         return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                           moe_layer, new_cache)
@@ -481,8 +481,8 @@ def _block(
     v = v.reshape(b, s, hkv, dh)
     if cfg.qk_norm:
         # Qwen3-style per-head-dim RMSNorm on q/k, applied before rope.
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps).astype(cdt)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps).astype(cdt)
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     sinks = lp["sinks"] if cfg.attn_sink else None
@@ -514,7 +514,7 @@ def _block(
             new_cache = (pool_k, pool_v)
         if fresh_cache:
             o = attention(
-                q, k, v, causal=True, window=window, impl=attn_impl,
+                q, k, v, causal=True, window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
@@ -525,7 +525,7 @@ def _block(
 
             o = paged_decode_attention(
                 q, pool_k, pool_v, page_tables, index,
-                window=window, impl=attn_impl,
+                window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks, k_scale=ks_l, v_scale=vs_l,
             )
@@ -558,7 +558,7 @@ def _block(
             # (exact values — identical to the dense path); the ring
             # only matters for later reads.
             o = attention(
-                q, k, v, causal=True, window=window, impl=attn_impl,
+                q, k, v, causal=True, window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
@@ -591,14 +591,14 @@ def _block(
             # Prefill computes on the exact (unquantized) chunk; only
             # later reads see the int8 rounding.
             o = attention(
-                q, k, v, causal=True, window=window, impl=attn_impl,
+                q, k, v, causal=True, window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
         else:
             o = decode_attention(
                 q, cache_k, cache_v, index,
-                window=window, impl=attn_impl,
+                window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks, k_scale=ks_l, v_scale=vs_l,
             )
@@ -613,7 +613,7 @@ def _block(
             # Every row's positions start at 0, so plain causal masking
             # already excludes the right-pad tail of shorter prompts.
             o = attention(
-                q, k, v, causal=True, window=window, impl=attn_impl,
+                q, k, v, causal=True, window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
@@ -622,7 +622,7 @@ def _block(
 
             o = decode_attention(
                 q, cache_k, cache_v, index,
-                window=window, impl=attn_impl,
+                window=window, impl=attn_impl, mesh=mesh,
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
@@ -632,7 +632,7 @@ def _block(
     if cfg.post_norms:
         # Gemma-2 sandwich norm: the branch OUTPUT is normed before the
         # residual add (HF post_attention_layernorm placement).
-        o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps).astype(cdt)
+        o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     x = x + constrain(o, mesh, ("batch", "seq", None))
     return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                       moe_layer, new_cache)
@@ -642,7 +642,7 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
                new_cache):
     """The MLP half of a block (shared by the MHA/GQA and MLA paths)."""
     cdt = cfg.compute_dtype
-    hx = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).astype(cdt)
+    hx = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     moe_out = _zero_aux()
     # moe_layer overrides the config for interleaved stacks (grouped_moe):
     # dense sub-layers of a MoE model run the plain gated MLP.
@@ -702,7 +702,7 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
         up = constrain(up, mesh, ("batch", "seq", "mlp"))
         down = pdot(_gated_act(cfg)(gate, up), lp["w_down"])
     if cfg.post_norms:
-        down = rms_norm(down, lp["post_mlp_norm"], cfg.norm_eps).astype(cdt)
+        down = rms_norm(down, lp["post_mlp_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     x = x + constrain(down, mesh, ("batch", "seq", None))
     return x, new_cache, moe_out
 
@@ -776,7 +776,7 @@ def _training_attention(cfg, mesh, attn_impl, q, k, v, segments,
     return attention(
         q, k, v, causal=cfg.causal, window=window,
         scale=cfg.attn_scale, softcap=cfg.attn_softcap, sinks=sinks,
-        q_segments=segments, kv_segments=segments, impl=attn_impl,
+        q_segments=segments, kv_segments=segments, impl=attn_impl, mesh=mesh,
     )
 
 
@@ -810,7 +810,7 @@ def _mla_attention(
         q = pdot(hx, lp["wq"])
     else:
         qa = rms_norm(
-            pdot(hx, lp["wq_a"]), lp["q_a_norm"], cfg.norm_eps
+            pdot(hx, lp["wq_a"]), lp["q_a_norm"], cfg.norm_eps, mesh=mesh
         ).astype(cdt)
         q = pdot(qa, lp["wq_b"])
     q = q.reshape(b, s, h, m.qk_head_dim)
@@ -820,7 +820,8 @@ def _mla_attention(
 
     ckv = pdot(hx, lp["wkv_a"])  # (b, s, kv_rank + rope)
     c = rms_norm(
-        ckv[..., : m.kv_lora_rank], lp["kv_a_norm"], cfg.norm_eps
+        ckv[..., : m.kv_lora_rank], lp["kv_a_norm"], cfg.norm_eps,
+        mesh=mesh,
     ).astype(cdt)
     k_pe = apply_rope_interleaved(
         ckv[..., None, m.kv_lora_rank:], cos, sin
@@ -893,7 +894,7 @@ def _mla_attention(
             # serves both roles, values are its first kv_rank lanes.
             o_lat = paged_decode_attention(
                 absorbed_q(), pool_k, pool_k, page_tables, index,
-                scale=scale, impl=attn_impl,
+                scale=scale, impl=attn_impl, mesh=mesh,
                 k_scale=ks_l, v_scale=ks_l,
             )[..., : m.kv_lora_rank]
             o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
@@ -917,7 +918,7 @@ def _mla_attention(
         else:
             o_lat = decode_attention(
                 absorbed_q(), cache_k, cache_k, index, scale=scale,
-                impl=attn_impl, k_scale=ks_l, v_scale=ks_l,
+                impl=attn_impl, mesh=mesh, k_scale=ks_l, v_scale=ks_l,
             )[..., : m.kv_lora_rank]
             o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
         return o.reshape(b, s, h * m.v_head_dim), new_cache
@@ -934,7 +935,7 @@ def _mla_attention(
         # after the weighted sum), so no second copy is ever stored.
         o_lat = decode_attention(
             absorbed_q(), cache_k, cache_k, index, scale=scale,
-            impl=attn_impl,
+            impl=attn_impl, mesh=mesh,
         )[..., : m.kv_lora_rank]
         o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
     return o.reshape(b, s, h * m.v_head_dim), new_cache
@@ -1310,12 +1311,12 @@ def forward(
         }
 
     if return_hidden:
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cdt)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
         x = constrain(x, mesh, ("batch", "seq", None))
         if return_aux:
             return x, aux
         return x
-    logits = unembed(cfg, params, x)
+    logits = unembed(cfg, params, x, mesh=mesh)
     logits = constrain(logits, mesh, ("batch", "seq", "vocab"))
     if return_aux:
         return logits, aux
@@ -1329,7 +1330,8 @@ def output_weights(cfg: ModelConfig, params: Params, cdt) -> jax.Array:
     return params["lm_head"].astype(cdt)
 
 
-def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
+            mesh=None) -> jax.Array:
     """Final RMSNorm + output projection (+ logit softcap): the model
     tail shared by forward, forward_with_cache, and the pipelined
     decode's per-group exit (inference/pp_pipeline.py), so a head
@@ -1337,7 +1339,7 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     hidden; returns fp32 (B, S, V) logits. Callers own any mesh
     constraint on the result."""
     cdt = cfg.compute_dtype
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cdt)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     logits = jnp.einsum(
         "bsd,dv->bsv", x, output_weights(cfg, params, cdt),
         preferred_element_type=jnp.float32,
@@ -1642,7 +1644,7 @@ def forward_with_cache(
         else:
             new_k, new_v = news
 
-    logits = unembed(cfg, params, x)
+    logits = unembed(cfg, params, x, mesh=mesh)
     if new_tokens_len is None:
         new_lengths = index + s
     else:

@@ -14,10 +14,13 @@ grouped-query attention when Hkv < H. Softmax/logits are always fp32.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from shellac_tpu.ops.dispatch import on_mesh, per_shard
 
 NEG_INF = -2.0e38
 
@@ -139,8 +142,12 @@ def attention(
     q_segments: Optional[jax.Array] = None,
     kv_segments: Optional[jax.Array] = None,
     impl: str = "auto",
+    mesh=None,
 ) -> jax.Array:
-    """Dispatching attention. impl: "auto" | "flash" | "ref"."""
+    """Dispatching attention. impl: "auto" | "flash" | "ref".
+
+    `mesh`: the mesh the caller is partitioned over, if any; the flash
+    kernel then runs per shard of batch and heads (see _flash)."""
     if impl not in ("auto", "flash", "ref"):
         raise ValueError(f"unknown attention impl {impl!r}")
     if impl == "ref":
@@ -166,22 +173,47 @@ def attention(
                 "impl='flash' needs q_segments and kv_segments to be the "
                 "same packed-segment array"
             )
-        return flash_attention(
-            q, k, v, causal=causal, scale=scale, window=window,
-            softcap=softcap, sinks=sinks, segments=q_segments,
-        )
+        out = _flash(q, k, v, q_segments, sinks, mesh, causal=causal,
+                     scale=scale, window=window, softcap=softcap)
+        if out is None:
+            raise ValueError(
+                f"impl='flash': q={q.shape} k={k.shape} do not divide "
+                f"over mesh {dict(mesh.shape)}"
+            )
+        return out
     if impl == "auto" and flash_supported(
         q, k, v, window=window, q_positions=q_positions,
         kv_positions=kv_positions, kv_mask=kv_mask, causal=causal,
         q_segments=q_segments, kv_segments=kv_segments,
     ):
-        return flash_attention(
-            q, k, v, causal=causal, scale=scale, window=window,
-            softcap=softcap, sinks=sinks, segments=q_segments,
-        )
+        out = _flash(q, k, v, q_segments, sinks, mesh, causal=causal,
+                     scale=scale, window=window, softcap=softcap)
+        if out is not None:
+            return out
     return attention_ref(
         q, k, v, causal=causal, window=window, scale=scale,
         softcap=softcap, sinks=sinks,
         q_positions=q_positions, kv_positions=kv_positions, kv_mask=kv_mask,
         q_segments=q_segments, kv_segments=kv_segments,
+    )
+
+
+def _flash(q, k, v, segments, sinks, mesh, **kw):
+    """The flash kernel, per shard when the caller is partitioned over
+    a mesh: batch over the data axes, q and kv heads over the tensor
+    axis (each shard keeps whole GQA groups), the sequence whole —
+    sequence parallelism is ring/ulysses' job, upstream of here.
+    Returns None when the shapes do not divide over the mesh."""
+    from shellac_tpu.ops.flash_attention import flash_attention
+
+    if not on_mesh(mesh):
+        return flash_attention(q, k, v, segments=segments, sinks=sinks, **kw)
+    q_axes = ("batch", None, "heads", None)
+    kv_axes = ("batch", None, "kv_heads", None)
+    return per_shard(
+        functools.partial(flash_attention, **kw), mesh,
+        {"q": (q, q_axes), "k": (k, kv_axes), "v": (v, kv_axes),
+         "segments": (segments, ("batch", None)),
+         "sinks": (sinks, ("heads",))},
+        q_axes,
     )

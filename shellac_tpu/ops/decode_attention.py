@@ -10,8 +10,8 @@ index map and the compute are clamped to the live range. A slot at
 position 130 of a 4096-token buffer touches one or two KV blocks, not
 4096 rows.
 
-Two decode-specific grid decisions, both measured on a v5e (see
-BENCH_DECODE.json):
+Two decode-specific grid decisions (history of the measurements:
+PERF.md):
   - Head-major cache layout is load-bearing: Mosaic requires a block's
     trailing two dims to be tileable, so the per-head kv stream must be
     a contiguous (seq_block, head_dim) tile — the kvcache module stores
@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from shellac_tpu.ops.attention import attention_ref
-from shellac_tpu.ops.dispatch import pallas_supported
+from shellac_tpu.ops.dispatch import on_mesh, pallas_supported, per_shard
 from shellac_tpu.ops.flash_attention import _fit_block, sink_rebase
 
 DEFAULT_BLOCK_K = 512
@@ -270,6 +270,35 @@ def _decode_tile_any(
         )
 
 
+# Logical axes of q and of the result on a mesh: slots over the data
+# axes, heads over the tensor axis.
+_Q_AXES = ("batch", None, "heads", None)
+
+
+def _kv_axis(cache):
+    """Logical axis of a cache's kv-head dim (dim 1) on a mesh: kv
+    heads shard with the q heads, so every shard keeps whole GQA
+    groups; a single shared kv head (MQA, the MLA latent) is
+    replicated instead."""
+    return "kv_heads" if cache.shape[1] > 1 else None
+
+
+def _mesh_fallback(impl, quant, warning, q, cache, mesh):
+    """The kernel could not be cut over the mesh (per_shard refused):
+    a forced kernel is an error, an int8 cache says what it loses, and
+    a bf16 cache just takes the reference path."""
+    what = (f"q={tuple(q.shape)} cache={tuple(cache.shape)} do not divide "
+            f"over mesh {dict(mesh.shape)}")
+    if impl == "flash":
+        raise ValueError(f"impl='flash': {what}")
+    if quant:
+        warnings.warn(
+            f"int8-cache decode kernel unavailable: {what} — the "
+            "reference fallback dequantizes the cache every tick",
+            warning, stacklevel=3,
+        )
+
+
 def _live_range(idx, s, block_k, window, num_kv):
     """(first_ki, last_ki) of kv blocks any q row can attend."""
     last_ki = jnp.minimum((idx + s - 1) // block_k, num_kv - 1)
@@ -423,6 +452,7 @@ def _dense_flash(q, cache_k, cache_v, index, scale, window, block_k,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         interpret=interpret,
+        name="decode_dense",
     )(index.astype(jnp.int32), *operands)
     return _unflatten_o(out, b, s, h, d)
 
@@ -464,6 +494,7 @@ def decode_attention(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: Optional[bool] = None,
     k_scale=None, v_scale=None,
+    mesh=None,
 ):
     """Attention of q (B, s, H, D) against a dense cache (B, Hkv, L, D).
 
@@ -474,6 +505,9 @@ def decode_attention(
 
     k_scale/v_scale: (B, Hkv, L) per-token dequant scales for an int8
     cache (see kvcache.QuantKVCache); both or neither.
+
+    `mesh`: the mesh the caller is partitioned over, if any; the kernel
+    then runs per shard of slots and heads (_kv_axis).
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale come together")
@@ -511,13 +545,30 @@ def decode_attention(
                 stacklevel=2,
             )
     if use_kernel:
-        bk = _pick_block_k(cache_k.shape[2], cache_k.shape[1], block_k)
-        return _dense_flash(
-            q, cache_k, cache_v, index, float(scale), window, bk, interpret,
-            k_scale=k_scale, v_scale=v_scale,
-            softcap=None if softcap is None else float(softcap),
-            sinks=sinks,
-        )
+        def kernel(q, cache_k, cache_v, index, k_scale, v_scale, sinks):
+            bk = _pick_block_k(cache_k.shape[2], cache_k.shape[1], block_k)
+            return _dense_flash(
+                q, cache_k, cache_v, index, float(scale), window, bk,
+                interpret, k_scale=k_scale, v_scale=v_scale,
+                softcap=None if softcap is None else float(softcap),
+                sinks=sinks,
+            )
+
+        if not on_mesh(mesh):
+            return kernel(q, cache_k, cache_v, index, k_scale, v_scale, sinks)
+        kv = _kv_axis(cache_k)
+        out = per_shard(kernel, mesh, {
+            "q": (q, _Q_AXES),
+            "cache_k": (cache_k, ("batch", kv, None, None)),
+            "cache_v": (cache_v, ("batch", kv, None, None)),
+            "index": (index, ("batch",)),
+            "k_scale": (k_scale, ("batch", kv, None)),
+            "v_scale": (v_scale, ("batch", kv, None)),
+            "sinks": (sinks, ("heads",)),
+        }, _Q_AXES)
+        if out is not None:
+            return out
+        _mesh_fallback(impl, quant, QuantFallbackWarning, q, cache_k, mesh)
     return _decode_ref(
         q, cache_k, cache_v, index, window, scale, softcap=softcap,
         sinks=sinks, k_scale=k_scale, v_scale=v_scale,
@@ -567,9 +618,10 @@ def _paged_group_kernel(
     """Grouped paged decode: `group` pages gathered per grid step.
 
     The one-page-per-grid-step kernel loses to the XLA dense-gather ref
-    at serving page sizes (block_size 16 measured 0.61x on v5e,
-    BENCH_DECODE.json): each step pays full grid/pipeline overhead to
-    DMA a (hkv, 16, d) sliver and feed the MXU a 16-wide dot. Here the
+    at serving page sizes (block_size 16 measured 0.61x on a v5e in
+    July 2026 — PERF.md history): each step pays full grid/pipeline
+    overhead to DMA a (hkv, 16, d) sliver and feed the MXU a 16-wide
+    dot. Here the
     pool stays in HBM (memory_space=ANY) and the kernel gathers `group`
     pages itself with parallel async copies into one contiguous VMEM
     tile, so per-step overhead amortizes `group`-fold and the dot runs
@@ -738,6 +790,7 @@ def _paged_group_flash(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         interpret=interpret,
+        name="decode_paged_group",
     )(index.astype(jnp.int32), tables.astype(jnp.int32), *operands)
     return _unflatten_o(out, b, s, h, d)
 
@@ -834,6 +887,7 @@ def _paged_flash(q, pool_k, pool_v, tables, index, scale, window, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         interpret=interpret,
+        name="decode_paged",
     )(index.astype(jnp.int32), tables.astype(jnp.int32), *operands)
     return _unflatten_o(out, b, s, h, d)
 
@@ -843,11 +897,14 @@ def paged_decode_supported(q, pool_k, *, quant: bool = False) -> bool:
     hkv, bs, dk = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
     if d % 64 != 0 or dk != d:
         return False
-    if quant and (d % 128 != 0 or bs % 32 != 0):
+    if quant and (d % 128 != 0 or bs % 128 != 0):
         # Int8 runs through the grouped-gather kernel only: its tile
-        # body is the ref-slicing fast path (full-lane head dims) and
-        # the page gather lands each page at sublane offset g*bs, which
-        # int8's (32, 128) native tile requires to be 32-aligned.
+        # body is the ref-slicing fast path (full-lane head dims), and
+        # each page's scales are DMA'd out of an (n_blocks, hkv, bs)
+        # fp32 pool — Mosaic refuses to slice an HBM ref whose lane dim
+        # is below the 128-lane tiling (libtpu 0.0.34: "Slice shape
+        # along dimension 2 must be aligned to tiling (128)"), and the
+        # (hkv, group*bs) VMEM destination needs the same alignment.
         return False
     if h % hkv != 0 or bs % 8 != 0:
         return False
@@ -869,6 +926,7 @@ def paged_decode_attention(
     impl: str = "auto",
     interpret: Optional[bool] = None,
     k_scale=None, v_scale=None,
+    mesh=None,
 ):
     """Attention of q (B, s, H, D) against a paged pool via block tables.
 
@@ -882,6 +940,10 @@ def paged_decode_attention(
     neither. The grouped kernel gathers scale pages alongside value
     pages and folds them in after the integer dots (same exact algebra
     as the dense int8 kernel).
+
+    `mesh`: the mesh the caller is partitioned over, if any; the kernel
+    then runs per shard of slots and heads, every shard over its own
+    heads of the whole pool (_kv_axis).
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale come together")
@@ -899,12 +961,11 @@ def paged_decode_attention(
             )
         use_kernel = True
     else:
-        # 'auto' defaults bf16 pools to the XLA reference path: across
-        # three measurement rounds the grouped-gather paged kernel has
-        # never beaten the reference on hardware (BENCH_DECODE
-        # 2026-07-31 chip run: 284.7 vs 261.9 us/call, 0.92x, at
-        # serving page sizes) — and the engine tick is host-bound
-        # anyway, so the kernel cannot pay its complexity tax.
+        # 'auto' defaults bf16 pools to the XLA reference path: the
+        # grouped-gather paged kernel has never beaten the reference
+        # on hardware (July 2026 chip run: 284.7 vs 261.9 us/call,
+        # 0.92x, at serving page sizes — PERF.md history; ROADMAP D2
+        # re-measures on the attached chip).
         # impl='flash' still forces it (parity tests, future re-
         # measurement). Int8 pools KEEP the kernel under auto: their
         # reference fallback dequantizes gathered pages every tick,
@@ -932,29 +993,49 @@ def paged_decode_attention(
                 f"n_heads % kv_heads == 0 (got {h}/{hkv}), "
                 f"H*s <= 1024 (got {h * s})"
                 + (", and for int8 pools head_dim % 128 == 0 with "
-                   "block size % 32 == 0." if quant else "."),
+                   "block size % 128 == 0." if quant else "."),
                 PagedFallbackWarning,
                 stacklevel=2,
             )
     if use_kernel:
-        # Grouped gather kernel when the head dim keeps full-lane tiles
-        # (its tile body is the ref-slicing fast path) and grouping
-        # actually amortizes anything; one-page kernel otherwise. Int8
-        # pools always take the grouped kernel (the support gate
-        # guarantees its constraints): the one-page kernel's BlockSpec
-        # body has no scale plumbing.
-        group = _paged_group(tables, pool_k) if q.shape[-1] % 128 == 0 else 1
-        sc = None if softcap is None else float(softcap)
-        if group > 1 or quant:
-            return _paged_group_flash(
+        def kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale, sinks):
+            # Grouped gather kernel when the head dim keeps full-lane
+            # tiles (its tile body is the ref-slicing fast path) and
+            # grouping actually amortizes anything; one-page kernel
+            # otherwise. Int8 pools always take the grouped kernel (the
+            # support gate guarantees its constraints): the one-page
+            # kernel's BlockSpec body has no scale plumbing.
+            group = (_paged_group(tables, pool_k)
+                     if q.shape[-1] % 128 == 0 else 1)
+            sc = None if softcap is None else float(softcap)
+            if group > 1 or quant:
+                return _paged_group_flash(
+                    q, pool_k, pool_v, tables, index, float(scale), window,
+                    max(group, 1), interpret, softcap=sc, sinks=sinks,
+                    k_scale=k_scale, v_scale=v_scale,
+                )
+            return _paged_flash(
                 q, pool_k, pool_v, tables, index, float(scale), window,
-                max(group, 1), interpret, softcap=sc, sinks=sinks,
-                k_scale=k_scale, v_scale=v_scale,
+                interpret, softcap=sc, sinks=sinks,
             )
-        return _paged_flash(
-            q, pool_k, pool_v, tables, index, float(scale), window, interpret,
-            softcap=sc, sinks=sinks,
-        )
+
+        if not on_mesh(mesh):
+            return kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale,
+                          sinks)
+        kv = _kv_axis(pool_k)
+        out = per_shard(kernel, mesh, {
+            "q": (q, _Q_AXES),
+            "pool_k": (pool_k, (None, kv, None, None)),
+            "pool_v": (pool_v, (None, kv, None, None)),
+            "tables": (tables, ("batch", None)),
+            "index": (index, ("batch",)),
+            "k_scale": (k_scale, (None, kv, None)),
+            "v_scale": (v_scale, (None, kv, None)),
+            "sinks": (sinks, ("heads",)),
+        }, _Q_AXES)
+        if out is not None:
+            return out
+        _mesh_fallback(impl, quant, PagedFallbackWarning, q, pool_k, mesh)
     from shellac_tpu.inference.kvcache import (
         paged_gather_layer,
         paged_gather_scales,

@@ -295,7 +295,7 @@ def _flash_kernel(
             # exp(s - lse) are the true probabilities, delta =
             # sum(dO*O) still sums only real columns because the
             # sink's value is 0).
-            r, l2, m2 = sink_rebase(m, l, sinks_ref[0, 0])
+            r, l2, m2 = sink_rebase(m, l, sinks_ref[0, 0, 0])
             o_ref[0] = (acc_ref[...] * r / l2).astype(o_ref.dtype)
             lse_ref[0, 0, :] = (m2 + jnp.log(l2))[:, 0]
         else:
@@ -353,12 +353,14 @@ def _flash_forward(
     has_sinks = sinks is not None
     if has_sinks:
         # One scalar per q-head, tiled across a lane row (Mosaic wants
-        # a 128-lane trailing dim).
+        # a 128-lane trailing dim). The unit middle dim makes the
+        # block's last two dims equal the array's: a (1, 128) block of
+        # an (h, 128) array is refused (sublane blocks come in 8s).
         sinks_arr = jnp.tile(
-            sinks.astype(jnp.float32)[:, None], (1, 128)
+            sinks.astype(jnp.float32)[:, None, None], (1, 1, 128)
         )
         in_specs += [
-            pl.BlockSpec((1, 128), lambda bh, qi, ki: (bh % h, 0)),
+            pl.BlockSpec((1, 1, 128), lambda bh, qi, ki: (bh % h, 0, 0)),
         ]
         inputs += [sinks_arr]
 
@@ -393,6 +395,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse[:, 0, :]
 
@@ -592,6 +595,7 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(*inputs)
 
     # --- pass 2: dq ---
@@ -634,6 +638,7 @@ def _flash_backward(
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*inputs)
 
     unflat = lambda x, hh: x.reshape(b, hh, -1, d).transpose(0, 2, 1, 3)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from shellac_tpu.ops.dispatch import pallas_supported
+from shellac_tpu.ops.dispatch import on_mesh, pallas_supported, per_shard
 
 
 def rms_norm_ref(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -77,6 +77,7 @@ def _rms_forward(x, scale, eps, interpret):
         ],
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="rms_norm",
     )(x2, scale)
     if pad:
         out = out[:rows]
@@ -101,16 +102,24 @@ def _rms_bwd(eps, interpret, res, g):
 rms_norm_pallas.defvjp(_rms_fwd, _rms_bwd)
 
 
-def rms_norm(x, scale, eps: float = 1e-5, impl: str = "auto"):
-    """Dispatching RMSNorm. impl: "auto" | "pallas" | "ref"."""
+def rms_norm(x, scale, eps: float = 1e-5, impl: str = "auto", mesh=None):
+    """Dispatching RMSNorm. impl: "auto" | "pallas" | "ref".
+
+    `mesh`: the mesh the caller is partitioned over, if any. x is then
+    (batch, seq[, heads], d) in the model's logical axes and the kernel
+    runs per shard (ops/dispatch.per_shard); rows are independent."""
     if impl == "ref":
         return rms_norm_ref(x, scale, eps)
-    if impl == "pallas":
-        return rms_norm_pallas(x, scale, eps, not _on_tpu())
-    if pallas_supported() and x.shape[-1] % 128 == 0:
-        return rms_norm_pallas(x, scale, eps, False)
-    return rms_norm_ref(x, scale, eps)
-
-
-def _on_tpu() -> bool:
-    return pallas_supported()
+    if impl != "pallas" and not (
+        pallas_supported() and x.shape[-1] % 128 == 0
+    ):
+        return rms_norm_ref(x, scale, eps)
+    interpret = not pallas_supported()
+    if not on_mesh(mesh):
+        return rms_norm_pallas(x, scale, eps, interpret)
+    x_axes = ("batch", "seq", "heads")[: x.ndim - 1] + (None,)
+    out = per_shard(
+        lambda x, scale: rms_norm_pallas(x, scale, eps, interpret), mesh,
+        {"x": (x, x_axes), "scale": (scale, (None,))}, x_axes,
+    )
+    return rms_norm_ref(x, scale, eps) if out is None else out

@@ -8,6 +8,7 @@ this module moves data itself.
 
 from __future__ import annotations
 
+import sys
 from typing import Optional, Sequence
 
 import jax
@@ -60,9 +61,14 @@ def make_mesh(
              parallel.sp, parallel.tp)
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — any refusal takes the fallback
         # mesh_utils optimizes for physical topology; fall back to a plain
-        # reshape when it cannot (e.g. virtual CPU devices).
+        # reshape when it cannot — and say so: on real chips the plain
+        # order can put an inner axis across the slow links.
+        # (chip_smoke.py looks for this line's first words.)
+        print("make_mesh: create_device_mesh refused, plain reshape for "
+              f"shape {shape} ({type(e).__name__}: {e})",
+              file=sys.stderr, flush=True)
         dev_array = np.asarray(list(devices)).reshape(shape)
     return Mesh(dev_array, MESH_AXES)
 

@@ -25,23 +25,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-
-    _NO_CHECK = {"check_vma": False}
-except ImportError:  # pre-promotion jax: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map
-
-    _NO_CHECK = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from shellac_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR
-
-if hasattr(jax.lax, "axis_size"):
-    _axis_size = jax.lax.axis_size
-else:  # pre-0.5 jax: psum of a Python 1 folds to the static axis size
-    def _axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
 
 NEG_INF = -2.0e38
 
@@ -89,7 +76,7 @@ def _ring_local(
     hkv = k.shape[2]
     g = h // hkv
     my = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     # Keep q in its input dtype: preferred_element_type on the einsums
     # already gives fp32 accumulation, and bf16 inputs run the MXU at
@@ -205,6 +192,6 @@ def ring_attention(
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, seg_spec, sink_spec),
         out_specs=q_spec,
-        **_NO_CHECK,
+        check_vma=False,
     )
     return fn(q, k, v, segments, sinks)

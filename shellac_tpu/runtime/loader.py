@@ -1,14 +1,16 @@
 """ctypes bindings for the native (C++) data loader.
 
 The shared library builds lazily on first use (one g++ invocation,
-cached next to the sources); if the toolchain is unavailable the caller
-(shellac_tpu/training/data.py) falls back to the pure-Python reader with
-identical semantics.
+cached next to the sources, keyed by the source's content hash); if the
+toolchain is unavailable the caller (shellac_tpu/training/data.py)
+falls back to the pure-Python reader with identical semantics, and says
+so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,13 +21,28 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libshellac_runtime.so")
 _SRC = os.path.join(_DIR, "csrc", "dataloader.cpp")
+# Hash of the source the binary was built from. The binary is ignored by
+# git and a copied tree carries whatever was on disk with fresh mtimes,
+# so only the content says whether it is stale.
+_STAMP = _SO + ".srchash"
 _build_lock = threading.Lock()
 
 
+def _built_from() -> Optional[str]:
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
 def ensure_built() -> str:
-    """Build the shared library if missing; returns its path."""
+    """Build the shared library unless it was built from this exact
+    source; returns its path."""
     with _build_lock:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+        with open(_SRC, "rb") as f:
+            want = hashlib.sha256(f.read()).hexdigest()
+        if os.path.exists(_SO) and _built_from() == want:
             return _SO
         cmd = [
             os.environ.get("CXX", "g++"), "-O2", "-std=c++17", "-fPIC",
@@ -36,6 +53,8 @@ def ensure_built() -> str:
         except (subprocess.CalledProcessError, FileNotFoundError) as e:
             detail = getattr(e, "stderr", str(e))
             raise OSError(f"native loader build failed: {detail}") from e
+        with open(_STAMP, "w") as f:
+            f.write(want + "\n")
         return _SO
 
 

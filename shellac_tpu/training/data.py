@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import queue
 import struct
+import sys
 import threading
 from typing import Iterator, Optional, Sequence
 
@@ -120,6 +121,12 @@ def distribute_batches(it: Iterator[dict], mesh) -> Iterator[dict]:
         }
 
 
+def _note_reader(kind: str, why: str = "") -> None:
+    """Say on stderr which shard reader a stream got (once per stream)."""
+    print(f"shard reader: {kind}" + (f" ({why})" if why else ""),
+          file=sys.stderr, flush=True)
+
+
 def shard_batches(
     paths: Sequence[str],
     *,
@@ -132,8 +139,10 @@ def shard_batches(
 ) -> Iterator[dict]:
     """Batches drawn from a set of token shards (round-robin by epoch).
 
-    Uses the native C++ loader when built (mmap + prefetch threads);
-    falls back to the pure-Python reader transparently. `skip` resumes
+    Uses the native C++ loader when it builds (mmap + prefetch
+    threads); on a machine without a toolchain it falls back to the
+    pure-Python reader; either way the stream says on stderr which
+    reader it got. `skip` resumes
     the stream past already-trained batches (see token_batches); the
     native reader's prefetch threads make its order non-reproducible
     across run shapes, so there skipping discards real batches — cheap
@@ -145,6 +154,7 @@ def shard_batches(
             from shellac_tpu.runtime.loader import NativeShardReader
 
             reader = NativeShardReader(paths, seed=seed)
+            _note_reader("native")
             it = reader.batches(
                 batch_size=batch_size, seq_len=seq_len,
                 num_batches=num_batches + skip
@@ -154,8 +164,10 @@ def shard_batches(
                 next(it, None)
             yield from it
             return
-        except (ImportError, OSError):
-            pass
+        except (ImportError, OSError) as e:
+            _note_reader("python", f"native loader unavailable: {e}")
+    else:
+        _note_reader("python")
     corpus = np.concatenate([read_token_shard(p) for p in paths])
     yield from token_batches(
         corpus, batch_size=batch_size, seq_len=seq_len, seed=seed,

@@ -21,8 +21,46 @@ import numpy as np
 
 from shellac_tpu.obs import get_registry
 
-# v5e bf16 peak; single source of truth for MFU across bench scripts.
-TPU_V5E_BF16_PEAK_FLOPS = 197e12
+# Published bf16 peak FLOP/s per chip, keyed by jax's `device_kind` —
+# the one table MFU is computed against. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16 per chip).
+PEAK_BF16_FLOPS = {
+    "TPU v5 lite": 197e12,
+}
+
+
+def peak_bf16_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of this kind. A device that is not
+    in the table is an error, not a default: an MFU against the wrong
+    peak is worse than none."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published bf16 peak for device_kind={device_kind!r}; "
+            f"add it (with its source) to PEAK_BF16_FLOPS "
+            f"(known: {sorted(PEAK_BF16_FLOPS)})"
+        ) from None
+
+
+def device_info() -> dict:
+    """The device a result was produced on, as jax reports it — every
+    printed result carries this so no number travels without it."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def device_memory() -> list:
+    """Per local device, bytes in use now and at peak as the backend
+    reports them (None where it reports nothing, as the CPU does)."""
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        out.append({"id": d.id,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return out
 
 
 def train_flops_per_token(n_params: int, n_layers: int, d_model: int, seq: int) -> int:

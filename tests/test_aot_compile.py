@@ -1,0 +1,396 @@
+"""Chipless compile gate: the main path's Pallas kernels at shellac-1b
+widths, compiled by the installed TPU compiler for a *described* (not
+attached) v5e:2x2 device with interpret=False.
+
+Interpret-mode parity cannot see what Mosaic refuses (unaligned memref
+slices, VMEM overflows); a chip run can, but costs chip time. This
+compile costs none, so it guards every PR. Nothing runs here — results
+and timings come only from `chip_smoke.py` on the chip.
+
+The file name sorts early on purpose: tier-1's time window has never
+reached the late alphabet.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# shellac-1b (models/registry.py): 16 q / 8 kv heads, head_dim 128,
+# d_model 2048, seq 2048; train batch 6, serve 8 slots x ctx 2048.
+H, HKV, D, DMODEL = 16, 8, 128, 2048
+TRAIN_B, SEQ = 6, 2048
+SERVE_B, CTX = 8, 2048
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described (not attached) v5e:2x2; persistent compile cache off
+    around the module (a chipless compile writes entries no process can
+    read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / no topology support
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _flash(grad, *, window=None, segments=False, **kw):
+    from shellac_tpu.ops.flash_attention import flash_attention
+
+    shapes = [((TRAIN_B, SEQ, H, D), BF16), ((TRAIN_B, SEQ, HKV, D), BF16),
+              ((TRAIN_B, SEQ, HKV, D), BF16)]
+    if segments:
+        shapes.append(((TRAIN_B, SEQ), I32))
+
+    def fwd(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               segments=seg, interpret=False, **kw)
+
+    if not grad:
+        return fwd, shapes
+
+    def loss(q, k, v, seg=None):
+        return jnp.sum(fwd(q, k, v, seg).astype(F32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes
+
+
+def _dense_decode(*, quant=False, d=D, hkv=HKV, **kw):
+    from shellac_tpu.ops.decode_attention import decode_attention
+
+    cdt = I8 if quant else BF16
+    shapes = [((SERVE_B, 1, H, d), BF16), ((SERVE_B, hkv, CTX, d), cdt),
+              ((SERVE_B, hkv, CTX, d), cdt), ((SERVE_B,), I32)]
+    if quant:
+        shapes += [((SERVE_B, hkv, CTX), F32)] * 2
+
+    def fn(q, ck, cv, index, ks=None, vs=None):
+        return decode_attention(q, ck, cv, index, impl="flash",
+                                interpret=False, k_scale=ks, v_scale=vs,
+                                **kw)
+
+    return fn, shapes
+
+
+def _paged_decode(bs, *, quant=False):
+    from shellac_tpu.ops.decode_attention import paged_decode_attention
+
+    mb = CTX // bs
+    n_blocks = SERVE_B * mb + 1
+    cdt = I8 if quant else BF16
+    shapes = [((SERVE_B, 1, H, D), BF16), ((n_blocks, HKV, bs, D), cdt),
+              ((n_blocks, HKV, bs, D), cdt), ((SERVE_B, mb), I32),
+              ((SERVE_B,), I32)]
+    if quant:
+        shapes += [((n_blocks, HKV, bs), F32)] * 2
+
+    def fn(q, pk, pv, tables, index, ks=None, vs=None):
+        return paged_decode_attention(q, pk, pv, tables, index, impl="flash",
+                                      interpret=False, k_scale=ks,
+                                      v_scale=vs)
+
+    return fn, shapes
+
+
+def _rmsnorm(grad):
+    from shellac_tpu.ops.norms import rms_norm_pallas
+
+    shapes = [((TRAIN_B * SEQ, DMODEL), BF16), ((DMODEL,), F32)]
+
+    def fwd(x, scale):
+        return rms_norm_pallas(x, scale, 1e-5, False)
+
+    if not grad:
+        return fwd, shapes
+    return jax.grad(lambda x, s: jnp.sum(fwd(x, s).astype(F32) ** 2),
+                    argnums=(0, 1)), shapes
+
+
+def _int8_page_sizes():
+    """The engine's default int8 page size plus every size its error
+    message recommends — read from the engine, not repeated here."""
+    from shellac_tpu.inference.cache.paged import (
+        INT8_BLOCK_SIZE_DEFAULT,
+        INT8_BLOCK_SIZES_RECOMMENDED,
+    )
+
+    return sorted({INT8_BLOCK_SIZE_DEFAULT, *INT8_BLOCK_SIZES_RECOMMENDED})
+
+
+# (id, builder, expects a Mosaic kernel in the compiled text)
+CASES = [
+    ("flash-fwd", lambda: _flash(False), True),
+    ("flash-fwd-bwd", lambda: _flash(True), True),
+    ("flash-bwd-window-segments",
+     lambda: _flash(True, window=1024, segments=True), True),
+    ("flash-fwd-bwd-sinks-softcap",
+     lambda: _flash(True, sinks=jnp.zeros((H,), F32), softcap=30.0), True),
+    ("decode-dense-bf16", lambda: _dense_decode(), True),
+    ("decode-dense-int8", lambda: _dense_decode(quant=True), True),
+    ("decode-dense-window-sinks-softcap",
+     lambda: _dense_decode(window=1024, softcap=30.0,
+                           sinks=jnp.zeros((H,), F32)), True),
+    ("decode-mla-latent-d576",
+     lambda: _dense_decode(d=576, hkv=1, scale=192 ** -0.5), True),
+    ("paged-bf16-page16", lambda: _paged_decode(16), True),
+    ("paged-bf16-page64", lambda: _paged_decode(64), True),
+    *[(f"paged-int8-page{bs}",
+       lambda bs=bs: _paged_decode(bs, quant=True), True)
+      for bs in _int8_page_sizes()],
+    ("rmsnorm-fwd", lambda: _rmsnorm(False), True),
+    # The backward is plain XLA (vjp of the reference); it only has to
+    # compile.
+    ("rmsnorm-bwd", lambda: _rmsnorm(True), False),
+]
+
+
+@pytest.mark.parametrize("build,has_kernel",
+                         [pytest.param(b, k, id=i) for i, b, k in CASES])
+def test_kernel_compiles_for_v5e(chip, build, has_kernel):
+    fn, shapes = build()
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    if has_kernel:
+        assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# kernels inside a program partitioned over a mesh
+# ---------------------------------------------------------------------------
+#
+# GSPMD refuses to partition a Mosaic kernel, so on a mesh the
+# dispatchers run them per shard (ops/dispatch.per_shard). The CPU's
+# virtual devices never take the kernel branch under "auto", so only
+# these two tests see that path: a compile for the described 2x2 (the
+# dispatchers steered to their TPU branch), and interpret-mode numerics
+# on the virtual CPU mesh.
+
+Q_AXES = ("batch", None, "heads", None)
+KV_AXES = ("batch", None, "kv_heads", None)
+
+
+def _mesh_rmsnorm(mesh):
+    from shellac_tpu.ops.norms import rms_norm
+
+    return (lambda x, s: rms_norm(x, s, mesh=mesh),
+            [((4, SEQ, DMODEL), BF16, ("batch", "seq", None)),
+             ((DMODEL,), F32, (None,))])
+
+
+def _mesh_flash_grad(mesh):
+    from shellac_tpu.ops.attention import attention
+
+    def loss(q, k, v):
+        return jnp.sum(attention(q, k, v, mesh=mesh).astype(F32) ** 2)
+
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((4, SEQ, H, D), BF16, Q_AXES), ((4, SEQ, HKV, D), BF16, KV_AXES),
+             ((4, SEQ, HKV, D), BF16, KV_AXES)])
+
+
+def _mesh_dense_decode(mesh):
+    from shellac_tpu.ops.decode_attention import decode_attention
+
+    cache = ((SERVE_B, HKV, CTX, D), BF16, ("batch", "kv_heads", None, None))
+    return (lambda q, ck, cv, i: decode_attention(q, ck, cv, i, mesh=mesh),
+            [((SERVE_B, 1, H, D), BF16, Q_AXES), cache, cache,
+             ((SERVE_B,), I32, ("batch",))])
+
+
+def _mesh_paged_int8(mesh):
+    from shellac_tpu.inference.cache.paged import INT8_BLOCK_SIZE_DEFAULT
+    from shellac_tpu.ops.decode_attention import paged_decode_attention
+
+    bs = INT8_BLOCK_SIZE_DEFAULT
+    mb = CTX // bs
+    nb = SERVE_B * mb + 1
+    pool = ((nb, HKV, bs, D), I8, (None, "kv_heads", None, None))
+    scales = ((nb, HKV, bs), F32, (None, "kv_heads", None))
+
+    def fn(q, pk, pv, tables, index, ks, vs):
+        return paged_decode_attention(q, pk, pv, tables, index, k_scale=ks,
+                                      v_scale=vs, mesh=mesh)
+
+    return fn, [((SERVE_B, 1, H, D), BF16, Q_AXES), pool, pool,
+                ((SERVE_B, mb), I32, (None, None)),
+                ((SERVE_B,), I32, (None,)), scales, scales]
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_mesh_rmsnorm, id="rmsnorm"),
+    pytest.param(_mesh_flash_grad, id="flash-fwd-bwd"),
+    pytest.param(_mesh_dense_decode, id="decode-dense"),
+    pytest.param(_mesh_paged_int8, id="paged-int8-default-page"),
+])
+def test_kernel_compiles_per_shard_on_2x2(topo, monkeypatch, build):
+    from jax.sharding import NamedSharding
+
+    from shellac_tpu import ParallelConfig, make_mesh
+    from shellac_tpu.parallel.sharding import logical_to_spec
+
+    # The dispatchers ask the default backend whether compiled Pallas
+    # is live; here the target is the described chip, not the host.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh(ParallelConfig(fsdp=2, tp=2), devices=topo.devices)
+    fn, shapes = build(mesh)
+    args = [jax.ShapeDtypeStruct(
+        s, dt, sharding=NamedSharding(mesh, logical_to_spec(axes)))
+        for s, dt, axes in shapes]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _close(got, want, tol):
+    import numpy as np
+
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=tol, rtol=tol,
+    )
+
+
+def test_per_shard_kernels_match_reference(mesh8):
+    """Interpret-mode kernels under shard_map on the dp2/sp2/tp2 CPU
+    mesh agree with the unsharded references: the cut along batch and
+    heads keeps whole GQA groups and every operand's rows together."""
+    import numpy as np
+
+    from shellac_tpu.inference.kvcache import (
+        paged_gather_layer,
+        paged_gather_scales,
+        quantize_kv,
+    )
+    from shellac_tpu.ops.attention import attention, attention_ref
+    from shellac_tpu.ops.decode_attention import (
+        _decode_ref,
+        decode_attention,
+        paged_decode_attention,
+    )
+    from shellac_tpu.ops.norms import rms_norm, rms_norm_ref
+
+    B, S, Hq, Hk, Dh, L, bs = 4, 256, 4, 2, 128, 256, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    x = jax.random.normal(ks[0], (B, S, 256), F32)
+    w = jax.random.normal(ks[1], (256,), F32)
+    _close(jax.jit(lambda a, b: rms_norm(a, b, impl="pallas", mesh=mesh8))(
+        x, w), rms_norm_ref(x, w), 1e-5)
+
+    q = jax.random.normal(ks[2], (B, S, Hq, Dh), F32)
+    k = jax.random.normal(ks[3], (B, S, Hk, Dh), F32)
+    v = jax.random.normal(ks[4], (B, S, Hk, Dh), F32)
+    seg = jnp.asarray(np.repeat([[0, 1, 2, 3]], B, 0).repeat(S // 4, 1), I32)
+    sinks = jax.random.normal(ks[5], (Hq,), F32)
+    got = jax.jit(lambda *a: attention(
+        *a[:3], q_segments=a[3], kv_segments=a[3], sinks=a[4],
+        impl="flash", mesh=mesh8))(q, k, v, seg, sinks)
+    _close(got, attention_ref(q, k, v, q_segments=seg, kv_segments=seg,
+                              sinks=sinks), 2e-5)
+
+    # Decode against an int8 dense cache, then the same tokens paged.
+    q1 = jax.random.normal(ks[6], (B, 1, Hq, Dh), F32)
+    kq, ksc = quantize_kv(k)
+    vq, vsc = quantize_kv(v)
+    ck, cv = kq.transpose(0, 2, 1, 3), vq.transpose(0, 2, 1, 3)
+    cks, cvs = ksc.transpose(0, 2, 1), vsc.transpose(0, 2, 1)
+    index = jnp.asarray([0, 37, 130, L - 1], I32)
+    got = jax.jit(lambda *a: decode_attention(
+        *a[:4], k_scale=a[4], v_scale=a[5], impl="flash", mesh=mesh8))(
+        q1, ck, cv, index, cks, cvs)
+    _close(got, _decode_ref(q1, ck, cv, index, None, Dh ** -0.5,
+                            k_scale=cks, v_scale=cvs), 2e-5)
+
+    mb = L // bs
+    order = np.random.default_rng(0).permutation(B * mb) + 1
+    tables = jnp.asarray(order.reshape(B, mb), I32)
+
+    def pool(c):  # (B, Hk, L, ...) rows scattered to their pages
+        pages = c.reshape(B, Hk, mb, bs, *c.shape[3:])
+        pages = jnp.moveaxis(pages, 2, 1).reshape(B * mb, Hk, bs,
+                                                  *c.shape[3:])
+        out = jnp.zeros((B * mb + 1, *pages.shape[1:]), c.dtype)
+        return out.at[tables.reshape(-1)].set(pages)
+
+    pk, pv, pks, pvs = pool(ck), pool(cv), pool(cks), pool(cvs)
+    got = jax.jit(lambda *a: paged_decode_attention(
+        *a[:5], k_scale=a[5], v_scale=a[6], impl="flash", mesh=mesh8))(
+        q1, pk, pv, tables, index, pks, pvs)
+    k_all, v_all = paged_gather_layer(pk, pv, tables)
+    _close(got, _decode_ref(
+        q1, k_all, v_all, index, None, Dh ** -0.5,
+        k_scale=paged_gather_scales(pks, tables),
+        v_scale=paged_gather_scales(pvs, tables)), 2e-5)
+
+
+# ---------------------------------------------------------------------------
+# compile-cache helper and the smoke's no-chip contract
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set -> the helper sets nothing in code
+    (jax reads the variable itself)."""
+    from shellac_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: calls.append(a))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_fixed_path_in_checkout(monkeypatch):
+    """Unset -> one fixed directory inside the checkout, identical
+    across calls (the path is part of the cache key)."""
+    from shellac_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    seen = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.__setitem__(k, v))
+    first = compile_cache.enable_compile_cache()
+    second = compile_cache.enable_compile_cache()
+    assert first == second == os.path.join(REPO, ".jax_cache")
+    assert seen["jax_compilation_cache_dir"] == first
+
+
+def test_chip_smoke_fails_without_a_chip():
+    """On the CPU the smoke exits non-zero, says ok:false, runs no
+    phase and never prints ok:true."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO,
+    )
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert "phase" not in r.stdout

@@ -308,14 +308,14 @@ class TestBeamSearch:
         from shellac_tpu.inference.batching import PagedBatchingEngine
 
         cfg, params = model
-        dense = Engine(cfg, params, temperature=0.0, max_len=64,
+        dense = Engine(cfg, params, temperature=0.0, max_len=192,
                        kv_quant="int8")
-        paged = PagedBatchingEngine(cfg, params, n_slots=2, max_len=64,
-                                    block_size=32, kv_quant="int8",
-                                    pool_tokens=1024, temperature=0.0)
+        paged = PagedBatchingEngine(cfg, params, n_slots=2, max_len=192,
+                                    block_size=128, kv_quant="int8",
+                                    pool_tokens=2048, temperature=0.0)
         for prompt, k, steps in (
             ([7, 23, 5], 3, 6),        # within one block
-            ([1, 2], 2, 34),           # crosses a block boundary
+            (list(range(1, 121)), 2, 14),  # crosses a block boundary
         ):
             want = dense.beam_search(prompt, num_beams=k,
                                      max_new_tokens=steps)

@@ -205,6 +205,33 @@ class TestCLI:
         assert out["final_step"] == 3
 
 
+def test_mesh_params_are_born_sharded(mesh8):
+    """serve/batch --mesh: random-init params come out of
+    _restore_params already in the mesh's shardings (no whole-model
+    copy on the first device) and hold the same values as the
+    unsharded init of the same seed (to the last ulp or so: one fused
+    program against eager ops). The train final line and the
+    serve start-up line name the device."""
+    import argparse
+
+    from shellac_tpu import cli
+    from shellac_tpu.parallel.sharding import make_shardings
+    from shellac_tpu.utils.metrics import device_info, device_memory
+
+    cfg = get_model_config("tiny")
+    args = argparse.Namespace(seed=3, ckpt_dir=None, ema=False)
+    sharded = cli._restore_params(args, cfg, mesh=mesh8)
+    plain = cli._restore_params(args, cfg)
+    want = make_shardings(mesh8, transformer.logical_axes(cfg))
+    for got, ref, sh in zip(jax.tree.leaves(sharded), jax.tree.leaves(plain),
+                            jax.tree.leaves(want)):
+        assert got.sharding.is_equivalent_to(sh, got.ndim)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-8)
+    assert device_info() == {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert [m["id"] for m in device_memory()] == list(range(8))
+
+
 def test_data_skip_resumes_stream():
     """skip=N must continue the same deterministic stream at batch N."""
     import numpy as np

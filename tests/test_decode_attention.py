@@ -7,7 +7,8 @@ valid length ignored) across GQA, ragged lengths, s=1 and small-s
 decode. Paged variants walk a shuffled block table. Caches are
 head-major: dense (B, Hkv, L, D), pools (nb, Hkv, bs, D) — see
 kvcache.py. Compiled-mode parity runs on the chip via
-scripts/tpu_parity_decode.py (driven by tests/test_tpu_parity.py).
+scripts/tpu_parity_decode.py (chip_smoke.py's kernels phase); the
+chipless compile of the same kernels is tests/test_aot_compile.py.
 """
 
 import jax
@@ -276,7 +277,7 @@ def test_paged_quant_fallback_warns_on_tpu_like_backend(monkeypatch, bs, d):
 def test_paged_bf16_auto_prefers_reference(monkeypatch):
     """bf16 pools default to the XLA reference under auto even on a
     Pallas-capable backend (the grouped-gather kernel has never beaten
-    it on hardware — BENCH_DECODE), and that is a decision, not a
+    it on hardware — PERF.md history), and that is a decision, not a
     fallback: no warning."""
     import warnings as _w
 

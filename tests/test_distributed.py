@@ -81,10 +81,6 @@ print("WORKER_OK", jax.process_index(), flush=True)
 """
 
 
-from conftest import needs_multiprocess_cpu as _needs_multiprocess_cpu
-
-
-@_needs_multiprocess_cpu
 class TestTwoProcessRendezvous:
     """Actual 2-process jax.distributed bring-up over the CPU backend.
 

@@ -77,7 +77,7 @@ class TestEpTraining:
 
     def test_ep_deepseek_shared_experts(self, mesh_ep8):
         # MLA + first-k-dense + shared expert + narrow routed experts:
-        # the DeepSeek composition the VERDICT asked ep to cover.
+        # the DeepSeek composition ep has to cover.
         cfg = get_model_config("tiny-deepseek").replace(dtype="float32")
         tcfg = TrainConfig(warmup_steps=0, total_steps=100,
                            learning_rate=1e-3)

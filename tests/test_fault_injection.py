@@ -1117,10 +1117,6 @@ print("WORKER_OK", jax.process_index(), flush=True)
 """
 
 
-from conftest import needs_multiprocess_cpu as _needs_multiprocess_cpu
-
-
-@_needs_multiprocess_cpu
 class TestMultihostFaults:
     def test_follower_death_detected_loudly(self, tmp_path):
         run_two_process(tmp_path, _FOLLOWER_DEATH_WORKER, timeout=420,

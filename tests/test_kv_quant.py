@@ -163,15 +163,17 @@ class TestEngines:
     def test_guards(self, model):
         cfg, params = model
         # Int8 paged pools exist now; the remaining guard is the page
-        # alignment (int8 sublane tiling), an actionable config error.
-        # An unset block_size auto-resolves to the aligned 64, so the
+        # alignment (the kernel's scale DMA needs 128-token pages —
+        # cache/paged.py), an actionable config error. An unset
+        # block_size auto-resolves to the aligned default, so the
         # guard only fires on an EXPLICIT misaligned page size.
-        with pytest.raises(ValueError, match="block_size % 32"):
-            PagedBatchingEngine(cfg, params, kv_quant="int8",
-                                block_size=16)
+        for bad in (16, 32, 64):
+            with pytest.raises(ValueError, match="block_size % 128"):
+                PagedBatchingEngine(cfg, params, kv_quant="int8",
+                                    block_size=bad)
         assert PagedBatchingEngine(
             cfg, params, kv_quant="int8"
-        ).block_size == 64
+        ).block_size == 128
         # spec x int8 is no longer excluded (the verify round reads
         # the same write-then-read int8 bits sequential decode does);
         # composition is pinned in test_spec_batching.py and the
@@ -234,14 +236,16 @@ class TestPagedInt8:
         dequantize the read path)."""
         cfg, params = model
         rng = np.random.default_rng(7)
+        # Prompts end inside the first, second and third 128-token
+        # page, so decode crosses page boundaries.
         prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
-                   for n in (3, 37, 5, 61)]
+                   for n in (3, 125, 5, 253)]
         eng = PagedBatchingEngine(
-            cfg, params, n_slots=2, max_len=96, block_size=32,
+            cfg, params, n_slots=2, max_len=384, block_size=128,
             kv_quant="int8",
         )
         got = eng.run([(i, p, 8) for i, p in enumerate(prompts)])
-        single = Engine(cfg, params, temperature=0.0, max_len=96,
+        single = Engine(cfg, params, temperature=0.0, max_len=384,
                         kv_quant="int8")
         for i, p in enumerate(prompts):
             res = single.generate(
@@ -254,15 +258,15 @@ class TestPagedInt8:
         block reuse (scales ride with their blocks)."""
         cfg, params = model
         rng = np.random.default_rng(8)
-        shared = rng.integers(1, cfg.vocab_size, size=64).tolist()
+        shared = rng.integers(1, cfg.vocab_size, size=256).tolist()
         reqs = [(i, shared + rng.integers(1, cfg.vocab_size, size=5).tolist(), 6)
                 for i in range(4)]
         plain = PagedBatchingEngine(
-            cfg, params, n_slots=2, max_len=128, block_size=32,
+            cfg, params, n_slots=2, max_len=384, block_size=128,
             kv_quant="int8",
         ).run(reqs)
         cached_eng = PagedBatchingEngine(
-            cfg, params, n_slots=2, max_len=128, block_size=32,
+            cfg, params, n_slots=2, max_len=384, block_size=128,
             kv_quant="int8", prefix_cache=True,
         )
         cached = cached_eng.run(reqs)
@@ -281,7 +285,7 @@ class TestPagedInt8:
             paged_decode_attention,
         )
 
-        B, H, HKV, D, bs, mb = 2, 8, 4, 128, 32, 8
+        B, H, HKV, D, bs, mb = 2, 8, 4, 128, 128, 8
         n_blocks = B * mb + 1
         ks = jax.random.split(jax.random.PRNGKey(9), 3)
         q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
@@ -295,8 +299,8 @@ class TestPagedInt8:
         pvs = vsc.transpose(0, 2, 1)
         perm = np.random.default_rng(0).permutation(n_blocks - 1) + 1
         tables = jnp.asarray(perm.reshape(B, mb), jnp.int32)
-        index = jnp.array([45, mb * bs - 1], jnp.int32)
-        for window in (None, 70):
+        index = jnp.array([173, mb * bs - 1], jnp.int32)
+        for window in (None, 200):
             out = paged_decode_attention(
                 q, pool_k, pool_v, tables, index, window=window,
                 impl="flash", interpret=True, k_scale=pks, v_scale=pvs,
@@ -314,14 +318,14 @@ class TestPagedInt8:
     def test_chunked_prefill_parity(self, model):
         cfg, params = model
         rng = np.random.default_rng(10)
-        prompts = [rng.integers(1, cfg.vocab_size, size=40).tolist(),
+        prompts = [rng.integers(1, cfg.vocab_size, size=140).tolist(),
                    rng.integers(1, cfg.vocab_size, size=23).tolist()]
         want = PagedBatchingEngine(
-            cfg, params, n_slots=2, max_len=96, block_size=32,
+            cfg, params, n_slots=2, max_len=256, block_size=128,
             kv_quant="int8",
         ).run([(i, p, 6) for i, p in enumerate(prompts)])
         got = PagedBatchingEngine(
-            cfg, params, n_slots=2, max_len=96, block_size=32,
+            cfg, params, n_slots=2, max_len=256, block_size=128,
             kv_quant="int8", prefill_chunk=16,
         ).run([(i, p, 6) for i, p in enumerate(prompts)])
         assert got == want

@@ -143,10 +143,6 @@ print("WORKER_OK", jax.process_index(), flush=True)
 """
 
 
-from conftest import needs_multiprocess_cpu as _needs_multiprocess_cpu
-
-
-@_needs_multiprocess_cpu
 class TestMultihostServing:
     def _run_pair(self, tmp_path, source):
         from conftest import run_two_process

@@ -152,10 +152,6 @@ print("WORKER_OK", proc, flush=True)
 from conftest import run_two_process as _run_pair
 
 
-from conftest import needs_multiprocess_cpu as _needs_multiprocess_cpu
-
-
-@_needs_multiprocess_cpu
 class TestMultihostTraining:
     def test_fit_checkpoint_resume(self, tmp_path):
         """fit() across 2 processes: collective orbax saves, proc-0-only

@@ -67,7 +67,7 @@ def _drain(eng):
 def _build(cfg, params, *, backend="dense", overlap_prefill=False,
            **kw):
     if backend.startswith("paged"):
-        kw.setdefault("block_size", 16 if backend == "paged" else 64)
+        kw.setdefault("block_size", 16 if backend == "paged" else 128)
         kw.setdefault("pool_tokens", 2048)
         return PagedBatchingEngine(
             cfg, params, cache_backend=backend,
