@@ -1,0 +1,6 @@
+"""Device: memory_stats()["peak_bytes_in_use"] of the fullest device, GB."""
+
+
+def read(ctx):
+    b = ctx["device"].get("memory_peak_bytes")
+    return b / 1e9 if b else None
