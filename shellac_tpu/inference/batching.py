@@ -411,13 +411,6 @@ class BatchingEngine:
         # (window) — a sleep-injecting RPC shim that lets CPU CI
         # imitate a host-bound decode loop.
         self._window_hooks = None
-        # Wall-clock the current step() spent blocked in decode-window
-        # syncs (read back out as the host-overhead histogram).
-        self._sync_block_s = 0.0
-        # Per-step phase attribution accumulators (obs.STEP_PHASES):
-        # reset by step(), written by the fill/prefill/settle helpers,
-        # observed into shellac_step_phase_seconds at step end.
-        self._phase_s: Dict[str, float] = {}
         # Cap prefills per engine step: a burst of queued prompts would
         # otherwise run n_slots sequential prefill programs before the
         # next decode tick, stalling every active request's output for
@@ -623,7 +616,10 @@ class BatchingEngine:
         # Richer observability (histograms + gauges) over the shared
         # registry — the Prometheus-facing counterpart of `stats`.
         # Everything it records is host-side and per engine STEP, never
-        # per token and never inside a jitted program.
+        # per token and never inside a jitted program. `obs.steps` is
+        # the step span recorder (obs.StepTrace): always reached
+        # through `self.obs`, which the auto-tuner swaps for a disabled
+        # bundle while it probes.
         self.obs = EngineMetrics(
             registry if registry is not None else get_registry()
         )
@@ -784,6 +780,7 @@ class BatchingEngine:
         return (cache, toks, lps, min_rem, counts, cstate, tlvs, tlis,
                 rem, done, acts)
 
+    @jax.named_scope("sample")
     def _row_decode_step(self, key, logits, cur_r, active_r, min_rem_r,
                          bias_r, pen_r, con_r, samp_r, seed_r, gen_idx_r,
                          greedy_only, use_pen, use_con, use_seed):
@@ -1109,6 +1106,7 @@ class BatchingEngine:
         return (fl[0][None], packed[3][None], fl[1][None], fl[2][None],
                 bias, packed[4][None], packed[5][None], cmask)
 
+    @jax.named_scope("sample")
     def _sample_first(self, key, last, samp):
         """Sample a prefill's first output token from the adjusted
         (biased, EOS-banned, constraint-masked) logits; the logprob
@@ -1262,24 +1260,29 @@ class BatchingEngine:
                 raise ValueError(
                     f"request {rid!r}: qos_weight must be > 0"
                 )
-        self._queue.append(_Request(
-            rid, tokens, max_new, stop=stop, min_tokens=min_tokens,
-            logit_bias=logit_bias, presence_penalty=pres,
-            frequency_penalty=freq,
-            prompt_logprobs=bool(prompt_logprobs), seed=seed,
-            constraint=constraint, trace=trace,
-            prefill_only=bool(prefill_only),
-            tenant=tenant if tenant is None else str(tenant),
-            qos_class=qos_class if qos_class is not None else 1,
-            qos_weight=qos_weight if qos_weight is not None else 4.0,
-            t_queued=time.monotonic(), **samp,
-        ))
-        if trace is not None:
-            # Flight-recorder timeline: the request entered the
-            # engine's admission queue (queue-wait ends at the span's
-            # prefill_start). No-op without a recorder on the trace.
-            trace.record("queue", src="engine", rid=rid,
-                         queue_depth=len(self._queue))
+        # The request is valid: from here it is in the engine. (Outside
+        # a step, so the span has no parent; it rides in the next
+        # step's record.)
+        with self.obs.steps.span("engine.submit", rid=rid):
+            self._queue.append(_Request(
+                rid, tokens, max_new, stop=stop, min_tokens=min_tokens,
+                logit_bias=logit_bias, presence_penalty=pres,
+                frequency_penalty=freq,
+                prompt_logprobs=bool(prompt_logprobs), seed=seed,
+                constraint=constraint, trace=trace,
+                prefill_only=bool(prefill_only),
+                tenant=tenant if tenant is None else str(tenant),
+                qos_class=qos_class if qos_class is not None else 1,
+                qos_weight=qos_weight if qos_weight is not None else 4.0,
+                t_queued=time.monotonic(), **samp,
+            ))
+            if trace is not None:
+                # Flight-recorder timeline: the request entered the
+                # engine's admission queue (queue-wait ends at the
+                # span's prefill_start). No-op without a recorder on
+                # the trace.
+                trace.record("queue", src="engine", rid=rid,
+                             queue_depth=len(self._queue))
 
     def _slot_footprint(self, req: _Request) -> int:
         """Worst-case token residency of `req`: prompt + budget + 1,
@@ -1298,8 +1301,9 @@ class BatchingEngine:
         """Reserve storage for `req` before its prefill (backend hook;
         paged allocates/attaches blocks). May raise PoolExhausted —
         _fill_slots requeues the request and retries after a release."""
-        self.cache_backend.prepare_slot(slot, req,
-                                        self._slot_footprint(req))
+        with self.obs.steps.span("cache.prepare_slot", slot=slot):
+            self.cache_backend.prepare_slot(slot, req,
+                                            self._slot_footprint(req))
 
     def _release_slot(self, slot: int) -> None:
         """A request left `slot`: release its storage (backend hook;
@@ -1308,7 +1312,8 @@ class BatchingEngine:
         back to the cheap no-bias decode variant — zeroing the row
         too, or a later unbiased request on this slot would silently
         inherit the stale biases."""
-        self.cache_backend.release_slot(slot)
+        with self.obs.steps.span("cache.release_slot", slot=slot):
+            self.cache_backend.release_slot(slot)
         if self._slot_bias[slot] is not None:
             self._sbias = self._sbias.at[slot].set(0.0)
             self._slot_bias[slot] = None
@@ -1450,6 +1455,7 @@ class BatchingEngine:
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = req.tokens
         self._key, sub = jax.random.split(self._key)
+        self._count_prefill(s, pad)
         cache, first, lp, plp, tlv, tli = self._prefill_jit[key](
             self.params, self._cache, jnp.asarray(padded),
             jnp.asarray([s], jnp.int32), slot, sub, self._slot_samp(slot, req),
@@ -1463,6 +1469,14 @@ class BatchingEngine:
         return (first, lp, ((tlv, tli) if self.top_logprobs else None),
                 plp if req.prompt_logprobs else None)
 
+    def _count_prefill(self, tokens: int, padded: int) -> None:
+        """A prefill or chunk program of `tokens` real and `padded`
+        bucketed prompt tokens is about to be dispatched (called
+        inside its engine.prefill_dispatch span)."""
+        steps = self.obs.steps
+        steps.count(prefill_tokens=tokens, prefill_padded_tokens=padded)
+        steps.annotate(bucket=padded)
+
     def _prefill_start_offset(self, slot: int) -> int:
         """Tokens already resident when prefill starts (the paged
         backend reports its matched prefix length)."""
@@ -1470,44 +1484,52 @@ class BatchingEngine:
 
     def _fill_slots(self, budget: Optional[int] = None):
         done = 0
+        steps = self.obs.steps
         for i in range(self.n_slots):
             if self._slots[i] is not None or not self._queue:
                 continue
             if budget is not None and done >= budget:
                 break
             done += 1
-            req = self._queue.popleft()
-            try:
-                self._prepare_slot(i, req)
-            except PoolExhausted:
-                # Backend capacity exhausted: put the request back and
-                # let it wait; retry after a slot frees its storage.
-                self._queue.appendleft(req)
-                break
-            if req.trace is not None:
-                # Queue wait ends here (after _prepare_slot: a paged
-                # pool miss requeues the request, so its wait goes on).
-                req.trace.prefill_start()
-            self._set_slot_sampling(i, req)
-            off = self._prefill_start_offset(i)
-            if (self.prefill_chunk is not None
-                    and req.tokens.size - off > self.prefill_chunk):
-                # Long prompt: admit now, prefill incrementally in
-                # step() (the slot stays out of decode until done).
-                self._slots[i] = req
-                self._prefilling[i] = off
-                continue
-            t_pf = time.perf_counter()
-            arrays = self._run_prefill(i, req)
-            self._dispatch_prefill(i, req, arrays)
-            # Phase attribution: the prefill program dispatch, split
-            # out of the surrounding admission bookkeeping (the settle
-            # sync times itself into prefill_settle — immediately below
-            # without overlap, at the next step boundary with it).
-            self._phase_s["prefill_dispatch"] = (
-                self._phase_s.get("prefill_dispatch", 0.0)
-                + time.perf_counter() - t_pf
-            )
+            # One admission, queue pop to prefill dispatched. The rid
+            # (and the request's trace id, when it has one) joins the
+            # step spans a request rode to its own RequestTrace.
+            with steps.span("engine.admit", slot=i) as adm:
+                req = self._queue.popleft()
+                adm.set(rid=req.rid, prompt_tokens=int(req.tokens.size))
+                if req.trace is not None and req.trace.trace_id:
+                    adm.set(trace_id=req.trace.trace_id)
+                try:
+                    self._prepare_slot(i, req)
+                except PoolExhausted:
+                    # Backend capacity exhausted: put the request back
+                    # and let it wait; retry after a slot frees its
+                    # storage.
+                    self._queue.appendleft(req)
+                    adm.set(requeued=True)
+                    break
+                if req.trace is not None:
+                    # Queue wait ends here (after _prepare_slot: a
+                    # paged pool miss requeues the request, so its wait
+                    # goes on).
+                    req.trace.prefill_start()
+                self._set_slot_sampling(i, req)
+                off = self._prefill_start_offset(i)
+                if (self.prefill_chunk is not None
+                        and req.tokens.size - off > self.prefill_chunk):
+                    # Long prompt: admit now, prefill incrementally in
+                    # step() (the slot stays out of decode until done).
+                    self._slots[i] = req
+                    self._prefilling[i] = off
+                    continue
+                # The prefill program dispatch, split out of the
+                # surrounding admission bookkeeping (the settle sync
+                # has its own span — immediately below without
+                # overlap, at the next step boundary with it).
+                with steps.span("engine.prefill_dispatch") as pf:
+                    arrays = self._run_prefill(i, req)
+                    self._dispatch_prefill(i, req, arrays)
+                adm.set(padded_tokens=pf.get("bucket"))
             if not self.overlap_prefill:
                 self._settle_prefills()
 
@@ -1552,19 +1574,17 @@ class BatchingEngine:
         if not self._pflights:
             return False
         flights, self._pflights = self._pflights, []
-        t0 = time.perf_counter()
-        if self._prefill_hooks is not None:
-            self._prefill_hooks.before_prefill_sync(flights)
-        host = jax.device_get([fl.arrays for fl in flights])  # shellac: ignore[SH002] — THE prefill settle: one batched pull for every in-flight prefill's first token / logprob / top-K / prompt scores (the per-admission pulls this replaces each paid their own round trip); the first tokens MUST reach the host here — settle is the TTFT point and the finish check needs them
-        for fl, (first, lp, tl, plp) in zip(flights, host):
-            if self._slots[fl.slot] is not fl.req:
-                continue
-            self._finish_prefill_host(fl.slot, fl.req, first, lp, tl,
-                                      plp)
-        self._phase_s["prefill_settle"] = (
-            self._phase_s.get("prefill_settle", 0.0)
-            + time.perf_counter() - t0
-        )
+        steps = self.obs.steps
+        with steps.span("engine.settle_prefills", prefills=len(flights)):
+            with steps.span("engine.wait_prefill"):
+                if self._prefill_hooks is not None:
+                    self._prefill_hooks.before_prefill_sync(flights)
+                host = jax.device_get([fl.arrays for fl in flights])  # shellac: ignore[SH002] — THE prefill settle: one batched pull for every in-flight prefill's first token / logprob / top-K / prompt scores (the per-admission pulls this replaces each paid their own round trip); the first tokens MUST reach the host here — settle is the TTFT point and the finish check needs them
+            for fl, (first, lp, tl, plp) in zip(flights, host):
+                if self._slots[fl.slot] is not fl.req:
+                    continue
+                self._finish_prefill_host(fl.slot, fl.req, first, lp, tl,
+                                          plp)
         return True
 
     @staticmethod
@@ -1619,6 +1639,7 @@ class BatchingEngine:
         if req.min_tokens > 0:
             self._smin = self._smin.at[slot].set(req.min_tokens - 1)
         req.out.append(first_tok)
+        self.obs.steps.count(tokens_delivered=1)
         if req.trace is not None:
             # The batched settle pull already synced: the first token
             # is a host value, so this is the request's TTFT point —
@@ -1653,8 +1674,7 @@ class BatchingEngine:
         first, drained depth-first — chunk N+1 reuses chunk N's cache
         row while it is hot."""
         used = 0
-        t_pf = time.perf_counter()
-        settle0 = self._phase_s.get("prefill_settle", 0.0)
+        steps = self.obs.steps
         while self._prefilling and (budget is None or used < budget):
             slot = min(self._prefilling)
             used += 1
@@ -1664,55 +1684,54 @@ class BatchingEngine:
             chunk = req.tokens[off:off + self.prefill_chunk]
             s = chunk.size
             pad = min(_bucket(s), self.max_len - off)
-            self._key, sub = jax.random.split(self._key)
             final = off + s >= req.tokens.size
-            boundary = (jnp.asarray(0, jnp.int32) if final
-                        else jnp.asarray(int(req.tokens[off + s]),
-                                         jnp.int32))
-            cache, first, lp, plp_w, blp, tlv, tli = self._chunk_prefill(
-                pad, off == 0, jnp.asarray(
-                    np.pad(chunk, (0, pad - s))[None]
-                ),
-                jnp.asarray([s], jnp.int32), jnp.asarray([off], jnp.int32),
-                slot, sub, self._slot_samp(slot, req),
-                boundary_next=boundary, want_plp=req.prompt_logprobs,
-            )
-            self._cache = cache
-            if req.prompt_logprobs:
-                # Collect DEVICE arrays; the one blocking transfer
-                # happens at the final chunk, so scoring does not
-                # serialize the chunk pipeline with per-chunk syncs.
-                if req.plp is None:
-                    req.plp = []
-                req.plp.append((plp_w, s, None if final else blp))
-            if final:
-                del self._prefilling[slot]
-                # The final chunk's stitching sync no longer happens
-                # here: the collected plp pieces (device arrays) ride
-                # the flight and settle in the ONE batched pull with
-                # the first token — _stitch_plp flattens them host-side
-                # at settle.
-                pieces = req.plp
-                req.plp = None
-                self._dispatch_prefill(
-                    slot, req,
-                    (first, lp,
-                     ((tlv, tli) if self.top_logprobs else None),
-                     pieces),
-                )
-                if not self.overlap_prefill:
-                    self._settle_prefills()
-            else:
-                self._prefilling[slot] = off + s
-        if used:
-            # The chunk loop's dispatch work (program dispatches + host
-            # glue); any final-chunk settle inside the loop timed
-            # itself into prefill_settle and is subtracted out.
-            self._phase_s["prefill_dispatch"] = (
-                self._phase_s.get("prefill_dispatch", 0.0)
-                + (time.perf_counter() - t_pf)
-                - (self._phase_s.get("prefill_settle", 0.0) - settle0)
-            )
+            # One chunk program's dispatch (a final chunk's inline
+            # settle has its own span, after this one).
+            with steps.span("engine.prefill_dispatch", slot=slot,
+                            offset=int(off)):
+                self._key, sub = jax.random.split(self._key)
+                boundary = (jnp.asarray(0, jnp.int32) if final
+                            else jnp.asarray(int(req.tokens[off + s]),
+                                             jnp.int32))
+                self._count_prefill(s, pad)
+                cache, first, lp, plp_w, blp, tlv, tli = \
+                    self._chunk_prefill(
+                        pad, off == 0, jnp.asarray(
+                            np.pad(chunk, (0, pad - s))[None]
+                        ),
+                        jnp.asarray([s], jnp.int32),
+                        jnp.asarray([off], jnp.int32),
+                        slot, sub, self._slot_samp(slot, req),
+                        boundary_next=boundary,
+                        want_plp=req.prompt_logprobs,
+                    )
+                self._cache = cache
+                if req.prompt_logprobs:
+                    # Collect DEVICE arrays; the one blocking transfer
+                    # happens at the final chunk, so scoring does not
+                    # serialize the chunk pipeline with per-chunk syncs.
+                    if req.plp is None:
+                        req.plp = []
+                    req.plp.append((plp_w, s, None if final else blp))
+                if final:
+                    del self._prefilling[slot]
+                    # The final chunk's stitching sync no longer happens
+                    # here: the collected plp pieces (device arrays)
+                    # ride the flight and settle in the ONE batched pull
+                    # with the first token — _stitch_plp flattens them
+                    # host-side at settle.
+                    pieces = req.plp
+                    req.plp = None
+                    self._dispatch_prefill(
+                        slot, req,
+                        (first, lp,
+                         ((tlv, tli) if self.top_logprobs else None),
+                         pieces),
+                    )
+                else:
+                    self._prefilling[slot] = off + s
+            if final and not self.overlap_prefill:
+                self._settle_prefills()
         return used
 
     def _chunk_prefill(self, pad, fresh, tokens, chunk_len, offset, slot,
@@ -1831,157 +1850,114 @@ class BatchingEngine:
         admission, bit-identical to the pre-pipeline engine."""
         finished: List[Tuple[Any, List[int]]] = []
         self.stats["engine_steps"] += 1
-        t_step0 = time.perf_counter()
-        self._sync_block_s = 0.0
-        self._phase_s = {}
-        synced = False
-        settled_prefills = False
-        if self._pflights:
-            # Step boundary: every prefill dispatched in earlier steps
-            # settles NOW, in one batched pull, BEFORE the next decode
-            # window is dispatched — settled slots join this step's
-            # window instead of waiting another boundary. A request
-            # satisfied by its prefill alone (max_new=1, instant EOS,
-            # stop completed by the first token) must be noticed here,
-            # before admissions, or its slot stays occupied a step.
-            settled_prefills = self._settle_prefills()
-            if settled_prefills:
-                self._finish_check(finished)
-        if self.overlap_decode and self._windows:
-            # Keep the device busy across the sync: dispatch the next
-            # window on the current (stale w.r.t. the un-synced window)
-            # slot view, THEN pay the previous window's sync. Slots
-            # whose request finished in the un-synced window carry a
-            # device-side done flag, so their extra window freezes.
-            rows = self._active_rows()
-            if any(rows):
-                self.obs.occupancy.observe(sum(rows) / self.n_slots)
-                self._dispatch_window(rows)
-            t_settle0 = time.perf_counter()
-            synced = self._settle_window(finished) or synced
-            # Split the settle section into its blocked-on-device part
-            # (decode_sync) and the host-side application (settle).
-            self._phase_s["decode_sync"] = self._sync_block_s
-            self._phase_s["settle"] = max(
-                0.0, time.perf_counter() - t_settle0 - self._sync_block_s
+        steps = self.obs.steps
+        steps.begin_step(
+            occupied=sum(r is not None for r in self._slots),
+            windows=len(self._windows), prefills=len(self._pflights),
+        )
+        did_work = False
+        try:
+            synced = False
+            settled_prefills = False
+            if self._pflights:
+                # Step boundary: every prefill dispatched in earlier steps
+                # settles NOW, in one batched pull, BEFORE the next decode
+                # window is dispatched — settled slots join this step's
+                # window instead of waiting another boundary. A request
+                # satisfied by its prefill alone (max_new=1, instant EOS,
+                # stop completed by the first token) must be noticed here,
+                # before admissions, or its slot stays occupied a step.
+                settled_prefills = self._settle_prefills()
+                if settled_prefills:
+                    self._finish_check(finished)
+            if self.overlap_decode and self._windows:
+                # Keep the device busy across the sync: dispatch the next
+                # window on the current (stale w.r.t. the un-synced window)
+                # slot view, THEN pay the previous window's sync. Slots
+                # whose request finished in the un-synced window carry a
+                # device-side done flag, so their extra window freezes.
+                rows = self._active_rows()
+                if any(rows):
+                    self.obs.occupancy.observe(sum(rows) / self.n_slots)
+                    self._dispatch_window(rows)
+                synced = self._settle_window(finished) or synced
+            prefills0 = self.stats["prefills"] + self.stats["prefill_chunks"]
+            # Fill/check until stable: a request satisfied by its prefill
+            # alone (max_new=1, instant EOS, or a stop sequence completed by
+            # the prefill token) frees its slot for the next queued request,
+            # which may itself finish at prefill — every admitted request
+            # must pass a finish check BEFORE the decode window, or its
+            # one-shot finish condition is missed forever. The prefill
+            # budget is shared across the loop's iterations (per step).
+            # The span's self time is the admission phase: queue pops, slot
+            # prep and finish checks, without the prefill dispatches and
+            # inline settles it ran.
+            with steps.span("engine.fill"):
+                remaining = self.max_prefills_per_step
+                # In-flight chunked prefills advance FIRST: they are older
+                # than anything still queued, and giving admissions
+                # priority would let a sustained stream of short prompts
+                # starve an admitted long prompt's chunks out of the
+                # per-step budget forever.
+                if self._prefilling:
+                    used = self._advance_prefills(remaining)
+                    if remaining is not None:
+                        remaining -= used
+                    # A request satisfied by its final chunk alone
+                    # (max_new=1, instant EOS) must be noticed before
+                    # admission/decode.
+                    self._finish_check(finished)
+                while True:
+                    before = self.stats["prefills"]
+                    self._fill_slots(remaining)
+                    if remaining is not None:
+                        remaining -= self.stats["prefills"] - before
+                    n_done = len(finished)
+                    self._finish_check(finished)
+                    if len(finished) == n_done or (
+                        remaining is not None and remaining <= 0
+                    ):
+                        break
+                if self._prefilling and (remaining is None or remaining > 0):
+                    # Chunked prompts admitted THIS step start their first
+                    # chunk immediately instead of idling a full decode
+                    # window.
+                    self._advance_prefills(remaining)
+                    self._finish_check(finished)
+            active_rows = self._active_rows()
+            if any(active_rows) and not self._windows:
+                self.obs.occupancy.observe(sum(active_rows) / self.n_slots)
+                if self.overlap_decode:
+                    # Pipeline warm-up (or re-fill after an idle/abort
+                    # gap): dispatch and leave in flight; the next step
+                    # settles it.
+                    self._dispatch_window(active_rows)
+                else:
+                    # Strict ordering: dispatch and sync within the step.
+                    pairs = [(i, self._slots[i])
+                             for i in range(self.n_slots) if active_rows[i]]
+                    per_slot, per_lps, per_tl = (
+                        self._decode_tokens(active_rows)
+                    )
+                    self._apply_window(pairs, per_slot, per_lps, per_tl,
+                                       finished)
+                    synced = True
+            self._observe_cache_gauges()
+            # A record (and the phase, host-overhead and prefill-section
+            # observations derived from its spans) only for steps that did
+            # work — synced a window, ran or settled a prefill, or finished
+            # a request: a server's idle polling steps would otherwise
+            # drown the distributions in zeros.
+            did_work = (
+                synced or settled_prefills or bool(finished)
+                or self.stats["prefills"] + self.stats["prefill_chunks"]
+                > prefills0
             )
-        t_fill0 = time.perf_counter()
-        settle_fill0 = self._phase_s.get("prefill_settle", 0.0)
-        prefills0 = self.stats["prefills"] + self.stats["prefill_chunks"]
-        # Fill/check until stable: a request satisfied by its prefill
-        # alone (max_new=1, instant EOS, or a stop sequence completed by
-        # the prefill token) frees its slot for the next queued request,
-        # which may itself finish at prefill — every admitted request
-        # must pass a finish check BEFORE the decode window, or its
-        # one-shot finish condition is missed forever. The prefill
-        # budget is shared across the loop's iterations (per step).
-        remaining = self.max_prefills_per_step
-        # In-flight chunked prefills advance FIRST: they are older than
-        # anything still queued, and giving admissions priority would
-        # let a sustained stream of short prompts starve an admitted
-        # long prompt's chunks out of the per-step budget forever.
-        if self._prefilling:
-            used = self._advance_prefills(remaining)
-            if remaining is not None:
-                remaining -= used
-            # A request satisfied by its final chunk alone (max_new=1,
-            # instant EOS) must be noticed before admission/decode.
-            self._finish_check(finished)
-        while True:
-            before = self.stats["prefills"]
-            self._fill_slots(remaining)
-            if remaining is not None:
-                remaining -= self.stats["prefills"] - before
-            n_done = len(finished)
-            self._finish_check(finished)
-            if len(finished) == n_done or (
-                remaining is not None and remaining <= 0
-            ):
-                break
-        if self._prefilling and (remaining is None or remaining > 0):
-            # Chunked prompts admitted THIS step start their first
-            # chunk immediately instead of idling a full decode window.
-            self._advance_prefills(remaining)
-            self._finish_check(finished)
-        if self.stats["prefills"] + self.stats["prefill_chunks"] > prefills0:
-            # Prefill-section wall time (the prefill/chunk programs this
-            # step ran, including their host syncs) — observed only on
-            # steps that actually prefilled.
-            self.obs.prefill_seconds.observe(time.perf_counter() - t_fill0)
-        # Admission phase: the fill section minus the prefill program
-        # dispatches and any inline (non-overlapped) settles it ran
-        # (queue pops, slot prep, finish checks in the loop).
-        self._phase_s["admission"] = max(
-            0.0,
-            time.perf_counter() - t_fill0
-            - self._phase_s.get("prefill_dispatch", 0.0)
-            - (self._phase_s.get("prefill_settle", 0.0) - settle_fill0),
-        )
-        active_rows = self._active_rows()
-        if any(active_rows) and not self._windows:
-            self.obs.occupancy.observe(sum(active_rows) / self.n_slots)
-            if self.overlap_decode:
-                # Pipeline warm-up (or re-fill after an idle/abort
-                # gap): dispatch and leave in flight; the next step
-                # settles it.
-                self._dispatch_window(active_rows)
-            else:
-                # Strict ordering: dispatch and sync within the step.
-                pairs = [(i, self._slots[i])
-                         for i in range(self.n_slots) if active_rows[i]]
-                sync0 = self._sync_block_s
-                per_slot, per_lps, per_tl = (
-                    self._decode_tokens(active_rows)
-                )
-                self._phase_s["decode_sync"] = (
-                    self._phase_s.get("decode_sync", 0.0)
-                    + self._sync_block_s - sync0
-                )
-                t_settle0 = time.perf_counter()
-                self._apply_pairs(pairs, per_slot, per_lps, per_tl)
-                self._finish_check(finished)
-                self._phase_s["settle"] = (
-                    self._phase_s.get("settle", 0.0)
-                    + time.perf_counter() - t_settle0
-                )
-                synced = True
-        self._observe_cache_gauges()
-        if synced:
-            # Host overhead this step: wall time minus the time spent
-            # blocked awaiting decode-window results — the part of the
-            # tick the device cannot see and overlap exists to hide.
-            self.obs.host_overhead.observe(max(
-                0.0,
-                time.perf_counter() - t_step0 - self._sync_block_s,
-            ))
-        self._observe_step_phases(t_step0, synced, finished, prefills0,
-                                  settled_prefills)
+        finally:
+            # A step that raises still closes its root span (and the
+            # profiler annotation under it) and records nothing.
+            steps.end_step(did_work)
         return finished
-
-    def _observe_step_phases(self, t_step0: float, synced: bool,
-                             finished, prefills0: int,
-                             settled_prefills: bool = False) -> None:
-        """Deposit this step's phase attribution (obs.STEP_PHASES) —
-        only for steps that did work (synced a window, ran or settled
-        a prefill, or finished a request): a server's idle polling
-        steps would otherwise drown the distributions in zeros.
-        host_bookkeeping is the remainder, so the six _sum series add
-        up to the step loop's non-idle wall time."""
-        did_work = synced or settled_prefills or bool(finished) or (
-            self.stats["prefills"] + self.stats["prefill_chunks"]
-            > prefills0
-        )
-        if not did_work or not self.obs.registry.enabled:
-            return
-        attributed = 0.0
-        for phase in ("admission", "prefill_dispatch", "prefill_settle",
-                      "decode_sync", "settle"):
-            v = self._phase_s.get(phase, 0.0)
-            attributed += v
-            self.obs.step_phase.labels(phase=phase).observe(v)
-        self.obs.step_phase.labels(phase="host_bookkeeping").observe(
-            max(0.0, time.perf_counter() - t_step0 - attributed)
-        )
 
     # ---- decode-window dispatch / settle ----------------------------
 
@@ -2018,100 +1994,106 @@ class BatchingEngine:
         the outputs as futures, and every per-slot device vector is
         rebound from them so admissions/releases that run before the
         sync compose in dispatch order."""
-        if self._decode is None:
-            impl = (self._decode_impl_pp if self.pp_pipeline
-                    else self._decode_impl)
-            self._decode = self._jit_cache_program(
-                impl, 10,
-                static_argnames=("greedy_only", "use_bias", "use_pen",
-                                 "use_seed", "use_con"),
+        with self.obs.steps.span("engine.dispatch_window",
+                                 ticks=self.decode_ticks,
+                                 rows=sum(active_rows)):
+            if self._decode is None:
+                impl = (self._decode_impl_pp if self.pp_pipeline
+                        else self._decode_impl)
+                self._decode = self._jit_cache_program(
+                    impl, 10,
+                    static_argnames=("greedy_only", "use_bias", "use_pen",
+                                     "use_seed", "use_con"),
+                )
+            adv = self._inflight_advance()
+            self._pre_decode(active_rows, adv)
+            active = jnp.asarray(active_rows)
+            self._key, sub = jax.random.split(self._key)
+            greedy_only = all(
+                r is None or r.temperature == 0.0 for r in self._slots
             )
-        adv = self._inflight_advance()
-        self._pre_decode(active_rows, adv)
-        active = jnp.asarray(active_rows)
-        self._key, sub = jax.random.split(self._key)
-        greedy_only = all(
-            r is None or r.temperature == 0.0 for r in self._slots
-        )
-        use_pen = any(self._slot_pen)
-        if self._con_dirty:
-            self._rebuild_constraints()
-        use_con = self._ctrans is not None
-        counts = (self._scounts if use_pen else self._zero_bias_row)
-        # Generated-token counts at the window's start: host-known
-        # len(out), projected past any window still in flight.
-        gen0 = jnp.asarray(
-            [len(r.out) + adv.get(i, 0) if r is not None else 0
-             for i, r in enumerate(self._slots)],
-            jnp.int32,
-        )
-        # Unconstrained steps pass the shared dummy table so the arg
-        # tree keeps its structure without holding a real table alive.
-        ctrans = self._ctrans if use_con else self._dummy_ctrans
-        (self._cache, toks, lps, self._smin, counts, cstate,
-         tlvs, tlis, self._srem, self._sdone, acts) = self._decode(
-            self.params, self._cache, self._cur, active, sub,
-            (self._stemp, self._stopk, self._stopp, self._sminp,
-             self._sbias if self._sbias is not None
-             else self._zero_bias_row, self._smin,
-             self._spres, self._sfreq, counts,
-             self._sseed, gen0, ctrans, self._coff, self._cstate,
-             self._srem, self._sdone),
-            greedy_only=greedy_only,
-            use_bias=self._sbias is not None and any(
-                b is not None for b in self._slot_bias
-            ),
-            use_pen=use_pen,
-            use_seed=any(
-                r is not None and r.seed is not None for r in self._slots
-            ),
-            use_con=use_con,
-        )
-        if use_pen:
-            self._scounts = counts
-        if use_con:
-            self._cstate = cstate
-        self._cur = toks[-1]
-        w = _DecodeWindow(
-            pairs=[(i, self._slots[i])
-                   for i in range(self.n_slots) if active_rows[i]],
-            ticks=self.decode_ticks,
-            arrays=(toks, lps, tlvs, tlis, acts),
-        )
-        self._windows.append(w)
-        for slot, req in w.pairs:
-            if req.trace is not None:
-                # Dispatch half of the overlap pipeline: recorded per
-                # request so a timeline shows every window the request
-                # rode, with the in-flight depth at dispatch.
-                req.trace.record("window-dispatch", src="engine",
-                                 rid=req.rid, slot=slot, ticks=w.ticks,
-                                 depth=len(self._windows))
-        if self._window_hooks is not None:
-            self._window_hooks.on_dispatch(w)
-        return w
+            use_pen = any(self._slot_pen)
+            if self._con_dirty:
+                self._rebuild_constraints()
+            use_con = self._ctrans is not None
+            counts = (self._scounts if use_pen else self._zero_bias_row)
+            # Generated-token counts at the window's start: host-known
+            # len(out), projected past any window still in flight.
+            gen0 = jnp.asarray(
+                [len(r.out) + adv.get(i, 0) if r is not None else 0
+                 for i, r in enumerate(self._slots)],
+                jnp.int32,
+            )
+            # Unconstrained steps pass the shared dummy table so the arg
+            # tree keeps its structure without holding a real table alive.
+            ctrans = self._ctrans if use_con else self._dummy_ctrans
+            (self._cache, toks, lps, self._smin, counts, cstate,
+             tlvs, tlis, self._srem, self._sdone, acts) = self._decode(
+                self.params, self._cache, self._cur, active, sub,
+                (self._stemp, self._stopk, self._stopp, self._sminp,
+                 self._sbias if self._sbias is not None
+                 else self._zero_bias_row, self._smin,
+                 self._spres, self._sfreq, counts,
+                 self._sseed, gen0, ctrans, self._coff, self._cstate,
+                 self._srem, self._sdone),
+                greedy_only=greedy_only,
+                use_bias=self._sbias is not None and any(
+                    b is not None for b in self._slot_bias
+                ),
+                use_pen=use_pen,
+                use_seed=any(
+                    r is not None and r.seed is not None for r in self._slots
+                ),
+                use_con=use_con,
+            )
+            if use_pen:
+                self._scounts = counts
+            if use_con:
+                self._cstate = cstate
+            self._cur = toks[-1]
+            w = _DecodeWindow(
+                pairs=[(i, self._slots[i])
+                       for i in range(self.n_slots) if active_rows[i]],
+                ticks=self.decode_ticks,
+                arrays=(toks, lps, tlvs, tlis, acts),
+            )
+            self._windows.append(w)
+            for slot, req in w.pairs:
+                if req.trace is not None:
+                    # Dispatch half of the overlap pipeline: recorded per
+                    # request so a timeline shows every window the request
+                    # rode, with the in-flight depth at dispatch.
+                    req.trace.record("window-dispatch", src="engine",
+                                     rid=req.rid, slot=slot, ticks=w.ticks,
+                                     depth=len(self._windows))
+            if self._window_hooks is not None:
+                self._window_hooks.on_dispatch(w)
+            return w
 
     def _sync_window(self, w: _DecodeWindow):
         """THE host sync: pull a dispatched window's packed results
         (tokens, validity flags, logprob sidecars — one transfer) and
         slice each slot's valid prefix. Returns (tokens, logprobs,
         top-K alternatives) keyed by slot."""
-        t0 = time.perf_counter()
-        if self._window_hooks is not None:
-            self._window_hooks.before_sync(w)
-        host_toks, host_lps, host_tlv, host_tli, host_acts = (
-            jax.device_get(w.arrays)  # shellac: ignore[SH002] — the decode window's ONE packed sync; everything the host needs arrives in this single transfer
-        )
-        t1 = time.perf_counter()
-        self._sync_block_s += t1 - t0
+        steps = self.obs.steps
+        with steps.span("engine.wait_window"):
+            if self._window_hooks is not None:
+                self._window_hooks.before_sync(w)
+            host_toks, host_lps, host_tlv, host_tli, host_acts = (
+                jax.device_get(w.arrays)  # shellac: ignore[SH002] — the decode window's ONE packed sync; everything the host needs arrives in this single transfer
+            )
         # Window wall time, dispatch to results-on-host: under
         # overlapped dispatch this spans the host work interleaved with
         # the window — the overlapped reality, not the serial span.
-        self.obs.decode_window_seconds.observe(t1 - w.t_dispatch)
+        self.obs.decode_window_seconds.observe(
+            time.perf_counter() - w.t_dispatch
+        )
         # Device-side stop decisions arrive as per-tick validity flags;
         # valid ticks are a prefix (done is sticky), so each slot's
         # token list is a slice, not a scan.
         n_valid = host_acts.sum(axis=0)
+        steps.count(decode_slot_ticks=w.ticks * self.n_slots,
+                    decode_valid_ticks=int(n_valid.sum()))
         per_slot = [host_toks[:n_valid[i], i].tolist()
                     for i in range(self.n_slots)]
         if not self.logprobs:
@@ -2128,13 +2110,25 @@ class BatchingEngine:
         ]
         return per_slot, per_lps, per_tl
 
-    def _apply_pairs(self, pairs, per_slot, per_lps, per_tl) -> None:
+    def _apply_window(self, pairs, per_slot, per_lps, per_tl,
+                      finished) -> None:
+        """Settle a synced window on the host: append its tokens, then
+        the finish checks and slot releases they trigger."""
+        with self.obs.steps.span("engine.apply_window") as sp:
+            n_done = len(finished)
+            n = self._apply_pairs(pairs, per_slot, per_lps, per_tl)
+            self._finish_check(finished)
+            sp.set(tokens=n, finished=len(finished) - n_done)
+
+    def _apply_pairs(self, pairs, per_slot, per_lps, per_tl) -> int:
         """Append a window's valid tokens to the requests that owned
-        the slots at dispatch. The identity check discards results for
-        slots cancelled or re-admitted while the window was in flight
-        (overlap), and the per-token break re-checks the host-only
-        finish conditions (stop sequences; EOS/budget are pre-cut
-        device-side but re-checked as the single source of truth)."""
+        the slots at dispatch; returns how many were appended. The
+        identity check discards results for slots cancelled or
+        re-admitted while the window was in flight (overlap), and the
+        per-token break re-checks the host-only finish conditions
+        (stop sequences; EOS/budget are pre-cut device-side but
+        re-checked as the single source of truth)."""
+        n_applied = 0
         for slot, req in pairs:
             if self._slots[slot] is not req or slot in self._prefilling:
                 # Cancelled or replaced while the window was in flight:
@@ -2148,6 +2142,7 @@ class BatchingEngine:
                                  n_tokens=len(per_slot[slot]))
             for j, tok in enumerate(per_slot[slot]):
                 req.out.append(int(tok))
+                n_applied += 1
                 if per_lps is not None:
                     req.lps.append(float(per_lps[slot][j]))
                 if per_tl is not None:
@@ -2163,6 +2158,8 @@ class BatchingEngine:
                     # decoding (stop sequence), and the request never
                     # sees them either way.
                     break
+        self.obs.steps.count(tokens_delivered=n_applied)
+        return n_applied
 
     def _settle_window(self, finished) -> bool:
         """Sync and settle the OLDEST in-flight window; False if none
@@ -2171,8 +2168,7 @@ class BatchingEngine:
             return False
         w = self._windows.popleft()
         per_slot, per_lps, per_tl = self._sync_window(w)
-        self._apply_pairs(w.pairs, per_slot, per_lps, per_tl)
-        self._finish_check(finished)
+        self._apply_window(w.pairs, per_slot, per_lps, per_tl, finished)
         return True
 
     def _observe_cache_gauges(self) -> None:
@@ -2638,6 +2634,7 @@ class PagedBatchingEngine(BatchingEngine):
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = suffix
         self._key, sub = jax.random.split(self._key)
+        self._count_prefill(s, pad)
         # One dispatch path: the chunk-continuation program IS the
         # suffix prefill (a suffix is a chunk past `p` resident tokens).
         cache, first, lp, _, _, tlv, tli = self._chunk_prefill(

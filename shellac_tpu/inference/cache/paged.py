@@ -183,13 +183,17 @@ class PagedBackend(CacheBackend):
             return True
         if need - have > len(self._free) + self.evictable():
             return False
-        new_ids = [self.alloc_block() for _ in range(need - have)]
-        self._slot_blocks[slot].extend(new_ids)
-        idx = jnp.arange(have, need, dtype=jnp.int32)
-        tables = eng._cache.tables.at[slot, idx].set(
-            jnp.asarray(new_ids, jnp.int32)
-        )
-        eng._cache = eng._cache.replace(tables=tables)
+        # Opened only when the table grows: the backstop calls of
+        # pre_window find nothing to do and record nothing.
+        with eng.obs.steps.span("cache.ensure_blocks", slot=slot,
+                                pages=need - have):
+            new_ids = [self.alloc_block() for _ in range(need - have)]
+            self._slot_blocks[slot].extend(new_ids)
+            idx = jnp.arange(have, need, dtype=jnp.int32)
+            tables = eng._cache.tables.at[slot, idx].set(
+                jnp.asarray(new_ids, jnp.int32)
+            )
+            eng._cache = eng._cache.replace(tables=tables)
         return True
 
     # ---- prefix cache ------------------------------------------------
@@ -252,6 +256,7 @@ class PagedBackend(CacheBackend):
         if not self.prefix_cache:
             if not self.ensure_blocks(slot, footprint):
                 raise PoolExhausted()
+            eng.obs.steps.annotate(pages=len(self._slot_blocks[slot]))
             return
 
         hashes, matched = self.attach_prefix(req.tokens)
@@ -281,6 +286,8 @@ class PagedBackend(CacheBackend):
             for j in range(m, req.tokens.size // self.block_size)
         ]
         self._slot_prefix_len[slot] = m * self.block_size
+        eng.obs.steps.annotate(pages=len(self._slot_blocks[slot]),
+                               prefix_pages=m)
         eng.stats["prefix_hit_tokens"] += m * self.block_size
         eng.stats["prefix_query_tokens"] += req.tokens.size
 

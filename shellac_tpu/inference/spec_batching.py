@@ -631,53 +631,58 @@ class _SpecDecodeMixin:
                 min_rem)
 
     def _decode_tokens(self, active_rows):
+        steps = self.obs.steps
         t0 = time.perf_counter()
-        # Backend backstop for the round's write span (paged: grow
-        # tables to cover cur + gamma positions; admission already
-        # reserved the full slack footprint, so this is the same
-        # no-op-in-steady-state check the dense window performs).
-        self._pre_decode(active_rows)
-        active = jnp.asarray(active_rows)
-        self._key, sub = jax.random.split(self._key)
-        use_bias = self._sbias is not None and any(
-            bb is not None for bb in self._slot_bias
-        )
-        use_seed = any(
-            r is not None and r.seed is not None for r in self._slots
-        )
-        gen0 = jnp.asarray(
-            [len(r.out) if r is not None else 0 for r in self._slots],
-            jnp.int32,
-        )
-        if self._spec_round is None:
-            round_kw = (
-                {"out_shardings": ((self._cache_sh, self._dcache_sh)
-                                   + (None,) * 7)}
-                if self._cache_sh is not None else {}
+        with steps.span("engine.dispatch_window", ticks=self.gamma + 1,
+                        rows=sum(active_rows)):
+            # Backend backstop for the round's write span (paged: grow
+            # tables to cover cur + gamma positions; admission already
+            # reserved the full slack footprint, so this is the same
+            # no-op-in-steady-state check the dense window performs).
+            self._pre_decode(active_rows)
+            active = jnp.asarray(active_rows)
+            self._key, sub = jax.random.split(self._key)
+            use_bias = self._sbias is not None and any(
+                bb is not None for bb in self._slot_bias
             )
-            self._spec_round = jax.jit(
-                self._spec_round_impl,
-                static_argnames=("use_bias", "use_seed"), **round_kw,
+            use_seed = any(
+                r is not None and r.seed is not None for r in self._slots
             )
-        (self._cache, self._dcache, emitted, counts, self._cur,
-         lps, tlv, tli, self._smin) = self._spec_round(
-            self.params, self.draft_params, self._cache, self._dcache,
-            self._cur, active, sub,
-            (self._stemp, self._stopk, self._stopp, self._sminp,
-             self._sbias if self._sbias is not None
-             else self._zero_bias_row, self._smin, self._sseed, gen0),
-            use_bias=use_bias, use_seed=use_seed,
-        )
-        # The one host sync.
-        em, cnt, host_lps, host_tlv, host_tli = jax.device_get(  # shellac: ignore[SH002] — the verify round's ONE packed sync (acceptance counts must reach the host before the next round)
-            (emitted, counts, lps, tlv, tli)
-        )
-        t1 = time.perf_counter()
-        # The base engine's window instruments live in _sync_window,
-        # which this override replaces: report the verify round as the
-        # decode window it is.
-        self._sync_block_s += t1 - t0
-        self.obs.decode_window_seconds.observe(t1 - t0)
+            gen0 = jnp.asarray(
+                [len(r.out) if r is not None else 0 for r in self._slots],
+                jnp.int32,
+            )
+            if self._spec_round is None:
+                round_kw = (
+                    {"out_shardings": ((self._cache_sh, self._dcache_sh)
+                                       + (None,) * 7)}
+                    if self._cache_sh is not None else {}
+                )
+                self._spec_round = jax.jit(
+                    self._spec_round_impl,
+                    static_argnames=("use_bias", "use_seed"), **round_kw,
+                )
+            (self._cache, self._dcache, emitted, counts, self._cur,
+             lps, tlv, tli, self._smin) = self._spec_round(
+                self.params, self.draft_params, self._cache, self._dcache,
+                self._cur, active, sub,
+                (self._stemp, self._stopk, self._stopp, self._sminp,
+                 self._sbias if self._sbias is not None
+                 else self._zero_bias_row, self._smin, self._sseed, gen0),
+                use_bias=use_bias, use_seed=use_seed,
+            )
+        # The one host sync. The base engine's window instruments live
+        # in _sync_window, which this override replaces: report the
+        # verify round as the decode window it is.
+        with steps.span("engine.wait_window"):
+            em, cnt, host_lps, host_tlv, host_tli = jax.device_get(  # shellac: ignore[SH002] — the verify round's ONE packed sync (acceptance counts must reach the host before the next round)
+                (emitted, counts, lps, tlv, tli)
+            )
+        self.obs.decode_window_seconds.observe(time.perf_counter() - t0)
+        # A round computes gamma + 1 positions a slot and keeps
+        # `counts` of them.
+        steps.count(decode_slot_ticks=(self.gamma + 1) * self.n_slots,
+                    decode_valid_ticks=int(cnt.sum()))
         self.stats["spec_rounds"] += 1
         self.stats["spec_proposed"] += int((cnt > 0).sum()) * self.gamma
         self.stats["spec_accepted"] += int(np.maximum(cnt - 1, 0).sum())

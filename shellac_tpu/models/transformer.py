@@ -377,6 +377,7 @@ def _gated_act(cfg: ModelConfig):
     )
 
 
+@jax.named_scope("embed")
 def _embed_tokens(cfg: ModelConfig, params: Params, tokens, cdt, mesh=None):
     from shellac_tpu.parallel.mesh import AXIS_TENSOR
 
@@ -463,26 +464,29 @@ def _block(
             fresh_cache, segments, pdot, page_tables=page_tables,
             kv_scales=kv_scales,
         )
-        o = pdot(o, lp["wo"])
-        if cfg.post_norms:
-            o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
-        x = x + constrain(o, mesh, ("batch", "seq", None))
+        with jax.named_scope("attn.out"):
+            o = pdot(o, lp["wo"])
+            if cfg.post_norms:
+                o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+            x = x + constrain(o, mesh, ("batch", "seq", None))
         return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                           moe_layer, new_cache)
-    q = pdot(hx, lp["wq"])
-    k = pdot(hx, lp["wk"])
-    v = pdot(hx, lp["wv"])
-    if cfg.attn_bias:
-        q = q + lp["bq"].astype(cdt)
-        k = k + lp["bk"].astype(cdt)
-        v = v + lp["bv"].astype(cdt)
-    q = q.reshape(b, s, h, dh)
-    k = k.reshape(b, s, hkv, dh)
-    v = v.reshape(b, s, hkv, dh)
-    if cfg.qk_norm:
-        # Qwen3-style per-head-dim RMSNorm on q/k, applied before rope.
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+    with jax.named_scope("attn.qkv"):
+        q = pdot(hx, lp["wq"])
+        k = pdot(hx, lp["wk"])
+        v = pdot(hx, lp["wv"])
+        if cfg.attn_bias:
+            q = q + lp["bq"].astype(cdt)
+            k = k + lp["bk"].astype(cdt)
+            v = v + lp["bv"].astype(cdt)
+        q = q.reshape(b, s, h, dh)
+        k = k.reshape(b, s, hkv, dh)
+        v = v.reshape(b, s, hkv, dh)
+        if cfg.qk_norm:
+            # Qwen3-style per-head-dim RMSNorm on q/k, applied before
+            # rope.
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     sinks = lp["sinks"] if cfg.attn_sink else None
@@ -497,21 +501,22 @@ def _block(
         )
 
         pool_k, pool_v, index, q_positions = cache  # pool: (nb, Hkv, bs, D)
-        if kv_scales is not None:
-            # Int8 pool: quantize at write (K post-rope, the
-            # QuantKVCache contract); scale pools scatter through the
-            # same block tables.
-            ks_l, vs_l = kv_scales
-            pool_k, pool_v, ks_l, vs_l = quant_paged_update_layer(
-                pool_k, pool_v, ks_l, vs_l, k, v, index, page_tables
-            )
-            new_cache = (pool_k, pool_v, ks_l, vs_l)
-        else:
-            ks_l = vs_l = None
-            pool_k, pool_v = paged_update_layer(
-                pool_k, pool_v, k, v, index, page_tables
-            )
-            new_cache = (pool_k, pool_v)
+        with jax.named_scope("kv.write"):
+            if kv_scales is not None:
+                # Int8 pool: quantize at write (K post-rope, the
+                # QuantKVCache contract); scale pools scatter through
+                # the same block tables.
+                ks_l, vs_l = kv_scales
+                pool_k, pool_v, ks_l, vs_l = quant_paged_update_layer(
+                    pool_k, pool_v, ks_l, vs_l, k, v, index, page_tables
+                )
+                new_cache = (pool_k, pool_v, ks_l, vs_l)
+            else:
+                ks_l = vs_l = None
+                pool_k, pool_v = paged_update_layer(
+                    pool_k, pool_v, k, v, index, page_tables
+                )
+                new_cache = (pool_k, pool_v)
         if fresh_cache:
             o = attention(
                 q, k, v, causal=True, window=window, impl=attn_impl, mesh=mesh,
@@ -539,20 +544,22 @@ def _block(
         )
 
         cache_k, cache_v, index, q_positions = cache  # ring buffers
-        if kv_scales is not None:
-            # Int8 ring: quantize at write (K post-rope, the QuantKVCache
-            # contract); reads dequantize the window-sized ring.
-            ks_l, vs_l = kv_scales
-            cache_k, cache_v, ks_l, vs_l = quant_roll_update_layer(
-                cache_k, cache_v, ks_l, vs_l, k, v, index,
-                valid_len=new_len,
-            )
-            new_cache = (cache_k, cache_v, ks_l, vs_l)
-        else:
-            cache_k, cache_v = roll_update_layer(
-                cache_k, cache_v, k, v, index, valid_len=new_len
-            )
-            new_cache = (cache_k, cache_v)
+        with jax.named_scope("kv.write"):
+            if kv_scales is not None:
+                # Int8 ring: quantize at write (K post-rope, the
+                # QuantKVCache contract); reads dequantize the
+                # window-sized ring.
+                ks_l, vs_l = kv_scales
+                cache_k, cache_v, ks_l, vs_l = quant_roll_update_layer(
+                    cache_k, cache_v, ks_l, vs_l, k, v, index,
+                    valid_len=new_len,
+                )
+                new_cache = (cache_k, cache_v, ks_l, vs_l)
+            else:
+                cache_k, cache_v = roll_update_layer(
+                    cache_k, cache_v, k, v, index, valid_len=new_len
+                )
+                new_cache = (cache_k, cache_v)
         if fresh_cache:
             # Whole-prompt prefill attends the incoming chunk itself
             # (exact values — identical to the dense path); the ring
@@ -583,9 +590,10 @@ def _block(
 
         cache_k, cache_v, index, q_positions = cache  # int8 cache layer
         ks_l, vs_l = kv_scales
-        cache_k, cache_v, ks_l, vs_l = quant_update_layer(
-            cache_k, cache_v, ks_l, vs_l, k, v, index
-        )
+        with jax.named_scope("kv.write"):
+            cache_k, cache_v, ks_l, vs_l = quant_update_layer(
+                cache_k, cache_v, ks_l, vs_l, k, v, index
+            )
         new_cache = (cache_k, cache_v, ks_l, vs_l)
         if fresh_cache:
             # Prefill computes on the exact (unquantized) chunk; only
@@ -606,7 +614,8 @@ def _block(
         from shellac_tpu.inference.kvcache import update_layer
 
         cache_k, cache_v, index, q_positions = cache  # index: (B,)
-        cache_k, cache_v = update_layer(cache_k, cache_v, k, v, index)
+        with jax.named_scope("kv.write"):
+            cache_k, cache_v = update_layer(cache_k, cache_v, k, v, index)
         new_cache = (cache_k, cache_v)
         if fresh_cache:
             # Empty-cache prefill: attend within the new chunk only.
@@ -626,14 +635,15 @@ def _block(
                 scale=cfg.attn_scale, softcap=cfg.attn_softcap,
                 sinks=sinks,
             )
-    o = pdot(o.reshape(b, s, h * dh), lp["wo"])
-    if cfg.attn_out_bias:
-        o = o + lp["bo"].astype(cdt)
-    if cfg.post_norms:
-        # Gemma-2 sandwich norm: the branch OUTPUT is normed before the
-        # residual add (HF post_attention_layernorm placement).
-        o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
-    x = x + constrain(o, mesh, ("batch", "seq", None))
+    with jax.named_scope("attn.out"):
+        o = pdot(o.reshape(b, s, h * dh), lp["wo"])
+        if cfg.attn_out_bias:
+            o = o + lp["bo"].astype(cdt)
+        if cfg.post_norms:
+            # Gemma-2 sandwich norm: the branch OUTPUT is normed before
+            # the residual add (HF post_attention_layernorm placement).
+            o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+        x = x + constrain(o, mesh, ("batch", "seq", None))
     return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                       moe_layer, new_cache)
 
@@ -684,11 +694,12 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
                 mesh=mesh, **bias_kw,
             )
         if cfg.moe.num_shared_experts > 0:
-            sg = hx @ materialize(lp["w_gate_shared"], cdt)
-            su = hx @ materialize(lp["w_up_shared"], cdt)
-            down = down + _gated_act(cfg)(sg, su) @ materialize(
-                lp["w_down_shared"], cdt
-            )
+            with jax.named_scope("moe.shared"):
+                sg = hx @ materialize(lp["w_gate_shared"], cdt)
+                su = hx @ materialize(lp["w_up_shared"], cdt)
+                down = down + _gated_act(cfg)(sg, su) @ materialize(
+                    lp["w_down_shared"], cdt
+                )
         moe_out = {
             "aux": aux,
             "balance_loss": metrics["moe_balance_loss"],
@@ -696,14 +707,17 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
             "dropped_frac": metrics["moe_dropped_frac"],
         }
     else:
-        gate = pdot(hx, lp["w_gate"])
-        up = pdot(hx, lp["w_up"])
-        gate = constrain(gate, mesh, ("batch", "seq", "mlp"))
-        up = constrain(up, mesh, ("batch", "seq", "mlp"))
-        down = pdot(_gated_act(cfg)(gate, up), lp["w_down"])
+        with jax.named_scope("mlp"):
+            gate = pdot(hx, lp["w_gate"])
+            up = pdot(hx, lp["w_up"])
+            gate = constrain(gate, mesh, ("batch", "seq", "mlp"))
+            up = constrain(up, mesh, ("batch", "seq", "mlp"))
+            down = pdot(_gated_act(cfg)(gate, up), lp["w_down"])
     if cfg.post_norms:
         down = rms_norm(down, lp["post_mlp_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
-    x = x + constrain(down, mesh, ("batch", "seq", None))
+    # The residual add belongs to whichever FFN ran.
+    with jax.named_scope("moe.combine" if use_moe else "mlp"):
+        x = x + constrain(down, mesh, ("batch", "seq", None))
     return x, new_cache, moe_out
 
 
@@ -806,23 +820,24 @@ def _mla_attention(
     h = cfg.n_heads
     scale = m.qk_head_dim ** -0.5
 
-    if m.q_lora_rank is None:
-        q = pdot(hx, lp["wq"])
-    else:
-        qa = rms_norm(
-            pdot(hx, lp["wq_a"]), lp["q_a_norm"], cfg.norm_eps, mesh=mesh
+    with jax.named_scope("attn.qkv"):
+        if m.q_lora_rank is None:
+            q = pdot(hx, lp["wq"])
+        else:
+            qa = rms_norm(
+                pdot(hx, lp["wq_a"]), lp["q_a_norm"], cfg.norm_eps,
+                mesh=mesh,
+            ).astype(cdt)
+            q = pdot(qa, lp["wq_b"])
+        q = q.reshape(b, s, h, m.qk_head_dim)
+        q = constrain(q, mesh, ("batch", "seq", "heads", None))
+        q_nope = q[..., : m.qk_nope_head_dim]
+        ckv = pdot(hx, lp["wkv_a"])  # (b, s, kv_rank + rope)
+        c = rms_norm(
+            ckv[..., : m.kv_lora_rank], lp["kv_a_norm"], cfg.norm_eps,
+            mesh=mesh,
         ).astype(cdt)
-        q = pdot(qa, lp["wq_b"])
-    q = q.reshape(b, s, h, m.qk_head_dim)
-    q = constrain(q, mesh, ("batch", "seq", "heads", None))
-    q_nope = q[..., : m.qk_nope_head_dim]
     q_pe = apply_rope_interleaved(q[..., m.qk_nope_head_dim:], cos, sin)
-
-    ckv = pdot(hx, lp["wkv_a"])  # (b, s, kv_rank + rope)
-    c = rms_norm(
-        ckv[..., : m.kv_lora_rank], lp["kv_a_norm"], cfg.norm_eps,
-        mesh=mesh,
-    ).astype(cdt)
     k_pe = apply_rope_interleaved(
         ckv[..., None, m.kv_lora_rank:], cos, sin
     )  # (b, s, 1, rope)
@@ -836,15 +851,17 @@ def _mla_attention(
         applies, slice the pad back off. Dispatches through the shared
         sequence-parallel selection (ring/ulysses on sp meshes), where
         the default q-width scale IS the MLA scale."""
-        k_nope = jnp.einsum("bsr,rhn->bshn", c, w_bk)
-        v = jnp.einsum("bsr,rhv->bshv", c, w_bv)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe, (b, s, h, m.qk_rope_head_dim))],
-            axis=-1,
-        )
-        qf = jnp.concatenate([q_nope, q_pe], axis=-1)
-        pad = m.qk_head_dim - m.v_head_dim
-        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
+        with jax.named_scope("attn.qkv"):
+            k_nope = jnp.einsum("bsr,rhn->bshn", c, w_bk)
+            v = jnp.einsum("bsr,rhv->bshv", c, w_bv)
+            k = jnp.concatenate(
+                [k_nope,
+                 jnp.broadcast_to(k_pe, (b, s, h, m.qk_rope_head_dim))],
+                axis=-1,
+            )
+            qf = jnp.concatenate([q_nope, q_pe], axis=-1)
+            pad = m.qk_head_dim - m.v_head_dim
+            vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
         o = _training_attention(cfg, mesh, attn_impl, qf, k, vp, segments)
         return o[..., : m.v_head_dim]
 
@@ -855,11 +872,19 @@ def _mla_attention(
     def absorbed_q():
         """Per-head queries projected into latent space + the roped
         slice: MQA rows against the latent cache."""
-        q_eff = jnp.einsum("bshn,rhn->bshr", q_nope, w_bk)
-        return jnp.concatenate([q_eff, q_pe], axis=-1)
+        with jax.named_scope("mla.absorb"):
+            q_eff = jnp.einsum("bshn,rhn->bshr", q_nope, w_bk)
+            return jnp.concatenate([q_eff, q_pe], axis=-1)
 
-    latent = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)  # (b,s,1,·)
-    v_stub = jnp.zeros((b, s, 1, 0), cdt)
+    def expand_values(o_lat):
+        """The other half of the absorption: values re-expand per head
+        AFTER the weighted sum over the latent rows."""
+        with jax.named_scope("mla.absorb"):
+            return jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
+
+    with jax.named_scope("mla.latent_write"):
+        latent = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)  # (b,s,1,·)
+        v_stub = jnp.zeros((b, s, 1, 0), cdt)
 
     if page_tables is not None:
         from shellac_tpu.inference.kvcache import (
@@ -869,24 +894,26 @@ def _mla_attention(
         from shellac_tpu.ops.decode_attention import paged_decode_attention
 
         pool_k, pool_v, index, _ = cache
-        if kv_scales is not None:
-            # Int8 latent pool: one scale per latent row, serving both
-            # attention roles like the dense int8 latent cache. (The
-            # latent width is not 128-aligned, so reads take the
-            # gather + dequant reference path — correct, with the
-            # paged-fallback warning naming the constraint.)
-            ks_l, vs_l = kv_scales
-            pool_k, pool_v, ks_l, vs_l = quant_paged_update_layer(
-                pool_k, pool_v, ks_l, vs_l, latent, v_stub, index,
-                page_tables,
-            )
-            new_cache = (pool_k, pool_v, ks_l, vs_l)
-        else:
-            ks_l = None
-            pool_k, pool_v = paged_update_layer(
-                pool_k, pool_v, latent, v_stub, index, page_tables
-            )
-            new_cache = (pool_k, pool_v)
+        with jax.named_scope("mla.latent_write"):
+            if kv_scales is not None:
+                # Int8 latent pool: one scale per latent row, serving
+                # both attention roles like the dense int8 latent
+                # cache. (The latent width is not 128-aligned, so reads
+                # take the gather + dequant reference path — correct,
+                # with the paged-fallback warning naming the
+                # constraint.)
+                ks_l, vs_l = kv_scales
+                pool_k, pool_v, ks_l, vs_l = quant_paged_update_layer(
+                    pool_k, pool_v, ks_l, vs_l, latent, v_stub, index,
+                    page_tables,
+                )
+                new_cache = (pool_k, pool_v, ks_l, vs_l)
+            else:
+                ks_l = None
+                pool_k, pool_v = paged_update_layer(
+                    pool_k, pool_v, latent, v_stub, index, page_tables
+                )
+                new_cache = (pool_k, pool_v)
         if fresh_cache:
             o = expanded_attention()
         else:
@@ -897,7 +924,7 @@ def _mla_attention(
                 scale=scale, impl=attn_impl, mesh=mesh,
                 k_scale=ks_l, v_scale=ks_l,
             )[..., : m.kv_lora_rank]
-            o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
+            o = expand_values(o_lat)
         return o.reshape(b, s, h * m.v_head_dim), new_cache
 
     from shellac_tpu.ops.decode_attention import decode_attention
@@ -909,9 +936,10 @@ def _mla_attention(
         from shellac_tpu.inference.kvcache import quant_update_layer
 
         ks_l, vs_l = kv_scales
-        cache_k, cache_v, ks_l, vs_l = quant_update_layer(
-            cache_k, cache_v, ks_l, vs_l, latent, v_stub, index
-        )
+        with jax.named_scope("mla.latent_write"):
+            cache_k, cache_v, ks_l, vs_l = quant_update_layer(
+                cache_k, cache_v, ks_l, vs_l, latent, v_stub, index
+            )
         new_cache = (cache_k, cache_v, ks_l, vs_l)
         if fresh_cache:
             o = expanded_attention()
@@ -920,12 +948,14 @@ def _mla_attention(
                 absorbed_q(), cache_k, cache_k, index, scale=scale,
                 impl=attn_impl, mesh=mesh, k_scale=ks_l, v_scale=ks_l,
             )[..., : m.kv_lora_rank]
-            o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
+            o = expand_values(o_lat)
         return o.reshape(b, s, h * m.v_head_dim), new_cache
 
     from shellac_tpu.inference.kvcache import update_layer
 
-    cache_k, cache_v = update_layer(cache_k, cache_v, latent, v_stub, index)
+    with jax.named_scope("mla.latent_write"):
+        cache_k, cache_v = update_layer(cache_k, cache_v, latent, v_stub,
+                                        index)
     new_cache = (cache_k, cache_v)
     if fresh_cache:
         o = expanded_attention()
@@ -937,7 +967,7 @@ def _mla_attention(
             absorbed_q(), cache_k, cache_k, index, scale=scale,
             impl=attn_impl, mesh=mesh,
         )[..., : m.kv_lora_rank]
-        o = jnp.einsum("bshr,rhv->bshv", o_lat, w_bv)
+        o = expand_values(o_lat)
     return o.reshape(b, s, h * m.v_head_dim), new_cache
 
 
@@ -1330,6 +1360,7 @@ def output_weights(cfg: ModelConfig, params: Params, cdt) -> jax.Array:
     return params["lm_head"].astype(cdt)
 
 
+@jax.named_scope("unembed")
 def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
             mesh=None) -> jax.Array:
     """Final RMSNorm + output projection (+ logit softcap): the model

@@ -84,10 +84,14 @@ from shellac_tpu.obs.spool import (
     spool_path,
 )
 from shellac_tpu.obs.trace import (
+    SPAN_PHASE,
+    STEP_COUNTS,
     STEP_PHASES,
     EngineMetrics,
     RequestTrace,
     ServeMetrics,
+    StepRecord,
+    StepTrace,
     TierMetrics,
 )
 from shellac_tpu.obs.train import (
@@ -118,6 +122,10 @@ __all__ = [
     "ResilienceMetrics",
     "train_interval_histogram",
     "STEP_PHASES",
+    "SPAN_PHASE",
+    "STEP_COUNTS",
+    "StepRecord",
+    "StepTrace",
     "ParsedMetrics",
     "parse_prometheus_text",
     "histogram_quantile",

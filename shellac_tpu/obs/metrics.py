@@ -28,7 +28,11 @@ from __future__ import annotations
 import bisect
 import math
 import threading
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import Deque, Dict, Iterable, List, Optional, Sequence, Tuple
+
+#: Engine-step records a registry keeps (Registry.step_records).
+STEP_RING_CAPACITY = 4096
 
 
 def log_buckets(lo: float = 0.001, hi: float = 60.0,
@@ -301,6 +305,11 @@ class Registry:
         self._lock = threading.Lock()
         self._families: Dict[str, _Family] = {}
         self.enabled = enabled
+        # Finished engine-step records (obs.trace.StepRecord), newest
+        # last. Kept here, not on the engine, so a reader can reach
+        # them after the engine is gone; bounded, so a long-lived
+        # server holds the last few thousand steps.
+        self.step_records: Deque = deque(maxlen=STEP_RING_CAPACITY)
 
     def disable(self) -> None:
         """Turn every write into a no-op (`serve --no-metrics`).

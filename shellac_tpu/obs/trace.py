@@ -9,6 +9,15 @@ the host already observes (queue pop, post-sync token arrival), so
 tracing adds no host-device syncs anywhere, let alone inside jitted
 code (the SH002 contract).
 
+A `StepTrace` is its sibling for ONE ENGINE: it records every engine
+step that did work as a tree of spans (`engine.step` down to the
+`cache.*` calls) plus the step's work counts, on
+`time.perf_counter_ns()`, mirrors each span as a
+`jax.profiler.TraceAnnotation` so a profiler capture shows it beside
+the device operations, and derives `shellac_step_phase_seconds` from
+the closed spans. Finished records land in a bounded ring on the
+registry (`Registry.step_records`), which outlives the engine.
+
 `ServeMetrics` / `EngineMetrics` bundle the instruments each layer
 writes so the metric names and bucket layouts are defined exactly once;
 both are cheap to construct repeatedly over the same registry
@@ -18,7 +27,8 @@ both are cheap to construct repeatedly over the same registry
 from __future__ import annotations
 
 import time
-from typing import Dict, Optional
+import weakref
+from typing import Any, Dict, List, Optional
 
 from shellac_tpu.obs.metrics import (
     Registry,
@@ -55,6 +65,31 @@ OCCUPANCY_BUCKETS = linear_buckets(0.125, 0.125, 8)
 #:                      updates, scheduler glue)
 STEP_PHASES = ("admission", "prefill_dispatch", "prefill_settle",
                "decode_sync", "settle", "host_bookkeeping")
+
+#: Which phase a step span's SELF time (its duration minus its
+#: children's) is credited to. A span not listed here (the `cache.*`
+#: spans) is credited to its parent's phase, so the six sums are the
+#: step's wall time exactly: self times partition the `engine.step`
+#: span.
+SPAN_PHASE = {
+    "engine.step": "host_bookkeeping",
+    "engine.settle_prefills": "prefill_settle",
+    "engine.wait_prefill": "prefill_settle",
+    "engine.dispatch_window": "host_bookkeeping",
+    "engine.wait_window": "decode_sync",
+    "engine.apply_window": "settle",
+    "engine.fill": "admission",
+    "engine.admit": "admission",
+    "engine.prefill_dispatch": "prefill_dispatch",
+}
+
+#: The work counts every step record carries (see docs/observability.md
+#: "Step spans"). The first five are taken where the number is known;
+#: `compiles`/`compile_s` are what the process-wide compile listener
+#: added to the registry since the previous record.
+STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
+               "decode_valid_ticks", "prefill_tokens",
+               "prefill_padded_tokens", "compiles", "compile_s")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:
@@ -512,6 +547,235 @@ class TierMetrics:
         )
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: Registries whose compile counters the one process-wide
+#: jax.monitoring listener feeds (a listener cannot be taken back, so
+#: it is registered once and holds the registries weakly).
+_compile_sinks: "weakref.WeakSet" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _on_compile(event: str, duration: float, **_: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    for reg in list(_compile_sinks):
+        reg.get("shellac_compile_events_total").inc()
+        reg.get("shellac_compile_seconds_total").inc(duration)
+
+
+def _listen_for_compiles(registry: Registry) -> None:
+    global _compile_listening
+    _compile_sinks.add(registry)
+    if not _compile_listening:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listening = True
+
+
+class _NullSpan:
+    """What `StepTrace.span` hands out while the registry is disabled."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def get(self, key):
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One open span: a row `[name, start_ns, end_ns, parent, attrs]`
+    in the recorder's span list (parent is an index into that list, -1
+    for none) and the profiler annotation entered with it."""
+
+    __slots__ = ("_tr", "_row", "_ann")
+
+    def __init__(self, tr: "StepTrace", name: str, attrs: Dict[str, Any]):
+        self._tr = tr
+        self._row = [name, 0, 0, -1, attrs]
+        # Profiler metadata takes numbers and short strings; anything
+        # else (a tuple rid) is recorded in the ring only.
+        self._ann = tr._annotation(name, **{
+            k: v for k, v in attrs.items()
+            if isinstance(v, (int, float, str))
+        })
+
+    def __enter__(self):
+        tr, row = self._tr, self._row
+        if tr._stack:
+            row[3] = tr._stack[-1]
+        tr._stack.append(len(tr._spans))
+        tr._spans.append(row)
+        self._ann.__enter__()
+        row[1] = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self._row[2] = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._tr._stack.pop()
+        return False
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (tokens applied,
+        pages reserved); they reach the ring, not the profiler."""
+        self._row[4].update(attrs)
+
+    def get(self, key):
+        return self._row[4].get(key)
+
+
+class StepRecord:
+    """One engine step that did work: its spans (rows `(name, start_ns,
+    end_ns, parent, attrs)` on `time.perf_counter_ns()`, parents before
+    children) and its work counts (`STEP_COUNTS`). `root` indexes the
+    `engine.step` span; spans before it, and any with parent -1, were
+    recorded outside the step's tree (`engine.submit`, a cancel's
+    `cache.release_slot`)."""
+
+    __slots__ = ("step", "root", "spans", "counts")
+
+    def __init__(self, step: int, root: int, spans: List[list],
+                 counts: Dict[str, float]):
+        self.step = step
+        self.root = root
+        self.spans = spans
+        self.counts = counts
+
+    @property
+    def start_ns(self) -> int:
+        return self.spans[self.root][1]
+
+    @property
+    def end_ns(self) -> int:
+        return self.spans[self.root][2]
+
+    def phases(self) -> Dict[str, float]:
+        """Seconds per STEP_PHASES entry: each span under `engine.step`
+        gives its self time to its phase (SPAN_PHASE; a `cache.*` span
+        to its parent's). The values sum to the step's wall time."""
+        spans = self.spans
+        self_ns = [sp[2] - sp[1] for sp in spans]
+        for sp in spans:
+            if sp[3] >= 0:
+                self_ns[sp[3]] -= sp[2] - sp[1]
+        phase_of: Dict[int, str] = {}
+        out = dict.fromkeys(STEP_PHASES, 0.0)
+        for i in range(self.root, len(spans)):
+            name, parent = spans[i][0], spans[i][3]
+            if i != self.root and parent not in phase_of:
+                continue  # outside the step's tree
+            ph = SPAN_PHASE.get(name) or phase_of[parent]
+            phase_of[i] = ph
+            out[ph] += self_ns[i] * 1e-9
+        return out
+
+    def blocked_s(self) -> float:
+        """Seconds the step spent blocked on a decode window's pull."""
+        return sum(sp[2] - sp[1] for sp in self.spans[self.root:]
+                   if sp[0] == "engine.wait_window") * 1e-9
+
+
+class StepTrace:
+    """Span and count recorder for one engine's steps.
+
+    `span(name, **attrs)` is a context manager; spans nest by the order
+    they are entered (one engine, one thread). `begin_step` opens the
+    `engine.step` span; `end_step(did_work)` closes it and either
+    commits a StepRecord to the registry's ring, observing the step's
+    phases and counts into the histograms and counters, or, for a step
+    that did nothing, drops its spans. Spans closed outside a step
+    wait for the next record. With the registry disabled every method
+    returns after one attribute check and nothing is recorded."""
+
+    def __init__(self, metrics: "EngineMetrics"):
+        from jax.profiler import TraceAnnotation
+
+        self._m = metrics
+        self._reg = metrics.registry
+        self._annotation = TraceAnnotation
+        self._spans: List[list] = []
+        self._stack: List[int] = []
+        self._counts = dict.fromkeys(STEP_COUNTS, 0)
+        self._root: Optional[tuple] = None  # (open engine.step, index)
+        self._n_steps = 0
+        self._compiles_seen = (metrics.compiles.value,
+                               metrics.compile_seconds.value)
+
+    # ---- spans -------------------------------------------------------
+
+    def span(self, name: str, **attrs):
+        if not self._reg.enabled:
+            return _NULL_SPAN
+        return _Span(self, name, attrs)
+
+    def annotate(self, **attrs) -> None:
+        """Set attributes on the innermost open span."""
+        if self._reg.enabled and self._stack:
+            self._spans[self._stack[-1]][4].update(attrs)
+
+    def count(self, **amounts) -> None:
+        if not self._reg.enabled:
+            return
+        for k, v in amounts.items():
+            self._counts[k] += v
+
+    # ---- step boundaries ---------------------------------------------
+
+    def begin_step(self, **attrs) -> None:
+        self._n_steps += 1
+        if not self._reg.enabled:
+            return
+        root = _Span(self, "engine.step", dict(attrs, step=self._n_steps))
+        self._root = (root, len(self._spans))
+        root.__enter__()
+
+    def end_step(self, did_work: bool) -> None:
+        if self._root is None:
+            return
+        (root, i), self._root = self._root, None
+        root.__exit__(None, None, None)
+        if not did_work or not self._reg.enabled:
+            del self._spans[i:]
+            return
+        m = self._m
+        seen = (m.compiles.value, m.compile_seconds.value)
+        counts, self._counts = self._counts, dict.fromkeys(STEP_COUNTS, 0)
+        counts["compiles"] = int(seen[0] - self._compiles_seen[0])
+        counts["compile_s"] = seen[1] - self._compiles_seen[1]
+        self._compiles_seen = seen
+        spans, self._spans = self._spans, []
+        rec = StepRecord(self._n_steps, i, spans, counts)
+        self._reg.step_records.append(rec)
+        for phase, v in rec.phases().items():
+            m.step_phase.labels(phase=phase).observe(v)
+        names = {sp[0] for sp in spans[i:]}
+        if "engine.wait_window" in names:
+            # A step that synced a window: what the device cannot see
+            # and overlap exists to hide.
+            wall = (rec.end_ns - rec.start_ns) * 1e-9
+            m.host_overhead.observe(max(0.0, wall - rec.blocked_s()))
+        if "engine.prefill_dispatch" in names:
+            # A step that ran a prefill or chunk program: its whole
+            # fill section, inline syncs included.
+            fill = next(sp for sp in spans[i:] if sp[0] == "engine.fill")
+            m.prefill_seconds.observe((fill[2] - fill[1]) * 1e-9)
+        for k, c in m.step_counters.items():
+            if counts[k]:
+                c.inc(counts[k])
+
+
 class EngineMetrics:
     """The engine-layer instruments: batch occupancy, prefill vs decode
     section durations, and cache-utilization gauges. All writes happen
@@ -579,3 +843,46 @@ class EngineMetrics:
             "Blocks currently registered in the prefix cache (paged "
             "engines with prefix_cache=True)",
         )
+        # The step records' work counts as running totals (StepTrace
+        # adds each committed record's counts), keyed as STEP_COUNTS.
+        c = registry.counter
+        self.step_counters = {
+            "tokens_delivered": c(
+                "shellac_engine_tokens_delivered_total",
+                "Output tokens appended to requests' outputs, credited "
+                "in the engine step that handed them out (first tokens "
+                "at prefill settle, decode tokens at window settle)",
+            ),
+            "decode_slot_ticks": c(
+                "shellac_engine_decode_slot_ticks_total",
+                "Decode rows computed: ticks x n_slots of every decode "
+                "window synced",
+            ),
+            "decode_valid_ticks": c(
+                "shellac_engine_decode_valid_ticks_total",
+                "Decode rows that produced a token: the per-tick "
+                "validity flags of every window synced, summed",
+            ),
+            "prefill_tokens": c(
+                "shellac_engine_prefill_tokens_total",
+                "Prompt tokens of the prefill and chunk programs "
+                "dispatched",
+            ),
+            "prefill_padded_tokens": c(
+                "shellac_engine_prefill_padded_tokens_total",
+                "Bucketed (padded) length of the prefill and chunk "
+                "programs dispatched",
+            ),
+        }
+        self.compiles = c(
+            "shellac_compile_events_total",
+            "Executables built by this process (XLA backend compiles, "
+            "persistent-cache loads included): a serve that keeps "
+            "counting after warm-up is recompiling under traffic",
+        )
+        self.compile_seconds = c(
+            "shellac_compile_seconds_total",
+            "Seconds spent building those executables",
+        )
+        _listen_for_compiles(registry)
+        self.steps = StepTrace(self)

@@ -7,13 +7,18 @@ parses the profiler's Chrome-trace event stream (the
 `*.trace.json.gz` every capture contains, host and TPU alike) into:
 
   op-level time attribution — every complete ('X') event on a device
-    process (a `process_name` containing "/device:", or — the CPU
-    backend's shape — any event whose args carry an `hlo_op`/
-    `hlo_module`) aggregated per op name: count, total time, share.
+    process's "XLA Ops" line (a `process_name` containing "/device:";
+    its other lines repeat the same time as whole programs and
+    asynchronous copies), or — the CPU backend's shape — any event
+    whose args carry an `hlo_op`/`hlo_module`, aggregated per op name:
+    count, SELF time (the op line nests: a `while` holds its body's
+    operations), share.
 
   phase alignment — each device op is classified against the
     `shellac_step_phase_seconds` phases by the HLO module / op name
-    it belongs to (the engine's jitted programs have recognizable
+    it belongs to (its `hlo_module` argument, or on a TPU capture the
+    program on the "XLA Modules" line that was running when it
+    started; the engine's jitted programs have recognizable
     names: prefill/chunk programs -> `prefill_dispatch`, decode
     window/beam programs -> `decode_sync`). `admission`,
     `prefill_settle`, `settle`, and `host_bookkeeping` are host-side
@@ -31,6 +36,17 @@ parses the profiler's Chrome-trace event stream (the
     less fused time — the regression class "Operator Fusion in XLA"
     (PAPERS.md) describes.
 
+  by scope — device SELF time per named scope of the model
+    (`jax.named_scope` in models/transformer.py and ops/: `attn.qkv`,
+    `kv.gather`, `moe.gemm`, ... — DEVICE_SCOPES below), the rest
+    under `unscoped`. An operation's scope is the innermost catalogued
+    name in its `op_name` path; a fusion is charged to the scope of
+    its root instruction. Self time, because the device's op line
+    nests (a `while` holds the layer scan's operations). The
+    `op_name` rides in the operation's arguments in the
+    `.trace.json.gz` (under `tf_op` on a TPU capture); a capture whose
+    operations carry none gets a note instead of a guess.
+
 `diff(before, after)` compares two reports and FLAGS regressions —
 per-op slowdowns past a threshold, expensive new ops, total device
 time growth, fusion breakup — so two committed captures answer "did
@@ -44,6 +60,7 @@ box, not just an accelerator host.
 
 from __future__ import annotations
 
+import bisect
 import gzip
 import json
 import os
@@ -65,6 +82,22 @@ PHASE_RULES: Tuple[Tuple[str, str], ...] = (
     (r"decode|beam", "decode_sync"),
 )
 _PHASE_RES = tuple((re.compile(p, re.I), phase) for p, phase in PHASE_RULES)
+
+#: The named scopes the model wraps its device operations in. Kept
+#: here as data so that reading a capture imports no model code;
+#: tests/test_step_trace.py checks the list against what the compiled
+#: programs carry.
+DEVICE_SCOPES = (
+    "embed", "norm", "attn.qkv", "attn.rope", "kv.write", "kv.gather",
+    "attn.core", "attn.out", "mla.absorb", "mla.latent_write", "mlp",
+    "moe.route", "moe.sort", "moe.gemm", "moe.combine", "moe.shared",
+    "unembed", "sample",
+)
+_SCOPE_RE = re.compile(
+    r"(?:^|/)(" + "|".join(
+        re.escape(n) for n in sorted(DEVICE_SCOPES, key=len, reverse=True)
+    ) + r")(?=/|$)"
+)
 
 #: XLA fusion op names: `fusion`, `fusion.123`, `%fusion.4`, plus the
 #:  kind-tagged `loop_fusion`/`input_fusion` variants.
@@ -130,6 +163,82 @@ def load_trace(path: str) -> Dict[str, Any]:
     return data
 
 
+# ---- by scope --------------------------------------------------------
+
+
+def scope_of(args: Dict[str, Any]) -> Optional[str]:
+    """The innermost catalogued scope in any string argument of one
+    op event (its `op_name` path, under whichever key the backend
+    writes it), or None."""
+    for v in args.values():
+        if isinstance(v, str) and "/" in v:
+            found = _SCOPE_RE.findall(v)
+            if found:
+                return found[-1]
+    return None
+
+
+def _self_us(events: List[Dict[str, Any]]) -> List[float]:
+    """Self time of each event (same order as given): its duration
+    minus what the events nested inside it on its own thread cover."""
+    out = [float(e.get("dur") or 0.0) for e in events]
+    by_thread: Dict[Any, List[int]] = {}
+    for i, e in enumerate(events):
+        by_thread.setdefault((e.get("pid"), e.get("tid")), []).append(i)
+    for idx in by_thread.values():
+        idx.sort(key=lambda i: (float(events[i].get("ts") or 0.0), -out[i]))
+        stack: List[Tuple[int, float]] = []  # (event, end)
+        for i in idx:
+            ts = float(events[i].get("ts") or 0.0)
+            dur = float(events[i].get("dur") or 0.0)
+            while stack and stack[-1][1] <= ts:
+                stack.pop()
+            if stack:
+                out[stack[-1][0]] -= min(dur, stack[-1][1] - ts)
+            stack.append((i, ts + dur))
+    return out
+
+
+def by_scope(op_events: List[Dict[str, Any]],
+             selfs: List[float]) -> Dict[str, Any]:
+    """Device self time per named scope, from the operations'
+    `op_name` in their trace-event arguments."""
+    rows: Dict[str, Dict[str, float]] = {}
+    loose: Dict[str, float] = {}
+    total = 0.0
+    for e, us in zip(op_events, selfs):
+        args = e.get("args") if isinstance(e.get("args"), dict) else {}
+        scope = scope_of(args)
+        if scope is None:
+            op = str(e["name"]).lstrip("%")
+            loose[op] = loose.get(op, 0.0) + us
+        row = rows.setdefault(scope or "unscoped",
+                              {"self_us": 0.0, "ops": 0})
+        row["self_us"] += us
+        row["ops"] += 1
+        total += us
+    for row in rows.values():
+        row["share"] = round(row["self_us"] / total, 4) if total else 0.0
+        row["self_us"] = round(row["self_us"], 3)
+    unscoped = rows.pop("unscoped", {"self_us": 0.0, "ops": 0, "share": 0.0})
+    # What XLA put around the model's own operations, largest first.
+    unscoped["top"] = [
+        [op, round(us, 3)] for op, us in
+        sorted(loose.items(), key=lambda kv: -kv[1])[:8]
+    ]
+    table = {
+        "device_self_us": round(total, 3),
+        "scopes": dict(sorted(rows.items(),
+                              key=lambda kv: -kv[1]["self_us"])),
+        "unscoped": unscoped,
+    }
+    if not rows:
+        table["note"] = ("no operation in this capture carries a scoped "
+                         "op_name (a program built before the scopes, or a "
+                         "backend that writes none)")
+    return table
+
+
 # ---- analysis --------------------------------------------------------
 
 
@@ -142,11 +251,28 @@ def _process_names(events: Iterable[Dict[str, Any]]) -> Dict[Any, str]:
     return out
 
 
-def _is_op_event(e: Dict[str, Any], device_pids) -> bool:
+def _thread_names(events: Iterable[Dict[str, Any]]) -> Dict[Any, str]:
+    out: Dict[Any, str] = {}
+    for e in events:
+        if (e.get("ph") == "M" and e.get("name") == "thread_name"
+                and isinstance(e.get("args"), dict)):
+            out[(e.get("pid"), e.get("tid"))] = str(
+                e["args"].get("name", ""))
+    return out
+
+
+#: The device process's threads: a TPU capture has one line of
+#: operations beside lines that repeat the same time (whole programs,
+#: asynchronous copies, overlays). Only the first is summed.
+_OPS_THREAD, _MODULES_THREAD = "XLA Ops", "XLA Modules"
+
+
+def _is_op_event(e: Dict[str, Any], device_pids, threads=None) -> bool:
     if e.get("ph") != "X" or not e.get("name"):
         return False
     if e.get("pid") in device_pids:
-        return True
+        name = (threads or {}).get((e.get("pid"), e.get("tid")))
+        return name is None or name == _OPS_THREAD
     args = e.get("args")
     # CPU-backend captures put the op stream on the host process but
     # tag each op event with its HLO identity.
@@ -163,6 +289,32 @@ def analyze(path: str, *, top: int = 20) -> Dict[str, Any]:
     procs = _process_names(events)
     device_pids = {pid for pid, name in procs.items()
                    if "/device:" in name}
+    threads = _thread_names(events)
+    # A TPU capture names the program on its own line, not on each
+    # operation: an operation belongs to the program running at its
+    # start.
+    programs: Dict[Any, List[Tuple[float, float, str]]] = {}
+    for e in events:
+        if (e.get("ph") == "X" and e.get("pid") in device_pids
+                and threads.get((e.get("pid"), e.get("tid")))
+                == _MODULES_THREAD):
+            ts = float(e.get("ts") or 0.0)
+            programs.setdefault(e.get("pid"), []).append(
+                (ts, ts + float(e.get("dur") or 0.0), str(e["name"])))
+    starts = {pid: [p[0] for p in sorted(rows)]
+              for pid, rows in programs.items()}
+    for rows in programs.values():
+        rows.sort()
+
+    def program_at(pid, ts) -> Optional[str]:
+        rows = programs.get(pid)
+        if not rows:
+            return None
+        i = bisect.bisect_right(starts[pid], ts) - 1
+        if i >= 0 and ts < rows[i][1]:
+            return re.sub(r"\(\d+\)$", "", rows[i][2])
+        return None
+
     ops: Dict[str, Dict[str, Any]] = {}
     modules: Dict[str, float] = {}
     # Phase attribution over the device ops (host-only phases report
@@ -180,14 +332,17 @@ def analyze(path: str, *, top: int = 20) -> Dict[str, Any]:
     fus_us = 0.0
     total_us = 0.0
     n_events = 0
-    for e in events:
-        if not _is_op_event(e, device_pids):
-            continue
-        dur = float(e.get("dur") or 0.0)
+    op_events = [e for e in events
+                 if _is_op_event(e, device_pids, threads)]
+    # Self time throughout: the device's op line nests (a `while`
+    # holds its body's operations), and a sum of durations would count
+    # the body twice.
+    selfs = _self_us(op_events)
+    for e, dur in zip(op_events, selfs):
         args = e.get("args") if isinstance(e.get("args"), dict) else {}
         raw = str(e["name"])
         module = str(args["hlo_module"]) if args.get("hlo_module") \
-            else None
+            else program_at(e.get("pid"), float(e.get("ts") or 0.0))
         op = _norm_op(str(args.get("hlo_op") or raw))
         n_events += 1
         total_us += dur
@@ -248,6 +403,7 @@ def analyze(path: str, *, top: int = 20) -> Dict[str, Any]:
         },
         "phases": phases,
         "unattributed": unattributed,
+        "by_scope": by_scope(op_events, selfs),
     }
 
 
@@ -368,6 +524,24 @@ def render_report(report: Dict[str, Any]) -> str:
         f"  {100 * (un.get('share') or 0):5.1f}%"
         f"  ({un.get('ops', 0)} ops)"
     )
+    bs = report.get("by_scope") or {}
+    out.append("")
+    out.append(
+        "by scope (device self time, "
+        f"{bs.get('device_self_us', 0) / 1e3:.3f} ms)"
+    )
+    if bs.get("note"):
+        out.append(f"  {bs['note']}")
+    rows = list((bs.get("scopes") or {}).items())
+    rows.append(("(unscoped)", bs.get("unscoped") or {}))
+    for name, r in rows:
+        out.append(
+            f"  {name:<18} {r.get('self_us', 0) / 1e3:10.3f} ms"
+            f"  {100 * (r.get('share') or 0):5.1f}%"
+            f"  ({r.get('ops', 0)} ops)"
+        )
+    for op, us in (bs.get("unscoped") or {}).get("top") or []:
+        out.append(f"      unscoped: {op[:40]:<40} {us / 1e3:10.3f} ms")
     out.append("")
     out.append(f"{'top ops':<28}{'count':>7}{'total ms':>11}"
                f"{'share':>8}  phase")

@@ -126,6 +126,7 @@ def attention_ref(
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
+@jax.named_scope("attn.core")
 def attention(
     q: jax.Array,
     k: jax.Array,

@@ -484,6 +484,7 @@ def decode_supported(
     return _pick_block_k(max_len, hkv, block_k or DEFAULT_BLOCK_K) != 0
 
 
+@jax.named_scope("attn.core")
 def decode_attention(
     q, cache_k, cache_v, index, *,
     window: Optional[int] = None,
@@ -1019,20 +1020,21 @@ def paged_decode_attention(
                 interpret, softcap=sc, sinks=sinks,
             )
 
-        if not on_mesh(mesh):
-            return kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale,
-                          sinks)
-        kv = _kv_axis(pool_k)
-        out = per_shard(kernel, mesh, {
-            "q": (q, _Q_AXES),
-            "pool_k": (pool_k, (None, kv, None, None)),
-            "pool_v": (pool_v, (None, kv, None, None)),
-            "tables": (tables, ("batch", None)),
-            "index": (index, ("batch",)),
-            "k_scale": (k_scale, (None, kv, None)),
-            "v_scale": (v_scale, (None, kv, None)),
-            "sinks": (sinks, ("heads",)),
-        }, _Q_AXES)
+        with jax.named_scope("attn.core"):
+            if not on_mesh(mesh):
+                return kernel(q, pool_k, pool_v, tables, index, k_scale,
+                              v_scale, sinks)
+            kv = _kv_axis(pool_k)
+            out = per_shard(kernel, mesh, {
+                "q": (q, _Q_AXES),
+                "pool_k": (pool_k, (None, kv, None, None)),
+                "pool_v": (pool_v, (None, kv, None, None)),
+                "tables": (tables, ("batch", None)),
+                "index": (index, ("batch",)),
+                "k_scale": (k_scale, (None, kv, None)),
+                "v_scale": (v_scale, (None, kv, None)),
+                "sinks": (sinks, ("heads",)),
+            }, _Q_AXES)
         if out is not None:
             return out
         _mesh_fallback(impl, quant, PagedFallbackWarning, q, pool_k, mesh)
@@ -1041,15 +1043,21 @@ def paged_decode_attention(
         paged_gather_scales,
     )
 
-    k_all, v_all = paged_gather_layer(pool_k, pool_v, tables)
-    ks_all = vs_all = None
-    if quant:
-        ks_all = paged_gather_scales(k_scale, tables)
-        vs_all = paged_gather_scales(v_scale, tables)
-    return _decode_ref(q, k_all, v_all, index, window, scale, softcap=softcap,
-                       sinks=sinks, k_scale=ks_all, v_scale=vs_all)
+    # The XLA path: materialize each slot's dense view through its
+    # table, then the masked reference attention over it.
+    with jax.named_scope("kv.gather"):
+        k_all, v_all = paged_gather_layer(pool_k, pool_v, tables)
+        ks_all = vs_all = None
+        if quant:
+            ks_all = paged_gather_scales(k_scale, tables)
+            vs_all = paged_gather_scales(v_scale, tables)
+    with jax.named_scope("attn.core"):
+        return _decode_ref(q, k_all, v_all, index, window, scale,
+                           softcap=softcap, sinks=sinks, k_scale=ks_all,
+                           v_scale=vs_all)
 
 
+@jax.named_scope("attn.core")
 def rolled_decode_attention(
     q, cache_k, cache_v, start, lengths_after, *,
     window: int,

@@ -222,48 +222,58 @@ def moe_ffn(
     _check_expert_shards(e, mesh)
 
     x2 = x.reshape(t, d)
-    slot, weight, aux, metrics = route(
-        x2, w_router, cfg, capacity=c, b_router=b_router
-    )
+    with jax.named_scope("moe.route"):
+        slot, weight, aux, metrics = route(
+            x2, w_router, cfg, capacity=c, b_router=b_router
+        )
     k = slot.shape[1]
 
-    # Scatter tokens into capacity buckets; one extra slot absorbs drops.
-    buckets = jnp.zeros((e * c + 1, d), cdt)
-    flat_slot = slot.reshape(-1)  # (T*k,)
-    x_rep = jnp.repeat(x2, k, axis=0)  # (T*k, D) — token for each assignment
-    buckets = buckets.at[flat_slot].add(x_rep, mode="drop")
-    # Dispatch boundary: constrain the buckets to expert sharding. The
-    # scatter's input is token-sharded (batch over dp/fsdp, seq over
-    # sp); forcing its output onto the ep axis here is what makes XLA
-    # emit the token all-to-all instead of replicating the buckets.
-    dispatched = constrain(
-        buckets[: e * c].reshape(e, c, d), mesh, ("experts", None, None)
-    )
+    with jax.named_scope("moe.sort"):
+        # Scatter tokens into capacity buckets; one extra slot absorbs
+        # drops.
+        buckets = jnp.zeros((e * c + 1, d), cdt)
+        flat_slot = slot.reshape(-1)  # (T*k,)
+        x_rep = jnp.repeat(x2, k, axis=0)  # (T*k, D) — one per assignment
+        buckets = buckets.at[flat_slot].add(x_rep, mode="drop")
+        # Dispatch boundary: constrain the buckets to expert sharding.
+        # The scatter's input is token-sharded (batch over dp/fsdp, seq
+        # over sp); forcing its output onto the ep axis here is what
+        # makes XLA emit the token all-to-all instead of replicating
+        # the buckets.
+        dispatched = constrain(
+            buckets[: e * c].reshape(e, c, d), mesh,
+            ("experts", None, None)
+        )
 
-    # Expert FFNs: batched over the expert axis (sharded over 'fsdp').
-    gate = jnp.einsum("ecd,edf->ecf", dispatched, materialize(w_gate, cdt),
-                      preferred_element_type=jnp.float32).astype(cdt)
-    up = jnp.einsum("ecd,edf->ecf", dispatched, materialize(w_up, cdt),
-                    preferred_element_type=jnp.float32).astype(cdt)
-    if b_gate is not None:
-        gate = gate + b_gate.astype(cdt)[:, None, :]
-    if b_up is not None:
-        up = up + b_up.astype(cdt)[:, None, :]
-    act = _expert_act(gate, up, cfg)
-    act = constrain(act, mesh, ("experts", None, "mlp"))
-    out_e = jnp.einsum("ecf,efd->ecd", act, materialize(w_down, cdt),
-                       preferred_element_type=jnp.float32).astype(cdt)
-    out_e = constrain(out_e, mesh, ("experts", None, None))
-    if b_down is not None:
-        # The per-expert output bias applies to every ROUTED token's
-        # expert output (dropped tokens still get zeros downstream).
-        out_e = out_e + b_down.astype(cdt)[:, None, :]
+    with jax.named_scope("moe.gemm"):
+        # Expert FFNs: batched over the expert axis (sharded over
+        # 'fsdp').
+        gate = jnp.einsum("ecd,edf->ecf", dispatched,
+                          materialize(w_gate, cdt),
+                          preferred_element_type=jnp.float32).astype(cdt)
+        up = jnp.einsum("ecd,edf->ecf", dispatched, materialize(w_up, cdt),
+                        preferred_element_type=jnp.float32).astype(cdt)
+        if b_gate is not None:
+            gate = gate + b_gate.astype(cdt)[:, None, :]
+        if b_up is not None:
+            up = up + b_up.astype(cdt)[:, None, :]
+        act = _expert_act(gate, up, cfg)
+        act = constrain(act, mesh, ("experts", None, "mlp"))
+        out_e = jnp.einsum("ecf,efd->ecd", act, materialize(w_down, cdt),
+                           preferred_element_type=jnp.float32).astype(cdt)
+        out_e = constrain(out_e, mesh, ("experts", None, None))
+        if b_down is not None:
+            # The per-expert output bias applies to every ROUTED token's
+            # expert output (dropped tokens still get zeros downstream).
+            out_e = out_e + b_down.astype(cdt)[:, None, :]
 
-    # Gather back and combine with router weights (dropped -> zeros row).
-    out_flat = jnp.concatenate([out_e.reshape(e * c, d),
-                                jnp.zeros((1, d), cdt)], axis=0)
-    gathered = jnp.take(out_flat, flat_slot, axis=0).reshape(t, k, d)
-    combined = jnp.sum(gathered * weight[..., None].astype(cdt), axis=1)
+    with jax.named_scope("moe.combine"):
+        # Gather back and combine with router weights (dropped -> zeros
+        # row).
+        out_flat = jnp.concatenate([out_e.reshape(e * c, d),
+                                    jnp.zeros((1, d), cdt)], axis=0)
+        gathered = jnp.take(out_flat, flat_slot, axis=0).reshape(t, k, d)
+        combined = jnp.sum(gathered * weight[..., None].astype(cdt), axis=1)
     return combined.reshape(b, s, d), aux, metrics
 
 

@@ -102,6 +102,7 @@ def _rms_bwd(eps, interpret, res, g):
 rms_norm_pallas.defvjp(_rms_fwd, _rms_bwd)
 
 
+@jax.named_scope("norm")
 def rms_norm(x, scale, eps: float = 1e-5, impl: str = "auto", mesh=None):
     """Dispatching RMSNorm. impl: "auto" | "pallas" | "ref".
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("attn.rope")
 def rope_angles(positions: jax.Array, head_dim: int, theta: float = 10000.0,
                 yarn=None, llama3=None, linear=None):
     """cos/sin tables for given absolute positions.
@@ -113,6 +114,7 @@ def _yarn_inv_freq(dim: int, base: float, yarn):
     return jnp.asarray(inv_freq, jnp.float32), float(attention_factor)
 
 
+@jax.named_scope("attn.rope")
 def apply_rope_interleaved(
     x: jax.Array, cos: jax.Array, sin: jax.Array
 ) -> jax.Array:
@@ -135,6 +137,7 @@ def apply_rope_interleaved(
     return out.astype(x.dtype)
 
 
+@jax.named_scope("attn.rope")
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """Rotate the head dimension of x.
 

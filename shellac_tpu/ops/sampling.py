@@ -60,6 +60,7 @@ def repetition_penalty(
     return jnp.where(seen, penalized, logits)
 
 
+@jax.named_scope("sample")
 def sample(
     key: jax.Array,
     logits: jax.Array,  # (..., V)
@@ -128,6 +129,7 @@ def filter_logits_batched(
     return x
 
 
+@jax.named_scope("sample")
 def sample_batched(
     key: jax.Array,
     logits: jax.Array,  # (B, V)

@@ -1,32 +1,13 @@
-"""Profiling / tracing helpers around jax.profiler.
+"""Wall-clock step timing for the training loops.
 
-Traces are viewable in TensorBoard or Perfetto; `annotate` scopes show
-up on the TPU timeline so step phases (data, step, checkpoint) are
-attributable.
+The profiler's host spans are written by one place, the engine's step
+recorder (shellac_tpu/obs/trace.py: StepTrace).
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
-from typing import Iterator, Optional
-
-import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture a profiler trace (TPU timeline + host) into log_dir."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Context manager labelling a region on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
+from typing import Optional
 
 
 class StepTimer:

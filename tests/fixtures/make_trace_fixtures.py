@@ -14,10 +14,17 @@ python-function events):
       slower, and a new convert op — the three regression classes
       `trace-report --diff` exists to flag.
 
-Run `python tests/fixtures/make_trace_fixtures.py` to rewrite both
-files byte-identically (gzip mtime pinned to 0); the test suite
-asserts the diff flags the regressed capture and passes the base
-against itself.
+  decode_scoped.trace.json.gz     one decode window whose operations
+      carry scoped `op_name`s, nested inside a `while` as the device's
+      op line nests them: what `trace-report`'s by-scope section reads
+      (self time per named scope; the `while` shell's own time and a
+      module-less copy stay unscoped).
+
+Run `python tests/fixtures/make_trace_fixtures.py` to rewrite the
+files (gzip mtime pinned to 0; the test suite compares the
+decompressed payloads, since the gzip header's OS byte differs between
+Python builds); the suite asserts the diff flags the regressed capture
+and passes the base against itself.
 """
 
 import gzip
@@ -96,11 +103,39 @@ REGRESSED_OPS = [
 ]
 
 
+_SCAN = "jit(_decode_impl)/jit(main)/while/body/closed_call/"
+
+# (name, ts, dur, op_name): a while shell of 1000 us holding three
+# scoped fusions (900 us: its own 100 us stay unscoped), then a copy
+# with no op_name, then a norm inside attn.qkv (the innermost wins).
+SCOPED_OPS = [
+    ("%while.1", 1000.0, 1000.0, "jit(_decode_impl)/jit(main)/while"),
+    ("%fusion.10", 1000.0, 300.0, _SCAN + "attn.qkv/dot_general"),
+    ("%fusion.11", 1300.0, 200.0, _SCAN + "kv.gather/gather"),
+    ("%fusion.12", 1500.0, 400.0, _SCAN + "attn.core/reduce_max"),
+    ("%copy.1", 2100.0, 150.0, None),
+    ("%fusion.13", 2300.0, 50.0, _SCAN + "attn.qkv/norm/mul"),
+]
+
+
+def _scoped_ops():
+    events = []
+    for name, ts, dur, op_name in SCOPED_OPS:
+        ev = {"ph": "X", "pid": _DEVICE_PID, "tid": 1, "ts": ts,
+              "dur": dur, "name": name}
+        if op_name:
+            ev["args"] = {"hlo_module": "jit__decode_impl",
+                          "op_name": op_name}
+        events.append(ev)
+    return events
+
+
 def _write(name, rows):
     doc = {
         "displayTimeUnit": "ns",
         "metadata": {"highres-ticks": True},
-        "traceEvents": _meta() + _host_events() + _ops(rows),
+        "traceEvents": _meta() + _host_events()
+        + (rows if isinstance(rows[0], dict) else _ops(rows)),
     }
     data = json.dumps(doc, sort_keys=True).encode()
     path = os.path.join(HERE, name)
@@ -113,6 +148,7 @@ def _write(name, rows):
 def main():
     _write("decode_base.trace.json.gz", BASE_OPS)
     _write("decode_regressed.trace.json.gz", REGRESSED_OPS)
+    _write("decode_scoped.trace.json.gz", _scoped_ops())
 
 
 if __name__ == "__main__":

@@ -47,6 +47,7 @@ from shellac_tpu.obs.top import run_top
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
 BASE_TRACE = os.path.join(FIXTURES, "decode_base.trace.json.gz")
+SCOPED_TRACE = os.path.join(FIXTURES, "decode_scoped.trace.json.gz")
 REGRESSED_TRACE = os.path.join(FIXTURES,
                                "decode_regressed.trace.json.gz")
 
@@ -342,9 +343,13 @@ class TestTraceReport:
         mod.HERE = str(tmp_path)
         mod.main()
         for name in ("decode_base.trace.json.gz",
-                     "decode_regressed.trace.json.gz"):
-            fresh = (tmp_path / name).read_bytes()
-            committed = open(os.path.join(FIXTURES, name), "rb").read()
+                     "decode_regressed.trace.json.gz",
+                     "decode_scoped.trace.json.gz"):
+            # The payloads, not the files: the gzip header's OS byte
+            # differs between Python builds.
+            fresh = gzip.decompress((tmp_path / name).read_bytes())
+            committed = gzip.decompress(
+                open(os.path.join(FIXTURES, name), "rb").read())
             assert fresh == committed, f"{name} drifted from generator"
 
     def test_analyze_base_capture(self):
@@ -366,6 +371,26 @@ class TestTraceReport:
         assert rep["fusion"]["total_us"] == pytest.approx(5200.0)
         assert rep["top_ops"][0]["name"] == "fusion"
         assert "jit__decode_impl" in rep["modules"]
+
+    def test_by_scope_is_self_time_per_named_scope(self):
+        rep = tracereport.analyze(SCOPED_TRACE)
+        bs = rep["by_scope"]
+        assert "note" not in bs
+        got = {k: v["self_us"] for k, v in bs["scopes"].items()}
+        # innermost scope wins (attn.qkv/norm -> norm); the while
+        # shell keeps only what its children do not cover
+        assert got == {"attn.core": 400.0, "attn.qkv": 300.0,
+                       "kv.gather": 200.0, "norm": 50.0}
+        assert list(got) == ["attn.core", "attn.qkv", "kv.gather", "norm"]
+        assert bs["unscoped"]["self_us"] == pytest.approx(250.0)
+        assert bs["device_self_us"] == pytest.approx(1200.0)
+        assert bs["scopes"]["attn.core"]["share"] == pytest.approx(
+            400 / 1200, abs=1e-4)
+        assert "by scope" in tracereport.render_report(rep)
+        # a capture from before the scopes says so instead of guessing
+        old = tracereport.analyze(BASE_TRACE)["by_scope"]
+        assert not old["scopes"] and "note" in old
+        assert old["unscoped"]["self_us"] == pytest.approx(8200.0)
 
     def test_self_diff_is_clean(self):
         rep = tracereport.analyze(BASE_TRACE)

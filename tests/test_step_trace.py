@@ -1,0 +1,345 @@
+"""The engine's step recorder (obs.StepTrace): span trees, work counts,
+the phase histograms derived from them, the ring, and the mirror onto the
+profiler's clock. CPU, tiny model."""
+
+import collections
+import glob
+import itertools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from shellac_tpu.inference.batching import _bucket
+from shellac_tpu.inference.cache import engine_class
+from shellac_tpu.models import transformer
+from shellac_tpu.models.registry import get_model_config
+from shellac_tpu.obs import (
+    SPAN_PHASE,
+    STEP_COUNTS,
+    STEP_PHASES,
+    Registry,
+    ServeMetrics,
+)
+from shellac_tpu.obs import tracereport
+from shellac_tpu.obs.metrics import STEP_RING_CAPACITY
+
+COMBOS = list(itertools.product((False, True), (False, True),
+                                ("dense", "paged")))
+IDS = [f"od{int(od)}-op{int(op)}-{b}" for od, op, b in COMBOS]
+N_SLOTS, TICKS = 3, 2
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = get_model_config("tiny").replace(dtype="float32",
+                                           param_dtype="float32")
+    return cfg, transformer.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _engine(model, backend, registry, **kw):
+    cfg, params = model
+    if backend == "paged":
+        kw.setdefault("block_size", 16)
+    return engine_class(backend)(
+        cfg, params, n_slots=N_SLOTS, max_len=96, decode_ticks=TICKS,
+        registry=registry, cache_backend=backend, **kw)
+
+
+def _requests(cfg, n=7, seed=0):
+    rng = np.random.default_rng(seed)
+    return [(i, rng.integers(0, cfg.vocab_size,
+                             size=int(rng.integers(3, 40))),
+             int(rng.integers(2, 12))) for i in range(n)]
+
+
+def _drive(eng, reqs, stop_after=None):
+    outs = {}
+    for rid, toks, max_new in reqs:
+        eng.submit(rid, toks, max_new)
+    n = 0
+    while eng.pending and (stop_after is None or n < stop_after):
+        outs.update(dict(eng.step()))
+        n += 1
+    return outs
+
+
+@pytest.fixture(scope="module")
+def runs(model):
+    """One drained run per combination, made on first use."""
+    done = {}
+
+    def get(combo):
+        if combo not in done:
+            od, op, backend = combo
+            reg = Registry()
+            eng = _engine(model, backend, reg, overlap_decode=od,
+                          overlap_prefill=op)
+            reqs = _requests(model[0])
+            done[combo] = (reg, reqs, _drive(eng, reqs))
+        return done[combo]
+
+    return get
+
+
+@pytest.mark.parametrize("combo", COMBOS, ids=IDS)
+def test_span_tree_is_well_formed(runs, combo):
+    reg, _, _ = runs(combo)
+    recs = list(reg.step_records)
+    assert recs
+    for rec in recs:
+        assert rec.spans[rec.root][0] == "engine.step"
+        for i, (name, start, end, parent, attrs) in enumerate(rec.spans):
+            assert end >= start and len(name) <= 24
+            if parent >= 0:
+                # children lie inside their parent, which comes first
+                assert parent < i
+                assert rec.spans[parent][1] <= start
+                assert end <= rec.spans[parent][2]
+        under_step = [i for i in range(rec.root, len(rec.spans))
+                      if i == rec.root or rec.spans[i][3] >= rec.root]
+        for i in under_step:
+            kids = sorted((sp[1], sp[2]) for sp in rec.spans if sp[3] == i)
+            for (_, e0), (s1, _) in zip(kids, kids[1:]):
+                assert e0 <= s1        # siblings do not overlap
+        # engine.step = sum of children + self: the self times, by
+        # phase, partition the step span exactly
+        wall = (rec.end_ns - rec.start_ns) * 1e-9
+        phases = rec.phases()
+        assert set(phases) == set(STEP_PHASES)
+        assert all(v >= 0 for v in phases.values())
+        assert sum(phases.values()) == pytest.approx(wall, abs=1e-9)
+        names = {sp[0] for sp in rec.spans}
+        assert all(n in SPAN_PHASE or n.startswith("cache.")
+                   or n == "engine.submit" for n in names)
+
+
+@pytest.mark.parametrize("combo", COMBOS, ids=IDS)
+def test_phase_histograms_still_add_up_to_the_steps_wall_time(runs, combo):
+    reg, _, _ = runs(combo)
+    recs = list(reg.step_records)
+    wall = sum(r.end_ns - r.start_ns for r in recs) * 1e-9
+    hists = [reg.get("shellac_step_phase_seconds", phase=p)
+             for p in STEP_PHASES]
+    assert all(h.count == len(recs) for h in hists)
+    assert sum(h.sum for h in hists) == pytest.approx(wall, rel=1e-9)
+    sync = reg.get("shellac_step_phase_seconds", phase="decode_sync").sum
+    waited = sum(r.blocked_s() for r in recs)
+    assert sync == pytest.approx(waited, rel=1e-9) and sync > 0
+    # the host-overhead histogram: wall minus blocked, synced steps only
+    synced = [r for r in recs if r.blocked_s() > 0]
+    ho = reg.get("shellac_decode_host_overhead_seconds")
+    assert ho.count == len(synced)
+    assert ho.sum == pytest.approx(
+        sum((r.end_ns - r.start_ns) * 1e-9 - r.blocked_s()
+            for r in synced), rel=1e-6)
+
+
+@pytest.mark.parametrize("combo", COMBOS, ids=IDS)
+def test_counts_are_what_the_engine_did(runs, combo):
+    reg, reqs, outs = runs(combo)
+    recs = list(reg.step_records)
+    tot = {k: sum(r.counts[k] for r in recs) for k in STEP_COUNTS}
+    assert tot["tokens_delivered"] == sum(len(o) for o in outs.values())
+    assert len(reqs) == len(outs)
+    assert 0 < tot["decode_valid_ticks"] <= tot["decode_slot_ticks"]
+    assert tot["decode_slot_ticks"] % (TICKS * N_SLOTS) == 0
+    assert tot["prefill_tokens"] == sum(t.size for _, t, _ in reqs)
+    assert tot["prefill_padded_tokens"] == sum(
+        _bucket(t.size) for _, t, _ in reqs)
+    assert tot["compiles"] > 0 and tot["compile_s"] > 0
+    for k in STEP_COUNTS[:5]:
+        assert reg.value(f"shellac_engine_{k}_total") == tot[k]
+    assert reg.value("shellac_compile_events_total") >= tot["compiles"]
+    # every admission names its request and its slot; the padded
+    # length is its prefill's bucket
+    admits = [sp for r in recs for sp in r.spans if sp[0] == "engine.admit"]
+    assert sorted(sp[4]["rid"] for sp in admits) == [r[0] for r in reqs]
+    by_rid = {rid: t.size for rid, t, _ in reqs}
+    for sp in admits:
+        assert sp[4]["prompt_tokens"] == by_rid[sp[4]["rid"]]
+        assert sp[4]["padded_tokens"] == _bucket(by_rid[sp[4]["rid"]])
+    assert len([sp for r in recs for sp in r.spans
+                if sp[0] == "engine.submit"]) == len(reqs)
+
+
+def test_tokens_of_resident_requests_are_counted_when_handed_out(model):
+    """Cut a run short: the records hold the tokens of finished AND of
+    still-resident requests (stats["tokens_generated"] only the
+    former)."""
+    reg = Registry()
+    eng = _engine(model, "paged", reg, overlap_decode=True,
+                  overlap_prefill=True)
+    outs = _drive(eng, _requests(model[0], n=6, seed=3), stop_after=4)
+    resident = [r for r in eng._slots if r is not None]
+    assert resident
+    delivered = sum(r.counts["tokens_delivered"] for r in reg.step_records)
+    assert delivered == (sum(len(o) for o in outs.values())
+                         + sum(len(r.out) for r in resident))
+    assert delivered > eng.stats["tokens_generated"]
+    eng.abort_all()
+
+
+def test_the_ring_is_bounded_and_a_disabled_registry_records_nothing(model):
+    reg = Registry()
+    assert reg.step_records.maxlen == STEP_RING_CAPACITY
+    reg.step_records = collections.deque(maxlen=4)
+    _drive(_engine(model, "dense", reg), _requests(model[0]))
+    assert len(reg.step_records) == 4
+    steps = [r.step for r in reg.step_records]
+    assert steps == sorted(steps) and steps[0] > 1
+
+    off = Registry(enabled=False)
+    eng = _engine(model, "dense", off)
+    assert len(_drive(eng, _requests(model[0]))) == 7
+    assert not off.step_records and not eng.obs.steps._spans
+    assert off.value("shellac_engine_tokens_delivered_total") == 0.0
+
+
+def test_an_idle_step_leaves_no_record_and_a_cancel_s_spans_wait(model):
+    reg = Registry()
+    eng = _engine(model, "paged", reg)
+    eng.step()
+    assert not reg.step_records
+    cfg = model[0]
+    eng.submit("a", np.arange(5) % cfg.vocab_size, 8)
+    eng.step()
+    assert eng.cancel("a")          # releases the slot outside a step
+    eng.submit("b", np.arange(7) % cfg.vocab_size, 2)
+    while eng.pending:
+        eng.step()
+    loose = [sp for r in reg.step_records for sp in r.spans[:r.root]]
+    assert {"engine.submit", "cache.release_slot"} <= {sp[0] for sp in loose}
+
+
+def test_a_step_that_raises_closes_its_root_span(model, monkeypatch):
+    reg = Registry()
+    eng = _engine(model, "dense", reg)
+    eng.submit("a", np.arange(5) % model[0].vocab_size, 4)
+
+    def boom(*a, **k):
+        raise RuntimeError("prefill failed")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(eng, "_prepare_slot", boom)
+        with pytest.raises(RuntimeError):
+            eng.step()
+    steps = eng.obs.steps
+    assert not steps._stack and steps._root is None
+    assert not reg.step_records
+    eng.submit("b", np.arange(7) % model[0].vocab_size, 2)
+    while eng.pending:
+        eng.step()
+    # the next records are whole trees again
+    assert all(r.spans[r.root][0] == "engine.step" and r.spans[r.root][3] == -1
+               for r in reg.step_records)
+    assert len(reg.step_records) > 0
+
+
+def test_an_admission_joins_the_request_s_own_trace(model):
+    reg = Registry()
+    eng = _engine(model, "dense", reg)
+    span = ServeMetrics(reg).trace(trace_id="0af7651916cd43dd8448eb211c80319c")
+    eng.submit("r", np.arange(9) % model[0].vocab_size, 3, trace=span)
+    while eng.pending:
+        eng.step()
+    admit = next(sp for r in reg.step_records for sp in r.spans
+                 if sp[0] == "engine.admit")
+    assert admit[4]["trace_id"] == span.trace_id and admit[4]["rid"] == "r"
+
+
+def test_the_speculative_round_opens_the_same_window_spans(model):
+    from shellac_tpu.inference.spec_batching import SpeculativeBatchingEngine
+
+    cfg, params = model
+    reg = Registry()
+    eng = SpeculativeBatchingEngine(cfg, params, cfg, params, gamma=2,
+                                    n_slots=2, max_len=64, registry=reg)
+    outs = _drive(eng, _requests(cfg, n=3, seed=1))
+    recs = list(reg.step_records)
+    names = collections.Counter(sp[0] for r in recs for sp in r.spans)
+    assert names["engine.wait_window"] == names["engine.dispatch_window"] > 0
+    assert sum(r.counts["tokens_delivered"] for r in recs) == \
+        sum(len(o) for o in outs.values())
+    slot_ticks = sum(r.counts["decode_slot_ticks"] for r in recs)
+    assert slot_ticks == names["engine.wait_window"] * 3 * 2
+    assert 0 < sum(r.counts["decode_valid_ticks"] for r in recs) <= slot_ticks
+
+
+def test_span_names_reach_the_profiler_s_host_plane(model, tmp_path):
+    """Under a capture with the benchmark harness's options (no Python
+    tracer) the spans are on /host:CPU with their attributes."""
+    reg = Registry()
+    eng = _engine(model, "paged", reg, overlap_decode=True,
+                  overlap_prefill=True)
+    _drive(eng, _requests(model[0], n=3, seed=5))      # compile first
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _drive(eng, [(f"p{r}", t, m) for r, t, m in _requests(model[0], n=4,
+                                                              seed=6)])
+    finally:
+        jax.profiler.stop_trace()
+    pb = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                   recursive=True)
+    host = next(p for p in jax.profiler.ProfileData.from_file(pb[0]).planes
+                if p.name == "/host:CPU")
+    seen = {}
+    for line in host.lines:
+        for ev in line.events:
+            if ev.name.startswith(("engine.", "cache.")):
+                seen.setdefault(ev.name, dict(ev.stats))
+    assert {"engine.step", "engine.fill", "engine.admit",
+            "engine.prefill_dispatch", "engine.settle_prefills",
+            "engine.wait_prefill", "engine.dispatch_window",
+            "engine.wait_window", "engine.apply_window", "engine.submit",
+            "cache.prepare_slot", "cache.ensure_blocks",
+            "cache.release_slot"} <= set(seen)
+    assert "slot" in seen["engine.admit"] and "ticks" in \
+        seen["engine.dispatch_window"]
+
+
+@pytest.mark.parametrize("preset,expect", [
+    ("tiny", {"embed", "norm", "attn.qkv", "attn.rope", "kv.write",
+              "kv.gather", "attn.core", "attn.out", "mlp", "unembed"}),
+    ("tiny-deepseek", {"mla.absorb", "mla.latent_write", "moe.route",
+                       "moe.sort", "moe.gemm", "moe.combine", "moe.shared",
+                       "kv.gather", "attn.core", "mlp"}),
+])
+def test_the_compiled_decode_step_carries_the_named_scopes(preset, expect):
+    from shellac_tpu.inference.kvcache import init_paged_cache
+
+    cfg = get_model_config(preset).replace(dtype="float32",
+                                           param_dtype="float32")
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, 2, 9, 16, 4))
+
+    def tick(params, cache, tokens):
+        logits, cache = transformer.forward_with_cache(
+            cfg, params, tokens, cache, attn_impl="ref")
+        return jnp.argmax(logits[:, -1], axis=-1), cache
+
+    text = jax.jit(tick).lower(
+        params, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    ).as_text(debug_info=True)
+    found = {tracereport.scope_of({"op_name": n})
+             for n in re.findall(r'loc\("([^"]+)"', text)}
+    assert expect <= found, sorted(expect - found)
+    assert found - {None} <= set(tracereport.DEVICE_SCOPES)
+    assert tracereport.scope_of(
+        {"op_name": "jit(tick)/while/body/attn.qkv/norm/mul"}) == "norm"
+
+
+def test_the_sampler_s_scope():
+    from shellac_tpu.ops.sampling import sample_batched
+
+    z, o = jnp.zeros((2,)), jnp.ones((2,))
+    text = jax.jit(sample_batched).lower(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8)), o,
+        jnp.full((2,), 8, jnp.int32), o, z).as_text(debug_info=True)
+    assert "/sample/" in text and "sample" in tracereport.DEVICE_SCOPES
