@@ -45,6 +45,7 @@ from shellac_tpu.inference.kvcache import (
     PagedKVCache,
     QuantPagedKVCache,
     kv_field_names,
+    paged_write_prompt,
     scatter_slot,
     slot_view,
 )
@@ -648,8 +649,14 @@ class BatchingEngine:
         cache's shardings on the mesh (no-op unsharded) and donating
         the cache argument: every program threads cache-in -> cache-out
         (arg index 1, after params) and the caller rebinds self._cache
-        from the result immediately, so XLA may write the update in
-        place instead of copying the whole pool each prefill/decode."""
+        from the result immediately. Donation lets the result alias the
+        argument; whether the program also leaves the buffer alone in
+        between is the program's doing. The paged programs do:
+        forward_with_cache carries the pool through its layer loops,
+        every writer goes through kvcache.paged_write, and
+        tests/test_paged_inplace.py (with test_aot_compile.py, for the
+        TPU's compiler) holds the compiled decode window to no
+        pool-sized temporary, copy, transpose or per-layer slice."""
         if self._cache_sh is not None:
             jit_kw["out_shardings"] = (self._cache_sh,) + (None,) * n_tail
         return jax.jit(fn, donate_argnums=(1,), **jit_kw)
@@ -2724,33 +2731,17 @@ class PagedBatchingEngine(BatchingEngine):
         )[0, 0]
         first, first_lp = self._sample_first(key, last, samp)
 
-        bs = self.block_size
         table_row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)[0]
-        pos = jnp.arange(s, dtype=jnp.int32)
-        blocks = jnp.take(table_row, pos // bs)
-        offs = pos % bs
-        # mini.k[:, 0] is (L, Hkv, S, Dh); the pool write below indexes
-        # (block, off) at dims 1 and 3 with slices at 0 and 2, so the
-        # value wants token rows leading: (S, L, Hkv, Dh).
-        k_src = mini.k[:, 0].astype(cache.k.dtype).transpose(2, 0, 1, 3)
-        v_src = mini.v[:, 0].astype(cache.v.dtype).transpose(2, 0, 1, 3)
-        fields = dict(
-            k=cache.k.at[:, blocks, :, offs].set(k_src),
-            v=cache.v.at[:, blocks, :, offs].set(v_src),
-            lengths=jax.lax.dynamic_update_slice(
-                cache.lengths, mini.lengths, (slot,)
-            ),
+        # The quant mini already quantized at write (K post-rope); its
+        # scales go through the same pages as its values.
+        names = kv_field_names(self.kv_quant)
+        fields = dict(zip(names, paged_write_prompt(
+            [getattr(cache, n) for n in names],
+            [getattr(mini, n) for n in names], table_row,
+        )))
+        fields["lengths"] = jax.lax.dynamic_update_slice(
+            cache.lengths, mini.lengths, (slot,)
         )
-        if self.kv_quant == "int8":
-            # The quant mini already quantized at write (K post-rope);
-            # its scales scatter through the same (block, off) coords —
-            # scale pools are (L, nb, Hkv, bs), value rows (S, L, Hkv).
-            fields["ks"] = cache.ks.at[:, blocks, :, offs].set(
-                mini.ks[:, 0].transpose(2, 0, 1)
-            )
-            fields["vs"] = cache.vs.at[:, blocks, :, offs].set(
-                mini.vs[:, 0].transpose(2, 0, 1)
-            )
         cache = cache.replace(**fields)
         plp = (self._plp_within(logits, tokens) if want_plp
                else jnp.zeros((tokens.shape[1],), jnp.float32))
@@ -2983,18 +2974,9 @@ class PagedBatchingEngine(BatchingEngine):
                 (prompt_len - 1)[:, None, None].astype(jnp.int32),
                 axis=1,
             )[0, 0]
-            pos = jnp.arange(s_pad, dtype=jnp.int32)
-            blocks = jnp.take(tables0[0], pos // bs)
-            offs = pos % bs
-            scattered = []
-            for pool, f in zip(pools, mini_fields):
-                src = getattr(mini, f)[:, 0].astype(pool.dtype)
-                # Value pools are (L, nb, H, bs, Dh), scale pools
-                # (L, nb, H, bs): token rows lead after the transpose.
-                src = (src.transpose(2, 0, 1, 3) if src.ndim == 4
-                       else src.transpose(2, 0, 1))
-                scattered.append(pool.at[:, blocks, :, offs].set(src))
-            pools = tuple(scattered)
+            pools = paged_write_prompt(
+                pools, [getattr(mini, f) for f in mini_fields], tables0[0]
+            )
 
         from shellac_tpu.inference.engine import (
             beam_expand,

@@ -26,6 +26,7 @@ from typing import Any, Optional
 import flax.struct
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from shellac_tpu.config import ModelConfig
 
@@ -322,6 +323,15 @@ class PagedKVCache:
         scratch: it is never handed to a slot, so stray writes and reads
         through unallocated table entries land there harmlessly).
     lengths: (n_slots,) int32 — valid tokens per slot.
+
+    How the pool travels through a model step (forward_with_cache): as
+    a CARRY of the layer loops, viewed as (L * n_blocks, Hkv, bs, Dh) —
+    a bitcast — with the tables offset by layer * n_blocks, so a
+    (layer, page) pair is one block index. New rows are written in
+    place by paged_write, slot by slot (why: its docstring); reads go
+    through the offset tables. No layer's pool is sliced out of the
+    stack or restacked into it, and the buffer a program was handed
+    (and donated) is the buffer it returns.
     """
 
     k: Any
@@ -356,37 +366,106 @@ def init_paged_cache(
     )
 
 
+def paged_write(pools, news, index, tables):
+    """Write S new positions a slot through the block tables, in place.
+
+    pools: (N, Hkv, bs[, D]) each — one layer's pool, or the stacked
+    (L, n_blocks, ...) pool viewed as (L * n_blocks, ...) with `tables`
+    offset by layer * n_blocks; news: (B, Hkv, S[, D]) each, head-major
+    like a page, in the pool's dtype. Positions index[b] + i map to
+    pool coords (tables[b, p // bs], :, p % bs). Slots must have blocks
+    allocated for every written position (the scheduler guarantees it);
+    writes through unallocated entries land in a scratch block, and so
+    does a position past the table's end. Returns the pools.
+
+    A loop over (slot, piece) updates, each a dynamic_update_slice that
+    keeps the head axis whole: decode's one row a slot goes in as that
+    row; a run of S > 1 rows goes in page by page, each piece merging
+    the run's rows into the page it read (a run starting mid-page
+    touches at most (S + bs - 2) // bs + 1 pages; a piece past the
+    run's end rewrites its page unchanged). The layout constraint pins
+    the pool to the layout it is held in. Left to itself the TPU
+    compiler suits the pool to its writer: a scatter at (block,
+    offset), a bare row update, or rows arriving token-major each made
+    it move the WHOLE pool head-innermost on entry and back on exit,
+    every call, with a second pool of temporaries (PERF.md, PR 26;
+    tests/test_aot_compile.py asks that compiler)."""
+    bs = pools[0].shape[2]
+    b, s = news[0].shape[0], news[0].shape[2]
+    last = tables.shape[1] - 1
+    # A piece is a whole page, or the row itself where a slot writes one.
+    h = bs if s > 1 else 1
+    n_pieces = (s + h - 2) // h + 1
+
+    def piece(i, pools):
+        slot, j = i // n_pieces, i % n_pieces
+        start = index[slot]
+        first = (start // h + j) * h  # the piece's first position
+        page = first // bs
+        block = jnp.where(
+            page <= last, tables[slot, jnp.minimum(page, last)], 0
+        )
+        # The run's row held at each offset of this piece.
+        row = first + jnp.arange(h, dtype=jnp.int32) - start
+        held = (row >= 0) & (row < s)
+        out = []
+        for pool, run in zip(pools, news):
+            at = (block, 0, first % bs) + (0,) * (pool.ndim - 3)
+            old = jax.lax.dynamic_slice(
+                pool, at, (1, pool.shape[1], h, *pool.shape[3:])
+            )
+            new = jnp.take(
+                jax.lax.dynamic_index_in_dim(run, slot, 0, keepdims=True),
+                jnp.clip(row, 0, s - 1), axis=2,
+            )  # (1, Hkv, h[, D])
+            mask = held.reshape(1, 1, h, *(1,) * (pool.ndim - 3))
+            out.append(jax.lax.dynamic_update_slice(
+                pool, jnp.where(mask, new, old), at
+            ))
+        return tuple(out)
+
+    held_as = tuple(
+        with_layout_constraint(
+            p, Layout(major_to_minor=tuple(range(p.ndim)))
+        ) for p in pools
+    )
+    return jax.lax.fori_loop(0, b * n_pieces, piece, held_as)
+
+
+def paged_write_prompt(pools, minis, table_row):
+    """Write a prefilled prompt into one slot's pages, every layer at
+    once: a batch-1 dense mini cache's S rows go through `table_row`
+    (max_blocks,) into the stacked pools, by paged_write with the
+    LAYERS as its slots — layer l's table is the row offset by
+    l * n_blocks, its run the mini's layer l from position 0.
+
+    pools: (L, n_blocks, Hkv, bs[, D]) each; minis: the mini cache's
+    matching fields, (L, 1, Hkv, S[, D]). Returns the pools."""
+    n_layers, n_blocks = pools[0].shape[:2]
+    layers = jnp.arange(n_layers, dtype=jnp.int32)
+    out = paged_write(
+        [p.reshape(n_layers * n_blocks, *p.shape[2:]) for p in pools],
+        [m[:, 0].astype(p.dtype) for m, p in zip(minis, pools)],
+        jnp.zeros_like(layers), table_row[None, :] + n_blocks * layers[:, None],
+    )
+    return tuple(o.reshape(p.shape) for o, p in zip(out, pools))
+
+
 def paged_update_layer(
-    pool_k: jax.Array,  # (n_blocks, Hkv, bs, Dh) — one layer's pool
+    pool_k: jax.Array,  # (N, Hkv, bs, Dh) — see paged_write
     pool_v: jax.Array,
     k_new: jax.Array,  # (B, S, Hkv, Dh)
     v_new: jax.Array,
     index: jax.Array,  # (B,) — per-slot write offsets (token positions)
     tables: jax.Array,  # (B, max_blocks) int32
 ):
-    """Scatter S new positions through the block tables; returns pools.
-
-    Positions index[b] + i map to pool coords
-    (tables[b, p // bs], :, p % bs). Slots must have blocks allocated
-    for every written position (the scheduler guarantees it); writes
-    through unallocated entries land in scratch block 0.
-    """
-    bs = pool_k.shape[2]
-    b, s = k_new.shape[:2]
-    pos = index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
-    block_ids = jnp.take_along_axis(tables, pos // bs, axis=1)  # (B, S)
-    offs = pos % bs
-    flat_blocks = block_ids.reshape(-1)
-    flat_offs = offs.reshape(-1)
-    # Advanced indices at dims 0 and 2 (separated by the head slice):
-    # the indexed result is (B*S, Hkv, Dh), matching k_new's token rows.
-    pk = pool_k.at[flat_blocks, :, flat_offs].set(
-        k_new.astype(pool_k.dtype).reshape(b * s, *k_new.shape[2:])
+    """Write S new positions through the block tables; returns pools."""
+    return paged_write(
+        (pool_k, pool_v),
+        (k_new.astype(pool_k.dtype).transpose(0, 2, 1, 3),
+         v_new.astype(pool_v.dtype).transpose(0, 2, 1, 3)),
+        index, tables,
     )
-    pv = pool_v.at[flat_blocks, :, flat_offs].set(
-        v_new.astype(pool_v.dtype).reshape(b * s, *v_new.shape[2:])
-    )
-    return pk, pv
 
 
 def paged_gather_layer(
@@ -477,29 +556,21 @@ def quant_paged_cache_logical_axes(cfg: Optional[ModelConfig] = None):
 
 
 def quant_paged_update_layer(
-    pool_k, pool_v, pool_ks, pool_vs,  # one layer's int8 pools + scales
+    pool_k, pool_v, pool_ks, pool_vs,  # int8 pools + scales (paged_write)
     k_new, v_new,  # (B, S, Hkv, Dh) unquantized
     index,  # (B,) int32 — per-slot write offsets (token positions)
     tables,  # (B, max_blocks) int32
 ):
-    """Quantize S new positions, scatter values and scales through the
-    block tables (same position->block arithmetic as the bf16 pool)."""
+    """Quantize S new positions, write values and scales through the
+    block tables in one pass over the same coordinates."""
     kq, ks = quantize_kv(k_new)
     vq, vs = quantize_kv(v_new)
-    pk, pv = paged_update_layer(pool_k, pool_v, kq, vq, index, tables)
-    bs = pool_k.shape[2]
-    b, s = k_new.shape[:2]
-    pos = index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    block_ids = jnp.take_along_axis(tables, pos // bs, axis=1)
-    flat_blocks = block_ids.reshape(-1)
-    flat_offs = (pos % bs).reshape(-1)
-    pks = pool_ks.at[flat_blocks, :, flat_offs].set(
-        ks.reshape(b * s, -1)
+    return paged_write(
+        (pool_k, pool_v, pool_ks, pool_vs),
+        (kq.transpose(0, 2, 1, 3), vq.transpose(0, 2, 1, 3),
+         ks.transpose(0, 2, 1), vs.transpose(0, 2, 1)),
+        index, tables,
     )
-    pvs = pool_vs.at[flat_blocks, :, flat_offs].set(
-        vs.reshape(b * s, -1)
-    )
-    return pk, pv, pks, pvs
 
 
 def paged_gather_scales(

@@ -21,6 +21,8 @@ from shellac_tpu.inference.cache.layout import (  # noqa: F401
     paged_gather_layer,
     paged_gather_scales,
     paged_update_layer,
+    paged_write,
+    paged_write_prompt,
     quant_cache_logical_axes,
     quant_paged_cache_logical_axes,
     quant_paged_update_layer,

@@ -500,7 +500,9 @@ def _block(
             quant_paged_update_layer,
         )
 
-        pool_k, pool_v, index, q_positions = cache  # pool: (nb, Hkv, bs, D)
+        # pool: (N, Hkv, bs, D) — the stacked pool viewed flat, read and
+        # written through tables offset to this layer's blocks.
+        pool_k, pool_v, index, q_positions = cache
         with jax.named_scope("kv.write"):
             if kv_scales is not None:
                 # Int8 pool: quantize at write (K post-rope, the
@@ -1450,6 +1452,7 @@ def forward_with_cache(
         QuantPatternedKVCache,
         QuantRollingKVCache,
         RollingKVCache,
+        kv_field_names,
     )
 
     if not cfg.causal:
@@ -1486,10 +1489,8 @@ def forward_with_cache(
     x = _embed_tokens(cfg, params, tokens, cdt, mesh=mesh)
     x = constrain(x, mesh, ("batch", "seq", None))
 
-    tables = cache.tables if paged else None
-
     def run_block(x, lp, ck, cv, moe_flag, scales=None, attn_kind=None,
-                  block_rolled=None):
+                  block_rolled=None, tables=None):
         local = cos_l is not None and attn_kind == "window"
         return _block(
             cfg, mesh, attn_impl, x, lp,
@@ -1506,22 +1507,99 @@ def forward_with_cache(
             cfg.attn_pattern, x, layer_stack, caches, body_one
         )
 
-    # Cache leaves riding the layer scans: values only (bf16) or values
+    # Cache leaves riding the layer loops: values only (bf16) or values
     # + scale stacks (int8). ONE set of stack-dispatch bodies serves
     # both, threading the scales to run_block when present — the same
-    # field-count parameterization the mixed branch uses. new_ks/new_vs
-    # exist only in quant mode (the final replace checks).
+    # field-count parameterization the mixed branch uses.
     if mixed or quant_mixed:
-        cleaves = ()  # mixed caches carry kw/vw/kf/vf, not k/v
-    elif quant:
-        cleaves = (cache.k, cache.v, cache.ks, cache.vs)
+        names = ()  # mixed caches carry kw/vw/kf/vf, named in their branch
     else:
-        cleaves = (cache.k, cache.v)
+        names = kv_field_names("int8" if quant else None)
+    cleaves = tuple(getattr(cache, n) for n in names)
 
     def _scales_of(vals):
         return (vals[2], vals[3]) if quant else None
 
-    if first_k_layout(cfg):
+    if paged:
+        # A paged pool rides the layer loops as a CARRY, never as xs/ys:
+        # the stacked (L, n_blocks, ...) pools are viewed as
+        # (L * n_blocks, ...) (a bitcast) and each block writes its rows
+        # in place and reads through the tables offset to its layer's
+        # blocks. No layer's pool is sliced out or restacked, and the
+        # donated buffers come back where they came in
+        # (tests/test_paged_inplace.py holds the compiled program to it).
+        n_blocks = cache.k.shape[1]
+        pools = tuple(
+            a.reshape(a.shape[0] * n_blocks, *a.shape[2:]) for a in cleaves
+        )
+
+        def step(x, pools, lp, li, moe_flag, attn_kind=None):
+            x, pools, _ = run_block(
+                x, lp, pools[0], pools[1], moe_flag, _scales_of(pools),
+                attn_kind=attn_kind, tables=cache.tables + li * n_blocks,
+            )
+            return x, pools
+
+        def scan_stack(x, pools, stack, first, moe_flag):
+            """Layers first, first + 1, ... of one homogeneous stack."""
+            n = jax.tree.leaves(stack)[0].shape[0]
+
+            def body(carry, inp):
+                return step(*carry, *inp, moe_flag), None
+
+            (x, pools), _ = jax.lax.scan(
+                body, (x, pools),
+                (stack, first + jnp.arange(n, dtype=jnp.int32)),
+            )
+            return x, pools
+
+        def group_firsts(size):
+            return size * jnp.arange(cfg.n_layers // size, dtype=jnp.int32)
+
+        if first_k_layout(cfg):
+            x, pools = scan_stack(
+                x, pools, params["layers"]["dense"], 0, False
+            )
+            x, pools = scan_stack(
+                x, pools, params["layers"]["moe"], cfg.first_k_dense, True
+            )
+        elif grouped_moe(cfg):
+            every = cfg.moe_every
+
+            def group_body(carry, inp):
+                glp, first = inp
+                x, pools = scan_stack(*carry, glp["dense"], first, False)
+                return step(
+                    x, pools, glp["moe"], first + every - 1, True
+                ), None
+
+            (x, pools), _ = jax.lax.scan(
+                group_body, (x, pools), (params["layers"], group_firsts(every))
+            )
+        elif cfg.attn_pattern is not None:
+            period = len(cfg.attn_pattern)
+            glp = jax.tree.map(
+                lambda a: a.reshape(
+                    a.shape[0] // period, period, *a.shape[1:]
+                ),
+                params["layers"],
+            )
+
+            def group_body(carry, inp):
+                gl, first = inp
+                x, pools = carry
+                for i, kind in enumerate(cfg.attn_pattern):
+                    lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
+                    x, pools = step(x, pools, lp_i, first + i, None, kind)
+                return (x, pools), None
+
+            (x, pools), _ = jax.lax.scan(
+                group_body, (x, pools), (glp, group_firsts(period))
+            )
+        else:
+            x, pools = scan_stack(x, pools, params["layers"], 0, None)
+        news = tuple(p.reshape(a.shape) for p, a in zip(pools, cleaves))
+    elif first_k_layout(cfg):
         # DeepSeek layout: dense prefix stack, then the all-MoE tail.
         kk = cfg.first_k_dense
 
@@ -1546,10 +1624,6 @@ def forward_with_cache(
         news = tuple(
             jnp.concatenate([d, m], axis=0) for d, m in zip(nd, nm)
         )
-        if quant:
-            new_k, new_v, new_ks, new_vs = news
-        else:
-            new_k, new_v = news
     elif grouped_moe(cfg):
         # Interleaved stacks: scan whole (dense^(every-1), moe) groups.
         every = cfg.moe_every
@@ -1582,10 +1656,6 @@ def forward_with_cache(
 
         x, gn = jax.lax.scan(group_body, x, (params["layers"],) + gc)
         news = tuple(a.reshape(cfg.n_layers, *a.shape[2:]) for a in gn)
-        if quant:
-            new_k, new_v, new_ks, new_vs = news
-        else:
-            new_k, new_v = news
     elif mixed or quant_mixed:
         # Mixed ring/dense stacks: the scan walks pattern periods with
         # per-kind cursors — "window" blocks consume ring rows (rolled
@@ -1642,11 +1712,7 @@ def forward_with_cache(
         x, news = jax.lax.scan(group_body, x, (glp,) + gw + gf)
         backflat = lambda a: a.reshape(-1, *a.shape[2:])  # noqa: E731
         news = [backflat(a) for a in news]
-        if quant_mixed:
-            (new_kw, new_vw, new_kws, new_vws,
-             new_kf, new_vf, new_kfs, new_vfs) = news
-        else:
-            new_kw, new_vw, new_kf, new_vf = news
+        names = w_names + f_names
     elif cfg.attn_pattern is not None:
         def body_one(x, lp, cs, kind):
             x, nc, _ = run_block(
@@ -1655,10 +1721,6 @@ def forward_with_cache(
             return x, nc
 
         x, news = pattern_scan(x, params["layers"], cleaves, body_one)
-        if quant:
-            new_k, new_v, new_ks, new_vs = news
-        else:
-            new_k, new_v = news
     else:
         def scan_body(x, layer_in):
             lp, vals = layer_in[0], layer_in[1:]
@@ -1670,33 +1732,13 @@ def forward_with_cache(
         x, news = jax.lax.scan(
             scan_body, x, (params["layers"],) + cleaves
         )
-        if quant:
-            new_k, new_v, new_ks, new_vs = news
-        else:
-            new_k, new_v = news
 
     logits = unembed(cfg, params, x, mesh=mesh)
     if new_tokens_len is None:
         new_lengths = index + s
     else:
         new_lengths = index + new_tokens_len.astype(jnp.int32)
-    if quant:
-        new_cache = cache.replace(
-            k=new_k, v=new_v, ks=new_ks, vs=new_vs, lengths=new_lengths
-        )
-    elif quant_mixed:
-        new_cache = cache.replace(
-            kw=new_kw, vw=new_vw, kws=new_kws, vws=new_vws,
-            kf=new_kf, vf=new_vf, kfs=new_kfs, vfs=new_vfs,
-            lengths=new_lengths,
-        )
-    elif mixed:
-        new_cache = cache.replace(
-            kw=new_kw, vw=new_vw, kf=new_kf, vf=new_vf,
-            lengths=new_lengths,
-        )
-    else:
-        new_cache = cache.replace(k=new_k, v=new_v, lengths=new_lengths)
+    new_cache = cache.replace(**dict(zip(names, news)), lengths=new_lengths)
     return logits, new_cache
 
 

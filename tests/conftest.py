@@ -95,6 +95,55 @@ def rng():
     return np.random.default_rng(0)
 
 
+#: The four stack layouts of forward_with_cache that can hold a paged
+#: pool, each as (preset, overrides) with kv_heads >= 2.
+PAGED_STACK_LAYOUTS = {
+    "plain": ("tiny", {}),
+    "first_k_dense": ("tiny-moe", {"first_k_dense": 2}),
+    "grouped_moe": ("tiny-moe-interleaved", {}),
+    "attn_pattern": ("tiny-gemma2", {}),
+}
+
+
+def _pool_sized_ops(hlo, pools):
+    """Instructions of the kinds that move a pool (copy, transpose,
+    concatenate, dynamic-slice) in optimized HLO text, whose result has
+    the shape of a whole pool — stacked (L, n_blocks, ...) or viewed
+    flat (L * n_blocks, ...) — or of one layer's."""
+    import re
+
+    shapes = set()
+    for shape in pools:
+        shapes |= {shape, shape[1:], (shape[0] * shape[1], *shape[2:])}
+    found = []
+    for line in hlo.splitlines():
+        kind = re.search(
+            r" (copy|transpose|concatenate|dynamic-slice)\(", line
+        )
+        dims = re.search(r"= \(?\w+\[([\d,]+)\]", line)
+        if kind and dims and tuple(
+            int(d) for d in dims.group(1).split(",")
+        ) in shapes:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.fixture
+def pool_sized_ops():
+    return _pool_sized_ops
+
+
+@pytest.fixture(params=list(PAGED_STACK_LAYOUTS))
+def paged_stack_cfg(request):
+    """A float32 eight-layer config of each paged stack layout."""
+    from shellac_tpu import get_model_config
+
+    preset, extra = PAGED_STACK_LAYOUTS[request.param]
+    return get_model_config(preset).replace(
+        dtype="float32", n_layers=8, **extra
+    )
+
+
 def run_two_process(tmp_path, source, timeout=300, ok_ranks=(0, 1)):
     """Launch `source` as 2 rendezvousing jax.distributed processes.
 

@@ -244,6 +244,56 @@ def _mesh_paged_int8(mesh):
                 ((SERVE_B,), I32, (None,)), scales, scales]
 
 
+def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
+        chip, pool_sized_ops):
+    """The TPU compiler's verdict on what tests/test_paged_inplace.py
+    reads off the CPU's: a window of decode ticks over a bf16 paged pool
+    at serving widths (8 kv heads x 128, 256-token pages) holds no
+    pool-sized temporary and moves no pool. The two compilers differ: a
+    one-row update at (block, offset) is in place on the CPU, while this
+    one then holds the pool head-innermost inside the window and copies
+    it in and out (PERF.md, PR 26)."""
+    from shellac_tpu import get_model_config
+    from shellac_tpu.inference.kvcache import init_paged_cache
+    from shellac_tpu.models import transformer
+
+    cfg = get_model_config("tiny").replace(
+        d_model=512, n_heads=H, n_kv_heads=HKV, head_dim=D, d_ff=1024,
+        n_layers=8, dtype="bfloat16", param_dtype="bfloat16",
+    ).validate()
+    slots, page, pages = 8, 256, 4
+
+    def window(params, cache, cur):
+        def tick(carry, _):
+            cache, cur = carry
+            logits, cache = transformer.forward_with_cache(
+                cfg, params, cur[:, None], cache
+            )
+            return (cache, jnp.argmax(logits[:, 0], -1).astype(I32)), None
+
+        return jax.lax.scan(tick, (cache, cur), None, length=2)[0]
+
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = shaped(jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))
+    ))
+    cache = shaped(jax.eval_shape(
+        lambda: init_paged_cache(cfg, slots, slots * pages + 1, page, pages)
+    ))
+    cur = jax.ShapeDtypeStruct((slots,), I32, sharding=chip)
+    compiled = jax.jit(window, donate_argnums=(1,)).lower(
+        params, cache, cur
+    ).compile()
+    pool_bytes = 2 * cache.k.size * cache.k.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 2, (temp, pool_bytes)
+    moved = pool_sized_ops(compiled.as_text(), [cache.k.shape])
+    assert not moved, "\n".join(moved)
+
+
 @pytest.mark.parametrize("build", [
     pytest.param(_mesh_rmsnorm, id="rmsnorm"),
     pytest.param(_mesh_flash_grad, id="flash-fwd-bwd"),

@@ -15,8 +15,12 @@ from shellac_tpu.inference.batching import PagedBatchingEngine
 from shellac_tpu.inference.engine import Engine
 from shellac_tpu.inference.kvcache import (
     init_cache,
+    init_cache_for,
     init_paged_cache,
+    init_quant_paged_cache,
+    kv_field_names,
     paged_gather_layer,
+    paged_gather_scales,
     paged_update_layer,
 )
 from shellac_tpu.models import transformer
@@ -79,6 +83,78 @@ class TestPagedOps:
         ld2, _ = transformer.forward_with_cache(cfg, params, nxt, dense)
         lp2, _ = transformer.forward_with_cache(cfg, params, nxt, paged)
         np.testing.assert_allclose(np.asarray(lp2), np.asarray(ld2), atol=1e-5)
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"],
+                             ids=["bf16", "int8"])
+    def test_paged_pool_matches_dense_rows(self, paged_stack_cfg, kv_quant):
+        """Every stack layout x pool kind: runs of s > 1 rows that cross
+        a page, then s = 1 ticks with one slot frozen, give the dense
+        slot cache's logits, and the pool holds the dense cache's rows
+        bit for bit at the same positions. A slot whose table is
+        unallocated writes into scratch block 0 and nowhere else."""
+        cfg = paged_stack_cfg
+        bs, mb, b = 4, 6, 3
+        params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+        dense = init_cache_for(cfg, b, mb * bs, kv_quant=kv_quant)
+        init = init_quant_paged_cache if kv_quant else init_paged_cache
+        owned = np.arange(1, 2 * mb + 1).reshape(2, mb)
+        paged = init(cfg, b, n_blocks=2 * mb + 3, block_size=bs,
+                     max_blocks_per_slot=mb)
+        # Slots 0 and 1 own their pages; slot 2's table is unallocated.
+        paged = paged.replace(tables=jnp.asarray(
+            np.concatenate([owned, np.zeros((1, mb), int)]), jnp.int32
+        ))
+        fields = kv_field_names(kv_quant)
+
+        def both(toks, dense, paged):
+            ld, dense = transformer.forward_with_cache(
+                cfg, params, toks, dense)
+            lp, paged = transformer.forward_with_cache(
+                cfg, params, toks, paged)
+            np.testing.assert_allclose(
+                np.asarray(lp[:2]), np.asarray(ld[:2]), atol=1e-5)
+            return ld, dense, paged
+
+        def slot_rows(cache, slot, n):
+            """The slot's first n rows of every field, every layer."""
+            out = []
+            for name in fields:
+                pool = getattr(cache, name)
+                if pool.ndim == 5:
+                    views = [paged_gather_layer(p, p, cache.tables)[0]
+                             for p in pool]
+                else:
+                    views = [paged_gather_scales(p, cache.tables)
+                             for p in pool]
+                out.append(np.stack([np.asarray(v[slot, :, :n])
+                                     for v in views]))
+            return out
+
+        # 7 rows from 0 cross pages 0|1; 3 more from 7 cross pages 1|2.
+        toks = jax.random.randint(jax.random.PRNGKey(3), (b, 10), 0,
+                                  cfg.vocab_size)
+        _, dense, paged = both(toks[:, :7], dense, paged)
+        ld, dense, paged = both(toks[:, 7:], dense, paged)
+        frozen = slot_rows(paged, 1, 10)
+        for _ in range(3):
+            # Slot 1 is inactive, as the engine freezes it: its length
+            # stays, so each tick's stray row lands past its valid rows.
+            nxt = jnp.argmax(ld[:, -1], -1).astype(jnp.int32)[:, None]
+            ld, dense, paged = both(nxt, dense, paged)
+            hold = paged.lengths.at[1].set(10)
+            dense = dense.replace(lengths=hold)
+            paged = paged.replace(lengths=hold)
+        for got, want in zip(slot_rows(paged, 1, 10), frozen):
+            np.testing.assert_array_equal(got, want)
+        for slot, n in ((0, 13), (1, 10)):
+            for name, got in zip(fields, slot_rows(paged, slot, n)):
+                want = np.asarray(getattr(dense, name))[:, slot, :, :n]
+                np.testing.assert_array_equal(got, want, err_msg=name)
+        # Slot 2 wrote 13 rows through unallocated entries: all of them
+        # in scratch block 0, none in a block no slot owns.
+        k = np.asarray(paged.k)
+        assert np.any(k[:, 0] != 0)
+        assert not np.any(k[:, 2 * mb + 1:])
 
 
 class TestPagedEngine:
