@@ -1562,7 +1562,7 @@ def build_parser() -> argparse.ArgumentParser:
     b.add_argument("--mesh", default="", help="e.g. tp=4")
     b.add_argument("--cache-backend", default=None, dest="cache_backend",
                    choices=["dense", "dense-int8", "paged", "paged-int8",
-                            "rolling", "rolling-int8"],
+                            "rolling", "rolling-int8", "eva"],
                    help="KV-cache storage policy (the registry the "
                         "engines resolve through; see docs/inference.md "
                         "capability table)")
@@ -1599,7 +1599,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "always has a target (docs/serving_tier.md)")
     s.add_argument("--cache-backend", default=None, dest="cache_backend",
                    choices=["dense", "dense-int8", "paged", "paged-int8",
-                            "rolling", "rolling-int8"],
+                            "rolling", "rolling-int8", "eva"],
                    help="KV-cache storage policy, resolved through the "
                         "same backend registry the engines use (the "
                         "legacy --paged/--kv-quant/--rolling-window "

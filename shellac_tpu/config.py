@@ -156,6 +156,25 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class EvaConfig:
+    """EVA attention (EvaByte; Zheng et al., arXiv:2302.04542).
+
+    A query at position i attends, in ONE softmax, the exact keys of its
+    own `window`-position window (positions (i // window) * window .. i)
+    and one pooled (key, value) row for every `chunk`-position chunk of
+    every EARLIER window. A chunk's pooled row is the softmax-of-
+    (k . phi) weighted mean of its keys (plus mu) and of its values,
+    phi and mu being two learned vectors per head and layer
+    (ops/eva_attention.py has the equations). The decode state is a ring
+    of `window` exact rows plus one pooled row per `chunk` positions of
+    the completed windows (inference/cache/eva.py).
+    """
+
+    window: int = 2048
+    chunk: int = 16
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Decoder-only transformer configuration (LLaMA-style)."""
 
@@ -241,6 +260,18 @@ class ModelConfig:
     rope_local_theta: Optional[float] = None
     # Per-head-dim RMSNorm on q and k before rope (Qwen3-style).
     qk_norm: bool = False
+    # EVA attention (EvaByte): replaces softmax-over-every-key with an
+    # exact window plus pooled rows; adds eva_phi / eva_mu (H, Dh) per
+    # layer. Exclusive with mla, attn_window and attn_pattern.
+    eva: Optional[EvaConfig] = None
+    # Multi-token prediction heads (EvaByte num_pred_heads): lm_head is
+    # (d_model, n_pred_heads * vocab_size), head m predicting the token
+    # m + 1 ahead. forward() returns every head's logits; cached
+    # generation unembeds head 0 only.
+    n_pred_heads: int = 1
+    # Keep the residual stream in float32 between blocks (EvaByte
+    # fp32_skip_add); projections still run in the compute dtype.
+    fp32_residual: bool = False
 
     def __post_init__(self):
         # JSON configs arrive with attn_pattern as a list; the frozen
@@ -249,6 +280,8 @@ class ModelConfig:
             self.attn_pattern, tuple
         ):
             object.__setattr__(self, "attn_pattern", tuple(self.attn_pattern))
+        if isinstance(self.eva, dict):
+            object.__setattr__(self, "eva", EvaConfig(**self.eva))
 
     @property
     def kv_heads(self) -> int:
@@ -403,6 +436,44 @@ class ModelConfig:
                 "rope_local_theta needs an attn_pattern with 'window' "
                 "layers (a uniform model just sets rope_theta)"
             )
+        if self.n_pred_heads < 1:
+            raise ValueError("n_pred_heads must be >= 1")
+        if self.n_pred_heads > 1 and self.tie_embeddings:
+            raise ValueError(
+                "n_pred_heads > 1 needs an untied lm_head "
+                "(tie_embeddings=False)"
+            )
+        if self.eva is not None:
+            e = self.eva
+            if e.chunk < 1 or e.window < e.chunk or e.window % e.chunk:
+                raise ValueError(
+                    f"eva window={e.window} must be a positive multiple "
+                    f"of chunk={e.chunk}"
+                )
+            if self.kv_heads != self.n_heads:
+                raise ValueError(
+                    "EVA attention pools per head: n_kv_heads must equal "
+                    "n_heads"
+                )
+            if (self.mla is not None or self.attn_window is not None
+                    or self.attn_pattern is not None):
+                raise ValueError(
+                    "eva is its own attention kind: mla, attn_window and "
+                    "attn_pattern do not combine with it"
+                )
+            if (self.attn_softcap is not None or self.attn_scale is not None
+                    or self.attn_sink or self.attn_bias
+                    or self.attn_out_bias or self.qk_norm
+                    or self.post_norms):
+                raise ValueError(
+                    "EVA attention takes none of attn_softcap, attn_scale, "
+                    "attn_sink, attn_bias, attn_out_bias, qk_norm, "
+                    "post_norms"
+                )
+            if not self.causal:
+                raise ValueError("EVA attention is decoder-only (causal=True)")
+            if self.moe is not None:
+                raise ValueError("EVA attention with MoE layers is not wired")
         if self.mla is not None:
             if self.n_kv_heads is not None:
                 raise ValueError(

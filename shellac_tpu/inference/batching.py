@@ -369,6 +369,8 @@ class BatchingEngine:
                 validate_pp_pipeline,
             )
 
+            backend.check_feature("pp_pipeline")
+
             self._pp = validate_pp_pipeline(
                 cfg, mesh, n_slots, self.kv_quant, self.rolling_window,
                 self.cache_backend.is_paged,
@@ -2100,7 +2102,8 @@ class BatchingEngine:
         # token list is a slice, not a scan.
         n_valid = host_acts.sum(axis=0)
         steps.count(decode_slot_ticks=w.ticks * self.n_slots,
-                    decode_valid_ticks=int(n_valid.sum()))
+                    decode_valid_ticks=int(n_valid.sum()),
+                    **self.cache_backend.window_counts(w.pairs, n_valid))
         per_slot = [host_toks[:n_valid[i], i].tolist()
                     for i in range(self.n_slots)]
         if not self.logprobs:
@@ -2391,6 +2394,8 @@ class BatchingEngine:
                 raise ValueError(
                     f"prefill_chunk must be >= 1, got {chunk}"
                 )
+        if chunk is not None:
+            self.cache_backend.check_feature("chunked_prefill")
         if self.cache_backend.is_rolling and (
             chunk or 1
         ) > self.cache_backend.chunk_slack:
@@ -2476,7 +2481,7 @@ class PagedBatchingEngine(BatchingEngine):
     computed, which also yields the last-token logits sampling needs).
     """
 
-    _backend_family = ("paged", "paged-int8")
+    _backend_family = ("paged", "paged-int8", "eva")
 
     def __init__(
         self,
@@ -2493,11 +2498,11 @@ class PagedBatchingEngine(BatchingEngine):
         **kw,
     ):
         from shellac_tpu.inference.cache import (
+            BACKENDS,
             CacheBackend,
             make_backend,
             resolve_backend_name,
         )
-        from shellac_tpu.inference.cache.paged import INT8_BLOCK_SIZE_DEFAULT
 
         if not isinstance(cache_backend, CacheBackend):
             name = (resolve_backend_name(None, paged=True,
@@ -2513,10 +2518,10 @@ class PagedBatchingEngine(BatchingEngine):
                     "inference.cache.engine_class"
                 )
             if block_size is None:
-                # int8 pools need 128-token pages (the grouped-gather
-                # kernel's scale DMA); bf16 keeps the finer 16.
-                block_size = (INT8_BLOCK_SIZE_DEFAULT
-                              if name == "paged-int8" else 16)
+                # The backend's own: bf16 pools the finer 16, int8
+                # pools 128 (the grouped-gather kernel's scale DMA), an
+                # EVA pool its window.
+                block_size = BACKENDS[name][0].default_block_size(cfg)
             chunk = kw.get("prefill_chunk")
             cache_backend = make_backend(
                 name, cfg, n_slots, max_len or cfg.max_seq_len,
@@ -2716,33 +2721,26 @@ class PagedBatchingEngine(BatchingEngine):
 
     def _prefill_impl(self, params, cache, tokens, prompt_len, slot, key,
                       samp, want_plp: bool = False):
-        """Mini-prefill (dense bf16 or int8+scales, matching the pool's
-        kind), then scatter through the slot's table. want_plp scores
-        the prompt from the mini-prefill's own logits — identical math
-        to the dense engine's whole-prompt scoring."""
-        s = tokens.shape[1]
-        mini = self._fresh_mini(s)
-        logits, mini = transformer.forward_with_cache(
-            self.cfg, params, tokens, mini, new_tokens_len=prompt_len,
-            fresh_cache=True, attn_impl=self.attn_impl, mesh=self.mesh,
+        """Prefill one prompt and leave its state in the slot's pages,
+        the backend's way (`prefill_into`: a dense mini cache of the
+        pool's kind scattered through the slot's table, or, for EVA
+        state, straight through a view of the slot). want_plp scores
+        the prompt from the prefill's own logits — identical math to
+        the dense engine's whole-prompt scoring."""
+        def forward(scratch):
+            return transformer.forward_with_cache(
+                self.cfg, params, tokens, scratch,
+                new_tokens_len=prompt_len, fresh_cache=True,
+                attn_impl=self.attn_impl, mesh=self.mesh,
+            )
+
+        logits, cache = self.cache_backend.prefill_into(
+            cache, slot, tokens.shape[1], forward
         )
         last = jnp.take_along_axis(
             logits, (prompt_len - 1)[:, None, None].astype(jnp.int32), axis=1
         )[0, 0]
         first, first_lp = self._sample_first(key, last, samp)
-
-        table_row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)[0]
-        # The quant mini already quantized at write (K post-rope); its
-        # scales go through the same pages as its values.
-        names = kv_field_names(self.kv_quant)
-        fields = dict(zip(names, paged_write_prompt(
-            [getattr(cache, n) for n in names],
-            [getattr(mini, n) for n in names], table_row,
-        )))
-        fields["lengths"] = jax.lax.dynamic_update_slice(
-            cache.lengths, mini.lengths, (slot,)
-        )
-        cache = cache.replace(**fields)
         plp = (self._plp_within(logits, tokens) if want_plp
                else jnp.zeros((tokens.shape[1],), jnp.float32))
         tlv, tli = self._first_tl(last)
@@ -2794,6 +2792,7 @@ class PagedBatchingEngine(BatchingEngine):
         """
         from shellac_tpu.inference.engine import check_beam_constraint
 
+        self.cache_backend.check_feature("beam_search")
         k_beams = int(num_beams)
         steps = int(max_new_tokens)
         if k_beams < 1:

@@ -3,7 +3,7 @@
 `layout` holds the cache pytrees and their update/gather free functions
 (the former inference/kvcache.py, still importable there); `base`
 defines the CacheBackend interface the engines hold; `dense` / `paged`
-/ `rolling` implement the storage policies. This registry is the ONE
+/ `rolling` / `eva` implement the storage policies. This registry is the ONE
 name->backend mapping every consumer resolves through — the engines,
 the CLI's --cache-backend flag (and its deprecated legacy aliases
 --paged / --kv-quant / --rolling-window), and the tests — so a new
@@ -16,6 +16,7 @@ from typing import Optional
 
 from shellac_tpu.inference.cache.base import CacheBackend, PoolExhausted
 from shellac_tpu.inference.cache.dense import DenseBackend
+from shellac_tpu.inference.cache.eva import EvaBackend
 from shellac_tpu.inference.cache.paged import PagedBackend, QuantPagedBackend
 from shellac_tpu.inference.cache.rolling import RollingBackend
 
@@ -23,6 +24,7 @@ __all__ = [
     "BACKENDS",
     "CacheBackend",
     "DenseBackend",
+    "EvaBackend",
     "PagedBackend",
     "PoolExhausted",
     "QuantPagedBackend",
@@ -42,6 +44,7 @@ BACKENDS = {
     "paged-int8": (QuantPagedBackend, {}),
     "rolling": (RollingBackend, {}),
     "rolling-int8": (RollingBackend, {"kv_quant": "int8"}),
+    "eva": (EvaBackend, {}),
 }
 
 # What the legacy engine/CLI flags would have been for each name —
@@ -53,6 +56,9 @@ _FLAGS = {
     "paged-int8": (True, "int8", False),
     "rolling": (False, None, True),
     "rolling-int8": (False, "int8", True),
+    # Paged: its pooled rows live in a block pool behind tables, and the
+    # paged engine (block_size, pool_tokens) drives it.
+    "eva": (True, None, False),
 }
 
 

@@ -55,11 +55,23 @@ class CacheBackend:
     #: True for ring-buffer backends (the engines' rolling_window
     #: compatibility attribute derives from this).
     is_rolling: bool = False
+    #: True for the backend that holds EVA attention state (cfg.eva):
+    #: such a model serves on it and on nothing else, and it serves
+    #: nothing else.
+    holds_eva: bool = False
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int, *,
                  kv_quant: Optional[str] = None, chunk_slack: int = 1):
         if kv_quant not in (None, "int8"):
             raise ValueError(f"kv_quant={kv_quant!r}; have None, 'int8'")
+        if (cfg.eva is not None) != self.holds_eva:
+            raise ValueError(
+                "a model with EVA attention (cfg.eva) keeps a ring of exact "
+                "rows and a pool of pooled rows, and serves on the 'eva' "
+                f"cache backend and no other; the {self.name!r} backend "
+                + ("holds no such state" if cfg.eva is not None
+                   else "holds nothing else (this model has no cfg.eva)")
+            )
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -127,6 +139,21 @@ class CacheBackend:
     def initial_stats(self) -> Dict[str, int]:
         """Backend-owned counters merged into engine.stats at
         construction (paged prefix caching adds its hit counters)."""
+        return {}
+
+    def check_feature(self, feature: str) -> None:
+        """Raise ValueError if this storage cannot carry `feature`
+        ("pp_pipeline", "chunked_prefill", "park_resume", "kv_export",
+        ...). Called where a switch that needs the feature is turned
+        on, so the refusal comes at construction and not mid-request.
+        The base policy refuses nothing here: each feature's own gate
+        (validate_pp_pipeline, disagg._check_exportable) still holds."""
+
+    def window_counts(self, pairs, n_valid) -> Dict[str, int]:
+        """Backend-owned work counts of one synced decode window
+        (`pairs`: the (slot, request) rows it ran; `n_valid[slot]`: the
+        ticks that produced a token), added to the step record. Host
+        arithmetic on lengths already known; never a device read."""
         return {}
 
     def prefix_manifest(self, since: int = -1, **_: Any) -> Dict[str, Any]:

@@ -21,6 +21,7 @@ ids stay continuous per sequence and pads are never attended.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import flax.struct
@@ -133,7 +134,16 @@ def init_cache_for(cfg: ModelConfig, batch: int, max_len: int,
                    kv_quant=None, rolling: bool = False,
                    chunk_slack: int = 1):
     """The engines' cache constructor: dense bf16, int8, or a rolling
-    ring buffer (sliding-window models) by flags."""
+    ring buffer (sliding-window models) by flags; an EVA model's slot
+    cache is its ring-plus-pool state with every slot's pages its own."""
+    if cfg.eva is not None:
+        if rolling or kv_quant is not None:
+            raise ValueError(
+                "EVA models keep their own state (a ring of exact rows "
+                "and a pool of pooled rows); rolling / kv_quant do not "
+                "apply"
+            )
+        return init_eva_slot_cache(cfg, batch, max_len)
     if rolling:
         if kv_quant is not None and kv_quant != "int8":
             raise ValueError(f"kv_quant={kv_quant!r}; have None, 'int8'")
@@ -163,6 +173,8 @@ def cache_logical_axes_for(cfg: ModelConfig, kv_quant=None,
     """Logical axes matching what init_cache_for builds for the same
     flags — the single place the cache-kind dispatch lives, so jit
     out_shardings can never desync from the cache pytree."""
+    if cfg.eva is not None:
+        return eva_cache_logical_axes(cfg)
     if rolling:
         patterned = (cfg.attn_pattern is not None
                      and "full" in cfg.attn_pattern)
@@ -366,7 +378,7 @@ def init_paged_cache(
     )
 
 
-def paged_write(pools, news, index, tables):
+def paged_write(pools, news, index, tables, layer=None, only=None):
     """Write S new positions a slot through the block tables, in place.
 
     pools: (N, Hkv, bs[, D]) each — one layer's pool, or the stacked
@@ -389,16 +401,40 @@ def paged_write(pools, news, index, tables):
     offset), a bare row update, or rows arriving token-major each made
     it move the WHOLE pool head-innermost on entry and back on exit,
     every call, with a second pool of temporaries (PERF.md, PR 26;
-    tests/test_aot_compile.py asks that compiler)."""
-    bs = pools[0].shape[2]
+    tests/test_aot_compile.py asks that compiler).
+
+    With `layer` the pools are a layer STACK held head-outermost,
+    (L, Hkv, N, bs[, D]) (EvaKVCache's pooled rows, which every slot
+    reads with the heads as the batch of one matmul), `tables` index one
+    layer's blocks, and the same pieces land in that layer. There a
+    single row goes in as the 16-row group it lies in (a whole tile of
+    the device's layout), read and merged like a page piece: a bare
+    one-row update of this stack made the TPU's compiler hold the whole
+    stack head-innermost through the program, constraint or none, with
+    a copy in and out and one of each layer's slice for every reader
+    (PERF.md, PR 27).
+
+    `only` (B,) bool names the slots that write at all; the loop then
+    visits those alone (a chunk's pooled row is due from one slot in
+    sixteen a tick, and a visit that merges into a tile group costs
+    ~50 us on the chip)."""
+    stacked = layer is not None
+    bs = pools[0].shape[3 if stacked else 2]
     b, s = news[0].shape[0], news[0].shape[2]
     last = tables.shape[1] - 1
-    # A piece is a whole page, or the row itself where a slot writes one.
-    h = bs if s > 1 else 1
+    # A piece is a whole page, or the row itself where a slot writes one
+    # (the row's tile group in a head-outermost stack).
+    h = bs if s > 1 else (math.gcd(bs, 16) if stacked else 1)
     n_pieces = (s + h - 2) // h + 1
+
+    if only is not None:
+        order = jnp.argsort(~only, stable=True)  # the writers first
+        b = jnp.sum(only.astype(jnp.int32))
 
     def piece(i, pools):
         slot, j = i // n_pieces, i % n_pieces
+        if only is not None:
+            slot = order[slot]
         start = index[slot]
         first = (start // h + j) * h  # the piece's first position
         page = first // bs
@@ -410,26 +446,37 @@ def paged_write(pools, news, index, tables):
         held = (row >= 0) & (row < s)
         out = []
         for pool, run in zip(pools, news):
-            at = (block, 0, first % bs) + (0,) * (pool.ndim - 3)
-            old = jax.lax.dynamic_slice(
-                pool, at, (1, pool.shape[1], h, *pool.shape[3:])
-            )
+            if stacked:
+                at = (layer, 0, block, first % bs) + (0,) * (pool.ndim - 4)
+                size = (1, pool.shape[1], 1, h, *pool.shape[4:])
+            else:
+                at = (block, 0, first % bs) + (0,) * (pool.ndim - 3)
+                size = (1, pool.shape[1], h, *pool.shape[3:])
+            old = jax.lax.dynamic_slice(pool, at, size)
             new = jnp.take(
                 jax.lax.dynamic_index_in_dim(run, slot, 0, keepdims=True),
                 jnp.clip(row, 0, s - 1), axis=2,
             )  # (1, Hkv, h[, D])
-            mask = held.reshape(1, 1, h, *(1,) * (pool.ndim - 3))
+            mask = held.reshape(1, 1, h, *(1,) * (new.ndim - 3))
+            if stacked:
+                new, mask = new[:, :, None], mask[:, :, None]
             out.append(jax.lax.dynamic_update_slice(
                 pool, jnp.where(mask, new, old), at
             ))
         return tuple(out)
 
-    held_as = tuple(
-        with_layout_constraint(
+    def held(p):
+        return with_layout_constraint(
             p, Layout(major_to_minor=tuple(range(p.ndim)))
-        ) for p in pools
+        )
+
+    out = jax.lax.fori_loop(
+        0, b * n_pieces, piece, tuple(held(p) for p in pools)
     )
-    return jax.lax.fori_loop(0, b * n_pieces, piece, held_as)
+    # A stack is pinned where it leaves the loop too: with the entry
+    # alone pinned the TPU's compiler still carried one of two stacks
+    # written side by side in a layout of its own.
+    return tuple(held(p) for p in out) if stacked else out
 
 
 def paged_write_prompt(pools, minis, table_row):
@@ -585,6 +632,129 @@ def paged_gather_scales(
     x = jnp.take(pool_s, tables.reshape(-1), axis=0)  # (B*mb, Hkv, bs)
     x = x.reshape(b, mb, hkv, bs).transpose(0, 2, 1, 3)
     return x.reshape(b, hkv, mb * bs)
+
+
+# ---------------------------------------------------------------------------
+# EVA state: a ring of exact rows and a paged pool of pooled rows
+# ---------------------------------------------------------------------------
+
+
+@flax.struct.dataclass
+class EvaKVCache:
+    """The decode state of EVA attention (cfg.eva; ops/eva_attention.py):
+    two kinds of state for one slot.
+
+    k, v: (L, W, n_slots, H, Dh) — a ring of the W = cfg.eva.window
+        exact rows of the slot's CURRENT window, position p at ring row
+        p % W; rows 0 .. (lengths - 1) % W hold the window so far, the
+        rest are the window before and are never read (a window attends
+        none of its predecessor's exact rows). Position-outermost: a
+        slot's new row is one contiguous (H, Dh) slab, and a tick reads
+        a layer's rings as one matrix-vector product a slot and head,
+        which the TPU's compiler runs on the vector unit and wants
+        row-outermost (held any other way it copied every layer's rings
+        to that layout, every tick: PERF.md, PR 27).
+    pk, pv: (L, H, n_blocks, R, Dh) — a paged pool of pooled rows, one
+        row a chunk of cfg.eva.chunk positions, R = W // chunk rows a
+        page, so a page is one window and chunk c of a slot lives at
+        (tables[slot, c // R], c % R). A chunk's row is written in the
+        tick that completes the chunk; a window's page is read only once
+        the window is complete. Block 0 is scratch, as in PagedKVCache.
+        Head-outermost: every slot is scored against every page in one
+        matmul whose batch is the heads (ops/eva_attention.py), and the
+        pool is read where it lies.
+    tables: (n_slots, max_blocks) int32 — pool block per window.
+    lengths: (n_slots,) int32 — positions seen.
+    slots: (n_slots,) int32 — which ring each batch row is; arange for
+        the engine's cache, [slot] for the batch-1 view a prefill writes
+        through.
+
+    Both kinds ride forward_with_cache's layer loop whole, as carries,
+    as the paged pool does: a layer writes its rows in place
+    (eva_ring_write; paged_write with `layer`) and reads its own slice of
+    the stack, and nothing is sliced out and restacked.
+    """
+
+    k: Any
+    v: Any
+    pk: Any
+    pv: Any
+    tables: Any
+    lengths: Any
+    slots: Any
+
+    @property
+    def block_size(self) -> int:
+        """Positions a page stands for (one window)."""
+        return self.k.shape[1]
+
+    @property
+    def max_blocks(self) -> int:
+        return self.tables.shape[1]
+
+
+def init_eva_cache(cfg: ModelConfig, n_slots: int, n_blocks: int,
+                   max_blocks_per_slot: int, tables=None) -> EvaKVCache:
+    e = cfg.eva
+    ring = (cfg.n_layers, e.window, n_slots, cfg.n_heads, cfg.dim_per_head)
+    pool = (cfg.n_layers, cfg.n_heads, n_blocks, e.window // e.chunk,
+            cfg.dim_per_head)
+    dt = cfg.compute_dtype
+    if tables is None:
+        tables = jnp.zeros((n_slots, max_blocks_per_slot), jnp.int32)
+    return EvaKVCache(
+        k=jnp.zeros(ring, dt), v=jnp.zeros(ring, dt),
+        pk=jnp.zeros(pool, dt), pv=jnp.zeros(pool, dt),
+        tables=tables,
+        lengths=jnp.zeros((n_slots,), jnp.int32),
+        slots=jnp.arange(n_slots, dtype=jnp.int32),
+    )
+
+
+def init_eva_slot_cache(cfg: ModelConfig, batch: int,
+                        max_len: int) -> EvaKVCache:
+    """The same state with nothing to allocate: every row owns the
+    pages its max_len needs (the single-request Engine's cache)."""
+    mb = -(-max_len // cfg.eva.window)
+    tables = 1 + jnp.arange(batch * mb, dtype=jnp.int32).reshape(batch, mb)
+    return init_eva_cache(cfg, batch, batch * mb + 1, mb, tables=tables)
+
+
+def eva_cache_logical_axes(cfg: Optional[ModelConfig] = None):
+    return EvaKVCache(
+        k=("layers", None, None, "kv_heads", None),
+        v=("layers", None, None, "kv_heads", None),
+        pk=("layers", "kv_heads", None, None, None),
+        pv=("layers", "kv_heads", None, None, None),
+        tables=(None, None), lengths=(None,), slots=(None,),
+    )
+
+
+def eva_ring_write(rings, rows, layer, slots, at):
+    """Write each slot's new exact rows into its ring, in place.
+
+    rings: (L, W, n_slots, H, D) each, the layer stack; rows: (B, S, H,
+    D) each, S = 1 (a decode tick's row) or W (a prefilled window);
+    rows[b] land at ring rows at[b] .. at[b] + S - 1 of ring slots[b] in
+    `layer`. A loop of one dynamic_update_slice a slot, under the
+    layout constraint that pins the stack as it is held (paged_write's
+    docstring has why)."""
+    def one(b, rings):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                ring,
+                jax.lax.dynamic_index_in_dim(new, b, 0, keepdims=False)[None, :, None]
+                .astype(ring.dtype),
+                (layer, at[b], slots[b], 0, 0),
+            ) for ring, new in zip(rings, rows)
+        )
+
+    held_as = tuple(
+        with_layout_constraint(
+            r, Layout(major_to_minor=tuple(range(r.ndim)))
+        ) for r in rings
+    )
+    return jax.lax.fori_loop(0, rows[0].shape[0], one, held_as)
 
 
 # ---------------------------------------------------------------------------

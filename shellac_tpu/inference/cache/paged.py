@@ -27,6 +27,7 @@ import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -37,7 +38,9 @@ from shellac_tpu.inference.cache.layout import (
     init_cache_for,
     init_paged_cache,
     init_quant_paged_cache,
+    kv_field_names,
     paged_cache_logical_axes,
+    paged_write_prompt,
     quant_paged_cache_logical_axes,
 )
 
@@ -130,6 +133,31 @@ class PagedBackend(CacheBackend):
         if self.kv_quant == "int8":
             return quant_paged_cache_logical_axes(self.cfg)
         return paged_cache_logical_axes(self.cfg)
+
+    @staticmethod
+    def default_block_size(cfg: ModelConfig) -> int:
+        """Tokens a page when the engine is given no block_size."""
+        return 16
+
+    def prefill_into(self, cache, slot, length: int, forward):
+        """Inside the engine's prefill program: run `forward(scratch) ->
+        (logits, scratch)` for one prompt padded to `length` and leave
+        its rows in `slot`'s pages. Here: a dense mini cache of the
+        pool's kind (bf16, or int8 + scales: the quant mini already
+        quantized at write, K post-rope, and its scales go through the
+        same pages as its values), then paged_write_prompt through the
+        slot's table row. Returns (logits, cache)."""
+        logits, mini = forward(self.init_mini(length))
+        table_row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)[0]
+        names = kv_field_names(self.kv_quant)
+        fields = dict(zip(names, paged_write_prompt(
+            [getattr(cache, n) for n in names],
+            [getattr(mini, n) for n in names], table_row,
+        )))
+        fields["lengths"] = jax.lax.dynamic_update_slice(
+            cache.lengths, mini.lengths, (slot,)
+        )
+        return logits, cache.replace(**fields)
 
     # ---- allocator ---------------------------------------------------
 
@@ -498,6 +526,11 @@ class QuantPagedBackend(PagedBackend):
     list, prefix refcounts, and tables need no changes."""
 
     name = "paged-int8"
+
+    @staticmethod
+    def default_block_size(cfg: ModelConfig) -> int:
+        # The grouped-gather kernel's scale DMA needs 128-token pages.
+        return INT8_BLOCK_SIZE_DEFAULT
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int, *,
                  kv_quant: Optional[str] = "int8",

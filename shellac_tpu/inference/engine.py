@@ -289,6 +289,11 @@ class Engine:
         PagedBatchingEngine.beam_search, which reorders via
         copy-on-write block tables and returns bit-identical beams.
         """
+        if self.cfg.eva is not None:
+            raise NotImplementedError(
+                "beam search reorders dense cache rows by beam; EVA state "
+                "(a ring and pooled pages a row) has no such reorder yet"
+            )
         if num_beams < 1:
             raise ValueError("num_beams must be >= 1")
         if max_new_tokens < 1:

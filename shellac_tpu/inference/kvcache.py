@@ -11,8 +11,12 @@ from shellac_tpu.inference.cache.layout import *  # noqa: F401,F403
 from shellac_tpu.inference.cache.layout import (  # noqa: F401
     cache_logical_axes,
     cache_logical_axes_for,
+    eva_cache_logical_axes,
+    eva_ring_write,
     init_cache,
     init_cache_for,
+    init_eva_cache,
+    init_eva_slot_cache,
     init_paged_cache,
     init_quant_cache,
     init_quant_paged_cache,

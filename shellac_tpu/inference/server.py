@@ -420,6 +420,15 @@ class InferenceServer:
                 engine_factory = functools.partial(
                     BatchingEngine, cfg, params, **engine_kw
                 )
+        # Switches that move a slot's state off this engine are refused
+        # here, at construction, by a cache backend that cannot ship
+        # its state (the 'eva' backend; base backends refuse nothing).
+        backend = getattr(engine, "cache_backend", None)
+        if backend is not None:
+            if park_dir or preempt_after is not None:
+                backend.check_feature("park_resume")
+            if role != "monolith":
+                backend.check_feature("kv_export")
         self.model_name = model_name
         self.tokenizer = tokenizer
         self._constraint_cache: "OrderedDict[str, Any]" = OrderedDict()

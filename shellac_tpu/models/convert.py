@@ -49,6 +49,8 @@ def config_from_hf(hf_cfg) -> ModelConfig:
         return _gemma3_config(hf_cfg)
     if getattr(hf_cfg, "model_type", "") == "gpt_oss":
         return _gptoss_config(hf_cfg)
+    if getattr(hf_cfg, "model_type", "") == "evabyte":
+        return _evabyte_config(hf_cfg)
     moe = None
     if getattr(hf_cfg, "num_local_experts", None):
         moe = MoEConfig(
@@ -118,6 +120,44 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             getattr(hf_cfg, "rope_scaling", None),
             hf_cfg.max_position_embeddings,
         ),
+    ).validate()
+
+
+def _evabyte_config(hf_cfg) -> ModelConfig:
+    """EvaByte: EVA attention (an exact window plus one pooled row a
+    chunk), multi-byte prediction heads, a float32 residual stream, and
+    RMSNorm in the (1 + w) form ours uses (norm_add_unit_offset, as
+    Gemma: see _norm_offset)."""
+    from shellac_tpu.config import EvaConfig
+
+    if getattr(hf_cfg, "attention_class", "eva") != "eva":
+        raise NotImplementedError(
+            f"evabyte attention_class={hf_cfg.attention_class!r}; have 'eva'"
+        )
+    if not getattr(hf_cfg, "norm_add_unit_offset", False):
+        raise NotImplementedError(
+            "evabyte without norm_add_unit_offset is not representable "
+            "(our RMSNorm multiplies by 1 + w)"
+        )
+    if getattr(hf_cfg, "rope_scaling", None) is not None:
+        raise NotImplementedError("evabyte with rope_scaling")
+    if getattr(hf_cfg, "attention_bias", False):
+        raise NotImplementedError("evabyte with attention_bias")
+    n_heads = hf_cfg.num_attention_heads
+    return ModelConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=n_heads,
+        n_kv_heads=getattr(hf_cfg, "num_key_value_heads", None) or n_heads,
+        d_ff=hf_cfg.intermediate_size,
+        max_seq_len=hf_cfg.max_position_embeddings,
+        rope_theta=float(getattr(hf_cfg, "rope_theta", 10000.0)),
+        norm_eps=hf_cfg.rms_norm_eps,
+        tie_embeddings=bool(getattr(hf_cfg, "tie_word_embeddings", False)),
+        eva=EvaConfig(window=hf_cfg.window_size, chunk=hf_cfg.chunk_size),
+        n_pred_heads=getattr(hf_cfg, "num_pred_heads", 1),
+        fp32_residual=bool(getattr(hf_cfg, "fp32_skip_add", False)),
     ).validate()
 
 
@@ -531,8 +571,9 @@ def _norm_offset(hf_cfg) -> float:
     The Gemma family stores (1 + w) semantics natively -> s = w.
     """
     gemma_family = ("gemma", "gemma2", "gemma3", "gemma3_text")
-    return (0.0 if getattr(hf_cfg, "model_type", "") in gemma_family
-            else -1.0)
+    unit_offset = (getattr(hf_cfg, "model_type", "") in gemma_family
+                   or getattr(hf_cfg, "norm_add_unit_offset", False))
+    return 0.0 if unit_offset else -1.0
 
 
 def _to_np(t) -> np.ndarray:

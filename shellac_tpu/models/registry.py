@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from shellac_tpu.config import MLAConfig, ModelConfig, MoEConfig
+from shellac_tpu.config import EvaConfig, MLAConfig, ModelConfig, MoEConfig
 
 # fmt: off
 PRESETS = {
@@ -72,6 +72,14 @@ PRESETS = {
                             mla=MLAConfig(kv_lora_rank=32, q_lora_rank=24,
                                           qk_nope_head_dim=16,
                                           qk_rope_head_dim=8, v_head_dim=16)),
+    # EvaByte in miniature: EVA attention (an exact 32-position window
+    # plus one pooled row per 4 positions), 3 byte-prediction heads, a
+    # float32 residual stream. Serves on the "eva" cache backend.
+    "tiny-eva": ModelConfig(vocab_size=256, d_model=64, n_layers=2,
+                            n_heads=4, max_seq_len=256, remat=False,
+                            tie_embeddings=False,
+                            eva=EvaConfig(window=32, chunk=4),
+                            n_pred_heads=3, fp32_residual=True),
     # The full DeepSeek-V2 shape in miniature: MLA + first-k-dense +
     # narrow routed experts + a shared expert, un-normalized scaled
     # top-k routing.

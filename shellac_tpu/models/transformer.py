@@ -173,6 +173,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                     "q_norm": jnp.zeros((dh,), pdt),
                     "k_norm": jnp.zeros((dh,), pdt),
                 })
+            if cfg.eva is not None:
+                # The pooling's two learned vectors a head. Their
+                # published initial law is not known; N(0, 1) and
+                # N(0, 0.5^2) spread the pooling weights and offset the
+                # pooled keys enough to matter from the first step.
+                kp = jax.random.split(ks[7], 2)
+                p.update({
+                    "eva_phi": dense(kp[0], (h, dh), 1),
+                    "eva_mu": dense(kp[1], (h, dh), 1, 0.5),
+                })
         if cfg.attn_bias:
             p.update({
                 "bq": jnp.zeros((h * dh,), pdt),
@@ -250,7 +260,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         "final_norm": jnp.zeros((d,), pdt),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense(k_head, (d, cfg.vocab_size), d)
+        # Head m of n_pred_heads holds columns [m V, (m + 1) V).
+        params["lm_head"] = dense(
+            k_head, (d, cfg.n_pred_heads * cfg.vocab_size), d
+        )
     return params
 
 
@@ -320,6 +333,11 @@ def _layer_axes(cfg: ModelConfig, moe_layer: bool, lead=("layers",)) -> dict:
             attn_axes.update({
                 "q_norm": (*lead, None),
                 "k_norm": (*lead, None),
+            })
+        if cfg.eva is not None:
+            attn_axes.update({
+                "eva_phi": (*lead, "heads", None),
+                "eva_mu": (*lead, "heads", None),
             })
     post_axes = {}
     if cfg.post_norms:
@@ -399,6 +417,11 @@ def _embed_tokens(cfg: ModelConfig, params: Params, tokens, cdt, mesh=None):
         # Gemma convention; the scale is computed in the compute dtype
         # (HF casts the normalizer to the embedding dtype too).
         x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
+    if cfg.fp32_residual:
+        # The stream between blocks stays float32: every block adds its
+        # compute-dtype branch outputs into it (jnp promotes the sum),
+        # and every norm reads it and hands the compute dtype on.
+        x = x.astype(jnp.float32)
     return x
 
 
@@ -469,6 +492,15 @@ def _block(
             if cfg.post_norms:
                 o = rms_norm(o, lp["post_attn_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
             x = x + constrain(o, mesh, ("batch", "seq", None))
+        return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
+                          moe_layer, new_cache)
+    if cfg.eva is not None:
+        o, new_cache = _eva_attention(
+            cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache, pdot,
+            eva_tables=page_tables, new_len=new_len,
+        )
+        with jax.named_scope("attn.out"):
+            x = x + constrain(pdot(o, lp["wo"]), mesh, ("batch", "seq", None))
         return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                           moe_layer, new_cache)
     with jax.named_scope("attn.qkv"):
@@ -973,6 +1005,138 @@ def _mla_attention(
     return o.reshape(b, s, h * m.v_head_dim), new_cache
 
 
+def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
+                   pdot, eva_tables=None, new_len=None):
+    """EVA attention (cfg.eva; ops/eva_attention.py has the equations).
+    hx: (B, S, D) normed input. Returns (o (B, S, H * Dh), new_cache).
+
+    Without a cache, and for a fresh prefill, the sequence starts at
+    position 0 and attends within itself: the pooled rows of all its
+    chunks are made once (`eva.pool`) and every query block reads its
+    own window's exact rows beside them (`eva.attend`).
+
+    With `cache=((ring_k, ring_v), (pool_k, pool_v), index, _)`, the
+    whole layer stacks (layout.EvaKVCache), and `eva_tables` saying
+    which layer, rings and pages are this call's:
+
+    * fresh prefill leaves what the same number of decode ticks would
+      have: the last, partial window's exact rows in the ring (the whole
+      ring is written; rows past the prompt are masked by position until
+      decode overwrites them) and the pooled rows of every chunk through
+      the slot's table (`eva.summary_write`; rows of chunks the prompt
+      did not complete are rewritten when they do complete);
+    * a decode tick writes its one exact row at position % W, pools the
+      chunk that row belongs to from the ring, and writes that pooled
+      row through the table if the row completed the chunk, then
+      attends its ring rows 0 .. p % W
+      and the pages of its completed windows in one softmax.
+    """
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    from shellac_tpu.inference.kvcache import eva_ring_write, paged_write
+    from shellac_tpu.ops.eva_attention import (
+        eva_attention,
+        eva_decode_attention,
+        eva_pool,
+        eva_pool_sequence,
+    )
+
+    e = cfg.eva
+    b, s, _ = hx.shape
+    h, dh = cfg.n_heads, cfg.dim_per_head
+    scale = dh ** -0.5
+    with jax.named_scope("attn.qkv"):
+        q = pdot(hx, lp["wq"]).reshape(b, s, h, dh)
+        k = pdot(hx, lp["wk"]).reshape(b, s, h, dh)
+        v = pdot(hx, lp["wv"]).reshape(b, s, h, dh)
+    q = apply_rope(q, cos, sin)
+    k = apply_rope(k, cos, sin)
+    phi, mu = lp["eva_phi"], lp["eva_mu"]
+
+    if cache is None or fresh_cache:
+        with jax.named_scope("eva.pool"):
+            kp, vp = eva_pool_sequence(k, v, phi, mu, e.chunk, scale)
+        with jax.named_scope("eva.attend"):
+            o = eva_attention(q, k, v, kp, vp, window=e.window,
+                              chunk=e.chunk, scale=scale, impl=attn_impl)
+        if cache is None:
+            return o.reshape(b, s, h * dh), None
+        ring, pool, _, _ = cache
+        layer = eva_tables["layer"]
+        n = jnp.full((b,), s, jnp.int32) if new_len is None else new_len
+        # The ring: the window the prompt ends in, from its start.
+        padded = -(-s // e.window) * e.window
+        pad = ((0, 0), (0, padded - s), (0, 0), (0, 0))
+        start = (n // e.window) * e.window
+
+        def last_window(a):
+            return jax.vmap(
+                lambda a_b, st: jax.lax.dynamic_slice_in_dim(
+                    a_b, st, e.window, axis=0)
+            )(jnp.pad(a, pad), start)
+
+        zero = jnp.zeros((b,), jnp.int32)
+        with jax.named_scope("kv.write"):
+            ring = eva_ring_write(ring, (last_window(k), last_window(v)),
+                                  layer, eva_tables["slots"], zero)
+        with jax.named_scope("eva.summary_write"):
+            pool = paged_write(
+                pool,
+                (kp.astype(pool[0].dtype).transpose(0, 2, 1, 3),
+                 vp.astype(pool[1].dtype).transpose(0, 2, 1, 3)),
+                zero, eva_tables["tables"], layer=layer,
+            )
+        return o.reshape(b, s, h * dh), (ring, pool)
+
+    if s != 1:
+        raise NotImplementedError(
+            "EVA state continues one row at a time: a cached chunk of "
+            f"{s} rows (chunked prefill, a speculative verify window) "
+            "would need the pooled rows of chunks that complete inside "
+            "it"
+        )
+    ring, pool, index, _ = cache
+    layer = eva_tables["layer"]
+    at = index % e.window  # the new row's place in the ring
+    with jax.named_scope("kv.write"):
+        ring = eva_ring_write(ring, (k, v), layer, eva_tables["slots"], at)
+
+    # This layer's rings and pages, read where they lie: the layout
+    # constraint keeps the slice in the stack's own layout (left alone,
+    # the TPU's compiler may suit a layout to a reader and copy the
+    # stack to get it: PERF.md, PR 27).
+    def of_layer(a):
+        return with_layout_constraint(
+            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            Layout(major_to_minor=tuple(range(a.ndim - 1))),
+        )
+
+    rk, rv = of_layer(ring[0]), of_layer(ring[1])  # (W, B, H, D)
+    with jax.named_scope("eva.pool"):
+        # The chunk's rows, (B, C, H, D): a gather of B x C slabs out of
+        # the stack itself. (A vmapped slice of the layer's rings moved
+        # their slot axis first, and a gather from the layer's slice
+        # made the slice real: either way every ring of the layer was
+        # copied, 2.4 ms a layer on the chip, half the tick.)
+        rows = ((at // e.chunk) * e.chunk)[:, None] + jnp.arange(
+            e.chunk, dtype=jnp.int32)
+        cols = eva_tables["slots"][:, None]
+        kp, vp = eva_pool(ring[0][layer, rows, cols],
+                          ring[1][layer, rows, cols], phi, mu, scale)
+    with jax.named_scope("eva.summary_write"):
+        pool = paged_write(
+            pool, (kp[:, :, None, :], vp[:, :, None, :]), index // e.chunk,
+            eva_tables["tables"], layer=layer,
+            only=(index % e.chunk) == e.chunk - 1,
+        )
+    with jax.named_scope("eva.attend"):
+        o = eva_decode_attention(
+            q[:, 0], rk, rv, at + 1, of_layer(pool[0]), of_layer(pool[1]),
+            eva_tables["owned"], scale=scale,
+        )
+    return o.reshape(b, 1, h * dh), (ring, pool)
+
+
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
     """Per-segment position ids: restart at 0 on every segment change.
 
@@ -1001,7 +1165,9 @@ def forward(
     return_aux: bool = False,
     return_hidden: bool = False,
 ) -> jax.Array:
-    """Full forward pass; returns fp32 logits (B, S, V).
+    """Full forward pass; returns fp32 logits (B, S, V), or
+    (B, S, n_pred_heads, V) for a model with several prediction heads
+    (head m scores the token m + 1 ahead).
 
     With return_hidden=True, skips the LM head and returns the
     post-final-norm hidden states (B, S, D) in compute dtype instead of
@@ -1019,6 +1185,13 @@ def forward(
     """
     cdt = cfg.compute_dtype
     b, s = tokens.shape
+    if cfg.eva is not None and (positions is not None
+                                or segment_ids is not None):
+        raise NotImplementedError(
+            "EVA attention windows and chunks count from position 0 of "
+            "one sequence per row: explicit positions and packed "
+            "segments are not defined for it"
+        )
     pos = positions
     if pos is None:
         if segment_ids is not None:
@@ -1060,6 +1233,8 @@ def forward(
     from shellac_tpu.parallel.mesh import AXIS_PIPE
 
     pp = mesh.shape.get(AXIS_PIPE, 1) if mesh is not None else 1
+    if pp > 1 and cfg.eva is not None:
+        raise NotImplementedError("pp over EVA attention is not wired yet")
     if pp > 1:
         from shellac_tpu.parallel.pipeline import pipeline_apply
 
@@ -1348,15 +1523,17 @@ def forward(
         if return_aux:
             return x, aux
         return x
-    logits = unembed(cfg, params, x, mesh=mesh)
-    logits = constrain(logits, mesh, ("batch", "seq", "vocab"))
+    logits = unembed(cfg, params, x, mesh=mesh, all_heads=True)
+    if cfg.n_pred_heads == 1:
+        logits = constrain(logits, mesh, ("batch", "seq", "vocab"))
     if return_aux:
         return logits, aux
     return logits
 
 
 def output_weights(cfg: ModelConfig, params: Params, cdt) -> jax.Array:
-    """The LM-head matrix (D, V) in compute dtype (tied or untied)."""
+    """The LM-head matrix (D, n_pred_heads * V) in compute dtype (tied
+    or untied)."""
     if cfg.tie_embeddings:
         return params["embed"].astype(cdt).T
     return params["lm_head"].astype(cdt)
@@ -1364,21 +1541,30 @@ def output_weights(cfg: ModelConfig, params: Params, cdt) -> jax.Array:
 
 @jax.named_scope("unembed")
 def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
-            mesh=None) -> jax.Array:
+            mesh=None, all_heads: bool = False) -> jax.Array:
     """Final RMSNorm + output projection (+ logit softcap): the model
     tail shared by forward, forward_with_cache, and the pipelined
     decode's per-group exit (inference/pp_pipeline.py), so a head
     change cannot drift between them. x: (B, S, D) pre-final-norm
     hidden; returns fp32 (B, S, V) logits. Callers own any mesh
-    constraint on the result."""
+    constraint on the result.
+
+    A model with n_pred_heads > 1 unembeds head 0, the next token,
+    which is all that cached generation reads; `all_heads` (forward:
+    scoring, training) returns (B, S, n_pred_heads, V)."""
     cdt = cfg.compute_dtype
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+    w = output_weights(cfg, params, cdt)
+    if cfg.n_pred_heads > 1 and not all_heads:
+        w = w[:, :cfg.vocab_size]
     logits = jnp.einsum(
-        "bsd,dv->bsv", x, output_weights(cfg, params, cdt),
-        preferred_element_type=jnp.float32,
+        "bsd,dv->bsv", x, w, preferred_element_type=jnp.float32,
     )
     if cfg.logit_softcap is not None:
         logits = softcap(logits, cfg.logit_softcap)
+    if cfg.n_pred_heads > 1 and all_heads:
+        logits = logits.reshape(*logits.shape[:2], cfg.n_pred_heads,
+                                cfg.vocab_size)
     return logits
 
 
@@ -1445,6 +1631,7 @@ def forward_with_cache(
     not rectangular, and flash-eligible via attn_impl="auto".
     """
     from shellac_tpu.inference.kvcache import (
+        EvaKVCache,
         PagedKVCache,
         PatternedKVCache,
         QuantKVCache,
@@ -1458,6 +1645,13 @@ def forward_with_cache(
     if not cfg.causal:
         raise ValueError(
             "KV-cache generation requires a causal model (cfg.causal=True)"
+        )
+    eva = isinstance(cache, EvaKVCache)
+    if eva != (cfg.eva is not None):
+        raise ValueError(
+            "an EVA model (cfg.eva) keeps EVA state and nothing else "
+            "does: serve it on the 'eva' cache backend (inference/cache), "
+            f"not with a {type(cache).__name__}"
         )
     paged = isinstance(cache, (PagedKVCache, QuantPagedKVCache))
     quant = isinstance(
@@ -1513,6 +1707,8 @@ def forward_with_cache(
     # field-count parameterization the mixed branch uses.
     if mixed or quant_mixed:
         names = ()  # mixed caches carry kw/vw/kf/vf, named in their branch
+    elif eva:
+        names = ("k", "v", "pk", "pv")
     else:
         names = kv_field_names("int8" if quant else None)
     cleaves = tuple(getattr(cache, n) for n in names)
@@ -1520,7 +1716,40 @@ def forward_with_cache(
     def _scales_of(vals):
         return (vals[2], vals[3]) if quant else None
 
-    if paged:
+    if eva:
+        # Both kinds of EVA state ride the layer loop whole, as carries,
+        # as the paged pool does below; a layer writes and reads its own
+        # slice of each stack. Which pages a row attends is the same in
+        # every layer: those of its completed windows.
+        n_blocks = cache.pk.shape[2]
+        done = index // cfg.eva.window  # completed windows a row
+        owned = jnp.any(
+            (cache.tables[:, :, None]
+             == jnp.arange(n_blocks, dtype=jnp.int32))
+            & (jnp.arange(cache.max_blocks, dtype=jnp.int32)[None, :, None]
+               < done[:, None, None]),
+            axis=1,
+        )
+
+        def eva_body(carry, inp):
+            x, ring, pool = carry
+            lp, li = inp
+            x, (ring, pool), _ = _block(
+                cfg, mesh, attn_impl, x, lp, cos, sin,
+                cache=(ring, pool, index, positions),
+                fresh_cache=fresh_cache, new_len=new_tokens_len,
+                page_tables={"layer": li, "slots": cache.slots,
+                             "tables": cache.tables, "owned": owned},
+            )
+            return (x, ring, pool), None
+
+        (x, ring, pool), _ = jax.lax.scan(
+            eva_body, (x, cleaves[:2], cleaves[2:]),
+            (params["layers"],
+             jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        )
+        news = ring + pool
+    elif paged:
         # A paged pool rides the layer loops as a CARRY, never as xs/ys:
         # the stacked (L, n_blocks, ...) pools are viewed as
         # (L * n_blocks, ...) (a bitcast) and each block writes its rows

@@ -86,10 +86,15 @@ SPAN_PHASE = {
 #: The work counts every step record carries (see docs/observability.md
 #: "Step spans"). The first five are taken where the number is known;
 #: `compiles`/`compile_s` are what the process-wide compile listener
-#: added to the registry since the previous record.
+#: added to the registry since the previous record; the last two are
+#: the cache backend's (`CacheBackend.window_counts`; 0 but on the 'eva'
+#: backend): over every slot-tick of a synced window that produced a
+#: token, the exact rows of its own window and the pooled rows of
+#: earlier windows that its query attended, from lengths the host has.
 STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
-               "prefill_padded_tokens", "compiles", "compile_s")
+               "prefill_padded_tokens", "compiles", "compile_s",
+               "eva_window_rows", "eva_summary_rows")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:

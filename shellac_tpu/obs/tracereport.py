@@ -91,6 +91,7 @@ DEVICE_SCOPES = (
     "embed", "norm", "attn.qkv", "attn.rope", "kv.write", "kv.gather",
     "attn.core", "attn.out", "mla.absorb", "mla.latent_write", "mlp",
     "moe.route", "moe.sort", "moe.gemm", "moe.combine", "moe.shared",
+    "eva.pool", "eva.summary_write", "eva.attend",
     "unembed", "sample",
 )
 _SCOPE_RE = re.compile(

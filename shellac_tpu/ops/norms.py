@@ -61,7 +61,11 @@ def _rms_forward(x, scale, eps, interpret):
     for s in orig_shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    block = min(_BLOCK_ROWS, rows)
+    # _BLOCK_ROWS rows of a 2-byte type; half as many of float32 (a
+    # float32 residual stream), so that a block in, a block out and
+    # their double buffers stay inside the kernel's 16 MiB of VMEM at a
+    # width of 4096 (256 float32 rows were refused on the chip by 12 KiB).
+    block = min(_BLOCK_ROWS * 2 // max(2, x.dtype.itemsize), rows)
     # Pad rows to a multiple of the block so the grid divides evenly.
     pad = (-rows) % block
     if pad:

@@ -74,6 +74,12 @@ def make_train_step(
         # model config itself (and any checkpoint metadata derived from
         # it) stays unquantized.
         model_cfg = model_cfg.replace(quant_training=train_cfg.quant).validate()
+    if model_cfg.n_pred_heads > 1:
+        raise NotImplementedError(
+            f"n_pred_heads={model_cfg.n_pred_heads}: forward() returns "
+            "every prediction head's logits and there is no loss over "
+            "the heads yet (ROADMAP, Queue 2)"
+        )
     optimizer = make_optimizer(train_cfg)
     accum = train_cfg.grad_accum
 
