@@ -116,10 +116,13 @@ def paged_decode_cases(checks):
     B, L, H, HKV, D = 4, 1024, 16, 8, 128
     # bs=64 runs the grouped gather with 2 groups; bs=16 is the serving
     # default page size (group=32, the shape the one-page kernel lost
-    # to the XLA ref on — BENCH_DECODE.json).
+    # to the XLA ref on — BENCH_DECODE.json); bs=256 is the page "auto"
+    # sends to the kernel (2 pages a step, 2 groups: the double buffer
+    # crosses groups and slots).
     for s, window, bs in [
         (1, None, 64), (1, 200, 64), (2, None, 64),
         (1, None, 16), (1, 200, 16),
+        (1, None, 256), (3, 600, 256),
     ]:
         max_blocks = L // bs
         n_blocks = B * max_blocks + 1

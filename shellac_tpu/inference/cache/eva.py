@@ -119,6 +119,11 @@ class EvaBackend(PagedBackend):
             self.refuse("mesh")
         super().bind(engine)
 
+    def initial_stats(self) -> Dict[str, int]:
+        # No "decode_attn": eva_decode_attention reads rings and pool
+        # itself, never through paged_decode_attention.
+        return {}
+
     # ---- device cache construction ----------------------------------
 
     def init_cache(self):

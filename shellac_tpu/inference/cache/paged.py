@@ -159,17 +159,36 @@ class PagedBackend(CacheBackend):
         )
         return logits, cache.replace(**fields)
 
+    def decode_attn(self) -> str:
+        """How the engine's decode program reads this pool: through the
+        block table in the kernel ("paged_kernel"), or through a
+        gathered dense view of every slot ("gather"). The dispatcher's
+        own rule, asked with the shapes built here."""
+        from shellac_tpu.ops.decode_attention import paged_decode_path
+
+        cfg = self.cfg
+        return paged_decode_path(
+            (self.n_slots, 1, cfg.n_heads, cfg.cache_head_dim),
+            (self.n_blocks, cfg.cache_kv_heads, self.block_size,
+             cfg.cache_head_dim),
+            jnp.int8 if self.kv_quant == "int8" else cfg.compute_dtype,
+            self.engine.attn_impl,
+        )
+
     # ---- allocator ---------------------------------------------------
 
     def initial_stats(self) -> Dict[str, int]:
-        if not self.prefix_cache:
-            return {}
-        return {
-            "prefix_hit_tokens": 0,
-            "prefix_query_tokens": 0,
-            "prefix_evictions": 0,
-            "prefix_seeded_blocks": 0,
-        }
+        # "decode_attn" is non-numeric, like "cache_backend": the
+        # /metrics mirror skips it, /stats shows it.
+        stats = {"decode_attn": self.decode_attn()}
+        if self.prefix_cache:
+            stats.update({
+                "prefix_hit_tokens": 0,
+                "prefix_query_tokens": 0,
+                "prefix_evictions": 0,
+                "prefix_seeded_blocks": 0,
+            })
+        return stats
 
     def evictable(self) -> int:
         return sum(1 for r in self._block_ref.values() if r == 0)

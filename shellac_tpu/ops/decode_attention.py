@@ -117,12 +117,14 @@ def _decode_tile(
 
         for kh in range(hkv):
             sl = pl.dslice(kh * rph, rph)
-            q = q_ref[sl, :].astype(jnp.float32) * scale
-            k = k_ref[kh].astype(jnp.float32)
+            # Operands in q's dtype (an int8 k is exact in it), float32
+            # accumulation, the scale on the float32 logits: the
+            # reference's precision, and one MXU pass for bf16.
+            q = q_ref[sl, :]
             logits = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k_ref[kh].astype(q.dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # (rph, block_k)
+            ) * scale  # (rph, block_k)
             if ks_ref is not None:
                 logits = logits * ks_ref[kh][None, :]
             if softcap is not None:
@@ -202,7 +204,7 @@ def _decode_tile_values(
         if window is not None:
             mask &= qpos - kpos < window
 
-        qall = q_ref[...][0].astype(jnp.float32) * scale  # (rows, d)
+        qall = q_ref[...][0]  # (rows, d)
         kall = k_ref[...][0]  # (hkv, block_k, d)
         vall = v_ref[...][0]
         acc_all = acc_ref[...]
@@ -215,9 +217,9 @@ def _decode_tile_values(
             q = jax.lax.slice_in_dim(qall, lo, hi, axis=0)
             k = jax.lax.slice_in_dim(kall, kh, kh + 1, axis=0)[0]
             logits = jax.lax.dot_general(
-                q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            ) * scale
             if softcap is not None:
                 logits = softcap * jnp.tanh(logits / softcap)
             logits = jnp.where(mask, logits, NEG_INF)
@@ -283,20 +285,23 @@ def _kv_axis(cache):
     return "kv_heads" if cache.shape[1] > 1 else None
 
 
-def _mesh_fallback(impl, quant, warning, q, cache, mesh):
+def _mesh_fallback(impl, loses, warning, q, cache, mesh):
     """The kernel could not be cut over the mesh (per_shard refused):
-    a forced kernel is an error, an int8 cache says what it loses, and
-    a bf16 cache just takes the reference path."""
+    a forced kernel is an error; otherwise the reference path runs, and
+    says what it `loses` against the kernel (None: nothing worth a
+    warning)."""
     what = (f"q={tuple(q.shape)} cache={tuple(cache.shape)} do not divide "
             f"over mesh {dict(mesh.shape)}")
     if impl == "flash":
         raise ValueError(f"impl='flash': {what}")
-    if quant:
+    if loses:
         warnings.warn(
-            f"int8-cache decode kernel unavailable: {what} — the "
-            "reference fallback dequantizes the cache every tick",
-            warning, stacklevel=3,
+            f"decode kernel unavailable: {what} — the reference fallback "
+            f"{loses}", warning, stacklevel=3,
         )
+
+
+_DEQUANT_EVERY_TICK = "dequantizes the cache every tick"
 
 
 def _live_range(idx, s, block_k, window, num_kv):
@@ -569,7 +574,8 @@ def decode_attention(
         }, _Q_AXES)
         if out is not None:
             return out
-        _mesh_fallback(impl, quant, QuantFallbackWarning, q, cache_k, mesh)
+        _mesh_fallback(impl, _DEQUANT_EVERY_TICK if quant else None,
+                       QuantFallbackWarning, q, cache_k, mesh)
     return _decode_ref(
         q, cache_k, cache_v, index, window, scale, softcap=softcap,
         sinks=sinks, k_scale=k_scale, v_scale=v_scale,
@@ -618,21 +624,25 @@ def _paged_group_kernel(
 ):
     """Grouped paged decode: `group` pages gathered per grid step.
 
-    The one-page-per-grid-step kernel loses to the XLA dense-gather ref
-    at serving page sizes (block_size 16 measured 0.61x on a v5e in
-    July 2026 — PERF.md history): each step pays full grid/pipeline
-    overhead to DMA a (hkv, 16, d) sliver and feed the MXU a 16-wide
-    dot. Here the
-    pool stays in HBM (memory_space=ANY) and the kernel gathers `group`
-    pages itself with parallel async copies into one contiguous VMEM
-    tile, so per-step overhead amortizes `group`-fold and the dot runs
-    group*bs wide. Skipping is page-granular: dead groups issue no DMAs
-    at all, and a live boundary group only fetches its live pages —
-    dead page slots are ZEROED in VMEM instead (cheaper than HBM
-    traffic, and required: unfetched scratch is uninitialized, and a
-    stray Inf/NaN bit pattern would poison the accumulator through the
-    masked-out p=0 rows as 0*Inf).
+    The pool stays in HBM (memory_space=ANY) and the kernel gathers a
+    step's pages itself, with parallel async copies into one contiguous
+    VMEM tile, so per-step overhead amortizes `group`-fold and the dot
+    runs group*bs wide. Skipping is page-granular: dead groups issue no
+    DMAs at all, and a live boundary group only fetches its live pages.
+    The v rows (and scales) of its dead page slots are ZEROED in VMEM
+    instead: unfetched scratch is uninitialized, and a stray Inf/NaN bit
+    pattern would poison the accumulator through the masked-out p=0
+    rows as 0*Inf (k needs none: its logits are replaced, not scaled).
+
+    The tile is double-buffered across grid steps: a live step starts
+    the copies of the NEXT live step (this slot's next group, else the
+    next slot's first) into the other half before it waits for its own,
+    so the dots of one step hide the DMA of the next. Grid steps run in
+    order on one core; `step_ref` counts the live ones, its parity is
+    the half in use.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     if quant:
         # Int8 pools travel with fp32 scale pools, gathered page-for-
         # page into their own VMEM tiles (sem rows 2/3).
@@ -641,88 +651,93 @@ def _paged_group_kernel(
     sink_ref, rest = _split_sink_rest(rest, has_sinks)
     if quant:
         (o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sems) = rest
+         sems, step_ref) = rest
     else:
-        o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
+        o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, step_ref = rest
         ks_buf = vs_buf = None
     b = pl.program_id(0)
     gi = pl.program_id(1)
-    idx = len_ref[b]
+    n_slots = pl.num_programs(0)
     block_k = group * bs
     num_groups = num_kv // group
-    first_gi, last_gi = _live_range(idx, s, block_k, window, num_groups)
-    live = (gi >= first_gi) & (gi * block_k <= idx + s - 1)
-    # Per-page live range (page granularity, not group granularity).
-    last_pg = jnp.minimum((idx + s - 1) // bs, num_kv - 1)
-    if window is None:
-        first_pg = jnp.int32(0)
-    else:
-        first_pg = jnp.maximum(idx - window + 1, 0) // bs
 
-    def _pg_live(g):
-        pg = gi * group + g
-        return (pg >= first_pg) & (pg <= last_pg)
+    def live_groups(slot):
+        return _live_range(len_ref[slot], s, block_k, window, num_groups)
+
+    def pages(slot, g_idx, half, wait):
+        """Start (or wait for) the copies of grid step (slot, g_idx)
+        into tile `half`, page by page (page granularity, not group
+        granularity); starting also zeroes its dead page slots."""
+        first_pg, last_pg = _live_range(len_ref[slot], s, bs, window, num_kv)
+        for g in range(group):
+            pg = g_idx * group + g
+            dst = pl.dslice(g * bs, bs)
+            pg_live = (pg >= first_pg) & (pg <= last_pg)
+
+            @pl.when(pg_live)
+            def _copy(g=g, pg=pg, dst=dst):
+                page = tab_ref[slot, pg]
+                copies = [
+                    pltpu.make_async_copy(
+                        k_hbm.at[page], k_buf.at[half, :, dst, :],
+                        sems.at[half, 0, g]),
+                    pltpu.make_async_copy(
+                        v_hbm.at[page], v_buf.at[half, :, dst, :],
+                        sems.at[half, 1, g]),
+                ]
+                if quant:
+                    copies += [
+                        pltpu.make_async_copy(
+                            ks_hbm.at[page], ks_buf.at[half, :, dst],
+                            sems.at[half, 2, g]),
+                        pltpu.make_async_copy(
+                            vs_hbm.at[page], vs_buf.at[half, :, dst],
+                            sems.at[half, 3, g]),
+                    ]
+                for c in copies:
+                    c.wait() if wait else c.start()
+
+            if not wait:
+                @pl.when(~pg_live)
+                def _zero(dst=dst):
+                    v_buf[half, :, dst, :] = jnp.zeros(
+                        (hkv, bs, v_buf.shape[-1]), v_buf.dtype)
+                    if quant:
+                        vs_buf[half, :, dst] = jnp.zeros(
+                            (hkv, bs), vs_buf.dtype)
+
+    @pl.when((b == 0) & (gi == 0))
+    def _prime():
+        step_ref[0] = 0
+        pages(0, live_groups(0)[0], 0, wait=False)
+
+    idx = len_ref[b]
+    first_gi, last_gi = live_groups(b)
+    live = (gi >= first_gi) & (gi <= last_gi)
+    half = step_ref[0] % 2
 
     @pl.when(live)
-    def _gather():
-        from jax.experimental.pallas import tpu as pltpu
+    def _stream():
+        more = gi < last_gi  # this slot has another live group
+        nxt = jnp.minimum(b + 1, n_slots - 1)
 
-        for g in range(group):
-            dst = pl.dslice(g * bs, bs)
+        @pl.when(more | (b + 1 < n_slots))
+        def _prefetch():
+            pages(jnp.where(more, b, nxt),
+                  jnp.where(more, gi + 1, live_groups(nxt)[0]),
+                  1 - half, wait=False)
 
-            @pl.when(_pg_live(g))
-            def _fetch(g=g, dst=dst):
-                page = tab_ref[b, gi * group + g]
-                pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[:, dst, :], sems.at[0, g]
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[:, dst, :], sems.at[1, g]
-                ).start()
-                if quant:
-                    pltpu.make_async_copy(
-                        ks_hbm.at[page], ks_buf.at[:, dst], sems.at[2, g]
-                    ).start()
-                    pltpu.make_async_copy(
-                        vs_hbm.at[page], vs_buf.at[:, dst], sems.at[3, g]
-                    ).start()
-
-            @pl.when(~_pg_live(g))
-            def _zero(dst=dst):
-                k_buf[:, dst, :] = jnp.zeros_like(k_buf[:, dst, :])
-                v_buf[:, dst, :] = jnp.zeros_like(v_buf[:, dst, :])
-                if quant:
-                    # Zero scales keep dead columns exactly zero through
-                    # the dequant multiplies (masked anyway; belt and
-                    # braces against uninitialized-scratch Inf/NaN).
-                    ks_buf[:, dst] = jnp.zeros_like(ks_buf[:, dst])
-                    vs_buf[:, dst] = jnp.zeros_like(vs_buf[:, dst])
-
-        for g in range(group):
-            dst = pl.dslice(g * bs, bs)
-
-            @pl.when(_pg_live(g))
-            def _await(g=g, dst=dst):
-                pltpu.make_async_copy(
-                    k_hbm.at[0], k_buf.at[:, dst, :], sems.at[0, g]
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[0], v_buf.at[:, dst, :], sems.at[1, g]
-                ).wait()
-                if quant:
-                    pltpu.make_async_copy(
-                        ks_hbm.at[0], ks_buf.at[:, dst], sems.at[2, g]
-                    ).wait()
-                    pltpu.make_async_copy(
-                        vs_hbm.at[0], vs_buf.at[:, dst], sems.at[3, g]
-                    ).wait()
+        pages(b, gi, half, wait=True)
+        step_ref[0] = step_ref[0] + 1
 
     _decode_tile(
-        idx, q_ref.at[0], k_buf, v_buf, o_ref.at[0],
+        idx, q_ref.at[0], k_buf.at[half], v_buf.at[half], o_ref.at[0],
         acc_ref, m_ref, l_ref,
         scale=scale, s=s, hkv=hkv, block_k=block_k, window=window,
         k_start=gi * block_k, ki=gi, last_ki=last_gi, first_ki=first_gi,
-        ks_ref=ks_buf, vs_ref=vs_buf, softcap=softcap, sink_ref=sink_ref,
+        ks_ref=None if ks_buf is None else ks_buf.at[half],
+        vs_ref=None if vs_buf is None else vs_buf.at[half],
+        softcap=softcap, sink_ref=sink_ref,
     )
 
 
@@ -764,15 +779,16 @@ def _paged_group_flash(
         pltpu.VMEM((rows, d), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((hkv, block_k, d), pool_k.dtype),
-        pltpu.VMEM((hkv, block_k, d), pool_v.dtype),
     ]
+    # Two halves of each gathered tile, one in use, one in flight; the
+    # count of live steps whose parity says which.
+    scratch += [pltpu.VMEM((2, hkv, block_k, d), pool_k.dtype),
+                pltpu.VMEM((2, hkv, block_k, d), pool_v.dtype)]
     if quant:
-        scratch += [
-            pltpu.VMEM((hkv, block_k), jnp.float32),
-            pltpu.VMEM((hkv, block_k), jnp.float32),
-        ]
-    scratch += [pltpu.SemaphoreType.DMA((4 if quant else 2, group))]
+        scratch += [pltpu.VMEM((2, hkv, block_k), jnp.float32),
+                    pltpu.VMEM((2, hkv, block_k), jnp.float32)]
+    scratch += [pltpu.SemaphoreType.DMA((2, 4 if quant else 2, group)),
+                pltpu.SMEM((1,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, num_groups),
@@ -797,8 +813,10 @@ def _paged_group_flash(
 
 
 def _paged_group(tables, pool_k) -> int:
-    """Pages per grid step: aim for a ~512-row kv tile, divide the
-    table, and respect the VMEM budget the one-page kernel enforces.
+    """Pages per grid step: aim for a ~512-row kv tile (on a v5e at 8
+    kv heads x 128 and 256-row pages, 1024 rows took the same time),
+    divide the table, and keep both halves of the k and v tiles within
+    the VMEM budget the one-page kernel enforces.
     Returns 1 (one-page kernel) when grouping cannot work: the gather
     lands each page at sublane offset g*bs of the VMEM tile, so bs
     must be a multiple of the dtype's sublane tile (fp32 8, bf16 16,
@@ -808,7 +826,7 @@ def _paged_group(tables, pool_k) -> int:
     sublane = 8 * max(1, 4 // jnp.dtype(pool_k.dtype).itemsize)
     if bs % sublane:
         return 1
-    cap = max(1, 8192 // max(hkv * bs, 1))  # hkv*group*bs <= 8192
+    cap = max(1, 4096 // max(hkv * bs, 1))  # 2 halves: hkv*group*bs <= 4096
     g = min(max(512 // bs, 1), cap, num_kv)
     while g > 1 and num_kv % g:
         g -= 1
@@ -894,8 +912,13 @@ def _paged_flash(q, pool_k, pool_v, tables, index, scale, window, interpret,
 
 
 def paged_decode_supported(q, pool_k, *, quant: bool = False) -> bool:
-    b, s, h, d = q.shape
-    hkv, bs, dk = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
+    """Can a compiled paged kernel handle these arrays?"""
+    return _paged_shapes_supported(q.shape, pool_k.shape, quant)
+
+
+def _paged_shapes_supported(q_shape, pool_shape, quant: bool) -> bool:
+    b, s, h, d = q_shape
+    hkv, bs, dk = pool_shape[1:]
     if d % 64 != 0 or dk != d:
         return False
     if quant and (d % 128 != 0 or bs % 128 != 0):
@@ -918,6 +941,44 @@ def paged_decode_supported(q, pool_k, *, quant: bool = False) -> bool:
     return h * s <= 1024
 
 
+# The shortest page, in rows, a bf16/fp32 pool reads through the kernel
+# under "auto": the shortest a benchmark cell (and the int8 pool) runs.
+# On a v5e the kernel also won a tick's micro-timing at 64 and 16 rows
+# (docs/decode_performance.md); those wait for an end-to-end reading.
+PAGED_KERNEL_MIN_PAGE = 128
+
+
+def paged_kernel_under_auto(q_shape, pool_shape, pool_dtype) -> bool:
+    """The rule of impl="auto" on a backend with compiled Pallas: does a
+    pool of this shape and dtype read through the block table in the
+    kernel (True), or through the gathered dense view (False)? A pure
+    function of (q.shape, pool.shape, pool.dtype): the dispatcher asks
+    it, and so does whoever wants to know what the dispatcher will do.
+
+    An int8 pool takes the kernel wherever the kernel can run (the
+    gather dequantizes every gathered page every tick). A bf16/fp32
+    pool takes it where it wins: the head dimension fills the lanes
+    (the grouped kernel's precondition) and a page is long enough for
+    a grid step to amortize (PAGED_KERNEL_MIN_PAGE).
+    """
+    if jnp.dtype(pool_dtype) == jnp.int8:
+        return _paged_shapes_supported(q_shape, pool_shape, quant=True)
+    return (_paged_shapes_supported(q_shape, pool_shape, quant=False)
+            and pool_shape[3] % 128 == 0
+            and pool_shape[2] >= PAGED_KERNEL_MIN_PAGE)
+
+
+def paged_decode_path(q_shape, pool_shape, pool_dtype,
+                      impl: str = "auto") -> str:
+    """What paged_decode_attention does with these shapes on this
+    backend: "paged_kernel" or "gather"."""
+    if impl == "flash":
+        return "paged_kernel"
+    kernel = (impl == "auto" and pallas_supported()
+              and paged_kernel_under_auto(q_shape, pool_shape, pool_dtype))
+    return "paged_kernel" if kernel else "gather"
+
+
 def paged_decode_attention(
     q, pool_k, pool_v, tables, index, *,
     window: Optional[int] = None,
@@ -933,8 +994,9 @@ def paged_decode_attention(
 
     pool_k/v: (n_blocks, Hkv, bs, D); tables: (B, max_blocks) int32;
     index: (B,) pre-write lengths. The kernel walks each slot's table —
-    the dense per-slot view is never materialized. Falls back to
-    gather + masked reference attention when unsupported.
+    the dense per-slot view is never materialized. impl="auto" takes it
+    where paged_kernel_under_auto says it wins and compiled Pallas is
+    live, and the gather + masked reference attention elsewhere.
 
     k_scale/v_scale: (n_blocks, Hkv, bs) fp32 per-token dequant scale
     pools for an int8 pool (see kvcache.QuantPagedKVCache); both or
@@ -954,50 +1016,39 @@ def paged_decode_attention(
     if interpret is None:
         interpret = not pallas_supported()
     shapes_ok = paged_decode_supported(q, pool_k, quant=quant)
-    if impl == "flash":
-        if not shapes_ok:
-            raise ValueError(
-                f"impl='flash' unsupported for q={q.shape} "
-                f"pool={pool_k.shape} quant={quant}"
-            )
-        use_kernel = True
-    else:
-        # 'auto' defaults bf16 pools to the XLA reference path: the
-        # grouped-gather paged kernel has never beaten the reference
-        # on hardware (July 2026 chip run: 284.7 vs 261.9 us/call,
-        # 0.92x, at serving page sizes — PERF.md history; ROADMAP D2
-        # re-measures on the attached chip).
-        # impl='flash' still forces it (parity tests, future re-
-        # measurement). Int8 pools KEEP the kernel under auto: their
-        # reference fallback dequantizes gathered pages every tick,
-        # inverting the kv_quant bandwidth win.
-        use_kernel = (impl == "auto" and pallas_supported() and shapes_ok
-                      and quant)
-        if (impl == "auto" and pallas_supported() and not shapes_ok
-                and quant):
-            # The operator asked for paged serving on a TPU but the pool
-            # shape silently disqualifies the kernel — the fallback
-            # materializes the dense (B, view, Hkv, D) gather every
-            # step, which defeats the point of paging. Say so once per
-            # shape (warnings' default "once per message+location"
-            # dedup), with the actionable constraint named.
-            b, s, h, d = q.shape
-            hkv, bs, dk = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
-            warnings.warn(
-                "paged_decode_attention: Pallas kernel unavailable for "
-                f"q={tuple(q.shape)} pool={tuple(pool_k.shape)} "
-                f"quant={quant} — falling back to a dense gather + "
-                "reference attention (paging's memory win is lost). "
-                "Kernel needs: head_dim % 64 == 0 "
-                f"(got {d}), pool head_dim == q head_dim (got {dk} vs {d}), "
-                f"page block size % 8 == 0 (got {bs}), "
-                f"n_heads % kv_heads == 0 (got {h}/{hkv}), "
-                f"H*s <= 1024 (got {h * s})"
-                + (", and for int8 pools head_dim % 128 == 0 with "
-                   "block size % 128 == 0." if quant else "."),
-                PagedFallbackWarning,
-                stacklevel=2,
-            )
+    if impl == "flash" and not shapes_ok:
+        raise ValueError(
+            f"impl='flash' unsupported for q={q.shape} "
+            f"pool={pool_k.shape} quant={quant}"
+        )
+    # impl="auto" reads through the table where paged_kernel_under_auto
+    # says the kernel wins, and every pool it sends to the gather goes
+    # there by decision, silently — but for an int8 pool whose shape
+    # disqualifies the kernel: that gather dequantizes every page every
+    # tick, which inverts what kv_quant was asked for.
+    use_kernel = paged_decode_path(
+        q.shape, pool_k.shape, pool_k.dtype, impl) == "paged_kernel"
+    if (impl == "auto" and pallas_supported() and not shapes_ok
+            and quant):
+        # Say so once per shape (warnings' default "once per
+        # message+location" dedup), with the actionable constraint
+        # named.
+        b, s, h, d = q.shape
+        hkv, bs, dk = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
+        warnings.warn(
+            "paged_decode_attention: Pallas kernel unavailable for "
+            f"q={tuple(q.shape)} int8 pool={tuple(pool_k.shape)} — "
+            "falling back to a dense gather + reference attention "
+            "(paging's memory win is lost). "
+            "Kernel needs: head_dim % 64 == 0 "
+            f"(got {d}), pool head_dim == q head_dim (got {dk} vs {d}), "
+            f"page block size % 8 == 0 (got {bs}), "
+            f"n_heads % kv_heads == 0 (got {h}/{hkv}), "
+            f"H*s <= 1024 (got {h * s}), and for int8 pools "
+            "head_dim % 128 == 0 with block size % 128 == 0.",
+            PagedFallbackWarning,
+            stacklevel=2,
+        )
     if use_kernel:
         def kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale, sinks):
             # Grouped gather kernel when the head dim keeps full-lane
@@ -1037,7 +1088,10 @@ def paged_decode_attention(
             }, _Q_AXES)
         if out is not None:
             return out
-        _mesh_fallback(impl, quant, PagedFallbackWarning, q, pool_k, mesh)
+        _mesh_fallback(
+            impl, _DEQUANT_EVERY_TICK if quant
+            else "gathers every slot's dense view every tick",
+            PagedFallbackWarning, q, pool_k, mesh)
     from shellac_tpu.inference.kvcache import (
         paged_gather_layer,
         paged_gather_scales,

@@ -162,6 +162,8 @@ CASES = [
      lambda: _dense_decode(d=576, hkv=1, scale=192 ** -0.5), True),
     ("paged-bf16-page16", lambda: _paged_decode(16), True),
     ("paged-bf16-page64", lambda: _paged_decode(64), True),
+    # The pool "auto" sends to the kernel (mistral-7b-batch's pages).
+    ("paged-bf16-page256", lambda: _paged_decode(256), True),
     *[(f"paged-int8-page{bs}",
        lambda bs=bs: _paged_decode(bs, quant=True), True)
       for bs in _int8_page_sizes()],
@@ -244,15 +246,29 @@ def _mesh_paged_int8(mesh):
                 ((SERVE_B,), I32, (None,)), scales, scales]
 
 
+@pytest.mark.parametrize("page,kernel", [
+    pytest.param(256, True, id="page256-kernel"),
+    pytest.param(64, False, id="page64-gather"),
+])
 def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
-        chip, pool_sized_ops):
+        chip, pool_sized_ops, monkeypatch, page, kernel):
     """The TPU compiler's verdict on what tests/test_paged_inplace.py
     reads off the CPU's: a window of decode ticks over a bf16 paged pool
-    at serving widths (8 kv heads x 128, 256-token pages) holds no
-    pool-sized temporary and moves no pool. The two compilers differ: a
-    one-row update at (block, offset) is in place on the CPU, while this
-    one then holds the pool head-innermost inside the window and copies
-    it in and out (PERF.md, PR 26)."""
+    at serving widths (8 kv heads x 128) holds no pool-sized temporary
+    and moves no pool. The two compilers differ: a one-row update at
+    (block, offset) is in place on the CPU, while this one then holds
+    the pool head-innermost inside the window and copies it in and out
+    (PERF.md, PR 26).
+
+    The dispatchers are steered to their TPU branch, as on the chip, so
+    256-row pages read through the block table in the Mosaic kernel and
+    the window that must hold is the one WITH THE KERNEL IN IT: a pool
+    whose layout the kernel's operand does not share would be copied in
+    front of every layer's call. Shorter pages take the gather, whose
+    window must hold too."""
+    from shellac_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from shellac_tpu import get_model_config
     from shellac_tpu.inference.kvcache import init_paged_cache
     from shellac_tpu.models import transformer
@@ -261,7 +277,10 @@ def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
         d_model=512, n_heads=H, n_kv_heads=HKV, head_dim=D, d_ff=1024,
         n_layers=8, dtype="bfloat16", param_dtype="bfloat16",
     ).validate()
-    slots, page, pages = 8, 256, 4
+    slots, pages = 8, 1024 // page
+    assert da.paged_decode_path(
+        (slots, 1, H, D), (slots * pages + 1, HKV, page, D), BF16
+    ) == ("paged_kernel" if kernel else "gather")
 
     def window(params, cache, cur):
         def tick(carry, _):
@@ -290,8 +309,57 @@ def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
     pool_bytes = 2 * cache.k.size * cache.k.dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes / 2, (temp, pool_bytes)
-    moved = pool_sized_ops(compiled.as_text(), [cache.k.shape])
+    text = compiled.as_text()
+    assert ("decode_paged_group" in text) is kernel
+    moved = pool_sized_ops(text, [cache.k.shape])
     assert not moved, "\n".join(moved)
+
+
+def test_latent_pool_is_relaid_for_the_kernel_on_v5e(chip):
+    """Why "auto" keeps a pool whose row does not fill the lanes on the
+    gather: the device holds a (n_blocks, 1, 128, 576) latent pool in a
+    tiling Mosaic's operand does not share, so the compiler copies the
+    WHOLE pool in front of the kernel's call (temporaries of a pool's
+    size, which the guard above refuses), where a 128-wide pool of the
+    same bytes is read in place. If this ever fails the copy is gone
+    and paged_kernel_under_auto can take D % 128 != 0 in (PERF.md,
+    section 7)."""
+    from shellac_tpu.ops.decode_attention import (
+        paged_decode_attention,
+        paged_kernel_under_auto,
+    )
+
+    layers, pages = 4, 16
+
+    def temporaries(heads, hkv, d, **kw):
+        n_blocks = SERVE_B * pages + 1
+        q = ((layers, SERVE_B, 1, heads, d), BF16)
+        pool = ((layers * n_blocks, hkv, 128, d), BF16)
+        shapes = [q, pool, pool, ((SERVE_B, pages), I32), ((SERVE_B,), I32)]
+        args = [jax.ShapeDtypeStruct(s, dt, sharding=chip)
+                for s, dt in shapes]
+
+        def tick(qs, pk, pv, tables, index):  # the layer loop's shape
+            def layer(i, acc):
+                return acc + paged_decode_attention(
+                    qs[i], pk, pv, tables + i * n_blocks, index,
+                    impl="flash", interpret=False, **kw).astype(F32)
+
+            return jax.lax.fori_loop(0, layers, layer,
+                                     jnp.zeros(q[0][1:], F32))
+
+        compiled = jax.jit(tick).lower(*args).compile()
+        pool_bytes = 2
+        for n in pool[0]:
+            pool_bytes *= n
+        assert paged_kernel_under_auto(
+            q[0][1:], pool[0], BF16) is (d % 128 == 0)
+        return compiled.memory_analysis().temp_size_in_bytes, pool_bytes
+
+    temp, pool_bytes = temporaries(16, 1, 576, scale=192 ** -0.5)
+    assert temp >= pool_bytes, (temp, pool_bytes)
+    temp, pool_bytes = temporaries(H, HKV, D)
+    assert temp < pool_bytes / 8, (temp, pool_bytes)
 
 
 @pytest.mark.parametrize("build", [

@@ -274,24 +274,163 @@ def test_paged_quant_fallback_warns_on_tpu_like_backend(monkeypatch, bs, d):
         )
 
 
-def test_paged_bf16_auto_prefers_reference(monkeypatch):
-    """bf16 pools default to the XLA reference under auto even on a
-    Pallas-capable backend (the grouped-gather kernel has never beaten
-    it on hardware — PERF.md history), and that is a decision, not a
-    fallback: no warning."""
+# (id, q heads, kv heads, head dim, page rows, pool dtype, kernel?)
+AUTO_RULE = [
+    ("bf16-page16-d128", 4, 4, 128, 16, jnp.bfloat16, False),
+    ("bf16-page64-d128", 4, 4, 128, 64, jnp.bfloat16, False),
+    ("bf16-page256-hkv8-d128", 32, 8, 128, 256, jnp.bfloat16, True),
+    ("bf16-page128-d128", 4, 4, 128, 128, jnp.bfloat16, True),
+    ("mla-hkv1-d576-page128", 16, 1, 576, 128, jnp.bfloat16, False),
+    ("bf16-page256-d64", 4, 4, 64, 256, jnp.bfloat16, False),
+    ("int8-page128-d128", 4, 4, 128, 128, jnp.int8, True),
+    ("int8-page256-d128", 4, 4, 128, 256, jnp.int8, True),
+]
+
+
+@pytest.mark.parametrize(
+    "h,hkv,d,bs,dtype,kernel",
+    [pytest.param(*c[1:], id=c[0]) for c in AUTO_RULE],
+)
+def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
+    """impl="auto" on a Pallas-capable backend: a pool reads through
+    the block table in the kernel where the rule says the kernel wins
+    (int8 wherever it runs; bf16 with full-lane heads and pages long
+    enough for a grid step to amortize), and through the gather
+    everywhere else BY DECISION: no warning. The rule is a pure
+    function of shapes and dtype, and the dispatcher does what it
+    says."""
     import warnings as _w
 
     import shellac_tpu.ops.decode_attention as da
 
+    q = jnp.zeros((1, 1, h, d), jnp.bfloat16)
+    pool = jnp.zeros((3, hkv, bs, d), dtype)
+    scale = (jnp.ones((3, hkv, bs), jnp.float32)
+             if dtype == jnp.int8 else None)
+    assert da.paged_kernel_under_auto(q.shape, pool.shape, pool.dtype) \
+        is kernel
+    # Off the TPU "auto" never takes a kernel, whatever the rule says.
+    assert da.paged_decode_path(q.shape, pool.shape, pool.dtype) == "gather"
     monkeypatch.setattr(da, "pallas_supported", lambda: True)
-    q = jnp.zeros((1, 1, 4, 128))
-    pool = jnp.zeros((5, 4, 16, 128))
-    tables = jnp.arange(1, 5, dtype=jnp.int32)[None, :]
-    index = jnp.zeros((1,), jnp.int32)
+    want = "paged_kernel" if kernel else "gather"
+    assert da.paged_decode_path(q.shape, pool.shape, pool.dtype) == want
+    assert da.paged_decode_path(q.shape, pool.shape, pool.dtype,
+                                "ref") == "gather"
+
+    took = []
+    for name in ("_paged_group_flash", "_paged_flash"):
+        real = getattr(da, name)
+        monkeypatch.setattr(
+            da, name,
+            lambda *a, _real=real, _name=name, **k: (
+                took.append(_name), _real(*a, **k))[1],
+        )
+    tables = jnp.asarray([[1, 2]], jnp.int32)
+    index = jnp.asarray([bs + 3], jnp.int32)
     with _w.catch_warnings():
         _w.simplefilter("error", da.PagedFallbackWarning)
-        da.paged_decode_attention(q, pool, pool, tables, index,
-                                  interpret=True)
+        out = da.paged_decode_attention(
+            q, pool, pool, tables, index, interpret=True,
+            k_scale=scale, v_scale=scale,
+        )
+    assert out.shape == q.shape
+    assert bool(took) is kernel, took
+
+
+@pytest.mark.parametrize("backend,head_dim,page,want", [
+    ("paged", 128, 256, "paged_kernel"),
+    ("paged", 128, 16, "gather"),
+    ("paged", 16, 256, "gather"),
+    ("paged-int8", 128, 128, "paged_kernel"),
+])
+def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
+                                             page, want):
+    """engine.stats["decode_attn"] is the dispatcher's own verdict for
+    the decode program's shapes, recorded once at construction beside
+    "cache_backend" (non-numeric: /stats shows it, the /metrics mirror
+    skips it). Off the TPU every pool reads through the gather."""
+    import shellac_tpu.ops.decode_attention as da
+    from shellac_tpu import get_model_config
+    from shellac_tpu.inference.batching import PagedBatchingEngine
+    from shellac_tpu.models import transformer
+
+    cfg = get_model_config("tiny-gqa").replace(
+        head_dim=head_dim, n_layers=1, max_seq_len=512).validate()
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+
+    def build():
+        return PagedBatchingEngine(cfg, params, n_slots=2, max_len=512,
+                                   block_size=page, cache_backend=backend)
+
+    assert build().stats["decode_attn"] == "gather"
+    monkeypatch.setattr(da, "pallas_supported", lambda: True)
+    eng = build()
+    assert eng.stats["decode_attn"] == want
+    assert eng.stats["cache_backend"] == backend
+    pool = eng._cache.k.shape[1:]
+    assert da.paged_decode_path(
+        (2, 1, cfg.n_heads, head_dim), pool, eng._cache.k.dtype
+    ) == want
+
+
+CELL_HKV, CELL_PAGE, CELL_PAGES = 8, 256, 4  # mistral-7b-batch's pool
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("s", [1, 3])
+def test_paged_kernel_at_serving_shape_matches_ref(s, dtype):
+    """The grouped kernel at the benchmark cell's pool (8 kv heads x
+    128, 256-row pages, 2 pages a grid step, 4 query heads a kv head):
+    lengths that end mid-page, on a page's last row, on a page's first
+    row, at one row and at the view's end, and a slot whose table row
+    was never allocated (every entry the scratch page 0). Every live
+    row of every slot is attended, no dead one: the pool's unowned
+    pages hold NaN, which any read past a slot's length would carry
+    into its output."""
+    from shellac_tpu.ops.decode_attention import _paged_group
+
+    view = CELL_PAGE * CELL_PAGES
+    index = jnp.asarray(
+        [300, CELL_PAGE - s, CELL_PAGE, 0, view - s, 0], jnp.int32)
+    n = index.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(40 + s), 3)
+    q = _rand(ks[0], (n, s, 4 * CELL_HKV, D)).astype(dtype)
+    dense_k = _rand(ks[1], (n, CELL_HKV, view, D)).astype(dtype)
+    dense_v = _rand(ks[2], (n, CELL_HKV, view, D)).astype(dtype)
+    pool_k, pool_v, tables = _scatter_pool(dense_k, dense_v, CELL_PAGE)
+    assert _paged_group(tables, pool_k) == 2
+    # Pages wholly past a slot's length: NaN in the pool (never to be
+    # read), zero in the reference's dense view (masked there). Rows
+    # past the length inside a live page keep their finite garbage in
+    # both, as a served pool's do.
+    live_pages = (np.arange(CELL_PAGES)[None, :] * CELL_PAGE
+                  < np.asarray(index)[:, None] + s)  # (n, pages)
+    keep = jnp.asarray(np.repeat(live_pages, CELL_PAGE, axis=1))
+    dense_k, dense_v = (jnp.where(keep[:, None, :, None], x, 0)
+                        for x in (dense_k, dense_v))
+    dead = np.ones(pool_k.shape[0], bool)
+    dead[0] = False
+    dead[np.asarray(tables)[live_pages]] = False
+    pool_k, pool_v = (
+        jnp.where(jnp.asarray(dead)[:, None, None, None], jnp.nan,
+                  x).astype(dtype) for x in (pool_k, pool_v))
+    # The last slot was never allocated: its table row names page 0,
+    # which holds finite scratch (the allocator's convention).
+    tables = tables.at[n - 1].set(0)
+    pool_k, pool_v = (x.at[0].set(1.0) for x in (pool_k, pool_v))
+    dense_k, dense_v = (x.at[n - 1].set(1.0) for x in (dense_k, dense_v))
+
+    ref = _decode_ref(q, dense_k, dense_v, index, None, D ** -0.5)
+    out = paged_decode_attention(
+        q, pool_k, pool_v, tables, index, impl="flash", interpret=True,
+    )
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=tol, rtol=tol,
+    )
 
 
 def test_paged_supported_shapes_do_not_warn():
