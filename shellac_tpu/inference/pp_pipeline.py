@@ -55,8 +55,8 @@ from shellac_tpu.config import ModelConfig
 from shellac_tpu.models.transformer import (
     _block,
     _embed_tokens,
-    pattern_period_scan,
     rope_angles,
+    scan_layers,
     unembed,
 )
 from shellac_tpu.parallel.sharding import constrain
@@ -151,8 +151,6 @@ def stage_apply(
     >= window + slack), so the dense self-healing argument holds on
     the ring too."""
     G = stage_x.shape[1]
-    quant = len(cache_st) == 4
-    pattern = cfg.attn_pattern
 
     def one_stage(sp, blocks, x, pos, gstart):
         slices = tuple(
@@ -173,31 +171,22 @@ def stage_apply(
         else:
             cos_l = sin_l = None
 
-        def run_one(xx, lp, vals, kind):
+        def step(xx, lp, li, vals, moe_layer, kind):
             local = cos_l is not None and kind == "window"
             xx, nc, _ = _block(
                 cfg, mesh, attn_impl, xx, lp,
                 cos_l if local else cos, sin_l if local else sin,
                 cache=(vals[0], vals[1], pos, positions),
-                kv_scales=(vals[2], vals[3]) if quant else None,
+                kv_scales=vals[2:] or None, moe_layer=moe_layer,
                 attn_kind=kind, rolled=rolled,
             )
             return xx, nc
 
-        if pattern is None:
-            def body(xx, layer_in):
-                return run_one(xx, layer_in[0], layer_in[1:], None)
-
-            x, news = jax.lax.scan(body, x, (sp,) + slices)
-        else:
-            # Patterned stacks (Gemma-2/3, GPT-OSS over DENSE caches):
-            # each stage's layer chunk starts at pattern phase 0
-            # (validate_pp_pipeline enforces Lp % period == 0), so the
-            # SHARED period walk (transformer.pattern_period_scan)
-            # applies to the stage chunk exactly as it does to the
-            # full stack.
-            x, news = pattern_period_scan(pattern, x, sp, slices,
-                                          run_one)
+        # The shared walk over this stage's layers, its cache slices
+        # riding as xs. A patterned stage starts at pattern phase 0
+        # (validate_pp_pipeline enforces Lp % period == 0), so the
+        # period walk applies to the chunk as it does to the full stack.
+        x, news = scan_layers(cfg, sp, x, step, xs=slices)
         blocks = tuple(
             jax.lax.dynamic_update_slice_in_dim(b, n, gstart, axis=1)
             for b, n in zip(blocks, news)

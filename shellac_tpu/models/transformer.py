@@ -73,27 +73,6 @@ def _add_aux(a, b):
     return jax.tree.map(lambda u, v: u + v, a, b)
 
 
-def _grouped_scan(blk_d, blk_m, x, aux0, glp_stack):
-    """Scan an interleaved layout: per group, (every-1) dense blocks
-    then one MoE block, accumulating aux. Shared by the plain forward
-    and each pipeline stage (blk_* close over their RoPE/segment
-    bindings)."""
-    def group_body(carry, glp):
-        x, acc = carry
-
-        def dense_body(c2, lp):
-            x2, acc2 = c2
-            x2, _, mo = blk_d(x2, lp)
-            return (x2, _add_aux(acc2, mo)), None
-
-        (x, acc), _ = jax.lax.scan(dense_body, (x, acc), glp["dense"])
-        x, _, mo = blk_m(x, glp["moe"])
-        return (x, _add_aux(acc, mo)), None
-
-    (x, acc), _ = jax.lax.scan(group_body, (x, aux0), glp_stack)
-    return x, acc
-
-
 def map_layer_stacks(layers, fn):
     """Apply `fn(stack, name)` to each per-layer stack of a layers tree.
 
@@ -105,6 +84,146 @@ def map_layer_stacks(layers, fn):
     if is_grouped_layers(layers):
         return {k: fn(layers[k], k) for k in ("dense", "moe")}
     return fn(layers, None)
+
+
+def n_routers(cfg: ModelConfig) -> int:
+    """Layers that hold a router: what MoE diagnostics average over
+    (every layer of a flat stack, a dense model's zeros included)."""
+    if grouped_moe(cfg):
+        return cfg.n_layers // cfg.moe_every
+    if first_k_layout(cfg):
+        return cfg.n_layers - cfg.first_k_dense
+    return cfg.n_layers
+
+
+def scan_layers(cfg: ModelConfig, layers, carry, step, xs=(), first=0):
+    """Walk a layers tree in layer order; returns (carry, ys).
+
+    The single place that knows how a layer stack is laid out and
+    scanned. Training, cached decode over every kind of cache and both
+    pipelines supply `step(carry, lp, li, xs_l, moe_layer, attn_kind)
+    -> (carry, ys_l)` and nothing else:
+
+      - `layers` is params["layers"] or a pipeline stage's slice of it,
+        `first` the index of its first layer; `li` = first, first + 1,
+        ... is TRACED (it rides the scan), for state held in the carry
+        that a layer addresses by its own index (a paged pool's blocks,
+        EVA's rings and pages).
+      - `moe_layer` (bool) and `attn_kind` (None, or the layer's entry
+        of cfg.attn_pattern) are Python values: they choose which block
+        body is compiled, so each kind costs one body whatever the
+        depth.
+      - `xs` is a pytree of per-layer stacks (L, ...) riding with the
+        layers (a slot cache's leaves) and `ys` the pytree of whatever
+        the step returns for each, restacked (L, ...). The walker
+        splits and restacks them as it splits the parameters. With
+        xs=() only the carry crosses the loops: a paged pool and EVA
+        state are carries, so that each layer writes its rows in place
+        and no stack is sliced out or put back (PERF.md, PR 26, 27).
+        On a patterned stack `xs` may be {"window": ..., "full": ...},
+        each kind's stacks holding that kind's layers only, in layer
+        order (the mixed ring/dense caches); `ys` come back the same.
+
+    The four layouts (ModelConfig.validate keeps them exclusive):
+    interleaved = a scan over (dense^(every-1), moe) groups holding a
+    scan over the group's dense layers and one MoE step; dense prefix =
+    two scans back to back; pattern = a scan over whole periods with
+    the kinds unrolled inside (a window is a static kernel argument),
+    the flat (L, ...) stacks viewed (L/period, period, ...) so params
+    and checkpoints keep one layers axis; flat = one scan.
+    """
+    tmap = jax.tree.map
+
+    def n_of(stack):
+        return jax.tree.leaves(stack)[0].shape[0]
+
+    def merged(ys):  # (groups, n, ...) -> (groups * n, ...)
+        return tmap(
+            lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys
+        )
+
+    def indices(n, start, stride=1):
+        return start + stride * jnp.arange(n, dtype=jnp.int32)
+
+    def scan_stack(carry, stack, xs, start, moe_layer):
+        """Layers start, start + 1, ... of one homogeneous stack."""
+        def body(c, inp):
+            return step(c, *inp, moe_layer, None)
+
+        return jax.lax.scan(
+            body, carry, (stack, indices(n_of(stack), start), xs)
+        )
+
+    if grouped_moe(cfg):
+        every, ng = cfg.moe_every, n_of(layers["moe"])
+
+        def group_body(c, inp):
+            glp, start, gx = inp
+            c, yd = scan_stack(
+                c, glp["dense"], tmap(lambda a: a[: every - 1], gx), start,
+                False,
+            )
+            c, ym = step(
+                c, glp["moe"], start + every - 1,
+                tmap(lambda a: a[every - 1], gx), True, None,
+            )
+            return c, tmap(
+                lambda d, m: jnp.concatenate([d, m[None]], axis=0), yd, ym
+            )
+
+        carry, ys = jax.lax.scan(
+            group_body, carry,
+            (layers, indices(ng, first, every),
+             tmap(lambda a: a.reshape(ng, every, *a.shape[1:]), xs)),
+        )
+        return carry, merged(ys)
+    if first_k_layout(cfg):
+        kk = n_of(layers["dense"])
+        carry, yd = scan_stack(
+            carry, layers["dense"], tmap(lambda a: a[:kk], xs), first, False
+        )
+        carry, ym = scan_stack(
+            carry, layers["moe"], tmap(lambda a: a[kk:], xs), first + kk, True
+        )
+        return carry, tmap(
+            lambda d, m: jnp.concatenate([d, m], axis=0), yd, ym
+        )
+    moe_layer = cfg.moe is not None
+    if cfg.attn_pattern is None:
+        return scan_stack(carry, layers, xs, first, moe_layer)
+    pattern = cfg.attn_pattern
+    ng = n_of(layers) // len(pattern)
+    # Where layer i of a period finds its xs: row i of every stack, or,
+    # with xs given per kind, its kind's next row.
+    by_kind = isinstance(xs, dict) and set(xs) == set(pattern)
+    rows = [(k, pattern[:i].count(k)) if by_kind else (None, i)
+            for i, k in enumerate(pattern)]
+    held = xs if by_kind else {None: xs}
+
+    def periods(a):
+        return a.reshape(ng, a.shape[0] // ng, *a.shape[1:])
+
+    def period_body(c, inp):
+        gl, start, gx = inp
+        outs = {k: [] for k in held}
+        for i, (kind, (of, row)) in enumerate(zip(pattern, rows)):
+            c, y = step(
+                c, tmap(lambda a, i=i: a[i], gl), start + i,
+                tmap(lambda a, row=row: a[row], gx[of]), moe_layer, kind,
+            )
+            outs[of].append(y)
+        return c, {
+            k: tmap(lambda *a: jnp.stack(a, axis=0), *v)
+            for k, v in outs.items()
+        }
+
+    carry, ys = jax.lax.scan(
+        period_body, carry,
+        (tmap(periods, layers), indices(ng, first, len(pattern)),
+         tmap(periods, held)),
+    )
+    ys = merged(ys)
+    return carry, ys if by_kind else ys[None]
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -1221,20 +1340,37 @@ def forward(
         # int32 ids.
         segment_ids = constrain(segment_ids, mesh, ("batch", None))
 
-    def make_block(moe_flag, attn_kind=None):
-        blk = functools.partial(
-            _block, cfg, mesh, attn_impl, segments=segment_ids,
-            moe_layer=moe_flag, attn_kind=attn_kind,
-        )
-        if cfg.remat:
-            blk = jax.checkpoint(blk, policy=_remat_policy(cfg.remat_policy))
-        return blk
+    def run_stack(layers, x, cos, sin, cos_l, sin_l, seg):
+        """Walk a layers tree: the whole stack, or one pipeline stage's
+        with the rope tables and segment ids of the microbatch it holds.
+        Returns (x, aux summed over the layers walked)."""
+        def step(carry, lp, li, xs_l, moe_layer, attn_kind):
+            def blk(x, lp, cos, sin, seg):
+                return _block(
+                    cfg, mesh, attn_impl, x, lp, cos, sin, segments=seg,
+                    moe_layer=moe_layer, attn_kind=attn_kind,
+                )
+
+            if cfg.remat:
+                blk = jax.checkpoint(
+                    blk, policy=_remat_policy(cfg.remat_policy)
+                )
+            x, acc = carry
+            # Gemma-3 dual rope: "window" layers take the local tables.
+            local = cos_l is not None and attn_kind == "window"
+            x, _, moe_out = blk(
+                x, lp, cos_l if local else cos, sin_l if local else sin, seg
+            )
+            return (x, _add_aux(acc, moe_out)), None
+
+        return scan_layers(cfg, layers, (x, _zero_aux()), step)[0]
 
     from shellac_tpu.parallel.mesh import AXIS_PIPE
 
     pp = mesh.shape.get(AXIS_PIPE, 1) if mesh is not None else 1
     if pp > 1 and cfg.eva is not None:
         raise NotImplementedError("pp over EVA attention is not wired yet")
+    n_micro = 1
     if pp > 1:
         from shellac_tpu.parallel.pipeline import pipeline_apply
 
@@ -1274,46 +1410,26 @@ def forward(
             params["layers"],
         )
 
-        aux0 = _zero_aux()
-
-        # The block partial above binds the whole-batch segment row;
-        # microbatches see a slice of the batch, so the pipeline needs
-        # unbound blocks whose RoPE tables / segment ids ride WITH
-        # each microbatch through the stage shift register.
-        def make_pp_block(moe_flag, attn_kind=None):
-            def raw(x, lp, cos_m, sin_m, seg_m):
-                return _block(
-                    cfg, mesh, attn_impl, x, lp, cos_m, sin_m,
-                    segments=seg_m, moe_layer=moe_flag, attn_kind=attn_kind,
-                )
-
-            if cfg.remat:
-                return jax.checkpoint(
-                    raw, policy=_remat_policy(cfg.remat_policy)
-                )
-            return raw
-
-
-
-        ragged = positions is not None or segment_ids is not None
-        if ragged:
+        # Microbatches see a slice of the batch, so per-row RoPE tables
+        # and segment ids ride WITH each microbatch through the stage
+        # shift register, as extras.
+        if positions is not None or segment_ids is not None:
             extras = {"cos": cos, "sin": sin}
-            extras_axes = {
-                "cos": ("batch", "seq", None),
-                "sin": ("batch", "seq", None),
-            }
             if cos_l is not None:
                 extras.update({"cos_l": cos_l, "sin_l": sin_l})
-                extras_axes.update({
-                    "cos_l": ("batch", "seq", None),
-                    "sin_l": ("batch", "seq", None),
-                })
+            extras_axes = {k: ("batch", "seq", None) for k in extras}
             if segment_ids is not None:
                 # Keep the sp replication set up above: sharding seg
                 # over "seq" here would reintroduce the per-layer sp
                 # all-gather inside every pipeline tick.
                 extras["seg"] = segment_ids
                 extras_axes["seg"] = ("batch", None)
+
+            def stage_fn(sp_lp, x, ex):
+                return run_stack(
+                    sp_lp, x, ex["cos"], ex["sin"], ex.get("cos_l"),
+                    ex.get("sin_l"), ex.get("seg"),
+                )
         else:
             extras = extras_axes = None
             # Uniform positions: a (1, S, half) table broadcasts over
@@ -1322,200 +1438,32 @@ def forward(
             if cos_l is not None:
                 cos_l, sin_l = cos_l[:1], sin_l[:1]
 
-        if grouped_moe(cfg):
-            pp_blk_d = make_pp_block(False)
-            pp_blk_m = make_pp_block(True)
-
-            def run_stack(sp_glp, x, cos_m, sin_m, seg_m,
-                          cos_lm=None, sin_lm=None):
-                # sp_glp: this stage's groups — {"dense": (Gs, every-1,
-                # ...), "moe": (Gs, ...)}.
-                def blk_d(x, lp):
-                    return pp_blk_d(x, lp, cos_m, sin_m, seg_m)
-
-                def blk_m(x, lp):
-                    return pp_blk_m(x, lp, cos_m, sin_m, seg_m)
-
-                return _grouped_scan(blk_d, blk_m, x, aux0, sp_glp)
-        elif cfg.attn_pattern is not None:
-            period = len(cfg.attn_pattern)
-            pp_blocks = [make_pp_block(None, kind)
-                         for kind in cfg.attn_pattern]
-
-            def run_stack(sp_lp, x, cos_m, sin_m, seg_m,
-                          cos_lm=None, sin_lm=None):
-                # sp_lp: (per_stage, ...) -> (groups, period, ...);
-                # the scan walks groups, the pattern unrolls inside (a
-                # window is a static kernel argument, so each kind
-                # compiles its own block body).
-                glp = jax.tree.map(
-                    lambda a: a.reshape(
-                        a.shape[0] // period, period, *a.shape[1:]
-                    ),
-                    sp_lp,
-                )
-
-                def body(carry, gl):
-                    x, acc = carry
-                    for i, blk in enumerate(pp_blocks):
-                        lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
-                        local = (cos_lm is not None
-                                 and cfg.attn_pattern[i] == "window")
-                        x, _, moe_out = blk(
-                            x, lp_i, cos_lm if local else cos_m,
-                            sin_lm if local else sin_m, seg_m,
-                        )
-                        acc = _add_aux(acc, moe_out)
-                    return (x, acc), None
-
-                (x, acc), _ = jax.lax.scan(body, (x, aux0), glp)
-                return x, acc
-        else:
-            pp_block = make_pp_block(None)
-
-            def run_stack(sp_lp, x, cos_m, sin_m, seg_m,
-                          cos_lm=None, sin_lm=None):
-                def body(carry, lp):
-                    x, acc = carry
-                    x, _, moe_out = pp_block(x, lp, cos_m, sin_m, seg_m)
-                    return (x, _add_aux(acc, moe_out)), None
-
-                (x, acc), _ = jax.lax.scan(body, (x, aux0), sp_lp)
-                return x, acc
-
-        if ragged:
-            def stage_fn(sp_lp, x, ex):
-                return run_stack(
-                    sp_lp, x, ex["cos"], ex["sin"], ex.get("seg"),
-                    ex.get("cos_l"), ex.get("sin_l"),
-                )
-        else:
             def stage_fn(sp_lp, x):
-                return run_stack(sp_lp, x, cos, sin, None, cos_l, sin_l)
+                return run_stack(sp_lp, x, cos, sin, cos_l, sin_l, None)
 
         n_micro = pipeline_microbatches or pp
         x, aux_sum = pipeline_apply(
             stage_fn, stage_params, x,
-            n_stages=pp, n_micro=n_micro, mesh=mesh, aux_init=aux0,
+            n_stages=pp, n_micro=n_micro, mesh=mesh, aux_init=_zero_aux(),
             extras=extras, extras_axes=extras_axes,
         )
-        # aux_sum holds every (layer, microbatch) contribution once.
-        # The aux loss is the per-microbatch estimate averaged over
-        # microbatches (each micro's balance loss is computed on its own
-        # token population — the standard grad-accum estimator);
-        # diagnostics additionally average over layers.
-        inv_m = 1.0 / n_micro
-        # Diagnostics average over the layers that actually have
-        # routers: every layer for uniform MoE, one per group for
-        # interleaved stacks.
-        routers = (cfg.n_layers // cfg.moe_every if grouped_moe(cfg)
-                   else cfg.n_layers)
-        inv_lm = inv_m / routers
-        aux = {
-            "aux": aux_sum["aux"] * inv_m,
-            "balance_loss": aux_sum["balance_loss"] * inv_lm,
-            "router_z_loss": aux_sum["router_z_loss"] * inv_lm,
-            "dropped_frac": aux_sum["dropped_frac"] * inv_lm,
-        }
-    elif grouped_moe(cfg):
-        aux0 = _zero_aux()
-        bd, bm = make_block(False), make_block(True)
-        x, aux_acc = _grouped_scan(
-            lambda x, lp: bd(x, lp, cos, sin),
-            lambda x, lp: bm(x, lp, cos, sin),
-            x, aux0, params["layers"],
-        )
-        # Aux loss sums over MoE layers; diagnostics average over the
-        # layers that actually have routers (one per group).
-        inv_l = cfg.moe_every / cfg.n_layers
-        aux = {
-            "aux": aux_acc["aux"],
-            "balance_loss": aux_acc["balance_loss"] * inv_l,
-            "router_z_loss": aux_acc["router_z_loss"] * inv_l,
-            "dropped_frac": aux_acc["dropped_frac"] * inv_l,
-        }
-    elif first_k_layout(cfg):
-        aux0 = _zero_aux()
-        bd, bm = make_block(False), make_block(True)
-
-        def stack_body(blk):
-            def body(carry, lp):
-                x, acc = carry
-                x, _, mo = blk(x, lp, cos, sin)
-                return (x, _add_aux(acc, mo)), None
-
-            return body
-
-        (x, acc), _ = jax.lax.scan(
-            stack_body(bd), (x, aux0), params["layers"]["dense"]
-        )
-        (x, aux_acc), _ = jax.lax.scan(
-            stack_body(bm), (x, acc), params["layers"]["moe"]
-        )
-        routers = cfg.n_layers - cfg.first_k_dense
-        aux = {
-            "aux": aux_acc["aux"],
-            "balance_loss": aux_acc["balance_loss"] / routers,
-            "router_z_loss": aux_acc["router_z_loss"] / routers,
-            "dropped_frac": aux_acc["dropped_frac"] / routers,
-        }
-    elif cfg.attn_pattern is not None:
-        # Patterned attention (Gemma-2/3 alternating local/global): the
-        # flat (L, ...) stack reshapes to (L/period, period, ...) and the
-        # scan walks whole periods, unrolling the kinds inside — window
-        # size is a static kernel argument, so each kind needs its own
-        # compiled block body, but params/checkpoints keep the flat
-        # layers axis (sharding, LoRA, conversion are unchanged).
-        aux0 = _zero_aux()
-        period = len(cfg.attn_pattern)
-        blocks = [make_block(None, kind) for kind in cfg.attn_pattern]
-        glp = jax.tree.map(
-            lambda a: a.reshape(
-                a.shape[0] // period, period, *a.shape[1:]
-            ),
-            params["layers"],
-        )
-
-        def group_body(carry, gl):
-            x, acc = carry
-            for i, blk in enumerate(blocks):
-                lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
-                local = (cos_l is not None
-                         and cfg.attn_pattern[i] == "window")
-                x, _, moe_out = blk(
-                    x, lp_i, cos_l if local else cos,
-                    sin_l if local else sin,
-                )
-                acc = _add_aux(acc, moe_out)
-            return (x, acc), None
-
-        (x, aux_acc), _ = jax.lax.scan(group_body, (x, aux0), glp)
-        inv_l = 1.0 / cfg.n_layers
-        aux = {
-            "aux": aux_acc["aux"],
-            "balance_loss": aux_acc["balance_loss"] * inv_l,
-            "router_z_loss": aux_acc["router_z_loss"] * inv_l,
-            "dropped_frac": aux_acc["dropped_frac"] * inv_l,
-        }
     else:
-        aux0 = _zero_aux()
-        block = make_block(None)
-
-        def scan_body(carry, lp):
-            x, acc = carry
-            x, _, moe_out = block(x, lp, cos, sin)
-            acc = jax.tree.map(lambda a, b: a + b, acc, moe_out)
-            return (x, acc), None
-
-        (x, aux_acc), _ = jax.lax.scan(scan_body, (x, aux0), params["layers"])
-        # Aux loss sums over layers; diagnostics average.
-        inv_l = 1.0 / cfg.n_layers
-        aux = {
-            "aux": aux_acc["aux"],
-            "balance_loss": aux_acc["balance_loss"] * inv_l,
-            "router_z_loss": aux_acc["router_z_loss"] * inv_l,
-            "dropped_frac": aux_acc["dropped_frac"] * inv_l,
-        }
+        x, aux_sum = run_stack(
+            params["layers"], x, cos, sin, cos_l, sin_l, segment_ids
+        )
+    # aux_sum holds every (layer, microbatch) contribution once. The aux
+    # loss sums over layers and averages over microbatches (each micro's
+    # balance loss is computed on its own token population — the
+    # standard grad-accum estimator); diagnostics additionally average
+    # over the layers that hold a router.
+    inv_m = 1.0 / n_micro
+    inv_lm = inv_m / n_routers(cfg)
+    aux = {
+        "aux": aux_sum["aux"] * inv_m,
+        "balance_loss": aux_sum["balance_loss"] * inv_lm,
+        "router_z_loss": aux_sum["router_z_loss"] * inv_lm,
+        "dropped_frac": aux_sum["dropped_frac"] * inv_lm,
+    }
 
     if return_hidden:
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
@@ -1568,45 +1516,6 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
     return logits
 
 
-def pattern_period_scan(pattern, x, layer_stack, caches, body_one):
-    """Scan whole attn_pattern periods: stacked leaves (L, ...)
-    reshape to (L/period, period, ...) and the kinds unroll inside
-    the scan body (window sizes are static kernel arguments).
-    caches: tuple of (L, ...) arrays riding with the layers;
-    body_one(x, lp, cache_slices, kind) -> (x, new_cache_tuple).
-    Returns (x, tuple of restacked (L, ...) caches).
-
-    The ONE definition of the period walk, shared by
-    forward_with_cache's patterned branch and the pipelined decode's
-    per-stage scan (inference/pp_pipeline.py) so the layer order and
-    field stacking cannot drift between them."""
-    period = len(pattern)
-
-    def greshape(a):
-        return a.reshape(a.shape[0] // period, period, *a.shape[1:])
-
-    glp = jax.tree.map(greshape, layer_stack)
-    gcaches = tuple(greshape(c) for c in caches)
-
-    def group_body(x, inp):
-        gl = inp[0]
-        outs = []
-        for i, kind in enumerate(pattern):
-            lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
-            x, nc = body_one(
-                x, lp_i, tuple(c[i] for c in inp[1:]), kind
-            )
-            outs.append(nc)
-        stacked = tuple(
-            jnp.stack([o[j] for o in outs], axis=0)
-            for j in range(len(outs[0]))
-        )
-        return x, stacked
-
-    x, gnew = jax.lax.scan(group_body, x, (glp,) + gcaches)
-    return x, tuple(c.reshape(-1, *c.shape[2:]) for c in gnew)
-
-
 def forward_with_cache(
     cfg: ModelConfig,
     params: Params,
@@ -1655,14 +1564,14 @@ def forward_with_cache(
         )
     paged = isinstance(cache, (PagedKVCache, QuantPagedKVCache))
     quant = isinstance(
-        cache, (QuantKVCache, QuantPagedKVCache, QuantRollingKVCache)
+        cache, (QuantKVCache, QuantPagedKVCache, QuantRollingKVCache,
+                QuantPatternedKVCache)
     )
     rolled = isinstance(cache, (RollingKVCache, QuantRollingKVCache))
-    mixed = isinstance(cache, PatternedKVCache)
-    quant_mixed = isinstance(cache, QuantPatternedKVCache)
-    if (rolled or mixed or quant_mixed) and cfg.attn_window is None:
+    mixed = isinstance(cache, (PatternedKVCache, QuantPatternedKVCache))
+    if (rolled or mixed) and cfg.attn_window is None:
         raise ValueError("rolling cache on a model without attn_window")
-    if (mixed or quant_mixed) and cfg.attn_pattern is None:
+    if mixed and cfg.attn_pattern is None:
         raise ValueError("patterned cache on a model without attn_pattern")
     cdt = cfg.compute_dtype
     b, s = tokens.shape
@@ -1683,38 +1592,29 @@ def forward_with_cache(
     x = _embed_tokens(cfg, params, tokens, cdt, mesh=mesh)
     x = constrain(x, mesh, ("batch", "seq", None))
 
-    def run_block(x, lp, ck, cv, moe_flag, scales=None, attn_kind=None,
-                  block_rolled=None, tables=None):
+    def block(x, lp, state, moe_layer, attn_kind, **kw):
+        """One layer over `state`, the (k, v) of its kind of cache."""
         local = cos_l is not None and attn_kind == "window"
         return _block(
             cfg, mesh, attn_impl, x, lp,
             cos_l if local else cos, sin_l if local else sin,
-            cache=(ck, cv, index, positions), fresh_cache=fresh_cache,
-            page_tables=tables, moe_layer=moe_flag, kv_scales=scales,
-            attn_kind=attn_kind,
-            rolled=rolled if block_rolled is None else block_rolled,
-            new_len=new_tokens_len,
+            cache=(*state, index, positions), fresh_cache=fresh_cache,
+            moe_layer=moe_layer, attn_kind=attn_kind,
+            new_len=new_tokens_len, **kw,
         )
 
-    def pattern_scan(x, layer_stack, caches, body_one):
-        return pattern_period_scan(
-            cfg.attn_pattern, x, layer_stack, caches, body_one
-        )
-
-    # Cache leaves riding the layer loops: values only (bf16) or values
-    # + scale stacks (int8). ONE set of stack-dispatch bodies serves
-    # both, threading the scales to run_block when present — the same
-    # field-count parameterization the mixed branch uses.
-    if mixed or quant_mixed:
-        names = ()  # mixed caches carry kw/vw/kf/vf, named in their branch
+    # Cache leaves, values only (bf16) or values + scale stacks (int8):
+    # one step a kind of state serves both, handing the scales to the
+    # block when they are there.
+    if mixed:
+        w_names = ("kw", "vw", "kws", "vws") if quant else ("kw", "vw")
+        f_names = ("kf", "vf", "kfs", "vfs") if quant else ("kf", "vf")
+        names = w_names + f_names
     elif eva:
         names = ("k", "v", "pk", "pv")
     else:
         names = kv_field_names("int8" if quant else None)
     cleaves = tuple(getattr(cache, n) for n in names)
-
-    def _scales_of(vals):
-        return (vals[2], vals[3]) if quant else None
 
     if eva:
         # Both kinds of EVA state ride the layer loop whole, as carries,
@@ -1731,22 +1631,17 @@ def forward_with_cache(
             axis=1,
         )
 
-        def eva_body(carry, inp):
+        def step(carry, lp, li, xs_l, moe_layer, attn_kind):
             x, ring, pool = carry
-            lp, li = inp
-            x, (ring, pool), _ = _block(
-                cfg, mesh, attn_impl, x, lp, cos, sin,
-                cache=(ring, pool, index, positions),
-                fresh_cache=fresh_cache, new_len=new_tokens_len,
+            x, (ring, pool), _ = block(
+                x, lp, (ring, pool), moe_layer, attn_kind,
                 page_tables={"layer": li, "slots": cache.slots,
                              "tables": cache.tables, "owned": owned},
             )
             return (x, ring, pool), None
 
-        (x, ring, pool), _ = jax.lax.scan(
-            eva_body, (x, cleaves[:2], cleaves[2:]),
-            (params["layers"],
-             jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        (x, ring, pool), _ = scan_layers(
+            cfg, params["layers"], (x, cleaves[:2], cleaves[2:]), step
         )
         news = ring + pool
     elif paged:
@@ -1758,209 +1653,42 @@ def forward_with_cache(
         # donated buffers come back where they came in
         # (tests/test_paged_inplace.py holds the compiled program to it).
         n_blocks = cache.k.shape[1]
-        pools = tuple(
-            a.reshape(a.shape[0] * n_blocks, *a.shape[2:]) for a in cleaves
+
+        def step(carry, lp, li, xs_l, moe_layer, attn_kind):
+            x, pools = carry
+            x, pools, _ = block(
+                x, lp, pools[:2], moe_layer, attn_kind,
+                kv_scales=pools[2:] or None,
+                page_tables=cache.tables + li * n_blocks,
+            )
+            return (x, pools), None
+
+        (x, pools), _ = scan_layers(
+            cfg, params["layers"],
+            (x, tuple(a.reshape(a.shape[0] * n_blocks, *a.shape[2:])
+                      for a in cleaves)), step,
         )
-
-        def step(x, pools, lp, li, moe_flag, attn_kind=None):
-            x, pools, _ = run_block(
-                x, lp, pools[0], pools[1], moe_flag, _scales_of(pools),
-                attn_kind=attn_kind, tables=cache.tables + li * n_blocks,
-            )
-            return x, pools
-
-        def scan_stack(x, pools, stack, first, moe_flag):
-            """Layers first, first + 1, ... of one homogeneous stack."""
-            n = jax.tree.leaves(stack)[0].shape[0]
-
-            def body(carry, inp):
-                return step(*carry, *inp, moe_flag), None
-
-            (x, pools), _ = jax.lax.scan(
-                body, (x, pools),
-                (stack, first + jnp.arange(n, dtype=jnp.int32)),
-            )
-            return x, pools
-
-        def group_firsts(size):
-            return size * jnp.arange(cfg.n_layers // size, dtype=jnp.int32)
-
-        if first_k_layout(cfg):
-            x, pools = scan_stack(
-                x, pools, params["layers"]["dense"], 0, False
-            )
-            x, pools = scan_stack(
-                x, pools, params["layers"]["moe"], cfg.first_k_dense, True
-            )
-        elif grouped_moe(cfg):
-            every = cfg.moe_every
-
-            def group_body(carry, inp):
-                glp, first = inp
-                x, pools = scan_stack(*carry, glp["dense"], first, False)
-                return step(
-                    x, pools, glp["moe"], first + every - 1, True
-                ), None
-
-            (x, pools), _ = jax.lax.scan(
-                group_body, (x, pools), (params["layers"], group_firsts(every))
-            )
-        elif cfg.attn_pattern is not None:
-            period = len(cfg.attn_pattern)
-            glp = jax.tree.map(
-                lambda a: a.reshape(
-                    a.shape[0] // period, period, *a.shape[1:]
-                ),
-                params["layers"],
-            )
-
-            def group_body(carry, inp):
-                gl, first = inp
-                x, pools = carry
-                for i, kind in enumerate(cfg.attn_pattern):
-                    lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
-                    x, pools = step(x, pools, lp_i, first + i, None, kind)
-                return (x, pools), None
-
-            (x, pools), _ = jax.lax.scan(
-                group_body, (x, pools), (glp, group_firsts(period))
-            )
-        else:
-            x, pools = scan_stack(x, pools, params["layers"], 0, None)
         news = tuple(p.reshape(a.shape) for p, a in zip(pools, cleaves))
-    elif first_k_layout(cfg):
-        # DeepSeek layout: dense prefix stack, then the all-MoE tail.
-        kk = cfg.first_k_dense
-
-        def stack_body(moe_flag):
-            def body(x, layer_in):
-                lp, vals = layer_in[0], layer_in[1:]
-                x, nc, _ = run_block(
-                    x, lp, vals[0], vals[1], moe_flag, _scales_of(vals)
-                )
-                return x, nc
-
-            return body
-
-        x, nd = jax.lax.scan(
-            stack_body(False), x,
-            (params["layers"]["dense"],) + tuple(a[:kk] for a in cleaves),
-        )
-        x, nm = jax.lax.scan(
-            stack_body(True), x,
-            (params["layers"]["moe"],) + tuple(a[kk:] for a in cleaves),
-        )
-        news = tuple(
-            jnp.concatenate([d, m], axis=0) for d, m in zip(nd, nm)
-        )
-    elif grouped_moe(cfg):
-        # Interleaved stacks: scan whole (dense^(every-1), moe) groups.
-        every = cfg.moe_every
-        ng = cfg.n_layers // every
-        gc = tuple(a.reshape(ng, every, *a.shape[1:]) for a in cleaves)
-
-        def group_body(x, inp):
-            glp, cg = inp[0], inp[1:]
-
-            def dense_body(x2, li):
-                lp, vals = li[0], li[1:]
-                x2, nc, _ = run_block(
-                    x2, lp, vals[0], vals[1], False, _scales_of(vals)
-                )
-                return x2, nc
-
-            x, nd = jax.lax.scan(
-                dense_body, x,
-                (glp["dense"],) + tuple(c[: every - 1] for c in cg),
-            )
-            moe_vals = tuple(c[every - 1] for c in cg)
-            x, nm, _ = run_block(
-                x, glp["moe"], moe_vals[0], moe_vals[1], True,
-                _scales_of(moe_vals),
-            )
-            return x, tuple(
-                jnp.concatenate([d, m[None]], axis=0)
-                for d, m in zip(nd, nm)
-            )
-
-        x, gn = jax.lax.scan(group_body, x, (params["layers"],) + gc)
-        news = tuple(a.reshape(cfg.n_layers, *a.shape[2:]) for a in gn)
-    elif mixed or quant_mixed:
-        # Mixed ring/dense stacks: the scan walks pattern periods with
-        # per-kind cursors — "window" blocks consume ring rows (rolled
-        # update + rolled read), "full" blocks consume dense rows (the
-        # Pallas decode kernel path). One body covers bf16 (2 fields
-        # per kind) and int8 (4: values + scale stacks, threading the
-        # scales to run_block so window blocks take the quantized ring
-        # and full blocks the dense int8 path).
-        from shellac_tpu.inference.kvcache import pattern_kind_counts
-
-        w_names = (("kw", "vw", "kws", "vws") if quant_mixed
-                   else ("kw", "vw"))
-        f_names = (("kf", "vf", "kfs", "vfs") if quant_mixed
-                   else ("kf", "vf"))
-        nfields = len(w_names)
-        period = len(cfg.attn_pattern)
-        ng = cfg.n_layers // period
-        nw, nf = pattern_kind_counts(cfg)
-        greshape = lambda a, n: a.reshape(ng, n, *a.shape[1:])  # noqa: E731
-        glp = jax.tree.map(
-            lambda a: a.reshape(ng, period, *a.shape[1:]),
-            params["layers"],
-        )
-        gw = tuple(greshape(getattr(cache, n), nw) for n in w_names)
-        gf = tuple(greshape(getattr(cache, n), nf) for n in f_names)
-
-        def group_body(x, inp):
-            gl = inp[0]
-            w_in = inp[1:1 + nfields]
-            f_in = inp[1 + nfields:]
-            w_out, f_out = [], []
-            cursors = {"window": 0, "full": 0}
-            for i, kind in enumerate(cfg.attn_pattern):
-                lp_i = jax.tree.map(lambda a, i=i: a[i], gl)
-                is_w = kind == "window"
-                src, outs = (w_in, w_out) if is_w else (f_in, f_out)
-                cur = cursors[kind]
-                scales = ((src[2][cur], src[3][cur]) if nfields == 4
-                          else None)
-                x, nc, _ = run_block(
-                    x, lp_i, src[0][cur], src[1][cur], None, scales,
-                    attn_kind=kind, block_rolled=is_w,
-                )
-                outs.append(nc)
-                cursors[kind] = cur + 1
-            stack = lambda outs, j: jnp.stack(  # noqa: E731
-                [o[j] for o in outs], axis=0
-            )
-            return x, tuple(
-                stack(outs, j)
-                for outs in (w_out, f_out) for j in range(nfields)
-            )
-
-        x, news = jax.lax.scan(group_body, x, (glp,) + gw + gf)
-        backflat = lambda a: a.reshape(-1, *a.shape[2:])  # noqa: E731
-        news = [backflat(a) for a in news]
-        names = w_names + f_names
-    elif cfg.attn_pattern is not None:
-        def body_one(x, lp, cs, kind):
-            x, nc, _ = run_block(
-                x, lp, cs[0], cs[1], None, _scales_of(cs), attn_kind=kind
-            )
-            return x, nc
-
-        x, news = pattern_scan(x, params["layers"], cleaves, body_one)
     else:
-        def scan_body(x, layer_in):
-            lp, vals = layer_in[0], layer_in[1:]
-            x, new_cache, _ = run_block(
-                x, lp, vals[0], vals[1], None, _scales_of(vals)
+        # Slot caches ride as xs/ys, each layer its own rows. The mixed
+        # ring/dense caches hold one set of stacks a kind: "window"
+        # layers take ring rows (rolled update + rolled read), "full"
+        # layers dense rows (the decode kernel's path).
+        def step(x, lp, li, vals, moe_layer, attn_kind):
+            x, new, _ = block(
+                x, lp, vals[:2], moe_layer, attn_kind,
+                kv_scales=vals[2:] or None,
+                rolled=rolled or (mixed and attn_kind == "window"),
             )
-            return x, new_cache
+            return x, new
 
-        x, news = jax.lax.scan(
-            scan_body, x, (params["layers"],) + cleaves
-        )
+        xs = cleaves
+        if mixed:
+            half = len(cleaves) // 2
+            xs = {"window": cleaves[:half], "full": cleaves[half:]}
+        x, news = scan_layers(cfg, params["layers"], x, step, xs=xs)
+        if mixed:
+            news = news["window"] + news["full"]
 
     logits = unembed(cfg, params, x, mesh=mesh)
     if new_tokens_len is None:
