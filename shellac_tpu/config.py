@@ -39,16 +39,20 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_loss_weight: float = 0.01
     router_z_loss_weight: float = 1e-3
-    # Never drop tokens: capacity is sized to the worst case (T per
-    # expert), costing O(E*T*D) dispatch buffers. Exact Mixtral-style
-    # computation — use for inference/conversion parity, not large-T
-    # training.
+    # Never drop tokens: exact Mixtral-style computation in every
+    # call (what a converted checkpoint needs for parity). On one
+    # device the T*k routed rows run sorted by expert as grouped GEMMs,
+    # the work and memory of a dense MLP over them; on a mesh, and
+    # over quantized experts, capacity is sized to the worst case (T
+    # per expert: O(E*T*D) dispatch buffers, not for large-T training
+    # there). ops/moe.py::moe_ffn_path is the rule.
     dropless: bool = False
-    # Dropless TRAINING: sorted-segment grouped expert matmuls
-    # (jax.lax.ragged_dot) — no capacity buckets, nothing drops
-    # (moe_dropped_frac == 0 by construction), O(T*k*F) memory like a
-    # dense MLP. The loss-sensitive fine-tuning option; decode keeps
-    # the capacity-at-T path (ops/moe.py:moe_ffn_grouped).
+    # Dropless TRAINING: forces the sorted-segment grouped matmuls
+    # (jax.lax.ragged_dot) wherever capacity buckets would run,
+    # on any mesh — nothing drops (moe_dropped_frac == 0 by
+    # construction), O(T*k*F) memory like a dense MLP. The
+    # loss-sensitive fine-tuning option; cached continuations follow
+    # the rule above (ops/moe.py:moe_ffn_grouped).
     grouped_dropless: bool = False
     # DeepSeek-style always-active shared experts: one fused FFN of
     # hidden size num_shared_experts * expert ff width added to the

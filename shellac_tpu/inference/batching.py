@@ -1464,7 +1464,7 @@ class BatchingEngine:
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = req.tokens
         self._key, sub = jax.random.split(self._key)
-        self._count_prefill(s, pad)
+        self._count_prefill(s, pad, True)
         cache, first, lp, plp, tlv, tli = self._prefill_jit[key](
             self.params, self._cache, jnp.asarray(padded),
             jnp.asarray([s], jnp.int32), slot, sub, self._slot_samp(slot, req),
@@ -1478,12 +1478,23 @@ class BatchingEngine:
         return (first, lp, ((tlv, tli) if self.top_logprobs else None),
                 plp if req.prompt_logprobs else None)
 
-    def _count_prefill(self, tokens: int, padded: int) -> None:
+    def _count_prefill(self, tokens: int, padded: int,
+                       fresh: bool) -> None:
         """A prefill or chunk program of `tokens` real and `padded`
         bucketed prompt tokens is about to be dispatched (called
-        inside its engine.prefill_dispatch span)."""
+        inside its engine.prefill_dispatch span), into an empty cache
+        (`fresh`: a whole prompt, a first chunk) or behind resident
+        tokens. The model's own rule says whether its expert FFN runs
+        over the sorted routed rows."""
         steps = self.obs.steps
-        steps.count(prefill_tokens=tokens, prefill_padded_tokens=padded)
+        path = transformer.expert_ffn_path(
+            self.cfg, self.params["layers"], cached=not fresh,
+            mesh=self.mesh,
+        )
+        steps.count(
+            prefill_tokens=tokens, prefill_padded_tokens=padded,
+            prefill_sorted_tokens=padded if path == "sorted" else 0,
+        )
         steps.annotate(bucket=padded)
 
     def _prefill_start_offset(self, slot: int) -> int:
@@ -1702,7 +1713,7 @@ class BatchingEngine:
                 boundary = (jnp.asarray(0, jnp.int32) if final
                             else jnp.asarray(int(req.tokens[off + s]),
                                              jnp.int32))
-                self._count_prefill(s, pad)
+                self._count_prefill(s, pad, off == 0)
                 cache, first, lp, plp_w, blp, tlv, tli = \
                     self._chunk_prefill(
                         pad, off == 0, jnp.asarray(
@@ -2646,7 +2657,7 @@ class PagedBatchingEngine(BatchingEngine):
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = suffix
         self._key, sub = jax.random.split(self._key)
-        self._count_prefill(s, pad)
+        self._count_prefill(s, pad, False)
         # One dispatch path: the chunk-continuation program IS the
         # suffix prefill (a suffix is a chunk past `p` resident tokens).
         cache, first, lp, _, _, tlv, tli = self._chunk_prefill(

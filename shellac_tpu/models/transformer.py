@@ -86,6 +86,28 @@ def map_layer_stacks(layers, fn):
     return fn(layers, None)
 
 
+def _expert_stack(layers):
+    """The stack of a layers tree that holds the MoE layers."""
+    return layers["moe"] if is_grouped_layers(layers) else layers
+
+
+def expert_ffn_path(cfg: ModelConfig, layers, *, cached: bool,
+                    mesh=None) -> Optional[str]:
+    """ops/moe.py::moe_ffn_path as this model's MoE layers will ask it
+    (`layers` is params["layers"]); None for a model without experts.
+    For callers that need to know the form before the call is traced:
+    the cached forward (does the kernel want the stacks whole?) and the
+    engine's `prefill_sorted_tokens` count."""
+    if cfg.moe is None:
+        return None
+    from shellac_tpu.ops.moe import experts_plain, moe_ffn_path
+
+    return moe_ffn_path(
+        cfg.moe, cached=cached, mesh=mesh,
+        plain=experts_plain(_expert_stack(layers), cfg.compute_dtype),
+    )
+
+
 def n_routers(cfg: ModelConfig) -> int:
     """Layers that hold a router: what MoE diagnostics average over
     (every layer of a flat stack, a dense model's zeros included)."""
@@ -96,7 +118,8 @@ def n_routers(cfg: ModelConfig) -> int:
     return cfg.n_layers
 
 
-def scan_layers(cfg: ModelConfig, layers, carry, step, xs=(), first=0):
+def scan_layers(cfg: ModelConfig, layers, carry, step, xs=(), first=0,
+                experts_whole=False):
     """Walk a layers tree in layer order; returns (carry, ys).
 
     The single place that knows how a layer stack is laid out and
@@ -124,6 +147,14 @@ def scan_layers(cfg: ModelConfig, layers, carry, step, xs=(), first=0):
         each kind's stacks holding that kind's layers only, in layer
         order (the mixed ring/dense caches); `ys` come back the same.
 
+      - `experts_whole` keeps the MoE layers' expert weights
+        (ops/moe.py::EXPERT_STACKS) out of the scans: a MoE layer's
+        `lp` then holds each as a `StackRow(stack, row)`, the whole
+        stack and the layer's row in it, for a kernel that reads the
+        layer's experts where they lie (a kernel operand sliced out by
+        the scan is a copy of them). `forward_with_cache` asks where
+        ops/moe.py::sorted_kernel_runs.
+
     The four layouts (ModelConfig.validate keeps them exclusive):
     interleaved = a scan over (dense^(every-1), moe) groups holding a
     scan over the group's dense layers and one MoE step; dense prefix =
@@ -133,6 +164,28 @@ def scan_layers(cfg: ModelConfig, layers, carry, step, xs=(), first=0):
     and checkpoints keep one layers axis; flat = one scan.
     """
     tmap = jax.tree.map
+
+    if experts_whole:
+        from shellac_tpu.ops.moe import EXPERT_STACKS, StackRow
+
+        # A MoE layer's row in its stack, from its index: the stack
+        # starts after the dense prefix, or holds every moe_every-th
+        # layer (the group's last).
+        stack = _expert_stack(layers)
+        experts = {n: stack[n] for n in EXPERT_STACKS}
+        stack = {n: v for n, v in stack.items() if n not in experts}
+        layers = ({**layers, "moe": stack} if is_grouped_layers(layers)
+                  else stack)
+        every = cfg.moe_every if grouped_moe(cfg) else 1
+        skip = cfg.first_k_dense if first_k_layout(cfg) else every - 1
+        inner = step
+
+        def step(carry, lp, li, xs_l, moe_layer, attn_kind):
+            if moe_layer:
+                row = (li - first - skip) // every
+                lp = {**lp, **{n: StackRow(w, row)
+                               for n, w in experts.items()}}
+            return inner(carry, lp, li, xs_l, moe_layer, attn_kind)
 
     def n_of(stack):
         return jax.tree.leaves(stack)[0].shape[0]
@@ -811,16 +864,23 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
     # dense sub-layers of a MoE model run the plain gated MLP.
     use_moe = cfg.moe is not None if moe_layer is None else moe_layer
     if use_moe:
-        from shellac_tpu.ops.moe import moe_ffn
+        from shellac_tpu.ops.moe import (
+            experts_plain,
+            moe_ffn,
+            moe_ffn_grouped,
+            moe_ffn_path,
+        )
 
         # Cached continuation (decode s=1, speculative verify windows,
         # prefix-cached suffix prefill) must never capacity-drop: a
         # dropped token's FFN output would silently become zero, and
-        # decode-path exactness is the serving contract. Only fresh
-        # prefill keeps routed capacity (unless cfg.moe.dropless asks
-        # for exact computation everywhere, or grouped_dropless picks
-        # the sorted-segment training path).
-        is_decode = cache is not None and not fresh_cache
+        # decode-path exactness is the serving contract. Which form
+        # the call then takes (and which a fresh call takes) is
+        # ops/moe.py's rule.
+        path = moe_ffn_path(
+            cfg.moe, cached=cache is not None and not fresh_cache,
+            mesh=mesh, plain=experts_plain(lp, cdt),
+        )
         # Strict lookups for biased gates: a missing bias must be a
         # loud KeyError, not a silent zero (it changes which experts
         # are selected / what they compute).
@@ -832,9 +892,7 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
             b_up=lp["b_up"] if cfg.moe.expert_bias else None,
             b_down=lp["b_down"] if cfg.moe.expert_bias else None,
         )
-        if cfg.moe.grouped_dropless and not is_decode:
-            from shellac_tpu.ops.moe import moe_ffn_grouped
-
+        if path == "sorted":
             down, aux, metrics = moe_ffn_grouped(
                 hx, lp["w_router"], lp["w_gate"], lp["w_up"],
                 lp["w_down"], cfg.moe, mesh=mesh, **bias_kw,
@@ -843,7 +901,7 @@ def _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache, moe_layer,
             down, aux, metrics = moe_ffn(
                 hx, lp["w_router"], lp["w_gate"], lp["w_up"],
                 lp["w_down"], cfg.moe,
-                drop_tokens=not (is_decode or cfg.moe.dropless),
+                drop_tokens=path == "capacity",
                 mesh=mesh, **bias_kw,
             )
         if cfg.moe.num_shared_experts > 0:
@@ -1616,6 +1674,19 @@ def forward_with_cache(
         names = kv_field_names("int8" if quant else None)
     cleaves = tuple(getattr(cache, n) for n in names)
 
+    from shellac_tpu.ops.moe import experts_plain, sorted_kernel_runs
+
+    layers = params["layers"]
+    walk = functools.partial(
+        scan_layers, cfg, layers,
+        experts_whole=(
+            expert_ffn_path(cfg, layers, cached=not fresh_cache, mesh=mesh)
+            == "sorted"
+            and sorted_kernel_runs(mesh)
+            and experts_plain(_expert_stack(layers), cdt)
+        ),
+    )
+
     if eva:
         # Both kinds of EVA state ride the layer loop whole, as carries,
         # as the paged pool does below; a layer writes and reads its own
@@ -1640,9 +1711,7 @@ def forward_with_cache(
             )
             return (x, ring, pool), None
 
-        (x, ring, pool), _ = scan_layers(
-            cfg, params["layers"], (x, cleaves[:2], cleaves[2:]), step
-        )
+        (x, ring, pool), _ = walk((x, cleaves[:2], cleaves[2:]), step)
         news = ring + pool
     elif paged:
         # A paged pool rides the layer loops as a CARRY, never as xs/ys:
@@ -1663,8 +1732,7 @@ def forward_with_cache(
             )
             return (x, pools), None
 
-        (x, pools), _ = scan_layers(
-            cfg, params["layers"],
+        (x, pools), _ = walk(
             (x, tuple(a.reshape(a.shape[0] * n_blocks, *a.shape[2:])
                       for a in cleaves)), step,
         )
@@ -1686,7 +1754,7 @@ def forward_with_cache(
         if mixed:
             half = len(cleaves) // 2
             xs = {"window": cleaves[:half], "full": cleaves[half:]}
-        x, news = scan_layers(cfg, params["layers"], x, step, xs=xs)
+        x, news = walk(x, step, xs=xs)
         if mixed:
             news = news["window"] + news["full"]
 

@@ -84,8 +84,11 @@ SPAN_PHASE = {
 }
 
 #: The work counts every step record carries (see docs/observability.md
-#: "Step spans"). The first five are taken where the number is known;
-#: `compiles`/`compile_s` are what the process-wide compile listener
+#: "Step spans"). The first six are taken where the number is known
+#: (`prefill_sorted_tokens`: the padded prompt rows whose expert FFN
+#: ran over the sorted routed rows, by ops/moe.py::moe_ffn_path; 0 on
+#: a model without experts); `compiles`/`compile_s` are what the
+#: process-wide compile listener
 #: added to the registry since the previous record; the last two are
 #: the cache backend's (`CacheBackend.window_counts`; 0 but on the 'eva'
 #: backend): over every slot-tick of a synced window that produced a
@@ -93,7 +96,8 @@ SPAN_PHASE = {
 #: earlier windows that its query attended, from lengths the host has.
 STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
-               "prefill_padded_tokens", "compiles", "compile_s",
+               "prefill_padded_tokens", "prefill_sorted_tokens",
+               "compiles", "compile_s",
                "eva_window_rows", "eva_summary_rows")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
@@ -877,6 +881,12 @@ class EngineMetrics:
                 "shellac_engine_prefill_padded_tokens_total",
                 "Bucketed (padded) length of the prefill and chunk "
                 "programs dispatched",
+            ),
+            "prefill_sorted_tokens": c(
+                "shellac_engine_prefill_sorted_tokens_total",
+                "Padded prompt rows whose expert FFN ran as grouped "
+                "GEMMs over the sorted routed rows (0 on a model "
+                "without experts, and where the buckets run)",
             ),
         }
         self.compiles = c(

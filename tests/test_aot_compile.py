@@ -315,6 +315,73 @@ def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
     assert not moved, "\n".join(moved)
 
 
+@pytest.mark.parametrize("rows", [2048, 64], ids=["prompt2048", "tick64"])
+def test_dropless_experts_multiply_the_routed_rows_on_v5e(
+        chip, monkeypatch, rows):
+    """A dropless MoE stack at DeepSeek-V2-Lite's expert widths (64
+    experts, top-6, 2048 -> 1408), through the cached forward's own
+    path, at a 2,048-token prompt and at a decode tick of 64 rows: the
+    expert FFN is the grouped-matmul kernel over the sorted routed
+    rows. The program builds no (E*T, D) bucket (two of 537 MB at 2,048
+    rows, before PR 30) and no copy of a layer's expert weights: the
+    kernel reads them in the whole stack, where the scan's slice in
+    front of a kernel call is a 369-MB rewrite of each matrix every
+    layer (PERF.md, PR 30)."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from shellac_tpu import MoEConfig, get_model_config
+    from shellac_tpu.inference.kvcache import init_cache
+    from shellac_tpu.models import transformer
+
+    e, k, d, f = 64, 6, 2048, 1408
+    cfg = get_model_config("tiny-moe").replace(
+        d_model=d, n_heads=H, n_kv_heads=HKV, head_dim=D, d_ff=f,
+        n_layers=2, max_seq_len=2048, dtype="bfloat16",
+        param_dtype="bfloat16",
+        moe=MoEConfig(num_experts=e, num_experts_per_token=k,
+                      d_ff_expert=f, norm_topk_prob=False, dropless=True),
+    ).validate()
+    prompt = rows > 64
+    batch, seq = (1, rows) if prompt else (rows, 1)
+
+    def run(params, cache, tokens):
+        return transformer.forward_with_cache(
+            cfg, params, tokens, cache, fresh_cache=prompt)
+
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = shaped(jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    # (A slot cache rides the layer loop as xs/ys, a copy: kept short
+    # at the tick so that the expert weights are what could show.)
+    cache = shaped(jax.eval_shape(
+        lambda: init_cache(cfg, batch, 2048 if prompt else 128)))
+    tokens = jax.ShapeDtypeStruct((batch, seq), I32, sharding=chip)
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call".*moe\.gemm/jit\(gmm\)', text
+    )) == 3
+    bucket = e * rows * d * 2
+    weights = e * d * f * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < weights / 2, (temp, weights)  # no matrix rewritten
+    if prompt:  # under a quarter of the gate/up and down buckets
+        assert temp < 2 * bucket / 4, (temp, bucket)
+    # No bucket (E*T rows flat, or (E, T, ...)), and no layer's experts
+    # (E, in, out) out of the (2, E, in, out) stacks.
+    made = re.findall(
+        rf"= \w+\[(?:{e * rows}|{e * rows + 1}|{e},{rows}|{e},{d},{f}"
+        rf"|{e},{f},{d}),[\d,]*\]\S* (?!parameter|bitcast|get-tuple)\w",
+        text,
+    )
+    assert not made, made[:5]
+
+
 def test_latent_pool_is_relaid_for_the_kernel_on_v5e(chip):
     """Why "auto" keeps a pool whose row does not fill the lanes on the
     gather: the device holds a (n_blocks, 1, 128, 576) latent pool in a

@@ -128,3 +128,45 @@ def test_n_routers(layout, routers):
     if routers is None:
         routers = L // cfg.moe_every
     assert n_routers(cfg) == routers
+
+
+@pytest.mark.parametrize("first", [0, L])
+@pytest.mark.parametrize("layout,preset,extra", [
+    ("flat", "tiny-moe", {}),
+    ("first_k_dense", *PAGED_STACK_LAYOUTS["first_k_dense"]),
+    ("grouped_moe", *PAGED_STACK_LAYOUTS["grouped_moe"]),
+    ("attn_pattern", "tiny-gptoss", {}),
+])
+def test_experts_whole_hands_each_moe_layer_its_row(layout, preset, extra,
+                                                    first):
+    """With experts_whole the expert weights stay out of the scans: a
+    MoE layer gets each as StackRow(the whole stack, its own row in
+    it), every other leaf sliced as before; dense layers get none."""
+    from shellac_tpu.ops.moe import EXPERT_STACKS, StackRow
+
+    cfg = get_model_config(preset).replace(n_layers=L, **extra).validate()
+    layers = _layers(cfg, first)
+    moe_stack = layers["moe"] if "moe" in layers else layers
+    for j, n in enumerate(EXPERT_STACKS):
+        moe_stack[n] = 1000 * (j + 1) + moe_stack["id"]
+
+    def step(c, lp, li, xs_l, moe_layer, attn_kind):
+        got = jnp.full((len(EXPERT_STACKS),), -1, jnp.int32)
+        if moe_layer:
+            assert all(isinstance(lp[n], StackRow) for n in EXPERT_STACKS)
+            assert all(lp[n].stack.shape == moe_stack[n].shape
+                       for n in EXPERT_STACKS)
+            got = jnp.stack([lp[n].stack[lp[n].row] for n in EXPERT_STACKS])
+        else:
+            assert not set(EXPERT_STACKS) & set(lp)
+        return c, (li, lp["id"], got)
+
+    _, (li, ids, got) = scan_layers(
+        cfg, layers, jnp.uint32(0), step, first=first, experts_whole=True
+    )
+    order = list(range(first, first + L))
+    assert list(li) == order and list(ids) == order
+    for i, row in zip(order, np.asarray(got)):
+        want = ([1000 * (j + 1) + i for j in range(len(EXPERT_STACKS))]
+                if _expected(cfg, i - first)[0] else [-1] * len(EXPERT_STACKS))
+        assert list(row) == want

@@ -150,8 +150,9 @@ def test_counts_are_what_the_engine_did(runs, combo):
     assert tot["prefill_tokens"] == sum(t.size for _, t, _ in reqs)
     assert tot["prefill_padded_tokens"] == sum(
         _bucket(t.size) for _, t, _ in reqs)
+    assert tot["prefill_sorted_tokens"] == 0  # a model without experts
     assert tot["compiles"] > 0 and tot["compile_s"] > 0
-    for k in STEP_COUNTS[:5]:
+    for k in STEP_COUNTS[:6]:
         assert reg.value(f"shellac_engine_{k}_total") == tot[k]
     assert reg.value("shellac_compile_events_total") >= tot["compiles"]
     # every admission names its request and its slot; the padded
@@ -164,6 +165,47 @@ def test_counts_are_what_the_engine_did(runs, combo):
         assert sp[4]["padded_tokens"] == _bucket(by_rid[sp[4]["rid"]])
     assert len([sp for r in recs for sp in r.spans
                 if sp[0] == "engine.submit"]) == len(reqs)
+
+
+@pytest.mark.parametrize("dropless,chunk", [
+    (True, None), (True, 16), (False, None), (False, 16),
+], ids=["dropless-whole", "dropless-chunked", "capacity-whole",
+        "capacity-chunked"])
+def test_prefill_sorted_tokens_follow_the_models_rule(dropless, chunk):
+    """The padded prompt rows whose expert FFN ran over the sorted
+    routed rows: all of them on a dropless MoE model (one device, plain
+    weights); with capacity buckets only the rows behind resident
+    tokens (a chunk past the first), which must not drop. The engine
+    asks the model's rule; the total is on /metrics."""
+    import dataclasses
+
+    cfg = get_model_config("tiny-moe").replace(dtype="float32",
+                                               param_dtype="float32")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, dropless=dropless))
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    reg = Registry()
+    eng = engine_class("paged")(
+        cfg, params, n_slots=2, max_len=96, decode_ticks=TICKS,
+        registry=reg, cache_backend="paged", block_size=16,
+        prefill_chunk=chunk)
+    reqs = _requests(cfg, n=3, seed=3)
+    _drive(eng, reqs)
+    recs = list(reg.step_records)
+    tot = {k: sum(r.counts[k] for r in recs) for k in STEP_COUNTS}
+    assert tot["prefill_tokens"] == sum(t.size for _, t, _ in reqs)
+    programs = [sp[4]["bucket"] for r in recs for sp in r.spans
+                if sp[0] == "engine.prefill_dispatch"]
+    assert tot["prefill_padded_tokens"] == sum(programs)
+    if dropless:
+        want = tot["prefill_padded_tokens"]
+    else:  # every chunk but a prompt's first continues a cache
+        first = sum(min(_bucket(min(t.size, chunk)), 96)
+                    for _, t, _ in reqs) if chunk else sum(programs)
+        want = tot["prefill_padded_tokens"] - first
+        assert (want > 0) is bool(chunk)
+    assert tot["prefill_sorted_tokens"] == want
+    assert (f"shellac_engine_prefill_sorted_tokens_total {want}\n"
+            in reg.render())
 
 
 def test_tokens_of_resident_requests_are_counted_when_handed_out(model):
