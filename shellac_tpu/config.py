@@ -179,6 +179,27 @@ class EvaConfig:
 
 
 @dataclass(frozen=True)
+class DSAConfig:
+    """Learned sparse attention: a per-layer indexer picks the cached
+    rows each query attends (the DeepSeek-Sparse-Attention indexer, over
+    GQA here; ops/dsa_attention.py has the equations).
+
+    Beside q/k/v a layer projects `index_heads` index queries and ONE
+    index key a token, `index_dim` wide, and a per-head weight of the
+    query token. A query's score of an earlier position is the weighted
+    sum over index heads of the ReLU'd dot products; it attends the
+    `topk` positions that score highest (ties to the lower position),
+    or every earlier position while there are no more than `topk`. One
+    set a query a layer, shared by all attention heads. The decode state
+    is the k/v rows plus the index key of every position
+    (layout.PagedKVCache.idx)."""
+
+    index_heads: int = 16
+    index_dim: int = 64
+    topk: int = 2048
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Decoder-only transformer configuration (LLaMA-style)."""
 
@@ -268,6 +289,10 @@ class ModelConfig:
     # exact window plus pooled rows; adds eva_phi / eva_mu (H, Dh) per
     # layer. Exclusive with mla, attn_window and attn_pattern.
     eva: Optional[EvaConfig] = None
+    # Learned sparse attention (a DeepSeek-Sparse-Attention indexer over
+    # GQA): adds dsa_wq / dsa_wk / dsa_k_norm / dsa_k_bias / dsa_ww per
+    # layer. Exclusive with mla, eva, attn_window and attn_pattern.
+    dsa: Optional[DSAConfig] = None
     # Multi-token prediction heads (EvaByte num_pred_heads): lm_head is
     # (d_model, n_pred_heads * vocab_size), head m predicting the token
     # m + 1 ahead. forward() returns every head's logits; cached
@@ -286,6 +311,8 @@ class ModelConfig:
             object.__setattr__(self, "attn_pattern", tuple(self.attn_pattern))
         if isinstance(self.eva, dict):
             object.__setattr__(self, "eva", EvaConfig(**self.eva))
+        if isinstance(self.dsa, dict):
+            object.__setattr__(self, "dsa", DSAConfig(**self.dsa))
 
     @property
     def kv_heads(self) -> int:
@@ -314,19 +341,29 @@ class ModelConfig:
 
     @property
     def cache_kv_heads(self) -> int:
-        """KV-cache head count: MLA caches ONE shared latent row."""
-        return 1 if self.mla is not None else self.kv_heads
+        """KV-cache head count: MLA caches ONE shared latent row, and a
+        model with an indexer ONE row a token holding all its kv heads
+        (a decode tick gathers chosen rows one by one, and a gather
+        costs by the slice, not by the byte: PERF.md, PR 31)."""
+        if self.mla is not None or self.dsa is not None:
+            return 1
+        return self.kv_heads
 
     @property
     def cache_head_dim(self) -> int:
-        """Per-token cache width: latent + roped key slice under MLA."""
-        return self.mla.cache_dim if self.mla is not None else self.dim_per_head
+        """Per-token cache width: latent + roped key slice under MLA;
+        every kv head side by side with an indexer."""
+        if self.mla is not None:
+            return self.mla.cache_dim
+        if self.dsa is not None:
+            return self.kv_heads * self.dim_per_head
+        return self.dim_per_head
 
     @property
     def cache_v_head_dim(self) -> int:
         """V-cache width: 0 under MLA (values re-expand from the SAME
         latent the key cache stores — no second copy exists)."""
-        return 0 if self.mla is not None else self.dim_per_head
+        return 0 if self.mla is not None else self.cache_head_dim
 
     @property
     def compute_dtype(self):
@@ -447,6 +484,30 @@ class ModelConfig:
                 "n_pred_heads > 1 needs an untied lm_head "
                 "(tie_embeddings=False)"
             )
+        if self.dsa is not None:
+            a = self.dsa
+            if a.index_heads < 1 or a.topk < 1:
+                raise ValueError("dsa index_heads and topk must be >= 1")
+            if a.index_dim < 2 or a.index_dim % 2:
+                raise ValueError(
+                    f"dsa index_dim={a.index_dim} must be even (the index "
+                    "queries and keys are roped over their whole width)"
+                )
+            if (self.mla is not None or self.eva is not None
+                    or self.attn_window is not None
+                    or self.attn_pattern is not None):
+                raise ValueError(
+                    "dsa chooses the rows a query attends: mla, eva, "
+                    "attn_window and attn_pattern do not combine with it"
+                )
+            if (self.attn_softcap is not None or self.attn_sink
+                    or self.rope_local_theta is not None):
+                raise ValueError(
+                    "dsa attention takes none of attn_softcap, attn_sink, "
+                    "rope_local_theta"
+                )
+            if not self.causal:
+                raise ValueError("dsa attention is decoder-only (causal=True)")
         if self.eva is not None:
             e = self.eva
             if e.chunk < 1 or e.window < e.chunk or e.window % e.chunk:

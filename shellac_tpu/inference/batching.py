@@ -1495,7 +1495,7 @@ class BatchingEngine:
             prefill_tokens=tokens, prefill_padded_tokens=padded,
             prefill_sorted_tokens=padded if path == "sorted" else 0,
         )
-        steps.annotate(bucket=padded)
+        steps.annotate(bucket=padded, tokens=tokens)
 
     def _prefill_start_offset(self, slot: int) -> int:
         """Tokens already resident when prefill starts (the paged
@@ -1793,10 +1793,12 @@ class BatchingEngine:
             self.cfg, params, tokens, view, new_tokens_len=chunk_len,
             fresh_cache=fresh,
             attn_impl=self.attn_impl if fresh else "ref", mesh=self.mesh,
+            # Only prompt scoring reads a chunk's other rows.
+            logits_at=None if want_plp else chunk_len - 1,
         )
         last = jnp.take_along_axis(
             logits, (chunk_len - 1)[:, None, None].astype(jnp.int32), axis=1
-        )[0, 0]
+        )[0, 0] if want_plp else logits[0, 0]
         first, first_lp = self._sample_first(key, last, samp)
         plp_within = jnp.zeros((tokens.shape[1],), jnp.float32)
         boundary_lp = jnp.zeros((), jnp.float32)
@@ -2700,15 +2702,20 @@ class PagedBatchingEngine(BatchingEngine):
         else:
             view = PagedKVCache(
                 k=cache.k, v=cache.v, tables=row,
-                lengths=prefix_len.astype(jnp.int32),
+                lengths=prefix_len.astype(jnp.int32), idx=cache.idx,
             )
         logits, view = transformer.forward_with_cache(
             self.cfg, params, tokens, view, new_tokens_len=suffix_len,
-            fresh_cache=False, attn_impl="ref", mesh=self.mesh,
+            fresh_cache=False, mesh=self.mesh,
+            # With an indexer a chunk attends under its queries' choices
+            # (ops/dsa_attention.py), a kernel of its own on the TPU.
+            attn_impl="ref" if self.cfg.dsa is None else self.attn_impl,
+            # Only prompt scoring reads a chunk's other rows.
+            logits_at=None if want_plp else suffix_len - 1,
         )
         last = jnp.take_along_axis(
             logits, (suffix_len - 1)[:, None, None].astype(jnp.int32), axis=1
-        )[0, 0]
+        )[0, 0] if want_plp else logits[0, 0]
         first, first_lp = self._sample_first(key, last, samp)
         plp_within = jnp.zeros((tokens.shape[1],), jnp.float32)
         boundary_lp = jnp.zeros((), jnp.float32)
@@ -2725,6 +2732,8 @@ class PagedBatchingEngine(BatchingEngine):
         )
         if self.kv_quant == "int8":
             fields.update(ks=view.ks, vs=view.vs)
+        if self.cfg.dsa is not None:
+            fields.update(idx=view.idx)
         cache = cache.replace(**fields)
         tlv, tli = self._first_tl(last)
         return (cache, first, first_lp, plp_within, boundary_lp,

@@ -72,6 +72,13 @@ class CacheBackend:
                 + ("holds no such state" if cfg.eva is not None
                    else "holds nothing else (this model has no cfg.eva)")
             )
+        if cfg.dsa is not None and not self.is_paged:
+            raise ValueError(
+                "a model with an indexer (cfg.dsa) keeps one index key a "
+                "token in a third pool under the block table, and serves "
+                f"on the 'paged' cache backend; the {self.name!r} backend "
+                "holds no such pool"
+            )
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -191,7 +198,9 @@ class CacheBackend:
             # int8 values + one fp32 scale per token/head for k and v.
             return cfg.n_layers * cfg.cache_kv_heads * (width + 2 * 4)
         itemsize = jnp.dtype(cfg.compute_dtype).itemsize
-        return cfg.n_layers * cfg.cache_kv_heads * width * itemsize
+        # With an indexer, one index key a token a layer beside k and v.
+        index = cfg.dsa.index_dim if cfg.dsa is not None else 0
+        return cfg.n_layers * (cfg.cache_kv_heads * width + index) * itemsize
 
     # ---- shared helpers ---------------------------------------------
 

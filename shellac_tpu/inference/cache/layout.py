@@ -144,6 +144,14 @@ def init_cache_for(cfg: ModelConfig, batch: int, max_len: int,
                 "apply"
             )
         return init_eva_slot_cache(cfg, batch, max_len)
+    if cfg.dsa is not None:
+        if rolling or kv_quant is not None:
+            raise ValueError(
+                "a model with an indexer (cfg.dsa) keeps bf16 paged pools "
+                "with one index key a token; rolling / kv_quant do not "
+                "apply"
+            )
+        return init_paged_slot_cache(cfg, batch, max_len)
     if rolling:
         if kv_quant is not None and kv_quant != "int8":
             raise ValueError(f"kv_quant={kv_quant!r}; have None, 'int8'")
@@ -175,6 +183,8 @@ def cache_logical_axes_for(cfg: ModelConfig, kv_quant=None,
     out_shardings can never desync from the cache pytree."""
     if cfg.eva is not None:
         return eva_cache_logical_axes(cfg)
+    if cfg.dsa is not None:
+        return paged_cache_logical_axes(cfg)
     if rolling:
         patterned = (cfg.attn_pattern is not None
                      and "full" in cfg.attn_pattern)
@@ -235,12 +245,17 @@ def paged_cache_logical_axes(cfg: Optional[ModelConfig] = None):
     arbitrary block ids) so it stays unsharded, and the tables/lengths
     are tiny scheduler metadata, replicated.
     """
-    heads = "kv_heads" if cfg is None or cfg.mla is None else None
+    # (Replicated under MLA, one shared latent row, and with an indexer,
+    # whose one row a token holds every kv head.)
+    heads = ("kv_heads" if cfg is None
+             or (cfg.mla is None and cfg.dsa is None) else None)
     return PagedKVCache(
         k=("layers", None, heads, None, None),
         v=("layers", None, heads, None, None),
         tables=(None, None),
         lengths=(None,),
+        idx=(("layers", None, None, None)
+             if cfg is not None and cfg.dsa is not None else None),
     )
 
 
@@ -335,6 +350,16 @@ class PagedKVCache:
         scratch: it is never handed to a slot, so stray writes and reads
         through unallocated table entries land there harmlessly).
     lengths: (n_slots,) int32 — valid tokens per slot.
+    idx: None, or for a model with an indexer (cfg.dsa) a third pool
+        under the SAME tables, (L, n_blocks, index_dim, block_size): one
+        index key a token, a page's keys held key-axis innermost. That
+        is the form the scores' matmul reads (index queries against a
+        page's (index_dim, block_size) tile), it keeps the lane axis a
+        whole number of 128s at any index width (a 64-wide row innermost
+        would not: PERF.md fault 1), and to paged_write it is a pool
+        with index_dim heads and no row width, the form an int8 pool's
+        scales already have. Written in the same tick and prompt writes
+        as k and v, freed with the same pages.
 
     How the pool travels through a model step (forward_with_cache): as
     a CARRY of the layer loops, viewed as (L * n_blocks, Hkv, bs, Dh) —
@@ -350,6 +375,7 @@ class PagedKVCache:
     v: Any
     tables: Any
     lengths: Any
+    idx: Any = None
 
     @property
     def block_size(self) -> int:
@@ -366,16 +392,38 @@ def init_paged_cache(
     n_blocks: int,
     block_size: int,
     max_blocks_per_slot: int,
+    tables=None,
 ) -> PagedKVCache:
     head = (cfg.n_layers, n_blocks, cfg.cache_kv_heads, block_size)
+    if tables is None:
+        tables = jnp.zeros((n_slots, max_blocks_per_slot), jnp.int32)
     return PagedKVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), cfg.compute_dtype),
         # MLA: zero-width v pool (values re-expand from the latent the
         # k pool stores), same convention as the dense cache.
         v=jnp.zeros((*head, cfg.cache_v_head_dim), cfg.compute_dtype),
-        tables=jnp.zeros((n_slots, max_blocks_per_slot), jnp.int32),
+        tables=tables,
         lengths=jnp.zeros((n_slots,), jnp.int32),
+        idx=(jnp.zeros((cfg.n_layers, n_blocks, cfg.dsa.index_dim,
+                        block_size), cfg.compute_dtype)
+             if cfg.dsa is not None else None),
     )
+
+
+#: Rows a page of the single-request cache of a model with an indexer.
+SLOT_PAGE = 128
+
+
+def init_paged_slot_cache(cfg: ModelConfig, batch: int,
+                          max_len: int) -> PagedKVCache:
+    """The paged pools with nothing to allocate: every row owns the
+    pages its max_len needs (the single-request Engine's cache of a
+    model with an indexer, whose state is the three pools)."""
+    page = min(SLOT_PAGE, max_len)
+    mb = -(-max_len // page)
+    tables = 1 + jnp.arange(batch * mb, dtype=jnp.int32).reshape(batch, mb)
+    return init_paged_cache(cfg, batch, batch * mb + 1, page, mb,
+                            tables=tables)
 
 
 def paged_write(pools, news, index, tables, layer=None, only=None):

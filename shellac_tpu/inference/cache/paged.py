@@ -51,6 +51,40 @@ from shellac_tpu.inference.cache.layout import (
 #: is narrower than the 128-lane tiling — so a page holds at least 128
 #: tokens. The chipless compile gate (tests/test_aot_compile.py)
 #: compiles the kernel at every size named here.
+#: feature -> why the third pool of a model with an indexer (cfg.dsa:
+#: one index key a token under the same tables as k and v) cannot carry
+#: it yet (ROADMAP Queue 2). Refused where the switch is turned on: the
+#: constructor, bind(), check_feature().
+INDEX_POOL_UNSUPPORTED = {
+    "kv_quant": (
+        "the index keys have no int8 form, and a tick gathers its chosen "
+        "rows out of bf16 pools"
+    ),
+    "prefix_cache": (
+        "a shared page holds its index rows, but registration, eviction "
+        "and seeding name k and v alone: nothing shows yet that an "
+        "attached page's index rows are the attaching prompt's"
+    ),
+    "speculative": (
+        "a verify window scores several queries at once and rolls back, "
+        "and a rejected token's index row would stay behind"
+    ),
+    "pp_pipeline": "the per-stage registers hold k and v rows, no index keys",
+    "mesh": "the index pool and the choice are not sharded yet",
+    "park_resume": (
+        "parking a slot would have to ship its index rows with its k and "
+        "v rows"
+    ),
+    "kv_export": (
+        "disaggregated export ships the k and v fields; the index rows "
+        "would be left behind"
+    ),
+    "beam_search": (
+        "beams copy k and v pages on write; the index pages would not "
+        "follow"
+    ),
+}
+
 INT8_BLOCK_ALIGN = 128
 INT8_BLOCK_SIZES_RECOMMENDED = (INT8_BLOCK_ALIGN, 2 * INT8_BLOCK_ALIGN)
 INT8_BLOCK_SIZE_DEFAULT = INT8_BLOCK_ALIGN
@@ -66,6 +100,11 @@ class PagedBackend(CacheBackend):
                  prefix_cache: bool = False, chunk_slack: int = 1):
         super().__init__(cfg, n_slots, max_len, kv_quant=kv_quant,
                          chunk_slack=chunk_slack)
+        if cfg.dsa is not None:
+            if kv_quant is not None:
+                self.refuse_index_pool("kv_quant")
+            if prefix_cache:
+                self.refuse_index_pool("prefix_cache")
         if kv_quant == "int8":
             if block_size % INT8_BLOCK_ALIGN:
                 # An engine knob, so an error beats a per-tick fallback
@@ -115,6 +154,28 @@ class PagedBackend(CacheBackend):
         self._prefix_hits: Dict[bytes, int] = {}
         self._prefix_version = 0
 
+    @staticmethod
+    def refuse_index_pool(feature: str) -> None:
+        raise ValueError(
+            f"a model with an indexer (cfg.dsa) does not support {feature} "
+            f"on the 'paged' cache backend yet: "
+            f"{INDEX_POOL_UNSUPPORTED[feature]}"
+        )
+
+    def check_feature(self, feature: str) -> None:
+        if self.cfg.dsa is not None and feature in INDEX_POOL_UNSUPPORTED:
+            self.refuse_index_pool(feature)
+
+    def bind(self, engine) -> None:
+        if self.cfg.dsa is not None:
+            from shellac_tpu.inference.spec_batching import _SpecDecodeMixin
+
+            if isinstance(engine, _SpecDecodeMixin):
+                self.refuse_index_pool("speculative")
+            if engine.mesh is not None:
+                self.refuse_index_pool("mesh")
+        super().bind(engine)
+
     # ---- device cache construction ----------------------------------
 
     def init_cache(self):
@@ -147,6 +208,21 @@ class PagedBackend(CacheBackend):
         quantized at write, K post-rope, and its scales go through the
         same pages as its values), then paged_write_prompt through the
         slot's table row. Returns (logits, cache)."""
+        if self.cfg.dsa is not None:
+            # Three pools and no dense mini of their kind: the prompt
+            # goes straight through a batch-1 view of the slot's table
+            # row, as a cached chunk does.
+            view = cache.replace(
+                tables=jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0),
+                lengths=jnp.zeros((1,), jnp.int32),
+            )
+            logits, view = forward(view)
+            return logits, cache.replace(
+                k=view.k, v=view.v, idx=view.idx,
+                lengths=jax.lax.dynamic_update_slice(
+                    cache.lengths, view.lengths, (slot,)
+                ),
+            )
         logits, mini = forward(self.init_mini(length))
         table_row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)[0]
         names = kv_field_names(self.kv_quant)
@@ -167,6 +243,9 @@ class PagedBackend(CacheBackend):
         from shellac_tpu.ops.decode_attention import paged_decode_path
 
         cfg = self.cfg
+        if cfg.dsa is not None:
+            # Neither: the tick gathers the rows its indexer chose.
+            return "chosen_rows"
         return paged_decode_path(
             (self.n_slots, 1, cfg.n_heads, cfg.cache_head_dim),
             (self.n_blocks, cfg.cache_kv_heads, self.block_size,
@@ -527,7 +606,7 @@ class PagedBackend(CacheBackend):
         return (pool - len(self._free)) / pool
 
     def residency(self) -> Dict[str, Any]:
-        return {
+        out = {
             "backend": self.name,
             "slot_tokens": self._slot_tokens(),
             "slot_blocks": [len(b) for b in self._slot_blocks],
@@ -536,6 +615,33 @@ class PagedBackend(CacheBackend):
             "blocks_free": len(self._free),
             "prefix_cached_blocks": len(self._hash_to_block),
         }
+        if self.cfg.dsa is not None:
+            # A page holds three pools' rows; utilization() counts
+            # pages, so it counts all three.
+            item = jnp.dtype(self.cfg.compute_dtype).itemsize
+            out.update(
+                row_bytes=self.bytes_per_token(),
+                index_row_bytes=(self.cfg.n_layers
+                                 * self.cfg.dsa.index_dim * item),
+                rows_kept=self.cfg.dsa.topk,
+            )
+        return out
+
+    def window_counts(self, pairs, n_valid) -> Dict[str, int]:
+        """With an indexer: over every (slot, tick) of a synced window
+        that produced a token, the rows the indexer scored (the query's
+        context: it sits at position prompt + outputs settled - 1 + tick
+        and scores every row up to itself) and the rows it then attended
+        (no more than are kept). Host arithmetic on lengths."""
+        if self.cfg.dsa is None:
+            return {}
+        scored = kept = 0
+        for slot, req in pairs:
+            first = req.tokens.size + len(req.out)
+            ctx = first + np.arange(int(n_valid[slot]))
+            scored += int(ctx.sum())
+            kept += int(np.minimum(ctx, self.cfg.dsa.topk).sum())
+        return {"dsa_index_rows": scored, "dsa_selected_rows": kept}
 
 
 class QuantPagedBackend(PagedBackend):

@@ -294,6 +294,12 @@ class Engine:
                 "beam search reorders dense cache rows by beam; EVA state "
                 "(a ring and pooled pages a row) has no such reorder yet"
             )
+        if self.cfg.dsa is not None:
+            raise NotImplementedError(
+                "beam search reorders dense cache rows by beam; a model "
+                "with an indexer keeps paged pools (k, v and index keys) "
+                "that have no such reorder yet"
+            )
         if num_beams < 1:
             raise ValueError("num_beams must be >= 1")
         if max_new_tokens < 1:

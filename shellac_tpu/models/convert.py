@@ -66,8 +66,12 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             raise NotImplementedError(
                 "phi3 partial_rotary_factor != 1 is not supported"
             )
-    is_qwen3 = getattr(hf_cfg, "model_type", "") in ("qwen3", "qwen3_moe")
-    if getattr(hf_cfg, "model_type", "") == "qwen3_moe":
+    # Keye-VL-2.0's text model carries the Qwen3-MoE block's keys plus
+    # `sa_config`, the indexer of its learned sparse attention.
+    is_keye = getattr(hf_cfg, "model_type", "") == "KeyeVL2"
+    is_qwen3 = is_keye or getattr(hf_cfg, "model_type", "") in (
+        "qwen3", "qwen3_moe")
+    if is_keye or getattr(hf_cfg, "model_type", "") == "qwen3_moe":
         if getattr(hf_cfg, "mlp_only_layers", None):
             raise NotImplementedError(
                 "qwen3_moe with mlp_only_layers is a mixed layout we "
@@ -114,6 +118,7 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             or getattr(hf_cfg, "model_type", "") == "qwen2"
         ),
         qk_norm=is_qwen3,
+        dsa=_dsa_from_hf(hf_cfg) if is_keye else None,
         # Long-context checkpoints: yarn/llama3 convert exactly; any
         # other rope_scaling type fails loudly.
         **_rope_from_hf(
@@ -121,6 +126,25 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             hf_cfg.max_position_embeddings,
         ),
     ).validate()
+
+
+def _dsa_from_hf(hf_cfg):
+    """DSAConfig from Keye-VL-2.0's `sa_config`. Token-only input: the
+    three position axes of M-RoPE (`rope_scaling.mrope_section`) are
+    equal for text, which makes it the ordinary rope; image tokens'
+    three-axis positions refuse where they would enter
+    (ops/rope.py::mrope_token_positions)."""
+    from shellac_tpu.config import DSAConfig
+
+    sa = hf_cfg.sa_config
+    sa = sa if isinstance(sa, dict) else vars(sa)
+    if sa.get("indexer_num_kv_heads", 1) != 1:
+        raise NotImplementedError(
+            "sa_config.indexer_num_kv_heads != 1: the indexer caches one "
+            "index key a token"
+        )
+    return DSAConfig(index_heads=sa["indexer_num_heads"],
+                     index_dim=sa["indexer_head_dim"], topk=sa["topk"])
 
 
 def _evabyte_config(hf_cfg) -> ModelConfig:

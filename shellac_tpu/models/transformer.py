@@ -355,6 +355,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                     "eva_phi": dense(kp[0], (h, dh), 1),
                     "eva_mu": dense(kp[1], (h, dh), 1, 0.5),
                 })
+            if cfg.dsa is not None:
+                # The indexer: index queries, one index key a token
+                # (LayerNorm'd: gain in the 1 + w form the RMS norms
+                # use, and a bias), and the per-head weight.
+                a = cfg.dsa
+                kd = jax.random.split(ks[7], 3)
+                p.update({
+                    "dsa_wq": dense(kd[0], (d, a.index_heads * a.index_dim), d),
+                    "dsa_wk": dense(kd[1], (d, a.index_dim), d),
+                    "dsa_k_norm": jnp.zeros((a.index_dim,), pdt),
+                    "dsa_k_bias": jnp.zeros((a.index_dim,), pdt),
+                    "dsa_ww": dense(kd[2], (d, a.index_heads), d),
+                })
         if cfg.attn_bias:
             p.update({
                 "bq": jnp.zeros((h * dh,), pdt),
@@ -510,6 +523,14 @@ def _layer_axes(cfg: ModelConfig, moe_layer: bool, lead=("layers",)) -> dict:
             attn_axes.update({
                 "eva_phi": (*lead, "heads", None),
                 "eva_mu": (*lead, "heads", None),
+            })
+        if cfg.dsa is not None:
+            attn_axes.update({
+                "dsa_wq": (*lead, "embed", None),
+                "dsa_wk": (*lead, "embed", None),
+                "dsa_k_norm": (*lead, None),
+                "dsa_k_bias": (*lead, None),
+                "dsa_ww": (*lead, "embed", None),
             })
     post_axes = {}
     if cfg.post_norms:
@@ -675,6 +696,13 @@ def _block(
             x = x + constrain(pdot(o, lp["wo"]), mesh, ("batch", "seq", None))
         return _block_mlp(cfg, mesh, x, lp, pdot, cache, fresh_cache,
                           moe_layer, new_cache)
+    dsa_rope = None
+    if cfg.dsa is not None:
+        # The tables carry the index queries' and keys' beside the
+        # heads' (dsa_rope_tables).
+        half = dh // 2
+        dsa_rope = (cos[..., half:], sin[..., half:])
+        cos, sin = cos[..., :half], sin[..., :half]
     with jax.named_scope("attn.qkv"):
         q = pdot(hx, lp["wq"])
         k = pdot(hx, lp["wk"])
@@ -695,7 +723,13 @@ def _block(
     k = apply_rope(k, cos, sin)
     sinks = lp["sinks"] if cfg.attn_sink else None
     new_cache = None
-    if cache is None:
+    if cfg.dsa is not None:
+        o, new_cache = _dsa_attention(
+            cfg, mesh, attn_impl, hx, lp, q, k, v, dsa_rope, cache,
+            fresh_cache, segments, pdot, page_tables=page_tables,
+            new_len=new_len,
+        )
+    elif cache is None:
         o = _training_attention(cfg, mesh, attn_impl, q, k, v, segments,
                                 window=window, sinks=sinks)
     elif page_tables is not None:
@@ -1314,6 +1348,184 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
     return o.reshape(b, 1, h * dh), (ring, pool)
 
 
+def dsa_rope_tables(cfg: ModelConfig, positions, cos, sin):
+    """Rope tables of a model with an indexer: the attention heads'
+    (dim_per_head wide) with the index queries' and keys' (index_dim
+    wide, the same theta and scaling) appended on the last axis.
+    _dsa_attention splits them again; riding as one pair they cross the
+    layer walks, remat and the pipeline's extras like any other."""
+    icos, isin = rope_angles(positions, cfg.dsa.index_dim, cfg.rope_theta,
+                             yarn=cfg.rope_yarn, llama3=cfg.rope_llama3,
+                             linear=cfg.rope_linear)
+    return (jnp.concatenate([cos, icos], axis=-1),
+            jnp.concatenate([sin, isin], axis=-1))
+
+
+def _dsa_attention(cfg, mesh, attn_impl, hx, lp, q, k, v, rope, cache,
+                   fresh_cache, segments, pdot, page_tables=None,
+                   new_len=None):
+    """Attention of a model with an indexer (cfg.dsa;
+    ops/dsa_attention.py has the equations). hx: (B, S, D) normed
+    input; q (B, S, H, Dh), k, v (B, S, Hkv, Dh), roped; rope: the
+    (cos, sin) of the index queries and keys. Returns (o (B, S, H, Dh),
+    new_cache).
+
+    Without a cache the run attends within itself under each query's
+    choice. With `cache=(pool_k, pool_v, pool_i, index, _)`, the paged
+    pools viewed flat (forward_with_cache) and `page_tables` offset to
+    this layer's blocks, the run's k, v and index-key rows are written
+    through the tables first (`kv.write`, `dsa.index_write`), then:
+
+    * a fresh prompt attends within itself, as without a cache;
+    * a cached chunk of several rows reads the slot's rows back densely
+      (its own among them) and attends under its queries' choices among
+      them, over the smallest extent of the table that holds them
+      (`dsa.live_widths`: one branch of a switch each);
+    * a decode tick scores its one query against the slot's index rows
+      through the table (`dsa.score`), takes the choice as row numbers
+      (`dsa.select`), gathers those k and v rows by (page, offset) and
+      attends them (`dsa.attend`).
+
+    A state that cannot hold more rows than are kept takes the same
+    paths with every row chosen and no index read."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    from shellac_tpu.ops import dsa_attention as dsa
+    from shellac_tpu.ops.norms import layer_norm_ref
+
+    a = cfg.dsa
+    cdt = cfg.compute_dtype
+    b, s, h, dh = q.shape
+    hkv = k.shape[2]
+    scale = cfg.attn_scale if cfg.attn_scale is not None else dh ** -0.5
+    if segments is not None:
+        raise NotImplementedError(
+            "the indexer chooses among the earlier positions of ONE "
+            "sequence a row: packed segments are not defined for it"
+        )
+    icos, isin = rope
+    with jax.named_scope("dsa.index_proj"):
+        u = pdot(hx, lp["dsa_wq"]).reshape(b, s, a.index_heads, a.index_dim)
+        u = apply_rope(u, icos, isin)
+        c = layer_norm_ref(
+            pdot(hx, lp["dsa_wk"]),
+            1.0 + lp["dsa_k_norm"].astype(jnp.float32), lp["dsa_k_bias"],
+            cfg.norm_eps,
+        ).astype(cdt)
+        c = apply_rope(c[:, :, None, :], icos, isin)[:, :, 0, :]
+        w = jnp.einsum("bsd,dj->bsj", hx, materialize(lp["dsa_ww"], cdt),
+                       preferred_element_type=jnp.float32)
+    steps = jnp.arange(s, dtype=jnp.int32)
+
+    def within(n):
+        """The run attends within itself; n (B,) of its rows exist."""
+        if s <= a.topk:
+            return attention(q, k, v, causal=True, impl=attn_impl,
+                             mesh=mesh, scale=scale)
+        at = jnp.broadcast_to(steps, (b, s))
+        # The kernels have no backward pass: a run without a cache
+        # (scoring, training) takes the plain forms.
+        impl = attn_impl if cache is not None else "ref"
+        mask = dsa.choice_mask(u, w, c.transpose(0, 2, 1), at, n, a.topk, s,
+                               impl=impl)
+        with jax.named_scope("dsa.attend"):
+            return dsa.masked_attention(q, k, v, mask, at, scale, impl=impl)
+
+    if cache is None:
+        return within(jnp.full((b,), s, jnp.int32)), None
+    if page_tables is None:
+        raise ValueError(
+            "a model with an indexer (cfg.dsa) keeps its rows in the "
+            "paged pools (k, v and the index keys under one block "
+            "table): serve it on the 'paged' cache backend"
+        )
+    from shellac_tpu.inference.kvcache import paged_write
+
+    # pool_k, pool_v: (N, 1, bs, Hkv * Dh), a token's kv heads side by
+    # side in one row (cfg.cache_head_dim): a tick gathers whole rows.
+    pool_k, pool_v, pool_i, index, _ = cache
+    with jax.named_scope("kv.write"):
+        pool_k, pool_v = paged_write(
+            (pool_k, pool_v),
+            (k.astype(pool_k.dtype).reshape(b, 1, s, hkv * dh),
+             v.astype(pool_v.dtype).reshape(b, 1, s, hkv * dh)),
+            index, page_tables,
+        )
+    with jax.named_scope("dsa.index_write"):
+        # (N, Di, bs): a page holds its index keys key-axis innermost,
+        # the form the scores' matmul reads; pinned as held on the way
+        # out as well as in (paged_write's docstring has why).
+        (pool_i,) = paged_write(
+            (pool_i,), (c.astype(pool_i.dtype).transpose(0, 2, 1),),
+            index, page_tables,
+        )
+        pool_i = with_layout_constraint(
+            pool_i, Layout(major_to_minor=(0, 1, 2)))
+    new_cache = (pool_k, pool_v, pool_i)
+    n_new = jnp.full((b,), s, jnp.int32) if new_len is None else new_len
+    if fresh_cache:
+        return within(n_new), new_cache
+
+    mb = page_tables.shape[1]
+    bs = pool_k.shape[2]
+    cap = mb * bs
+
+    def index_keys():
+        """(B, Di, cap): the slot's index keys through its table."""
+        x = jnp.take(pool_i, page_tables.reshape(-1), axis=0)
+        x = x.reshape(b, mb, a.index_dim, bs).transpose(0, 2, 1, 3)
+        return x.reshape(b, a.index_dim, cap)
+
+    if s > 1:
+        with jax.named_scope("kv.gather"):
+            # Whole pages through the table: (B, cap, Hkv, Dh), the
+            # run's own rows among them.
+            k_all, v_all = (
+                jnp.take(p, page_tables.reshape(-1), axis=0).reshape(
+                    b, cap, hkv, dh) for p in (pool_k, pool_v))
+            c_t = index_keys() if cap > a.topk else None
+        at = index[:, None] + steps[None, :]
+        k_len = index + n_new
+
+        def over(wd):
+            """The run against the slot's first `wd` rows."""
+            def run():
+                mask = dsa.choice_mask(
+                    u, w, None if c_t is None else c_t[..., :wd], at, k_len,
+                    a.topk, wd, impl=attn_impl)
+                with jax.named_scope("dsa.attend"):
+                    return dsa.masked_attention(
+                        q, k_all[:, :wd], v_all[:, :wd], mask, at, scale,
+                        impl=attn_impl)
+            return run
+
+        # One program holds the attention at a few extents of the table
+        # and a chunk takes the smallest that holds its rows: the
+        # program does not depend on the chunk's offset, its cost does.
+        widths = dsa.live_widths(cap, a.topk, bs)
+        which = sum((jnp.max(k_len) > wd).astype(jnp.int32)
+                    for wd in widths[:-1])
+        return jax.lax.switch(which, [over(wd) for wd in widths]), new_cache
+
+    allowed = jnp.arange(cap, dtype=jnp.int32)[None, :] <= index[:, None]
+    if cap <= a.topk:
+        # A state that cannot hold more rows than are kept: every row.
+        rows = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (b, cap))
+        ok = allowed
+    else:
+        with jax.named_scope("dsa.score"):
+            scores = dsa.index_scores(u, w, index_keys())[:, 0]
+        with jax.named_scope("dsa.select"):
+            rows, ok = dsa.select_rows(scores, allowed, a.topk)
+    with jax.named_scope("dsa.attend"):
+        block = jnp.take_along_axis(page_tables, rows // bs, axis=1)
+        k_rows, v_rows = (
+            dsa.gather_rows(p, block, rows % bs).reshape(b, -1, hkv, dh)
+            for p in (pool_k, pool_v))
+        o = dsa.attend_rows(q[:, 0], k_rows, v_rows, ok, scale)
+    return o[:, None], new_cache
+
+
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
     """Per-segment position ids: restart at 0 on every segment change.
 
@@ -1369,6 +1581,11 @@ def forward(
             "one sequence per row: explicit positions and packed "
             "segments are not defined for it"
         )
+    if positions is not None and jnp.ndim(positions) == 3:
+        # M-RoPE ids (3, B, S): token input only (three equal axes).
+        from shellac_tpu.ops.rope import mrope_token_positions
+
+        positions = mrope_token_positions(positions)
     pos = positions
     if pos is None:
         if segment_ids is not None:
@@ -1384,6 +1601,8 @@ def forward(
         cos_l, sin_l = rope_angles(pos, cfg.rope_dim, cfg.rope_local_theta)
     else:
         cos_l = sin_l = None
+    if cfg.dsa is not None:
+        cos, sin = dsa_rope_tables(cfg, pos, cos, sin)
 
     x = _embed_tokens(cfg, params, tokens, cdt, mesh=mesh)
     x = constrain(x, mesh, ("batch", "seq", None))
@@ -1584,6 +1803,7 @@ def forward_with_cache(
     mesh=None,
     fresh_cache: bool = False,
     attn_impl: str = "auto",
+    logits_at: Optional[jax.Array] = None,  # (B,) — the one row to unembed
 ):
     """Incremental forward: consumes `tokens` starting at cache.lengths.
 
@@ -1592,6 +1812,11 @@ def forward_with_cache(
     actual prompt lengths) and decode (S = 1). Writes land at each
     sequence's own length, so ragged batches decode with continuous
     positions and pads never pollute later steps.
+
+    logits_at (B,) unembeds that row of each sequence alone and returns
+    logits (B, 1, V): a prompt chunk that only samples from its last
+    position has no use for S rows over the vocabulary (at S = 4096 and
+    151,936 words they are 2.5 GB of float32 and a tenth of the chunk).
 
     fresh_cache=True (prefill into an all-empty cache) attends within
     the incoming chunk instead of over the max_len buffer — quadratic
@@ -1646,6 +1871,8 @@ def forward_with_cache(
         )
     else:
         cos_l = sin_l = None
+    if cfg.dsa is not None:
+        cos, sin = dsa_rope_tables(cfg, positions, cos, sin)
 
     x = _embed_tokens(cfg, params, tokens, cdt, mesh=mesh)
     x = constrain(x, mesh, ("batch", "seq", None))
@@ -1672,6 +1899,15 @@ def forward_with_cache(
         names = ("k", "v", "pk", "pv")
     else:
         names = kv_field_names("int8" if quant else None)
+        if cfg.dsa is not None:
+            if not isinstance(cache, PagedKVCache) or cache.idx is None:
+                raise ValueError(
+                    "a model with an indexer (cfg.dsa) keeps one index "
+                    "key a token beside its k and v rows: it serves on a "
+                    "PagedKVCache that holds the third pool (the 'paged' "
+                    f"cache backend), not on a {type(cache).__name__}"
+                )
+            names = names + ("idx",)
     cleaves = tuple(getattr(cache, n) for n in names)
 
     from shellac_tpu.ops.moe import experts_plain, sorted_kernel_runs
@@ -1722,12 +1958,15 @@ def forward_with_cache(
         # donated buffers come back where they came in
         # (tests/test_paged_inplace.py holds the compiled program to it).
         n_blocks = cache.k.shape[1]
+        # k and v, and with an indexer the index keys: the rest are an
+        # int8 pool's scales.
+        n_state = 3 if cfg.dsa is not None else 2
 
         def step(carry, lp, li, xs_l, moe_layer, attn_kind):
             x, pools = carry
             x, pools, _ = block(
-                x, lp, pools[:2], moe_layer, attn_kind,
-                kv_scales=pools[2:] or None,
+                x, lp, pools[:n_state], moe_layer, attn_kind,
+                kv_scales=pools[n_state:] or None,
                 page_tables=cache.tables + li * n_blocks,
             )
             return (x, pools), None
@@ -1758,6 +1997,9 @@ def forward_with_cache(
         if mixed:
             news = news["window"] + news["full"]
 
+    if logits_at is not None:
+        x = jnp.take_along_axis(
+            x, logits_at.astype(jnp.int32)[:, None, None], axis=1)
     logits = unembed(cfg, params, x, mesh=mesh)
     if new_tokens_len is None:
         new_lengths = index + s

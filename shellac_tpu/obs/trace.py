@@ -93,12 +93,17 @@ SPAN_PHASE = {
 #: the cache backend's (`CacheBackend.window_counts`; 0 but on the 'eva'
 #: backend): over every slot-tick of a synced window that produced a
 #: token, the exact rows of its own window and the pooled rows of
-#: earlier windows that its query attended, from lengths the host has.
+#: earlier windows that its query attended, from lengths the host has;
+#: and, for a model with an indexer (cfg.dsa, the 'paged' backend; 0
+#: elsewhere), over the same slot-ticks the rows the indexer scored
+#: (the context) and the rows the query then attended (the context or
+#: the rows kept, whichever is less).
 STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
                "prefill_padded_tokens", "prefill_sorted_tokens",
                "compiles", "compile_s",
-               "eva_window_rows", "eva_summary_rows")
+               "eva_window_rows", "eva_summary_rows",
+               "dsa_index_rows", "dsa_selected_rows")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:

@@ -92,6 +92,8 @@ DEVICE_SCOPES = (
     "attn.core", "attn.out", "mla.absorb", "mla.latent_write", "mlp",
     "moe.route", "moe.sort", "moe.gemm", "moe.combine", "moe.shared",
     "eva.pool", "eva.summary_write", "eva.attend",
+    "dsa.index_proj", "dsa.index_write", "dsa.score", "dsa.select",
+    "dsa.attend",
     "unembed", "sample",
 )
 _SCOPE_RE = re.compile(

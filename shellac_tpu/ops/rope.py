@@ -154,3 +154,32 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     out1 = x1f * c - x2f * s
     out2 = x2f * c + x1f * s
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+
+
+def mrope_token_positions(position_ids) -> jax.Array:
+    """(B, S) positions from M-RoPE position ids (3, B, S), for token
+    input. Keye-VL-2.0 ropes with three position axes (temporal, height,
+    width); for text tokens all three equal the token's index and the
+    rope is the ordinary one, which is what this program computes.
+    Anything else (image or video tokens) refuses: the check reads the
+    values, so the ids must be concrete, not traced."""
+    import numpy as np
+
+    if isinstance(position_ids, jax.core.Tracer):
+        raise NotImplementedError(
+            "M-RoPE position ids are checked on the host (three equal "
+            "axes, or refused): pass them concrete, not traced"
+        )
+    p = np.asarray(position_ids)
+    if p.ndim != 3 or p.shape[0] != 3:
+        raise ValueError(
+            f"M-RoPE position ids are (3, B, S), got {p.shape}"
+        )
+    if not (np.array_equal(p[0], p[1]) and np.array_equal(p[0], p[2])):
+        raise NotImplementedError(
+            "three unequal M-RoPE position axes (image or video tokens): "
+            "only token-id input is served, where the three axes are equal "
+            "and M-RoPE is the ordinary rope; the vision tower is not part "
+            "of this program"
+        )
+    return jnp.asarray(p[0], jnp.int32)

@@ -134,6 +134,25 @@ def _rmsnorm(grad):
                     argnums=(0, 1)), shapes
 
 
+def _dsa_chunk(which):
+    """The two kernels of a prompt chunk under a learned indexer, at
+    keye-vl-2.0-30b-a3b-batch-long's shapes: 4096 queries of 32 heads on
+    4 kv heads of 128 (16 index heads of 64) against the 33,792 rows a
+    slot can hold."""
+    from shellac_tpu.ops import dsa_attention as dsa
+
+    sq, sk, h, hkv, j, di = 4096, 33792, 32, 4, 16, 64
+    live = ((1, sq // dsa.BLOCK_Q), I32)
+    if which == "scores":
+        return (lambda u, w, c, n: dsa.index_scores_flash(u, w, c, n, False),
+                [((1, sq, j, di), BF16), ((1, sq, j), F32),
+                 ((1, di, sk), BF16), live])
+    return (lambda q, k, v, m, n: dsa.masked_flash(q, k, v, m, n, D ** -0.5,
+                                                   False),
+            [((1, sq, h, D), BF16), ((1, sk, hkv, D), BF16),
+             ((1, sk, hkv, D), BF16), ((1, sq, sk), I8), live])
+
+
 def _int8_page_sizes():
     """The engine's default int8 page size plus every size its error
     message recommends — read from the engine, not repeated here."""
@@ -167,6 +186,8 @@ CASES = [
     *[(f"paged-int8-page{bs}",
        lambda bs=bs: _paged_decode(bs, quant=True), True)
       for bs in _int8_page_sizes()],
+    ("dsa-index-scores-chunk4096", lambda: _dsa_chunk("scores"), True),
+    ("dsa-masked-flash-chunk4096", lambda: _dsa_chunk("attend"), True),
     ("rmsnorm-fwd", lambda: _rmsnorm(False), True),
     # The backward is plain XLA (vjp of the reference); it only has to
     # compile.
