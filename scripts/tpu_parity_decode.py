@@ -375,7 +375,42 @@ def mla_shape_cases(checks):
     ref = _jitted(_decode_ref)(q, lat, lat, index, None, 192 ** -0.5)
     check("mla latent decode d=576", out, ref, atol=2e-2, checks=checks)
 
-    S, HKV, DQ = 1024, 8, 192
+    # The same latent as a paged pool (deepseek-v2-lite-batch's read):
+    # rows held 640 wide with zeros in the pad lanes, 20 pages of 128,
+    # ONE array as k and as v (a page is copied once, v read out of the
+    # k tile), 4 pages a grid step, so the double buffer crosses groups
+    # and slots; pages no slot owns hold NaN.
+    from shellac_tpu.inference.kvcache import held_width
+    from shellac_tpu.ops.decode_attention import paged_decode_attention
+
+    B, bs, mb = 4, 128, 20
+    L, W = bs * mb, held_width(D)
+    for s in (1, 3):
+        ks = jax.random.split(jax.random.PRNGKey(15 + s), 2)
+        q = _normal(ks[0], (B, s, H, D), jnp.bfloat16)
+        lat = np.zeros((B, 1, L, W), np.float32)
+        lat[..., :D] = _host(_normal(ks[1], (B, 1, L, D), jnp.bfloat16))
+        index = np.array([0, 37, 1300, L - s], np.int32)
+        tables = (np.random.default_rng(s).permutation(B * mb) + 1).reshape(
+            B, mb)
+        pool = np.full((B * mb + 1, 1, bs, W), np.nan, np.float32)
+        pool[0] = 0.0
+        for b in range(B):
+            for j in range(-(-(index[b] + s) // bs)):
+                pool[tables[b, j]] = lat[b, :, j * bs:(j + 1) * bs]
+        out = _jitted(lambda q, p, t, i: paged_decode_attention(
+            q, p, p, t, i, impl="flash", scale=192 ** -0.5, interpret=False,
+        ))(q, jnp.asarray(pool, jnp.bfloat16), jnp.asarray(tables, jnp.int32),
+           jnp.asarray(index))
+        ref = _jitted(_decode_ref)(
+            jnp.pad(q, ((0, 0),) * 3 + ((0, W - D),)),
+            jnp.asarray(lat, jnp.bfloat16), jnp.asarray(lat, jnp.bfloat16),
+            jnp.asarray(index), None, 192 ** -0.5)
+        check(f"mla latent paged d=576 held 640 k-as-v s={s}",
+              _host(out)[..., :512], _host(ref)[..., :512], atol=2e-2,
+              checks=checks)
+
+    B, S, HKV, DQ = 2, 1024, 8, 192
     ks = jax.random.split(jax.random.PRNGKey(14), 3)
     qf = _normal(ks[0], (B, S, HKV, DQ), jnp.bfloat16)
     kf = _normal(ks[1], (B, S, HKV, DQ), jnp.bfloat16)

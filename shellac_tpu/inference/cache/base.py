@@ -183,6 +183,11 @@ class CacheBackend:
         this is the storage view only."""
         raise NotImplementedError
 
+    def row_width(self, width: int) -> int:
+        """Lanes this storage holds a row of `width` at: the row's own,
+        unless the policy pads it (the paged pool: whole lane tiles)."""
+        return width
+
     def bytes_per_token(self) -> int:
         """Resident KV bytes one token costs under this storage policy
         (per-slot view; paged block rounding ignored). Exposed as the
@@ -193,7 +198,8 @@ class CacheBackend:
         import jax.numpy as jnp
 
         cfg = self.cfg
-        width = cfg.cache_head_dim + cfg.cache_v_head_dim
+        width = (self.row_width(cfg.cache_head_dim)
+                 + self.row_width(cfg.cache_v_head_dim))
         if self.kv_quant == "int8":
             # int8 values + one fp32 scale per token/head for k and v.
             return cfg.n_layers * cfg.cache_kv_heads * (width + 2 * 4)

@@ -343,8 +343,20 @@ class PagedKVCache:
     host-side free list (see PagedBatchingEngine); the device side only
     ever sees the tables.
 
-    k, v: (L, n_blocks, Hkv, block_size, Dh) — head-major inside each
-        block, same Pallas tiling requirement as the dense cache.
+    k, v: (L, n_blocks, Hkv, block_size, W) — head-major inside each
+        block, same Pallas tiling requirement as the dense cache. W is
+        held_width(Dh): a row wider than one 128-lane tile is held at a
+        whole number of tiles (the MLA latent's 576 lanes at 640; 128,
+        256, 512 as they are). The device keeps such a pool
+        row-innermost, the form paged_write pins and a Mosaic operand
+        takes where it lies; at 576 it kept the page's token axis
+        innermost and copied the WHOLE pool in front of either (PERF.md,
+        PR 32). The pad lanes hold zeros and stay zeros: the pool starts
+        as zeros, the scratch block included, and every writer
+        zero-extends its rows (fit_row), so a reader that zero-extends
+        its queries gets the logits of the narrow row and zeros in the
+        output's pad lanes. The int8 pool (QuantPagedKVCache) keeps its
+        rows as wide as the model's.
     tables: (n_slots, max_blocks) int32 — pool block id per logical
         block; unallocated entries MUST point at block 0 (reserved as
         scratch: it is never handed to a slot, so stray writes and reads
@@ -386,6 +398,26 @@ class PagedKVCache:
         return self.tables.shape[1]
 
 
+#: Lanes of one tile of the device's layout.
+LANES = 128
+
+
+def held_width(width: int) -> int:
+    """The width a bf16/fp32 paged pool holds rows of `width` at: wider
+    than one lane tile, the next whole number of tiles (PagedKVCache's
+    docstring has why); a row inside one tile stays as it is."""
+    return width if width <= LANES else -(-width // LANES) * LANES
+
+
+def fit_row(x: jax.Array, width: int) -> jax.Array:
+    """`x` with its last axis zero-extended to `width` lanes (a pool's
+    held width); unchanged where it already has them."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def init_paged_cache(
     cfg: ModelConfig,
     n_slots: int,
@@ -398,10 +430,12 @@ def init_paged_cache(
     if tables is None:
         tables = jnp.zeros((n_slots, max_blocks_per_slot), jnp.int32)
     return PagedKVCache(
-        k=jnp.zeros((*head, cfg.cache_head_dim), cfg.compute_dtype),
+        k=jnp.zeros((*head, held_width(cfg.cache_head_dim)),
+                    cfg.compute_dtype),
         # MLA: zero-width v pool (values re-expand from the latent the
         # k pool stores), same convention as the dense cache.
-        v=jnp.zeros((*head, cfg.cache_v_head_dim), cfg.compute_dtype),
+        v=jnp.zeros((*head, held_width(cfg.cache_v_head_dim)),
+                    cfg.compute_dtype),
         tables=tables,
         lengths=jnp.zeros((n_slots,), jnp.int32),
         idx=(jnp.zeros((cfg.n_layers, n_blocks, cfg.dsa.index_dim,
@@ -535,12 +569,19 @@ def paged_write_prompt(pools, minis, table_row):
     l * n_blocks, its run the mini's layer l from position 0.
 
     pools: (L, n_blocks, Hkv, bs[, D]) each; minis: the mini cache's
-    matching fields, (L, 1, Hkv, S[, D]). Returns the pools."""
+    matching fields, (L, 1, Hkv, S[, D]), rows zero-extended to the
+    width the pool holds them at (held_width). Returns the pools."""
     n_layers, n_blocks = pools[0].shape[:2]
     layers = jnp.arange(n_layers, dtype=jnp.int32)
+
+    def run(mini, pool):
+        rows = mini[:, 0].astype(pool.dtype)
+        # (An int8 pool's scales have no row width.)
+        return fit_row(rows, pool.shape[-1]) if pool.ndim == 5 else rows
+
     out = paged_write(
         [p.reshape(n_layers * n_blocks, *p.shape[2:]) for p in pools],
-        [m[:, 0].astype(p.dtype) for m, p in zip(minis, pools)],
+        [run(m, p) for m, p in zip(minis, pools)],
         jnp.zeros_like(layers), table_row[None, :] + n_blocks * layers[:, None],
     )
     return tuple(o.reshape(p.shape) for o, p in zip(out, pools))
@@ -554,11 +595,14 @@ def paged_update_layer(
     index: jax.Array,  # (B,) — per-slot write offsets (token positions)
     tables: jax.Array,  # (B, max_blocks) int32
 ):
-    """Write S new positions through the block tables; returns pools."""
+    """Write S new positions through the block tables, the rows
+    zero-extended to the width the pool holds them at (held_width);
+    returns pools."""
     return paged_write(
         (pool_k, pool_v),
-        (k_new.astype(pool_k.dtype).transpose(0, 2, 1, 3),
-         v_new.astype(pool_v.dtype).transpose(0, 2, 1, 3)),
+        tuple(fit_row(new.astype(pool.dtype), pool.shape[-1])
+              .transpose(0, 2, 1, 3)
+              for new, pool in ((k_new, pool_k), (v_new, pool_v))),
         index, tables,
     )
 
@@ -598,7 +642,10 @@ class QuantPagedKVCache:
     allocator run covers both, so the free list and prefix-cache
     refcounts need no changes.
 
-    k, v: (L, n_blocks, Hkv, block_size, Dh) int8
+    k, v: (L, n_blocks, Hkv, block_size, Dh) int8 — rows as wide as the
+        model's, never padded to whole lane tiles as a bf16 pool's are
+        (held_width): an int8 MLA latent pool keeps its 576-wide row and
+        reads through the gather (no cell runs it; PERF.md section 7).
     ks, vs: (L, n_blocks, Hkv, block_size) fp32
     tables: (n_slots, max_blocks) int32
     lengths: (n_slots,) int32

@@ -35,6 +35,7 @@ from shellac_tpu.config import ModelConfig
 from shellac_tpu.inference import prefix as prefix_mod
 from shellac_tpu.inference.cache.base import CacheBackend, PoolExhausted
 from shellac_tpu.inference.cache.layout import (
+    held_width,
     init_cache_for,
     init_paged_cache,
     init_quant_paged_cache,
@@ -246,13 +247,19 @@ class PagedBackend(CacheBackend):
         if cfg.dsa is not None:
             # Neither: the tick gathers the rows its indexer chose.
             return "chosen_rows"
+        width = self.row_width(cfg.cache_head_dim)
         return paged_decode_path(
-            (self.n_slots, 1, cfg.n_heads, cfg.cache_head_dim),
-            (self.n_blocks, cfg.cache_kv_heads, self.block_size,
-             cfg.cache_head_dim),
+            (self.n_slots, 1, cfg.n_heads, width),
+            (self.n_blocks, cfg.cache_kv_heads, self.block_size, width),
             jnp.int8 if self.kv_quant == "int8" else cfg.compute_dtype,
             self.engine.attn_impl,
         )
+
+    def row_width(self, width: int) -> int:
+        """Lanes this pool holds a row of `width` at: a bf16/fp32 pool
+        whole lane tiles (layout.held_width), the int8 pool the row's
+        own."""
+        return width if self.kv_quant == "int8" else held_width(width)
 
     # ---- allocator ---------------------------------------------------
 

@@ -1143,10 +1143,11 @@ def _mla_attention(
             if kv_scales is not None:
                 # Int8 latent pool: one scale per latent row, serving
                 # both attention roles like the dense int8 latent
-                # cache. (The latent width is not 128-aligned, so reads
-                # take the gather + dequant reference path — correct,
-                # with the paged-fallback warning naming the
-                # constraint.)
+                # cache. (It keeps the row's own width, which does not
+                # fill the lanes, where the bf16 pool pads it
+                # (kvcache.held_width): reads take the gather + dequant
+                # reference path — correct, with the paged-fallback
+                # warning naming the constraint.)
                 ks_l, vs_l = kv_scales
                 pool_k, pool_v, ks_l, vs_l = quant_paged_update_layer(
                     pool_k, pool_v, ks_l, vs_l, latent, v_stub, index,
@@ -1163,7 +1164,11 @@ def _mla_attention(
             o = expanded_attention()
         else:
             # Same k-as-v trick as the dense path: the latent pool
-            # serves both roles, values are its first kv_rank lanes.
+            # serves both roles, values are its first kv_rank lanes
+            # (handed in as ONE array, the kernel copies a page once).
+            # The bf16 pool holds the row at whole lane tiles, 576 at
+            # 640 (kvcache.held_width), its pad lanes zeros: the writer
+            # above and this read zero-extend the row and the query.
             o_lat = paged_decode_attention(
                 absorbed_q(), pool_k, pool_k, page_tables, index,
                 scale=scale, impl=attn_impl, mesh=mesh,
@@ -1439,16 +1444,18 @@ def _dsa_attention(cfg, mesh, attn_impl, hx, lp, q, k, v, rope, cache,
             "paged pools (k, v and the index keys under one block "
             "table): serve it on the 'paged' cache backend"
         )
-    from shellac_tpu.inference.kvcache import paged_write
+    from shellac_tpu.inference.kvcache import fit_row, paged_write
 
-    # pool_k, pool_v: (N, 1, bs, Hkv * Dh), a token's kv heads side by
-    # side in one row (cfg.cache_head_dim): a tick gathers whole rows.
+    # pool_k, pool_v: (N, 1, bs, W), a token's kv heads side by side in
+    # one row (cfg.cache_head_dim, zero-extended to the width the pool
+    # holds rows at: kvcache.held_width): a tick gathers whole rows.
     pool_k, pool_v, pool_i, index, _ = cache
     with jax.named_scope("kv.write"):
         pool_k, pool_v = paged_write(
             (pool_k, pool_v),
-            (k.astype(pool_k.dtype).reshape(b, 1, s, hkv * dh),
-             v.astype(pool_v.dtype).reshape(b, 1, s, hkv * dh)),
+            tuple(fit_row(x.astype(p.dtype).reshape(b, 1, s, hkv * dh),
+                          p.shape[-1])
+                  for x, p in ((k, pool_k), (v, pool_v))),
             index, page_tables,
         )
     with jax.named_scope("dsa.index_write"):
@@ -1482,7 +1489,8 @@ def _dsa_attention(cfg, mesh, attn_impl, hx, lp, q, k, v, rope, cache,
             # run's own rows among them.
             k_all, v_all = (
                 jnp.take(p, page_tables.reshape(-1), axis=0).reshape(
-                    b, cap, hkv, dh) for p in (pool_k, pool_v))
+                    b, cap, -1)[..., :hkv * dh].reshape(b, cap, hkv, dh)
+                for p in (pool_k, pool_v))
             c_t = index_keys() if cap > a.topk else None
         at = index[:, None] + steps[None, :]
         k_len = index + n_new
@@ -1520,7 +1528,8 @@ def _dsa_attention(cfg, mesh, attn_impl, hx, lp, q, k, v, rope, cache,
     with jax.named_scope("dsa.attend"):
         block = jnp.take_along_axis(page_tables, rows // bs, axis=1)
         k_rows, v_rows = (
-            dsa.gather_rows(p, block, rows % bs).reshape(b, -1, hkv, dh)
+            dsa.gather_rows(p, block, rows % bs)[..., :hkv * dh].reshape(
+                b, -1, hkv, dh)
             for p in (pool_k, pool_v))
         o = dsa.attend_rows(q[:, 0], k_rows, v_rows, ok, scale)
     return o[:, None], new_cache

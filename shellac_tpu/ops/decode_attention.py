@@ -618,9 +618,9 @@ def _decode_ref(q, cache_k, cache_v, index, window, scale, softcap=None,
 
 
 def _paged_group_kernel(
-    len_ref, tab_ref, q_ref, k_hbm, v_hbm, *rest,
+    len_ref, tab_ref, q_ref, k_hbm, *rest,
     scale, s, hkv, bs, group, window, num_kv, softcap=None,
-    has_sinks=False, quant=False,
+    has_sinks=False, quant=False, shared=False,
 ):
     """Grouped paged decode: `group` pages gathered per grid step.
 
@@ -643,18 +643,25 @@ def _paged_group_kernel(
     """
     from jax.experimental.pallas import tpu as pltpu
 
+    if not shared:
+        v_hbm, rest = rest[0], rest[1:]
     if quant:
         # Int8 pools travel with fp32 scale pools, gathered page-for-
         # page into their own VMEM tiles (sem rows 2/3).
         ks_hbm, vs_hbm = rest[0], rest[1]
         rest = rest[2:]
     sink_ref, rest = _split_sink_rest(rest, has_sinks)
+    ks_buf = vs_buf = None
     if quant:
         (o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
          sems, step_ref) = rest
+    elif shared:
+        # One pool serves as k and as v (the MLA latent): v_hbm is the
+        # k operand again and a page is copied once, into the one tile.
+        o_ref, acc_ref, m_ref, l_ref, k_buf, sems, step_ref = rest
+        v_buf = k_buf
     else:
         o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, step_ref = rest
-        ks_buf = vs_buf = None
     b = pl.program_id(0)
     gi = pl.program_id(1)
     n_slots = pl.num_programs(0)
@@ -677,14 +684,13 @@ def _paged_group_kernel(
             @pl.when(pg_live)
             def _copy(g=g, pg=pg, dst=dst):
                 page = tab_ref[slot, pg]
-                copies = [
-                    pltpu.make_async_copy(
-                        k_hbm.at[page], k_buf.at[half, :, dst, :],
-                        sems.at[half, 0, g]),
-                    pltpu.make_async_copy(
+                copies = [pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[half, :, dst, :],
+                    sems.at[half, 0, g])]
+                if not shared:
+                    copies.append(pltpu.make_async_copy(
                         v_hbm.at[page], v_buf.at[half, :, dst, :],
-                        sems.at[half, 1, g]),
-                ]
+                        sems.at[half, 1, g]))
                 if quant:
                     copies += [
                         pltpu.make_async_copy(
@@ -754,15 +760,21 @@ def _paged_group_flash(
     num_groups = num_kv // group
     block_k = group * bs
     quant = k_scale is not None
+    # pool_v None: the k rows serve as v too (the MLA latent), and a
+    # page is copied once.
+    shared = pool_v is None
+    dv = d if shared else pool_v.shape[-1]
 
     qf = _flatten_q(q, hkv)
 
     in_specs = [
         pl.BlockSpec((1, rows, d), lambda bi, gi, lr, tr: (bi, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # k pool stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),  # v pool stays in HBM
     ]
-    operands = [qf, pool_k, pool_v]
+    operands = [qf, pool_k]
+    if not shared:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # v pool too
+        operands.append(pool_v)
     if quant:
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),  # scale pools too
@@ -776,14 +788,15 @@ def _paged_group_flash(
         ]
         operands += [_row_sinks(sinks, s)]
     scratch = [
-        pltpu.VMEM((rows, d), jnp.float32),
+        pltpu.VMEM((rows, dv), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
         pltpu.VMEM((rows, 128), jnp.float32),
     ]
     # Two halves of each gathered tile, one in use, one in flight; the
     # count of live steps whose parity says which.
-    scratch += [pltpu.VMEM((2, hkv, block_k, d), pool_k.dtype),
-                pltpu.VMEM((2, hkv, block_k, d), pool_v.dtype)]
+    scratch += [pltpu.VMEM((2, hkv, block_k, d), pool_k.dtype)]
+    if not shared:
+        scratch += [pltpu.VMEM((2, hkv, block_k, dv), pool_v.dtype)]
     if quant:
         scratch += [pltpu.VMEM((2, hkv, block_k), jnp.float32),
                     pltpu.VMEM((2, hkv, block_k), jnp.float32)]
@@ -794,7 +807,7 @@ def _paged_group_flash(
         grid=(b, num_groups),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, rows, d), lambda bi, gi, lr, tr: (bi, 0, 0)
+            (1, rows, dv), lambda bi, gi, lr, tr: (bi, 0, 0)
         ),
         scratch_shapes=scratch,
     )
@@ -802,32 +815,42 @@ def _paged_group_flash(
         functools.partial(
             _paged_group_kernel, scale=scale, s=s, hkv=hkv, bs=bs,
             group=group, window=window, num_kv=num_kv, softcap=softcap,
-            has_sinks=has_sinks, quant=quant,
+            has_sinks=has_sinks, quant=quant, shared=shared,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, dv), q.dtype),
         interpret=interpret,
         name="decode_paged_group",
     )(index.astype(jnp.int32), tables.astype(jnp.int32), *operands)
-    return _unflatten_o(out, b, s, h, d)
+    return _unflatten_o(out, b, s, h, dv)
 
 
-def _paged_group(tables, pool_k) -> int:
-    """Pages per grid step: aim for a ~512-row kv tile (on a v5e at 8
-    kv heads x 128 and 256-row pages, 1024 rows took the same time),
-    divide the table, and keep both halves of the k and v tiles within
-    the VMEM budget the one-page kernel enforces.
+# The most k and v elements a grid step of the grouped kernel gathers
+# into VMEM (two such tiles are held, one in use, one in flight: 8 MiB
+# of fp32).
+PAGED_TILE_ELEMS = 1 << 20
+
+
+def _paged_group(tables, pool_k, pool_v) -> int:
+    """Pages per grid step: aim for a ~512-row kv tile, divide the
+    table, and keep a step's k and v tiles within PAGED_TILE_ELEMS
+    (pool_v None: v is read out of the k tile and takes no room). On a
+    v5e, at 8 kv heads x 128 and 256-row pages 1024 rows took the same
+    time as 512; over the 640-lane latent at the batch mix's contexts
+    (mean 785 rows) 512 and 640 rows tied, 256 took 34 % longer, 1280
+    rows 4 % and 2560 rows 37 %: rows past a slot's length in a tile are
+    multiplied all the same (PERF.md, PR 28 and PR 32).
     Returns 1 (one-page kernel) when grouping cannot work: the gather
     lands each page at sublane offset g*bs of the VMEM tile, so bs
     must be a multiple of the dtype's sublane tile (fp32 8, bf16 16,
     int8 32) or Mosaic rejects the slice."""
     num_kv = tables.shape[1]
-    hkv, bs = pool_k.shape[1], pool_k.shape[2]
+    hkv, bs, dk = pool_k.shape[1:]
     sublane = 8 * max(1, 4 // jnp.dtype(pool_k.dtype).itemsize)
     if bs % sublane:
         return 1
-    cap = max(1, 4096 // max(hkv * bs, 1))  # 2 halves: hkv*group*bs <= 4096
-    g = min(max(512 // bs, 1), cap, num_kv)
+    page = hkv * bs * (dk + (0 if pool_v is None else pool_v.shape[3]))
+    g = min(max(512 // bs, 1), max(PAGED_TILE_ELEMS // page, 1), num_kv)
     while g > 1 and num_kv % g:
         g -= 1
     return g
@@ -1004,6 +1027,12 @@ def paged_decode_attention(
     pages and folds them in after the integer dots (same exact algebra
     as the dense int8 kernel).
 
+    A pool may hold its rows wider than q's (kvcache.held_width: whole
+    lane tiles, the pad lanes zeros): q is then zero-extended to the
+    pool's width, which changes no logit, `scale` defaults from q's own
+    width, and the result comes back as wide as q (its pad lanes, zeros
+    under a zero-padded v, dropped).
+
     `mesh`: the mesh the caller is partitioned over, if any; the kernel
     then runs per shard of slots and heads, every shard over its own
     heads of the whole pool (_kv_axis).
@@ -1011,8 +1040,20 @@ def paged_decode_attention(
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale come together")
     quant = k_scale is not None
+    from shellac_tpu.inference.kvcache import (
+        fit_row,
+        paged_gather_layer,
+        paged_gather_scales,
+    )
+
+    width = q.shape[-1]
     if scale is None:
-        scale = q.shape[-1] ** -0.5
+        scale = width ** -0.5
+    q = fit_row(q, max(width, pool_k.shape[-1]))
+
+    def narrowed(o):
+        return o if o.shape[-1] <= width else o[..., :width]
+
     if interpret is None:
         interpret = not pallas_supported()
     shapes_ok = paged_decode_supported(q, pool_k, quant=quant)
@@ -1050,6 +1091,12 @@ def paged_decode_attention(
             stacklevel=2,
         )
     if use_kernel:
+        # One array handed in as k and as v (the MLA latent pool) goes
+        # on as ONE operand, pool_v None, across a mesh's shards too: the
+        # grouped kernel then copies a page once and reads v out of the
+        # k tile. (An int8 pool's tiles travel with their scales, apart.)
+        kernel_v = None if pool_v is pool_k and not quant else pool_v
+
         def kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale, sinks):
             # Grouped gather kernel when the head dim keeps full-lane
             # tiles (its tile body is the ref-slicing fast path) and
@@ -1057,7 +1104,7 @@ def paged_decode_attention(
             # otherwise. Int8 pools always take the grouped kernel (the
             # support gate guarantees its constraints): the one-page
             # kernel's BlockSpec body has no scale plumbing.
-            group = (_paged_group(tables, pool_k)
+            group = (_paged_group(tables, pool_k, pool_v)
                      if q.shape[-1] % 128 == 0 else 1)
             sc = None if softcap is None else float(softcap)
             if group > 1 or quant:
@@ -1067,19 +1114,20 @@ def paged_decode_attention(
                     k_scale=k_scale, v_scale=v_scale,
                 )
             return _paged_flash(
-                q, pool_k, pool_v, tables, index, float(scale), window,
-                interpret, softcap=sc, sinks=sinks,
+                q, pool_k, pool_k if pool_v is None else pool_v, tables,
+                index, float(scale), window, interpret, softcap=sc,
+                sinks=sinks,
             )
 
         with jax.named_scope("attn.core"):
             if not on_mesh(mesh):
-                return kernel(q, pool_k, pool_v, tables, index, k_scale,
-                              v_scale, sinks)
+                return narrowed(kernel(q, pool_k, kernel_v, tables, index,
+                                       k_scale, v_scale, sinks))
             kv = _kv_axis(pool_k)
             out = per_shard(kernel, mesh, {
                 "q": (q, _Q_AXES),
                 "pool_k": (pool_k, (None, kv, None, None)),
-                "pool_v": (pool_v, (None, kv, None, None)),
+                "pool_v": (kernel_v, (None, kv, None, None)),
                 "tables": (tables, ("batch", None)),
                 "index": (index, ("batch",)),
                 "k_scale": (k_scale, (None, kv, None)),
@@ -1087,16 +1135,11 @@ def paged_decode_attention(
                 "sinks": (sinks, ("heads",)),
             }, _Q_AXES)
         if out is not None:
-            return out
+            return narrowed(out)
         _mesh_fallback(
             impl, _DEQUANT_EVERY_TICK if quant
             else "gathers every slot's dense view every tick",
             PagedFallbackWarning, q, pool_k, mesh)
-    from shellac_tpu.inference.kvcache import (
-        paged_gather_layer,
-        paged_gather_scales,
-    )
-
     # The XLA path: materialize each slot's dense view through its
     # table, then the masked reference attention over it.
     with jax.named_scope("kv.gather"):
@@ -1106,9 +1149,9 @@ def paged_decode_attention(
             ks_all = paged_gather_scales(k_scale, tables)
             vs_all = paged_gather_scales(v_scale, tables)
     with jax.named_scope("attn.core"):
-        return _decode_ref(q, k_all, v_all, index, window, scale,
-                           softcap=softcap, sinks=sinks, k_scale=ks_all,
-                           v_scale=vs_all)
+        return narrowed(_decode_ref(
+            q, k_all, v_all, index, window, scale, softcap=softcap,
+            sinks=sinks, k_scale=ks_all, v_scale=vs_all))
 
 
 @jax.named_scope("attn.core")

@@ -120,6 +120,25 @@ def _paged_decode(bs, *, quant=False):
     return fn, shapes
 
 
+def _paged_latent_decode():
+    """deepseek-v2-lite-batch's read: 16 q heads on the one shared
+    latent row, held at 640 lanes (kvcache.held_width(576)), 128-row
+    pages, the pool serving as k and as v; q arrives 576 wide."""
+    from shellac_tpu.inference.kvcache import held_width
+    from shellac_tpu.ops.decode_attention import paged_decode_attention
+
+    slots, pages, d = 64, 20, 576
+    pool = ((slots * pages + 1, 1, 128, held_width(d)), BF16)
+    shapes = [((slots, 1, H, d), BF16), pool, ((slots, pages), I32),
+              ((slots,), I32)]
+
+    def fn(q, pk, tables, index):
+        return paged_decode_attention(q, pk, pk, tables, index, impl="flash",
+                                      interpret=False, scale=192 ** -0.5)
+
+    return fn, shapes
+
+
 def _rmsnorm(grad):
     from shellac_tpu.ops.norms import rms_norm_pallas
 
@@ -183,6 +202,8 @@ CASES = [
     ("paged-bf16-page64", lambda: _paged_decode(64), True),
     # The pool "auto" sends to the kernel (mistral-7b-batch's pages).
     ("paged-bf16-page256", lambda: _paged_decode(256), True),
+    # And the latent pool it sends there (deepseek-v2-lite-batch's).
+    ("paged-latent-page128", _paged_latent_decode, True),
     *[(f"paged-int8-page{bs}",
        lambda bs=bs: _paged_decode(bs, quant=True), True)
       for bs in _int8_page_sizes()],
@@ -267,12 +288,37 @@ def _mesh_paged_int8(mesh):
                 ((SERVE_B,), I32, (None,)), scales, scales]
 
 
-@pytest.mark.parametrize("page,kernel", [
-    pytest.param(256, True, id="page256-kernel"),
-    pytest.param(64, False, id="page64-gather"),
+def _gqa_stack():
+    from shellac_tpu import get_model_config
+
+    return get_model_config("tiny").replace(
+        d_model=512, n_heads=H, n_kv_heads=HKV, head_dim=D, d_ff=1024,
+        n_layers=8, dtype="bfloat16", param_dtype="bfloat16",
+    ).validate()
+
+
+def _mla_stack(d_model=512, **kw):
+    """DeepSeek-V2-Lite's attention widths (16 heads, latent 512 + rope
+    64, no q compression) over a narrow FFN."""
+    from shellac_tpu import get_model_config
+    from shellac_tpu.config import MLAConfig
+
+    return get_model_config("tiny-mla").replace(
+        d_model=d_model, n_heads=H, d_ff=1024, n_layers=8, dtype="bfloat16",
+        param_dtype="bfloat16",
+        mla=MLAConfig(kv_lora_rank=512, q_lora_rank=None,
+                      qk_nope_head_dim=128, qk_rope_head_dim=64,
+                      v_head_dim=128), **kw,
+    ).validate()
+
+
+@pytest.mark.parametrize("stack,page,kernel", [
+    pytest.param(_gqa_stack, 256, True, id="page256-kernel"),
+    pytest.param(_gqa_stack, 64, False, id="page64-gather"),
+    pytest.param(_mla_stack, 128, True, id="latent-page128-kernel"),
 ])
 def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
-        chip, pool_sized_ops, monkeypatch, page, kernel):
+        chip, pool_sized_ops, monkeypatch, stack, page, kernel):
     """The TPU compiler's verdict on what tests/test_paged_inplace.py
     reads off the CPU's: a window of decode ticks over a bf16 paged pool
     at serving widths (8 kv heads x 128) holds no pool-sized temporary
@@ -286,22 +332,17 @@ def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
     the window that must hold is the one WITH THE KERNEL IN IT: a pool
     whose layout the kernel's operand does not share would be copied in
     front of every layer's call. Shorter pages take the gather, whose
-    window must hold too."""
+    window must hold too. So must the window over an MLA model's latent
+    pool, held at 640 lanes so that writer and kernel share its form
+    (at 576 the compiler copied the whole pool twice a window)."""
     from shellac_tpu.ops import decode_attention as da
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    from shellac_tpu import get_model_config
     from shellac_tpu.inference.kvcache import init_paged_cache
     from shellac_tpu.models import transformer
 
-    cfg = get_model_config("tiny").replace(
-        d_model=512, n_heads=H, n_kv_heads=HKV, head_dim=D, d_ff=1024,
-        n_layers=8, dtype="bfloat16", param_dtype="bfloat16",
-    ).validate()
+    cfg = stack()
     slots, pages = 8, 1024 // page
-    assert da.paged_decode_path(
-        (slots, 1, H, D), (slots * pages + 1, HKV, page, D), BF16
-    ) == ("paged_kernel" if kernel else "gather")
 
     def window(params, cache, cur):
         def tick(carry, _):
@@ -324,10 +365,13 @@ def test_paged_decode_window_keeps_the_pool_in_place_on_v5e(
         lambda: init_paged_cache(cfg, slots, slots * pages + 1, page, pages)
     ))
     cur = jax.ShapeDtypeStruct((slots,), I32, sharding=chip)
+    assert da.paged_decode_path(
+        (slots, 1, H, cache.k.shape[-1]), cache.k.shape[1:], BF16
+    ) == ("paged_kernel" if kernel else "gather")
     compiled = jax.jit(window, donate_argnums=(1,)).lower(
         params, cache, cur
     ).compile()
-    pool_bytes = 2 * cache.k.size * cache.k.dtype.itemsize
+    pool_bytes = (cache.k.size + cache.v.size) * cache.k.dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes / 2, (temp, pool_bytes)
     text = compiled.as_text()
@@ -403,51 +447,113 @@ def test_dropless_experts_multiply_the_routed_rows_on_v5e(
     assert not made, made[:5]
 
 
-def test_latent_pool_is_relaid_for_the_kernel_on_v5e(chip):
-    """Why "auto" keeps a pool whose row does not fill the lanes on the
-    gather: the device holds a (n_blocks, 1, 128, 576) latent pool in a
-    tiling Mosaic's operand does not share, so the compiler copies the
-    WHOLE pool in front of the kernel's call (temporaries of a pool's
-    size, which the guard above refuses), where a 128-wide pool of the
-    same bytes is read in place. If this ever fails the copy is gone
-    and paged_kernel_under_auto can take D % 128 != 0 in (PERF.md,
-    section 7)."""
+@pytest.mark.parametrize("heads,hkv,d,in_place", [
+    pytest.param(16, 1, 576, False, id="latent-d576-copied"),
+    pytest.param(16, 1, 640, True, id="latent-d640-in-place"),
+    pytest.param(H, HKV, D, True, id="gqa-d128-in-place"),
+])
+def test_latent_pool_is_relaid_for_the_kernel_on_v5e(chip, heads, hkv, d,
+                                                     in_place):
+    """Why a bf16 pool's row is held at whole lane tiles
+    (kvcache.held_width): the device holds a (n_blocks, 1, 128, 576)
+    latent pool in a tiling Mosaic's operand does not share, so the
+    compiler copies the WHOLE pool in front of the kernel's call
+    (temporaries of a pool's size, which the guard above refuses),
+    where the same rows held 640 wide, like a 128-wide pool, are read
+    in place in the layer loop. If the 576 case ever fails the copy is
+    gone and the pad can go (PERF.md, PR 32)."""
+    from shellac_tpu.inference.kvcache import held_width
     from shellac_tpu.ops.decode_attention import (
         paged_decode_attention,
         paged_kernel_under_auto,
     )
 
     layers, pages = 4, 16
+    n_blocks = SERVE_B * pages + 1
+    q = ((layers, SERVE_B, 1, heads, d), BF16)
+    pool = ((layers * n_blocks, hkv, 128, d), BF16)
+    shapes = [q, pool, pool, ((SERVE_B, pages), I32), ((SERVE_B,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    kw = {"scale": 192 ** -0.5} if hkv == 1 else {}
 
-    def temporaries(heads, hkv, d, **kw):
-        n_blocks = SERVE_B * pages + 1
-        q = ((layers, SERVE_B, 1, heads, d), BF16)
-        pool = ((layers * n_blocks, hkv, 128, d), BF16)
-        shapes = [q, pool, pool, ((SERVE_B, pages), I32), ((SERVE_B,), I32)]
-        args = [jax.ShapeDtypeStruct(s, dt, sharding=chip)
-                for s, dt in shapes]
+    def tick(qs, pk, pv, tables, index):  # the layer loop's shape
+        def layer(i, acc):
+            return acc + paged_decode_attention(
+                qs[i], pk, pv, tables + i * n_blocks, index,
+                impl="flash", interpret=False, **kw).astype(F32)
 
-        def tick(qs, pk, pv, tables, index):  # the layer loop's shape
-            def layer(i, acc):
-                return acc + paged_decode_attention(
-                    qs[i], pk, pv, tables + i * n_blocks, index,
-                    impl="flash", interpret=False, **kw).astype(F32)
+        return jax.lax.fori_loop(0, layers, layer, jnp.zeros(q[0][1:], F32))
 
-            return jax.lax.fori_loop(0, layers, layer,
-                                     jnp.zeros(q[0][1:], F32))
+    compiled = jax.jit(tick).lower(*args).compile()
+    pool_bytes = 2
+    for n in pool[0]:
+        pool_bytes *= n
+    assert paged_kernel_under_auto(q[0][1:], pool[0], BF16) is in_place
+    assert (held_width(d) == d) is in_place
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if in_place:
+        assert temp < pool_bytes / 8, (temp, pool_bytes)
+    else:
+        assert temp >= pool_bytes, (temp, pool_bytes)
 
-        compiled = jax.jit(tick).lower(*args).compile()
-        pool_bytes = 2
-        for n in pool[0]:
-            pool_bytes *= n
-        assert paged_kernel_under_auto(
-            q[0][1:], pool[0], BF16) is (d % 128 == 0)
-        return compiled.memory_analysis().temp_size_in_bytes, pool_bytes
 
-    temp, pool_bytes = temporaries(16, 1, 576, scale=192 ** -0.5)
-    assert temp >= pool_bytes, (temp, pool_bytes)
-    temp, pool_bytes = temporaries(H, HKV, D)
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mla_engine_programs_keep_the_latent_pool_in_place_on_v5e(
+        chip, pool_sized_ops, monkeypatch, program):
+    """The engine's REAL programs over a latent pool at
+    DeepSeek-V2-Lite's attention widths and deepseek-v2-lite-batch's
+    pages: the decode window reads the pool through the block table in
+    the kernel (no gathered view of every slot, no `kv.gather` scope)
+    and the whole-prompt prefill writes its rows through the table, and
+    neither holds a pool-sized temporary nor moves the pool: at a
+    576-wide row each copied the whole pool in and out (two copies a
+    window, four a prompt; PERF.md, PR 32)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from shellac_tpu.inference.batching import PagedBatchingEngine
+    from shellac_tpu.models import transformer
+
+    cfg = _mla_stack(d_model=1024, vocab_size=1024, max_seq_len=2560)
+    slots, prompt = 32, 1024
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = PagedBatchingEngine(
+        cfg, params, n_slots=slots, max_len=2560, block_size=128,
+        pool_tokens=slots * 2560, decode_ticks=2,
+    )
+    assert eng.stats["decode_attn"] == "paged_kernel"
+    cache = eng._cache
+    assert cache.k.shape == (8, slots * 20 + 1, 1, 128, 640)
+    key = jax.random.PRNGKey(0)
+    row = eng._zero_bias_row
+    if program == "decode":
+        fn = eng._jit_cache_program(
+            eng._decode_impl, 10, static_argnames=("greedy_only",))
+        args = (eng._cur, jnp.ones((slots,), bool), key, (
+            eng._stemp, eng._stopk, eng._stopp, eng._sminp, row, eng._smin,
+            eng._spres, eng._sfreq, row, eng._sseed,
+            jnp.zeros((slots,), I32), eng._dummy_ctrans, eng._coff,
+            eng._cstate, eng._srem, eng._sdone))
+        kw = {"greedy_only": True}
+    else:
+        fn = eng._jit_cache_program(
+            eng._prefill_impl, 5, static_argnames=("want_plp",))
+        args = (jnp.zeros((1, prompt), I32), jnp.asarray([prompt], I32),
+                jnp.int32(0), key, (jnp.zeros((6,), I32), row, row))
+        kw = {"want_plp": False}
+    compiled = fn.lower(shaped(params), shaped(cache), *shaped(args),
+                        **kw).compile()
+    text = compiled.as_text()
+    pool_bytes = cache.k.size * cache.k.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes / 8, (temp, pool_bytes)
+    assert ("decode_paged_group" in text) is (program == "decode")
+    assert "kv.gather" not in text
+    moved = pool_sized_ops(text, [cache.k.shape])
+    assert not moved, "\n".join(moved)
 
 
 @pytest.mark.parametrize("build", [
@@ -552,6 +658,18 @@ def test_per_shard_kernels_match_reference(mesh8):
         q1, k_all, v_all, index, None, Dh ** -0.5,
         k_scale=paged_gather_scales(pks, tables),
         v_scale=paged_gather_scales(pvs, tables)), 2e-5)
+
+    # One shared row a token as k AND v (the MLA latent, replicated over
+    # the tensor axis), held wider than q: one operand a shard.
+    rows = jnp.pad(k[:, :, 0], ((0, 0), (0, 0), (0, Dh)))  # (B, L, 2 Dh)
+    lat = jnp.zeros((B * mb + 1, 1, bs, 2 * Dh), F32).at[
+        tables.reshape(-1)].set(rows.reshape(B * mb, 1, bs, 2 * Dh))
+    got = jax.jit(lambda *a: paged_decode_attention(
+        a[0], a[1], a[1], *a[2:], impl="flash", mesh=mesh8))(
+        q1, lat, tables, index)
+    k_all, _ = paged_gather_layer(lat, lat, tables)
+    _close(got, _decode_ref(jnp.pad(q1, ((0, 0),) * 3 + ((0, Dh),)), k_all,
+                            k_all, index, None, Dh ** -0.5)[..., :Dh], 2e-5)
 
 
 # ---------------------------------------------------------------------------

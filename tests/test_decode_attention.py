@@ -151,7 +151,7 @@ def test_paged_grouped_multi_group(window):
     # One short slot (first group boundary) and one near the end.
     index = jnp.array([7, big_l - 1], jnp.int32)
     pool_k, pool_v, tables = _scatter_pool(dense_k, dense_v, bs)
-    assert tables.shape[1] // _paged_group(tables, pool_k) > 1
+    assert tables.shape[1] // _paged_group(tables, pool_k, pool_v) > 1
 
     ref = _decode_ref(q, dense_k, dense_v, index, window, D ** -0.5)
     out = paged_decode_attention(
@@ -187,10 +187,39 @@ def test_paged_group_respects_sublane_tiling():
     from shellac_tpu.ops.decode_attention import _paged_group
 
     tables = jnp.zeros((2, 64), jnp.int32)
-    assert _paged_group(tables, jnp.zeros((9, 4, 8, 128), jnp.bfloat16)) == 1
-    assert _paged_group(tables, jnp.zeros((9, 4, 16, 128), jnp.bfloat16)) > 1
-    assert _paged_group(tables, jnp.zeros((9, 4, 8, 128), jnp.float32)) > 1
-    assert _paged_group(tables, jnp.zeros((9, 4, 16, 128), jnp.int8)) == 1
+
+    def group(bs, dtype):
+        pool = jnp.zeros((9, 4, bs, 128), dtype)
+        return _paged_group(tables, pool, jnp.zeros_like(pool))
+
+    assert group(8, jnp.bfloat16) == 1
+    assert group(16, jnp.bfloat16) > 1
+    assert group(8, jnp.float32) > 1
+    assert group(16, jnp.int8) == 1
+
+
+@pytest.mark.parametrize("hkv,bs,d,pages,shared,want", [
+    pytest.param(8, 256, 128, 10, False, 2, id="mistral-7b-batch"),
+    pytest.param(1, 128, 640, 20, True, 4, id="deepseek-v2-lite-batch"),
+    pytest.param(8, 16, 128, 128, False, 32, id="shellac-1b-page16"),
+    pytest.param(8, 128, 128, 16, False, 4, id="int8-default-page"),
+    # The tile's bound, counted in elements whatever the row's width: a
+    # 2048-lane row, and k and v of 1024 lanes each, fit 2 pages.
+    pytest.param(1, 256, 2048, 8, True, 2, id="wide-row-bound-by-the-tile"),
+    pytest.param(2, 128, 1024, 8, False, 2, id="k-and-v-apart-count-twice"),
+    pytest.param(2, 128, 1024, 8, True, 4, id="v-in-the-k-tile-counts-once"),
+])
+def test_paged_group_is_sized_from_the_tile(hkv, bs, d, pages, shared, want):
+    """Pages a grid step, from the shapes alone: about 512 rows, no more
+    than keep a step's k and v tiles within PAGED_TILE_ELEMS (v's not
+    counted where it is read out of the k tile), a count that divides
+    the table. At the pools the chip timed these are the values it
+    preferred (PERF.md, PR 28 and PR 32)."""
+    from shellac_tpu.ops.decode_attention import _paged_group
+
+    pool_k = jnp.zeros((3, hkv, bs, d), jnp.bfloat16)
+    tables = jnp.zeros((2, pages), jnp.int32)
+    assert _paged_group(tables, pool_k, None if shared else pool_k) == want
 
 
 def test_auto_falls_back_to_ref_off_tpu():
@@ -281,6 +310,9 @@ AUTO_RULE = [
     ("bf16-page256-hkv8-d128", 32, 8, 128, 256, jnp.bfloat16, True),
     ("bf16-page128-d128", 4, 4, 128, 128, jnp.bfloat16, True),
     ("mla-hkv1-d576-page128", 16, 1, 576, 128, jnp.bfloat16, False),
+    # ... which is why the latent pool is held 640 wide (held_width).
+    ("mla-hkv1-d640-page128", 16, 1, 640, 128, jnp.bfloat16, True),
+    ("mla-int8-hkv1-d576-page128", 16, 1, 576, 128, jnp.int8, False),
     ("bf16-page256-d64", 4, 4, 64, 256, jnp.bfloat16, False),
     ("int8-page128-d128", 4, 4, 128, 128, jnp.int8, True),
     ("int8-page256-d128", 4, 4, 128, 256, jnp.int8, True),
@@ -296,7 +328,8 @@ def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
     the block table in the kernel where the rule says the kernel wins
     (int8 wherever it runs; bf16 with full-lane heads and pages long
     enough for a grid step to amortize), and through the gather
-    everywhere else BY DECISION: no warning. The rule is a pure
+    everywhere else BY DECISION: no warning, but for an int8 pool the
+    kernel cannot run on. The rule is a pure
     function of shapes and dtype, and the dispatcher does what it
     says."""
     import warnings as _w
@@ -327,12 +360,15 @@ def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
         )
     tables = jnp.asarray([[1, 2]], jnp.int32)
     index = jnp.asarray([bs + 3], jnp.int32)
-    with _w.catch_warnings():
-        _w.simplefilter("error", da.PagedFallbackWarning)
+    with _w.catch_warnings(record=True) as said:
+        _w.simplefilter("always", da.PagedFallbackWarning)
         out = da.paged_decode_attention(
             q, pool, pool, tables, index, interpret=True,
             k_scale=scale, v_scale=scale,
         )
+    # Only an int8 pool the kernel cannot take is worth a word: its
+    # gather dequantizes every page every tick (the int8 latent pool).
+    assert bool(said) is (dtype == jnp.int8 and not kernel), said
     assert out.shape == q.shape
     assert bool(took) is kernel, took
 
@@ -342,6 +378,11 @@ def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
     ("paged", 128, 16, "gather"),
     ("paged", 16, 256, "gather"),
     ("paged-int8", 128, 128, "paged_kernel"),
+    # An MLA model's latent row, 512 + 64 lanes: held at 640 in the
+    # bf16 pool, which the kernel reads; at its own 576 in the int8
+    # pool, which the gather reads.
+    ("paged", 576, 128, "paged_kernel"),
+    ("paged-int8", 576, 128, "gather"),
 ])
 def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
                                              page, want):
@@ -351,11 +392,19 @@ def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
     skips it). Off the TPU every pool reads through the gather."""
     import shellac_tpu.ops.decode_attention as da
     from shellac_tpu import get_model_config
+    from shellac_tpu.config import MLAConfig
     from shellac_tpu.inference.batching import PagedBatchingEngine
     from shellac_tpu.models import transformer
 
-    cfg = get_model_config("tiny-gqa").replace(
-        head_dim=head_dim, n_layers=1, max_seq_len=512).validate()
+    if head_dim == 576:
+        cfg = get_model_config("tiny-mla").replace(
+            n_layers=1, max_seq_len=512,
+            mla=MLAConfig(kv_lora_rank=512, q_lora_rank=None,
+                          qk_nope_head_dim=16, qk_rope_head_dim=64,
+                          v_head_dim=16)).validate()
+    else:
+        cfg = get_model_config("tiny-gqa").replace(
+            head_dim=head_dim, n_layers=1, max_seq_len=512).validate()
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
 
     def build():
@@ -368,68 +417,95 @@ def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
     assert eng.stats["decode_attn"] == want
     assert eng.stats["cache_backend"] == backend
     pool = eng._cache.k.shape[1:]
+    assert pool[-1] == (640 if (head_dim, backend) == (576, "paged")
+                        else head_dim)
     assert da.paged_decode_path(
-        (2, 1, cfg.n_heads, head_dim), pool, eng._cache.k.dtype
+        (2, 1, cfg.n_heads, pool[-1]), pool, eng._cache.k.dtype
     ) == want
 
 
-CELL_HKV, CELL_PAGE, CELL_PAGES = 8, 256, 4  # mistral-7b-batch's pool
+# (kv heads, q heads a kv head, row, held row, page rows, pages a slot,
+#  pages a grid step, k served as v)
+CELLS = {
+    # mistral-7b-batch's pool.
+    "gqa": (8, 4, D, D, 256, 4, 2, False),
+    # deepseek-v2-lite-batch's: the MLA latent, 576 lanes held at 640,
+    # one pool as k and as v (values are its first 512 lanes).
+    "latent": (1, 16, 576, 640, 128, 20, 4, True),
+}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("s", [1, 3])
-def test_paged_kernel_at_serving_shape_matches_ref(s, dtype):
-    """The grouped kernel at the benchmark cell's pool (8 kv heads x
-    128, 256-row pages, 2 pages a grid step, 4 query heads a kv head):
-    lengths that end mid-page, on a page's last row, on a page's first
-    row, at one row and at the view's end, and a slot whose table row
-    was never allocated (every entry the scratch page 0). Every live
-    row of every slot is attended, no dead one: the pool's unowned
-    pages hold NaN, which any read past a slot's length would carry
-    into its output."""
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_paged_kernel_at_serving_shape_matches_ref(cell, s, dtype):
+    """The grouped kernel at a benchmark cell's pool (8 kv heads x 128,
+    256-row pages, 2 pages a grid step, 4 query heads a kv head; and
+    the latent pool, one 640-lane row a token under 16 heads, 4
+    128-row pages a step, the k tile read as v): lengths that end
+    mid-page, on a page's last row, on a page's first row, at one row
+    and at the view's end, and a slot whose table row was never
+    allocated (every entry the scratch page 0). Every live row of
+    every slot is attended, no dead one: the pool's unowned pages hold
+    NaN, which any read past a slot's length would carry into its
+    output. The latent's q arrives 576 wide against rows whose pad
+    lanes hold zeros, as the model's does."""
     from shellac_tpu.ops.decode_attention import _paged_group
 
-    view = CELL_PAGE * CELL_PAGES
+    hkv, per_kv, d, held, page, pages, group, k_as_v = CELLS[cell]
+    view = page * pages
     index = jnp.asarray(
-        [300, CELL_PAGE - s, CELL_PAGE, 0, view - s, 0], jnp.int32)
+        [page + 44, page - s, page, 0, view - s, 0], jnp.int32)
     n = index.shape[0]
     ks = jax.random.split(jax.random.PRNGKey(40 + s), 3)
-    q = _rand(ks[0], (n, s, 4 * CELL_HKV, D)).astype(dtype)
-    dense_k = _rand(ks[1], (n, CELL_HKV, view, D)).astype(dtype)
-    dense_v = _rand(ks[2], (n, CELL_HKV, view, D)).astype(dtype)
-    pool_k, pool_v, tables = _scatter_pool(dense_k, dense_v, CELL_PAGE)
-    assert _paged_group(tables, pool_k) == 2
+    q = _rand(ks[0], (n, s, per_kv * hkv, d)).astype(dtype)
+    dense_k = _rand(ks[1], (n, hkv, view, d)).astype(dtype)
+    dense_k = jnp.pad(dense_k, ((0, 0),) * 3 + ((0, held - d),))
+    dense_v = (dense_k if k_as_v
+               else _rand(ks[2], (n, hkv, view, d)).astype(dtype))
+    pool_k, pool_v, tables = _scatter_pool(dense_k, dense_v, page)
+    assert _paged_group(tables, pool_k, None if k_as_v else pool_v) == group
     # Pages wholly past a slot's length: NaN in the pool (never to be
     # read), zero in the reference's dense view (masked there). Rows
     # past the length inside a live page keep their finite garbage in
     # both, as a served pool's do.
-    live_pages = (np.arange(CELL_PAGES)[None, :] * CELL_PAGE
+    live_pages = (np.arange(pages)[None, :] * page
                   < np.asarray(index)[:, None] + s)  # (n, pages)
-    keep = jnp.asarray(np.repeat(live_pages, CELL_PAGE, axis=1))
-    dense_k, dense_v = (jnp.where(keep[:, None, :, None], x, 0)
-                        for x in (dense_k, dense_v))
+    keep = jnp.asarray(np.repeat(live_pages, page, axis=1))
     dead = np.ones(pool_k.shape[0], bool)
     dead[0] = False
     dead[np.asarray(tables)[live_pages]] = False
-    pool_k, pool_v = (
-        jnp.where(jnp.asarray(dead)[:, None, None, None], jnp.nan,
-                  x).astype(dtype) for x in (pool_k, pool_v))
     # The last slot was never allocated: its table row names page 0,
-    # which holds finite scratch (the allocator's convention).
+    # which holds finite scratch (the allocator's convention: zeros in
+    # a held row's pad lanes there too).
     tables = tables.at[n - 1].set(0)
-    pool_k, pool_v = (x.at[0].set(1.0) for x in (pool_k, pool_v))
-    dense_k, dense_v = (x.at[n - 1].set(1.0) for x in (dense_k, dense_v))
 
-    ref = _decode_ref(q, dense_k, dense_v, index, None, D ** -0.5)
+    def served(pool, dense):
+        pool = jnp.where(jnp.asarray(dead)[:, None, None, None], jnp.nan,
+                         pool).astype(dtype)
+        dense = jnp.where(keep[:, None, :, None], dense, 0)
+        return (pool.at[0, ..., :d].set(1.0),
+                dense.at[n - 1, ..., :d].set(1.0))
+
+    pool_k, dense_k = served(pool_k, dense_k)
+    pool_v, dense_v = (pool_k, dense_k) if k_as_v else served(pool_v, dense_v)
+
+    scale = (192 if k_as_v else d) ** -0.5
+    ref = _decode_ref(jnp.pad(q, ((0, 0),) * 3 + ((0, held - d),)),
+                      dense_k, dense_v, index, None, scale)
     out = paged_decode_attention(
         q, pool_k, pool_v, tables, index, impl="flash", interpret=True,
+        scale=scale,
     )
+    assert out.shape == q.shape
+    # What the model reads of the latent's output: its first 512 lanes.
+    lanes = 512 if k_as_v else d
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert np.isfinite(np.asarray(out, np.float32)).all()
     np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        atol=tol, rtol=tol,
+        np.asarray(out, np.float32)[..., :lanes],
+        np.asarray(ref, np.float32)[..., :lanes], atol=tol, rtol=tol,
     )
 
 

@@ -266,6 +266,115 @@ class TestServing:
         assert cache.v.shape == (cfg.n_layers, 2, 1, 32, 0)
 
 
+def _wide_cfg():
+    """tiny-mla with a latent row wider than one lane tile (136 + 8 =
+    144 lanes): the paged pool holds it at 256 (kvcache.held_width),
+    as DeepSeek-V2-Lite's 576 at 640."""
+    from shellac_tpu.config import MLAConfig
+
+    return _cfg().replace(mla=MLAConfig(
+        kv_lora_rank=136, q_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16)).validate()
+
+
+@pytest.fixture(scope="class")
+def wide(request):
+    """One dense-slot run and ONE paged engine shape for the class:
+    (cfg, params, prompts, the dense engine's greedy tokens, a builder
+    of the paged engine)."""
+    cfg = _wide_cfg()
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(29)
+    common = rng.integers(1, cfg.vocab_size, size=16).tolist()
+    prompts = [common + rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n in (4, 9, 2, 6)]
+    want = BatchingEngine(cfg, params, n_slots=2, max_len=64).run(
+        [(i, p, 6) for i, p in enumerate(prompts)])
+
+    def paged(**kw):
+        return PagedBatchingEngine(cfg, params, n_slots=2, max_len=64,
+                                   block_size=16, **kw)
+
+    return cfg, params, prompts, want, paged
+
+
+class TestHeldWidth:
+    """The bf16/fp32 paged pool holds an MLA latent row at whole lane
+    tiles, its pad lanes zeros (PagedKVCache's docstring)."""
+
+    def test_padded_pool_matches_dense_slots(self, wide):
+        """Greedy tokens through the padded pool (whole-prompt prefill,
+        decode through the table, churn over two slots) equal the
+        dense-slot engine's; the pad lanes of every page are still
+        zeros afterwards, the scratch page's too."""
+        cfg, _, prompts, want, paged = wide
+        eng = paged()
+        assert cfg.cache_head_dim == 144
+        assert eng._cache.k.shape[2:] == (1, 16, 256)
+        assert eng._cache.v.shape[-1] == 0
+        got = eng.run([(i, p, 6) for i, p in enumerate(prompts)])
+        assert got == want
+        assert not np.asarray(eng._cache.k[..., 144:]).any()
+        assert np.asarray(eng._cache.k[..., :144]).any()
+
+    def test_prefix_cache_and_chunks_over_the_padded_pool(self, wide):
+        """Shared prefix pages and a prompt prefilled in chunks (rows
+        of s > 1 read through the table against the held row)."""
+        _, _, prompts, want, paged = wide
+        eng = paged(prefix_cache=True, prefill_chunk=8)
+        got = eng.run([(i, p, 6) for i, p in enumerate(prompts)])
+        assert got == want
+        assert eng.stats["prefix_hit_tokens"] > 0
+        assert not np.asarray(eng._cache.k[..., 144:]).any()
+
+    def test_bytes_per_token_is_what_the_pool_holds(self, wide):
+        cfg, params, _, _, paged = wide
+        eng = paged()
+        pool = eng._cache.k
+        rows = pool.shape[1] * pool.shape[3]  # pages x rows a page
+        held = (pool.size + eng._cache.v.size) * pool.dtype.itemsize
+        assert eng.cache_backend.bytes_per_token() * rows == held
+        assert eng.stats["kv_bytes_per_token"] == cfg.n_layers * 256 * 4
+        # The int8 pool keeps the row's own width (and its gather).
+        q8 = PagedBatchingEngine(cfg, params, n_slots=2, max_len=128,
+                                 block_size=128, kv_quant="int8")
+        assert q8._cache.k.shape[-1] == 144
+        assert q8.cache_backend.bytes_per_token() == cfg.n_layers * (144 + 8)
+        # Dense slots hold the row as the model writes it.
+        dense = BatchingEngine(cfg, params, n_slots=2, max_len=64)
+        assert dense._cache.k.shape[-1] == 144
+        assert dense.cache_backend.bytes_per_token() \
+            == cfg.n_layers * 144 * 4
+
+    def test_latent_slot_export_import_round_trip(self, wide):
+        """disagg ships a paged slot's pages as they are held: the
+        blob's rows are 256 wide, pad lanes included, and a fresh
+        engine that imports them continues token for token."""
+        from shellac_tpu.inference import disagg
+
+        _, _, prompts, want, paged = wide
+        a = paged()
+        for i, p in enumerate(prompts[:2]):
+            a.submit(i, np.asarray(p, np.int32), 6, prefill_only=True)
+        while len(a.frozen_prefills) < 2:
+            a.step()
+        blobs = {}
+        for rid, slot in list(a.frozen_prefills.items()):
+            blob = disagg.export_slot(a, slot, a._slots[slot])
+            assert blob.arrays["k"].shape[-1] == 256
+            assert not blob.arrays["k"][..., 144:].any()
+            assert blob.header["model"]["head_dim"] == 144
+            blobs[rid] = disagg.MigrationBlob.deserialize(blob.serialize())
+            a.release_frozen(rid)
+        b = paged()
+        for rid, blob in blobs.items():
+            disagg.import_blob(b, blob, rid=rid)
+        got = {}
+        while b.pending:
+            got.update(b.step())
+        assert got == {i: want[i] for i in (0, 1)}
+
+
 class TestLoRA:
     def test_mla_lora_trains_and_merges(self, model):
         """LoRA on MLA: the generic default resolves to the latent
