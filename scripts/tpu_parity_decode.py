@@ -113,16 +113,19 @@ def paged_decode_cases(checks):
         paged_decode_attention,
     )
 
-    B, L, H, HKV, D = 4, 1024, 16, 8, 128
+    B, H, D = 4, 16, 128
     # bs=64 runs the grouped gather with 2 groups; bs=16 is the serving
     # default page size (group=32, the shape the one-page kernel lost
     # to the XLA ref on — BENCH_DECODE.json); bs=256 is the page "auto"
     # sends to the kernel (2 pages a step, 2 groups: the double buffer
-    # crosses groups and slots).
-    for s, window, bs in [
-        (1, None, 64), (1, 200, 64), (2, None, 64),
-        (1, None, 16), (1, 200, 16),
-        (1, None, 256), (3, 600, 256),
+    # crosses groups and slots). The last two are ouro-2.6b-batch-
+    # reason's read: MHA 16/16 over FIVE 128-row pages a slot, which two
+    # pages a step do not divide: the one-page kernel.
+    for s, window, bs, L, HKV in [
+        (1, None, 64, 1024, 8), (1, 200, 64, 1024, 8), (2, None, 64, 1024, 8),
+        (1, None, 16, 1024, 8), (1, 200, 16, 1024, 8),
+        (1, None, 256, 1024, 8), (3, 600, 256, 1024, 8),
+        (1, None, 128, 640, 16), (3, None, 128, 640, 16),
     ]:
         max_blocks = L // bs
         n_blocks = B * max_blocks + 1
@@ -157,7 +160,8 @@ def paged_decode_cases(checks):
             index, window, D ** -0.5,
         )
         check(
-            f"paged s={s} window={window} bs={bs} shuffled-table",
+            f"paged s={s} window={window} bs={bs} L={L} hkv={HKV} "
+            "shuffled-table",
             out, ref,
             atol=2e-2, checks=checks,
         )

@@ -200,6 +200,26 @@ class DSAConfig:
 
 
 @dataclass(frozen=True)
+class LoopConfig:
+    """A looped stack (Ouro, arXiv:2510.25741): the whole layer stack
+    runs `steps` times a token over the SAME weights.
+
+    The state that enters pass t + 1 is pass t's output under the final
+    norm, and a learned gate (params["loop_gate"]: one linear map with a
+    bias) reads each pass's normed state: lam_t = sigmoid(w . h + b).
+    The exit step of a token is the first t at which p_0 + ... + p_t
+    reaches `exit_threshold`, p_t = lam_t prod_{j<t} (1 - lam_j) and
+    the last pass taking what is left, else the last; the state of that
+    pass is unembedded (it is already normed). Every pass runs for
+    every token whatever its exit step: pass t of layer l attends pass
+    t's rows of layer l, cached layer t * n_layers + l
+    (ModelConfig.cache_layers), so no pass's rows may be missing."""
+
+    steps: int = 4
+    exit_threshold: float = 1.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Decoder-only transformer configuration (LLaMA-style)."""
 
@@ -293,6 +313,10 @@ class ModelConfig:
     # GQA): adds dsa_wq / dsa_wk / dsa_k_norm / dsa_k_bias / dsa_ww per
     # layer. Exclusive with mla, eva, attn_window and attn_pattern.
     dsa: Optional[DSAConfig] = None
+    # A looped stack (Ouro): the layers run loop.steps times a token
+    # over shared weights, each pass with its own cached rows; adds the
+    # exit gate loop_gate {"w": (D,), "b": ()}.
+    loop: Optional[LoopConfig] = None
     # Multi-token prediction heads (EvaByte num_pred_heads): lm_head is
     # (d_model, n_pred_heads * vocab_size), head m predicting the token
     # m + 1 ahead. forward() returns every head's logits; cached
@@ -313,6 +337,8 @@ class ModelConfig:
             object.__setattr__(self, "eva", EvaConfig(**self.eva))
         if isinstance(self.dsa, dict):
             object.__setattr__(self, "dsa", DSAConfig(**self.dsa))
+        if isinstance(self.loop, dict):
+            object.__setattr__(self, "loop", LoopConfig(**self.loop))
 
     @property
     def kv_heads(self) -> int:
@@ -338,6 +364,14 @@ class ModelConfig:
         """Width of the rotary tables: MLA ropes only its qk_rope slice."""
         return (self.mla.qk_rope_head_dim if self.mla is not None
                 else self.dim_per_head)
+
+    @property
+    def cache_layers(self) -> int:
+        """Leading entries of every cache stack: one a layer, and with a
+        looped stack one a layer a pass (pass t of layer l holds cached
+        layer t * n_layers + l). The one count that cache shapes and
+        per-token byte counts read."""
+        return self.n_layers * (self.loop.steps if self.loop else 1)
 
     @property
     def cache_kv_heads(self) -> int:
@@ -508,6 +542,21 @@ class ModelConfig:
                 )
             if not self.causal:
                 raise ValueError("dsa attention is decoder-only (causal=True)")
+        if self.loop is not None:
+            lp = self.loop
+            if lp.steps < 1:
+                raise ValueError(f"loop steps={lp.steps} must be >= 1")
+            if not 0.0 < lp.exit_threshold <= 1.0:
+                raise ValueError(
+                    f"loop exit_threshold={lp.exit_threshold} must be in "
+                    "(0, 1]: it is compared with a cumulative probability"
+                )
+            for name, on, why in LOOP_EXCLUDES:
+                if on(self):
+                    raise ValueError(
+                        f"a looped stack (cfg.loop) does not combine with "
+                        f"{name}: {why}"
+                    )
         if self.eva is not None:
             e = self.eva
             if e.chunk < 1 or e.window < e.chunk or e.window % e.chunk:
@@ -572,6 +621,30 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+#: What ModelConfig.validate refuses beside a looped stack: (name, test,
+#: reason). Each needs state a pass would have to address by its own
+#: index, or a head that the exit rule does not define.
+LOOP_EXCLUDES = (
+    ("moe", lambda c: c.moe is not None,
+     "no looped model with experts is published, and router diagnostics "
+     "have no rule for a router that runs once a pass"),
+    ("mla / dsa", lambda c: c.mla is not None or c.dsa is not None,
+     "latent and index pools a pass are not tested"),
+    ("attn_window / attn_pattern",
+     lambda c: c.attn_window is not None or c.attn_pattern is not None,
+     "ring caches hold one ring a layer, and no published looped model "
+     "has a window"),
+    ("eva", lambda c: c.eva is not None,
+     "EVA state (rings and pooled pages) is addressed by layer inside its "
+     "kernels, and nothing gives a pass its own"),
+    ("n_pred_heads > 1", lambda c: c.n_pred_heads > 1,
+     "the exit rule chooses one state a token; heads that predict further "
+     "ahead have no published rule"),
+    ("causal=False", lambda c: not c.causal,
+     "the gate and the per-pass rows are defined for a decoder"),
+)
 
 
 @dataclass(frozen=True)

@@ -41,6 +41,51 @@ class PoolExhausted(Exception):
     The engine requeues the request and retries after a release."""
 
 
+#: feature -> why a looped stack (cfg.loop: cfg.cache_layers cached
+#: layers, n_layers a pass, every cache kind's leading axis) cannot
+#: carry it yet (ROADMAP Queue 2). Refused where the switch is turned
+#: on: a backend's constructor, bind(), check_feature(), and the
+#: single-request Engine's constructor. The 'dense' and 'paged'
+#: backends, int8 pools and slot caches, the prefix cache and chunked
+#: prefill carry a looped stack (tests/test_loop.py).
+LOOP_UNSUPPORTED = {
+    "rolling": (
+        "a ring holds a window's rows of one layer; no published looped "
+        "model has a window, and a ring a pass is not tested"
+    ),
+    "speculative": (
+        "a verify window rolls rejected tokens back, and every pass's "
+        "rows of each would have to go"
+    ),
+    "pp_pipeline": (
+        "a stage's registers hold its layers' rows for one pass; the "
+        "stream would cross the stages once a pass"
+    ),
+    "mesh": (
+        "the gate and the cached layers of a pass are not sharded yet"
+    ),
+    "park_resume": (
+        "a parked slot's blob names n_layers layers; shipping a row a "
+        "pass is not tested"
+    ),
+    "kv_export": (
+        "the disaggregated blob's agreement block names n_layers layers; "
+        "a decode replica would read a pass's rows as the model's"
+    ),
+    "beam_search": (
+        "beams copy pages on write and reorder slot rows; neither is "
+        "tested over a row a pass"
+    ),
+}
+
+
+def refuse_loop(feature: str) -> None:
+    raise ValueError(
+        f"a looped stack (cfg.loop) does not support {feature} yet: "
+        f"{LOOP_UNSUPPORTED[feature]}"
+    )
+
+
 class CacheBackend:
     """Base storage policy: one slot row per request, nothing to
     allocate. Subclasses override the hooks that their policy needs;
@@ -79,6 +124,8 @@ class CacheBackend:
                 f"on the 'paged' cache backend; the {self.name!r} backend "
                 "holds no such pool"
             )
+        if cfg.loop is not None and self.is_rolling:
+            refuse_loop("rolling")
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -97,6 +144,13 @@ class CacheBackend:
                 f"{self.name} backend is already bound to an engine; "
                 "construct one backend per engine"
             )
+        if self.cfg.loop is not None:
+            from shellac_tpu.inference.spec_batching import _SpecDecodeMixin
+
+            if isinstance(engine, _SpecDecodeMixin):
+                refuse_loop("speculative")
+            if engine.mesh is not None:
+                refuse_loop("mesh")
         self.engine = engine
 
     # ---- device cache construction ----------------------------------
@@ -153,15 +207,33 @@ class CacheBackend:
         ("pp_pipeline", "chunked_prefill", "park_resume", "kv_export",
         ...). Called where a switch that needs the feature is turned
         on, so the refusal comes at construction and not mid-request.
-        The base policy refuses nothing here: each feature's own gate
+        The base policy refuses what a looped stack cannot carry
+        (LOOP_UNSUPPORTED) and nothing else: each feature's own gate
         (validate_pp_pipeline, disagg._check_exportable) still holds."""
+        if self.cfg.loop is not None and feature in LOOP_UNSUPPORTED:
+            refuse_loop(feature)
 
     def window_counts(self, pairs, n_valid) -> Dict[str, int]:
         """Backend-owned work counts of one synced decode window
         (`pairs`: the (slot, request) rows it ran; `n_valid[slot]`: the
         ticks that produced a token), added to the step record. Host
-        arithmetic on lengths already known; never a device read."""
-        return {}
+        arithmetic on lengths already known; never a device read.
+
+        The base policy counts a looped stack's passes (cfg.loop; no
+        counts otherwise): over every slot-tick that produced a token,
+        the stack passes it ran (`steps` each) and the cached rows it
+        read over all of them (`steps` x its context: a query at
+        position p reads p + 1 rows of its own pass in every pass)."""
+        if self.cfg.loop is None:
+            return {}
+        steps = self.cfg.loop.steps
+        ticks = rows = 0
+        for slot, req in pairs:
+            n = int(n_valid[slot])
+            first = req.tokens.size + len(req.out)  # the first tick's context
+            ticks += n
+            rows += n * first + n * (n - 1) // 2
+        return {"loop_passes": steps * ticks, "loop_kv_rows": steps * rows}
 
     def prefix_manifest(self, since: int = -1, **_: Any) -> Dict[str, Any]:
         """Directory feed for GET /kv/prefixes. Backends without a
@@ -200,13 +272,16 @@ class CacheBackend:
         cfg = self.cfg
         width = (self.row_width(cfg.cache_head_dim)
                  + self.row_width(cfg.cache_v_head_dim))
+        # One row a cached layer: a looped stack holds one a layer a
+        # pass (cfg.cache_layers, which the pools' shapes read too).
         if self.kv_quant == "int8":
             # int8 values + one fp32 scale per token/head for k and v.
-            return cfg.n_layers * cfg.cache_kv_heads * (width + 2 * 4)
+            return cfg.cache_layers * cfg.cache_kv_heads * (width + 2 * 4)
         itemsize = jnp.dtype(cfg.compute_dtype).itemsize
         # With an indexer, one index key a token a layer beside k and v.
         index = cfg.dsa.index_dim if cfg.dsa is not None else 0
-        return cfg.n_layers * (cfg.cache_kv_heads * width + index) * itemsize
+        return (cfg.cache_layers * (cfg.cache_kv_heads * width + index)
+                * itemsize)
 
     # ---- shared helpers ---------------------------------------------
 

@@ -47,4 +47,6 @@ class DenseBackend(CacheBackend):
             "backend": self.name,
             "slot_tokens": self._slot_tokens(),
             "capacity_tokens": self.n_slots * self.max_len,
+            "row_bytes": self.bytes_per_token(),
+            "cached_layers": self.cfg.cache_layers,
         }

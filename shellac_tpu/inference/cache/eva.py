@@ -109,6 +109,7 @@ class EvaBackend(PagedBackend):
     def check_feature(self, feature: str) -> None:
         if feature in UNSUPPORTED:
             self.refuse(feature)
+        super().check_feature(feature)
 
     def bind(self, engine) -> None:
         from shellac_tpu.inference.spec_batching import _SpecDecodeMixin
@@ -162,7 +163,7 @@ class EvaBackend(PagedBackend):
     def _row_bytes(self) -> int:
         """One exact row, or one pooled row: k and v, every layer."""
         cfg = self.cfg
-        return (2 * cfg.n_layers * cfg.n_heads * cfg.dim_per_head
+        return (2 * cfg.cache_layers * cfg.n_heads * cfg.dim_per_head
                 * jnp.dtype(cfg.compute_dtype).itemsize)
 
     def resident_rows(self, tokens: int):

@@ -44,7 +44,7 @@ class KVCache:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:
-    head = (cfg.n_layers, batch, cfg.cache_kv_heads, max_len)
+    head = (cfg.cache_layers, batch, cfg.cache_kv_heads, max_len)
     return KVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), cfg.compute_dtype),
         # MLA: v is a zero-width placeholder — values re-expand from the
@@ -101,7 +101,7 @@ class QuantKVCache:
 
 
 def init_quant_cache(cfg: ModelConfig, batch: int, max_len: int) -> QuantKVCache:
-    head = (cfg.n_layers, batch, cfg.cache_kv_heads, max_len)
+    head = (cfg.cache_layers, batch, cfg.cache_kv_heads, max_len)
     return QuantKVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), jnp.int8),
         v=jnp.zeros((*head, cfg.cache_v_head_dim), jnp.int8),
@@ -426,7 +426,7 @@ def init_paged_cache(
     max_blocks_per_slot: int,
     tables=None,
 ) -> PagedKVCache:
-    head = (cfg.n_layers, n_blocks, cfg.cache_kv_heads, block_size)
+    head = (cfg.cache_layers, n_blocks, cfg.cache_kv_heads, block_size)
     if tables is None:
         tables = jnp.zeros((n_slots, max_blocks_per_slot), jnp.int32)
     return PagedKVCache(
@@ -438,7 +438,7 @@ def init_paged_cache(
                     cfg.compute_dtype),
         tables=tables,
         lengths=jnp.zeros((n_slots,), jnp.int32),
-        idx=(jnp.zeros((cfg.n_layers, n_blocks, cfg.dsa.index_dim,
+        idx=(jnp.zeros((cfg.cache_layers, n_blocks, cfg.dsa.index_dim,
                         block_size), cfg.compute_dtype)
              if cfg.dsa is not None else None),
     )
@@ -674,7 +674,7 @@ def init_quant_paged_cache(
     block_size: int,
     max_blocks_per_slot: int,
 ) -> QuantPagedKVCache:
-    head = (cfg.n_layers, n_blocks, cfg.cache_kv_heads, block_size)
+    head = (cfg.cache_layers, n_blocks, cfg.cache_kv_heads, block_size)
     return QuantPagedKVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), jnp.int8),
         v=jnp.zeros((*head, cfg.cache_v_head_dim), jnp.int8),
@@ -791,8 +791,8 @@ class EvaKVCache:
 def init_eva_cache(cfg: ModelConfig, n_slots: int, n_blocks: int,
                    max_blocks_per_slot: int, tables=None) -> EvaKVCache:
     e = cfg.eva
-    ring = (cfg.n_layers, e.window, n_slots, cfg.n_heads, cfg.dim_per_head)
-    pool = (cfg.n_layers, cfg.n_heads, n_blocks, e.window // e.chunk,
+    ring = (cfg.cache_layers, e.window, n_slots, cfg.n_heads, cfg.dim_per_head)
+    pool = (cfg.cache_layers, cfg.n_heads, n_blocks, e.window // e.chunk,
             cfg.dim_per_head)
     dt = cfg.compute_dtype
     if tables is None:
@@ -915,7 +915,7 @@ def init_rolling_cache(
             "automatically); this constructor builds the uniform ring"
         )
     ring = rolling_ring(cfg, max_len, chunk_slack)
-    head = (cfg.n_layers, batch, cfg.cache_kv_heads, ring)
+    head = (cfg.cache_layers, batch, cfg.cache_kv_heads, ring)
     return RollingKVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), cfg.compute_dtype),
         v=jnp.zeros((*head, cfg.cache_head_dim), cfg.compute_dtype),
@@ -1100,7 +1100,7 @@ def init_quant_rolling_cache(
             "constructor builds the uniform int8 ring"
         )
     ring = rolling_ring(cfg, max_len, chunk_slack)
-    head = (cfg.n_layers, batch, cfg.cache_kv_heads, ring)
+    head = (cfg.cache_layers, batch, cfg.cache_kv_heads, ring)
     return QuantRollingKVCache(
         k=jnp.zeros((*head, cfg.cache_head_dim), jnp.int8),
         v=jnp.zeros((*head, cfg.cache_head_dim), jnp.int8),

@@ -166,6 +166,7 @@ class PagedBackend(CacheBackend):
     def check_feature(self, feature: str) -> None:
         if self.cfg.dsa is not None and feature in INDEX_POOL_UNSUPPORTED:
             self.refuse_index_pool(feature)
+        super().check_feature(feature)
 
     def bind(self, engine) -> None:
         if self.cfg.dsa is not None:
@@ -628,9 +629,17 @@ class PagedBackend(CacheBackend):
             item = jnp.dtype(self.cfg.compute_dtype).itemsize
             out.update(
                 row_bytes=self.bytes_per_token(),
-                index_row_bytes=(self.cfg.n_layers
+                index_row_bytes=(self.cfg.cache_layers
                                  * self.cfg.dsa.index_dim * item),
                 rows_kept=self.cfg.dsa.topk,
+            )
+        if self.cfg.loop is not None:
+            # A page holds a row a layer a pass; utilization() counts
+            # pages, so it counts every pass's.
+            out.update(
+                row_bytes=self.bytes_per_token(),
+                cached_layers=self.cfg.cache_layers,
+                loop_steps=self.cfg.loop.steps,
             )
         return out
 
@@ -641,7 +650,7 @@ class PagedBackend(CacheBackend):
         and scores every row up to itself) and the rows it then attended
         (no more than are kept). Host arithmetic on lengths."""
         if self.cfg.dsa is None:
-            return {}
+            return super().window_counts(pairs, n_valid)
         scored = kept = 0
         for slot, req in pairs:
             first = req.tokens.size + len(req.out)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from shellac_tpu.config import ModelConfig
+from shellac_tpu.inference.cache.base import refuse_loop
 from shellac_tpu.inference.kvcache import init_cache_for
 from shellac_tpu.models import transformer
 from shellac_tpu.ops.sampling import sample
@@ -74,6 +75,11 @@ class Engine:
     ):
         if kv_quant not in (None, "int8"):
             raise ValueError(f"kv_quant={kv_quant!r}; have None, 'int8'")
+        if cfg.loop is not None:
+            if mesh is not None:
+                refuse_loop("mesh")
+            if rolling_window:
+                refuse_loop("rolling")
         if rolling_window and cfg.attn_window is None:
             raise ValueError(
                 "rolling_window needs a sliding-window model (attn_window)"
@@ -300,6 +306,8 @@ class Engine:
                 "with an indexer keeps paged pools (k, v and index keys) "
                 "that have no such reorder yet"
             )
+        if self.cfg.loop is not None:
+            refuse_loop("beam_search")
         if num_beams < 1:
             raise ValueError("num_beams must be >= 1")
         if max_new_tokens < 1:

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from shellac_tpu.config import ModelConfig
+from shellac_tpu.inference.cache.base import refuse_loop
 from shellac_tpu.inference.kvcache import KVCache, init_cache
 from shellac_tpu.models import transformer
 from shellac_tpu.ops.sampling import sample
@@ -74,6 +75,8 @@ class SpeculativeEngine:
             )
         if gamma < 1:
             raise ValueError(f"gamma must be >= 1, got {gamma}")
+        if cfg.loop is not None or draft_cfg.loop is not None:
+            refuse_loop("speculative")
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.params = params

@@ -51,6 +51,7 @@ def config_from_hf(hf_cfg) -> ModelConfig:
         return _gptoss_config(hf_cfg)
     if getattr(hf_cfg, "model_type", "") == "evabyte":
         return _evabyte_config(hf_cfg)
+    is_ouro = getattr(hf_cfg, "model_type", "") == "ouro"
     moe = None
     if getattr(hf_cfg, "num_local_experts", None):
         moe = MoEConfig(
@@ -119,6 +120,10 @@ def config_from_hf(hf_cfg) -> ModelConfig:
         ),
         qk_norm=is_qwen3,
         dsa=_dsa_from_hf(hf_cfg) if is_keye else None,
+        # Ouro: sandwich norms and the whole stack run total_ut_steps
+        # times a token (LoopConfig).
+        post_norms=is_ouro,
+        loop=_loop_from_hf(hf_cfg) if is_ouro else None,
         # Long-context checkpoints: yarn/llama3 convert exactly; any
         # other rope_scaling type fails loudly.
         **_rope_from_hf(
@@ -126,6 +131,35 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             hf_cfg.max_position_embeddings,
         ),
     ).validate()
+
+
+def _loop_from_hf(hf_cfg):
+    """LoopConfig from Ouro's `total_ut_steps` / `early_exit_threshold`.
+    Every layer attends every earlier position: a `layer_types` entry
+    other than full_attention has no looped form here."""
+    from shellac_tpu.config import LoopConfig
+
+    kinds = set(getattr(hf_cfg, "layer_types", None) or ())
+    if kinds - {"full_attention"}:
+        raise NotImplementedError(
+            f"ouro layer_types {sorted(kinds - {'full_attention'})}: a "
+            "looped stack with windowed layers is not supported (rings "
+            "hold one window a layer, not one a pass)"
+        )
+    return LoopConfig(
+        steps=int(hf_cfg.total_ut_steps),
+        exit_threshold=float(getattr(hf_cfg, "early_exit_threshold", 1.0)),
+    )
+
+
+#: Ouro's names for the four norms of a layer, by ours. Gemma-2's
+#: sandwich uses other names for the same places (below).
+_OURO_NORMS = {
+    "attn_norm": "input_layernorm",
+    "post_attn_norm": "input_layernorm_2",
+    "mlp_norm": "post_attention_layernorm",
+    "post_mlp_norm": "post_attention_layernorm_2",
+}
 
 
 def _dsa_from_hf(hf_cfg):
@@ -818,6 +852,12 @@ def params_from_state_dict(
             for ours, (theirs, transpose) in _DENSE_MLP_MAP.items():
                 w = get(base + theirs)
                 layers[ours].append(w.T if transpose else w)
+        if cfg.loop is not None:
+            for ours, theirs in _OURO_NORMS.items():
+                layers[ours].append(
+                    get(base + theirs + ".weight") + norm_offset
+                )
+            continue
         layers["attn_norm"].append(
             get(base + "input_layernorm.weight") + norm_offset
         )
@@ -851,6 +891,11 @@ def params_from_state_dict(
         if lm_head is None:
             raise KeyError("untied config but no lm_head.weight in state_dict")
         params["lm_head"] = jnp.asarray(_to_np(lm_head).T, pdt)
+    if cfg.loop is not None:
+        params["loop_gate"] = {
+            "w": jnp.asarray(get("early_exit_gate.weight").reshape(-1), pdt),
+            "b": jnp.asarray(get("early_exit_gate.bias").reshape(()), pdt),
+        }
     return params
 
 
@@ -1063,6 +1108,10 @@ def to_state_dict(cfg: ModelConfig, params) -> Dict[str, np.ndarray]:
             for ours, (theirs, transpose) in _DENSE_MLP_MAP.items():
                 w = np_(layers[ours][i])
                 sd[base + theirs] = w.T if transpose else w
+        if cfg.loop is not None:
+            for ours, theirs in _OURO_NORMS.items():
+                sd[base + theirs + ".weight"] = np_(layers[ours][i]) + noff
+            continue
         sd[base + "input_layernorm.weight"] = (
             np_(layers["attn_norm"][i]) + noff
         )
@@ -1085,6 +1134,10 @@ def to_state_dict(cfg: ModelConfig, params) -> Dict[str, np.ndarray]:
         sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
     else:
         sd["lm_head.weight"] = np_(params["lm_head"]).T
+    if cfg.loop is not None:
+        gate = params["loop_gate"]
+        sd["model.early_exit_gate.weight"] = np_(gate["w"]).reshape(1, -1)
+        sd["model.early_exit_gate.bias"] = np_(gate["b"]).reshape(1)
     return sd
 
 

@@ -449,6 +449,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         params["lm_head"] = dense(
             k_head, (d, cfg.n_pred_heads * cfg.vocab_size), d
         )
+    if cfg.loop is not None:
+        # The exit gate of a looped stack: one linear map with a bias
+        # on each pass's normed state (loop_passes).
+        params["loop_gate"] = {
+            "w": dense(jax.random.fold_in(k_head, 1), (d,), d),
+            "b": jnp.zeros((), pdt),
+        }
     return params
 
 
@@ -575,6 +582,8 @@ def logical_axes(cfg: ModelConfig) -> Params:
     }
     if not cfg.tie_embeddings:
         la["lm_head"] = ("embed", "vocab")
+    if cfg.loop is not None:
+        la["loop_gate"] = {"w": (None,), "b": ()}
     return la
 
 
@@ -1535,6 +1544,71 @@ def _dsa_attention(cfg, mesh, attn_impl, hx, lp, q, k, v, rope, cache,
     return o[:, None], new_cache
 
 
+def loop_passes(cfg: ModelConfig, params: Params, x, one_pass, state,
+                xs=None, mesh=None):
+    """Run a looped stack (cfg.loop): the layer walk `steps` times over
+    the same parameters, ONE compiled body for the passes.
+
+    `one_pass(x, state, first, xs_t) -> (x, state, ys_t)` walks the
+    layers once with `first` = t * n_layers (traced) as the index of
+    its first cached layer: pass t of layer l reads and writes cached
+    layer t * n_layers + l and no other. `state` crosses the passes as
+    a carry (a paged pool, written in place; MoE aux sums), `xs` is a
+    pytree of (steps, ...) stacks of which a pass takes its own (a slot
+    cache's leaves viewed (steps, n_layers, ...)), `ys` what the passes
+    return, stacked.
+
+    Between passes the stream is normed with the final norm, and the
+    NORMED state is what enters the next pass and what the gate reads:
+    lam_t = sigmoid(w . h_{t+1} + b). A token's exit step is the first
+    t at which p_0 + ... + p_t >= exit_threshold, with p_t = lam_t
+    prod_{j<t} (1 - lam_j) and the last pass taking the rest, else the
+    last. Every pass runs for every token whatever its exit step (a
+    later token's pass t attends every earlier token's pass-t rows).
+
+    Returns (h, state, ys, exit_step): h (B, S, D) each token's normed
+    state at its exit step, ready for the output projection with no
+    second norm; exit_step (B, S) int32."""
+    lp, cdt = cfg.loop, cfg.compute_dtype
+    last = lp.steps - 1
+    gate_w = params["loop_gate"]["w"].astype(cdt)
+    gate_b = params["loop_gate"]["b"].astype(jnp.float32)
+    b, s, _ = x.shape
+
+    def body(c, inp):
+        x, state, left, cum, exit_step, chosen = c
+        t, xs_t = inp
+        y, state, ys_t = one_pass(x, state, t * cfg.n_layers, xs_t)
+        h = rms_norm(y, params["final_norm"], cfg.norm_eps, mesh=mesh,
+                     scope="loop.norm").astype(cdt)
+        with jax.named_scope("loop.gate"):
+            lam = jax.nn.sigmoid(jnp.einsum(
+                "bsd,d->bs", h, gate_w,
+                preferred_element_type=jnp.float32,
+            ) + gate_b)
+        with jax.named_scope("loop.exit"):
+            # `left` = prod_{j<t} (1 - lam_j): what no earlier pass took.
+            cum = cum + jnp.where(t == last, left, lam * left)
+            now = (exit_step < 0) & (
+                (cum >= lp.exit_threshold) | (t == last)
+            )
+            chosen = jnp.where(now[..., None], h, chosen)
+            exit_step = jnp.where(now, t, exit_step)
+            left = left * (1.0 - lam)
+        return (h.astype(x.dtype), state, left, cum, exit_step,
+                chosen), ys_t
+
+    init = (
+        x, state, jnp.ones((b, s), jnp.float32),
+        jnp.zeros((b, s), jnp.float32), jnp.full((b, s), -1, jnp.int32),
+        jnp.zeros(x.shape, cdt),
+    )
+    (_, state, _, _, exit_step, chosen), ys = jax.lax.scan(
+        body, init, (jnp.arange(lp.steps, dtype=jnp.int32), xs)
+    )
+    return chosen, state, ys, exit_step
+
+
 def segment_positions(segment_ids: jax.Array) -> jax.Array:
     """Per-segment position ids: restart at 0 on every segment change.
 
@@ -1656,8 +1730,25 @@ def forward(
     pp = mesh.shape.get(AXIS_PIPE, 1) if mesh is not None else 1
     if pp > 1 and cfg.eva is not None:
         raise NotImplementedError("pp over EVA attention is not wired yet")
+    if pp > 1 and cfg.loop is not None:
+        raise NotImplementedError(
+            "pp over a looped stack is not wired yet: a stage would "
+            "hold its layers for every pass, and the stream would cross "
+            "the stages once a pass"
+        )
     n_micro = 1
-    if pp > 1:
+    exit_step = None
+    if cfg.loop is not None:
+        def one_pass(x, acc, first, _):
+            x, aux = run_stack(
+                params["layers"], x, cos, sin, cos_l, sin_l, segment_ids
+            )
+            return x, _add_aux(acc, aux), None
+
+        x, aux_sum, _, exit_step = loop_passes(
+            cfg, params, x, one_pass, _zero_aux(), mesh=mesh
+        )
+    elif pp > 1:
         from shellac_tpu.parallel.pipeline import pipeline_apply
 
         if first_k_layout(cfg):
@@ -1750,14 +1841,22 @@ def forward(
         "router_z_loss": aux_sum["router_z_loss"] * inv_lm,
         "dropped_frac": aux_sum["dropped_frac"] * inv_lm,
     }
+    # A looped stack's state is each token's exit step's, already under
+    # the final norm (loop_passes); aux then says which step that was.
+    normed = cfg.loop is not None
+    if normed:
+        aux["loop_exit_step"] = exit_step
 
     if return_hidden:
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+        if not normed:
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                         mesh=mesh).astype(cdt)
         x = constrain(x, mesh, ("batch", "seq", None))
         if return_aux:
             return x, aux
         return x
-    logits = unembed(cfg, params, x, mesh=mesh, all_heads=True)
+    logits = unembed(cfg, params, x, mesh=mesh, all_heads=True,
+                     normed=normed)
     if cfg.n_pred_heads == 1:
         logits = constrain(logits, mesh, ("batch", "seq", "vocab"))
     if return_aux:
@@ -1775,7 +1874,8 @@ def output_weights(cfg: ModelConfig, params: Params, cdt) -> jax.Array:
 
 @jax.named_scope("unembed")
 def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
-            mesh=None, all_heads: bool = False) -> jax.Array:
+            mesh=None, all_heads: bool = False,
+            normed: bool = False) -> jax.Array:
     """Final RMSNorm + output projection (+ logit softcap): the model
     tail shared by forward, forward_with_cache, and the pipelined
     decode's per-group exit (inference/pp_pipeline.py), so a head
@@ -1785,9 +1885,13 @@ def unembed(cfg: ModelConfig, params: Params, x: jax.Array,
 
     A model with n_pred_heads > 1 unembeds head 0, the next token,
     which is all that cached generation reads; `all_heads` (forward:
-    scoring, training) returns (B, S, n_pred_heads, V)."""
+    scoring, training) returns (B, S, n_pred_heads, V). `normed`: x is
+    already under the final norm (a looped stack's exit state), and
+    takes no second one."""
     cdt = cfg.compute_dtype
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh).astype(cdt)
+    if not normed:
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                     mesh=mesh).astype(cdt)
     w = output_weights(cfg, params, cdt)
     if cfg.n_pred_heads > 1 and not all_heads:
         w = w[:, :cfg.vocab_size]
@@ -1956,8 +2060,14 @@ def forward_with_cache(
             )
             return (x, ring, pool), None
 
-        (x, ring, pool), _ = walk((x, cleaves[:2], cleaves[2:]), step)
-        news = ring + pool
+        def one_pass(x, state, first, _):
+            (x, ring, pool), _ = walk((x, *state), step, first=first)
+            return x, (ring, pool), None
+
+        state, xs = (cleaves[:2], cleaves[2:]), None
+
+        def finish(state, _):
+            return state[0] + state[1]
     elif paged:
         # A paged pool rides the layer loops as a CARRY, never as xs/ys:
         # the stacked (L, n_blocks, ...) pools are viewed as
@@ -1966,6 +2076,9 @@ def forward_with_cache(
         # blocks. No layer's pool is sliced out or restacked, and the
         # donated buffers come back where they came in
         # (tests/test_paged_inplace.py holds the compiled program to it).
+        # A looped stack's pool holds n_layers entries a pass
+        # (cfg.cache_layers) and rides every pass as the same carry:
+        # pass t's layers are first = t * n_layers onward.
         n_blocks = cache.k.shape[1]
         # k and v, and with an indexer the index keys: the rest are an
         # int8 pool's scales.
@@ -1980,11 +2093,16 @@ def forward_with_cache(
             )
             return (x, pools), None
 
-        (x, pools), _ = walk(
-            (x, tuple(a.reshape(a.shape[0] * n_blocks, *a.shape[2:])
-                      for a in cleaves)), step,
-        )
-        news = tuple(p.reshape(a.shape) for p, a in zip(pools, cleaves))
+        def one_pass(x, pools, first, _):
+            (x, pools), _ = walk((x, pools), step, first=first)
+            return x, pools, None
+
+        state, xs = tuple(
+            a.reshape(a.shape[0] * n_blocks, *a.shape[2:]) for a in cleaves
+        ), None
+
+        def finish(pools, _):
+            return tuple(p.reshape(a.shape) for p, a in zip(pools, cleaves))
     else:
         # Slot caches ride as xs/ys, each layer its own rows. The mixed
         # ring/dense caches hold one set of stacks a kind: "window"
@@ -1998,18 +2116,40 @@ def forward_with_cache(
             )
             return x, new
 
-        xs = cleaves
+        def one_pass(x, state, first, xs):
+            x, news = walk(x, step, xs=xs, first=first)
+            return x, state, news
+
+        state, xs = None, cleaves
         if mixed:
             half = len(cleaves) // 2
             xs = {"window": cleaves[:half], "full": cleaves[half:]}
-        x, news = walk(x, step, xs=xs)
-        if mixed:
-            news = news["window"] + news["full"]
+
+        def finish(_, news):
+            return news["window"] + news["full"] if mixed else news
+
+    if cfg.loop is None:
+        x, state, ys = one_pass(x, state, 0, xs)
+    else:
+        # One compiled body for the passes. A slot cache's stacks hold
+        # a pass's layers together, (steps * n_layers, ...) viewed
+        # (steps, n_layers, ...), and ride the passes as xs / ys.
+        steps = cfg.loop.steps
+        x, state, ys, _ = loop_passes(
+            cfg, params, x, one_pass, state, mesh=mesh,
+            xs=jax.tree.map(
+                lambda a: a.reshape(steps, a.shape[0] // steps,
+                                    *a.shape[1:]), xs),
+        )
+        ys = jax.tree.map(
+            lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys
+        )
+    news = finish(state, ys)
 
     if logits_at is not None:
         x = jnp.take_along_axis(
             x, logits_at.astype(jnp.int32)[:, None, None], axis=1)
-    logits = unembed(cfg, params, x, mesh=mesh)
+    logits = unembed(cfg, params, x, mesh=mesh, normed=cfg.loop is not None)
     if new_tokens_len is None:
         new_lengths = index + s
     else:

@@ -97,13 +97,17 @@ SPAN_PHASE = {
 #: and, for a model with an indexer (cfg.dsa, the 'paged' backend; 0
 #: elsewhere), over the same slot-ticks the rows the indexer scored
 #: (the context) and the rows the query then attended (the context or
-#: the rows kept, whichever is less).
+#: the rows kept, whichever is less); and, for a looped stack (cfg.loop;
+#: 0 elsewhere), over the same slot-ticks the stack passes run (`steps`
+#: each) and the cached rows read over all passes (`steps` x the
+#: context).
 STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
                "prefill_padded_tokens", "prefill_sorted_tokens",
                "compiles", "compile_s",
                "eva_window_rows", "eva_summary_rows",
-               "dsa_index_rows", "dsa_selected_rows")
+               "dsa_index_rows", "dsa_selected_rows",
+               "loop_passes", "loop_kv_rows")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:
@@ -892,6 +896,18 @@ class EngineMetrics:
                 "Padded prompt rows whose expert FFN ran as grouped "
                 "GEMMs over the sorted routed rows (0 on a model "
                 "without experts, and where the buckets run)",
+            ),
+            "loop_passes": c(
+                "shellac_engine_loop_passes_total",
+                "Passes over the layer stack run by decode slot-ticks "
+                "that produced a token (a looped stack, cfg.loop: its "
+                "steps for each; 0 on any other model)",
+            ),
+            "loop_kv_rows": c(
+                "shellac_engine_loop_kv_rows_total",
+                "Cached rows those slot-ticks read over all their "
+                "passes (steps x the query's context; 0 on a model "
+                "without a looped stack)",
             ),
         }
         self.compiles = c(

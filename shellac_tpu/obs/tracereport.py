@@ -94,6 +94,7 @@ DEVICE_SCOPES = (
     "eva.pool", "eva.summary_write", "eva.attend",
     "dsa.index_proj", "dsa.index_write", "dsa.score", "dsa.select",
     "dsa.attend",
+    "loop.norm", "loop.gate", "loop.exit",
     "unembed", "sample",
 )
 _SCOPE_RE = re.compile(

@@ -106,13 +106,20 @@ def _rms_bwd(eps, interpret, res, g):
 rms_norm_pallas.defvjp(_rms_fwd, _rms_bwd)
 
 
-@jax.named_scope("norm")
-def rms_norm(x, scale, eps: float = 1e-5, impl: str = "auto", mesh=None):
+def rms_norm(x, scale, eps: float = 1e-5, impl: str = "auto", mesh=None,
+             scope: str = "norm"):
     """Dispatching RMSNorm. impl: "auto" | "pallas" | "ref".
 
     `mesh`: the mesh the caller is partitioned over, if any. x is then
     (batch, seq[, heads], d) in the model's logical axes and the kernel
-    runs per shard (ops/dispatch.per_shard); rows are independent."""
+    runs per shard (ops/dispatch.per_shard); rows are independent.
+    `scope` names the device scope its operations are charged to (a
+    caller that wants its norm told apart in a trace gives its own)."""
+    with jax.named_scope(scope):
+        return _rms_norm(x, scale, eps, impl, mesh)
+
+
+def _rms_norm(x, scale, eps, impl, mesh):
     if impl == "ref":
         return rms_norm_ref(x, scale, eps)
     if impl != "pallas" and not (

@@ -95,13 +95,17 @@ def rng():
     return np.random.default_rng(0)
 
 
-#: The four stack layouts of forward_with_cache that can hold a paged
-#: pool, each as (preset, overrides) with kv_heads >= 2.
+#: The stack layouts of forward_with_cache that can hold a paged
+#: pool, each as (preset, overrides) with kv_heads >= 2. "looped" is
+#: the plain walk run twice over the same layers, each pass with its
+#: own cached layers (cfg.loop).
 PAGED_STACK_LAYOUTS = {
     "plain": ("tiny", {}),
     "first_k_dense": ("tiny-moe", {"first_k_dense": 2}),
     "grouped_moe": ("tiny-moe-interleaved", {}),
     "attn_pattern": ("tiny-gemma2", {}),
+    "looped": ("tiny", {"post_norms": True,
+                        "loop": {"steps": 2, "exit_threshold": 1.0}}),
 }
 
 

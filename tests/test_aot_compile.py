@@ -12,6 +12,7 @@ reached the late alphabet.
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -553,6 +554,91 @@ def test_mla_engine_programs_keep_the_latent_pool_in_place_on_v5e(
     assert ("decode_paged_group" in text) is (program == "decode")
     assert "kv.gather" not in text
     moved = pool_sized_ops(text, [cache.k.shape])
+    assert not moved, "\n".join(moved)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_looped_engine_programs_fit_one_chip_on_v5e(
+        chip, pool_sized_ops, monkeypatch, program):
+    """The engine's REAL programs for Ouro-2.6B at its published widths
+    (benchmark/configs/ouro-2.6b.json: 48 layers run four times, 192
+    cached layers) and the cell's serving numbers: 8 slots of five
+    128-row pages, a (192, 41, 16, 128, 128) k and v pool of 8.05 GB
+    beside 5.34 GB of weights. The decode window and the largest prompt
+    bucket each hold their arguments and temporaries under the chip's
+    15.75 GiB, read a tick's pages through the block table in the
+    kernel, and hold no pool-sized temporary: the pool rides all four
+    passes as one donated carry (one copy of k alone is 4 GB and would
+    not fit)."""
+    import types
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from shellac_tpu.inference.batching import PagedBatchingEngine
+    from shellac_tpu.models import transformer
+    from shellac_tpu.models.convert import config_from_hf
+
+    with open(os.path.join(REPO, "benchmark", "configs", "ouro-2.6b.json")) as f:
+        hf = json.load(f)
+    serving = hf["serving"]
+    cfg = config_from_hf(types.SimpleNamespace(**hf)).replace(
+        dtype="bfloat16", param_dtype="bfloat16").validate()
+    assert (cfg.n_layers, cfg.cache_layers, cfg.loop.steps) == (48, 192, 4)
+    slots, page, max_len = serving["n_slots"], serving["block_size"], 640
+    assert (slots, page) == (8, 128)
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert round(weights / 1e9, 2) == 5.34
+    # The engine is built over one slot's pages (0.3 GB of real zeros
+    # here); its programs take the cache as an argument, and are
+    # compiled for the cell's whole pool, described.
+    eng = PagedBatchingEngine(
+        cfg, params, n_slots=slots, max_len=max_len, block_size=page,
+        pool_tokens=max_len, decode_ticks=int(serving["decode_ticks"]),
+    )
+    assert eng.stats["decode_attn"] == "paged_kernel"
+    assert eng.stats["kv_bytes_per_token"] == 1572864
+    pool = (192, slots * (max_len // page) + 1, 16, page, 128)
+    cache = eng._cache.replace(
+        k=jax.ShapeDtypeStruct(pool, BF16), v=jax.ShapeDtypeStruct(pool, BF16))
+    pool_bytes = 2 * 2 * math.prod(pool)  # k and v, bfloat16
+    assert round(pool_bytes / 1e9, 2) == 8.25  # 8.05 GB + the scratch page
+    key = jax.random.PRNGKey(0)
+    row = eng._zero_bias_row
+    if program == "decode":
+        fn = eng._jit_cache_program(
+            eng._decode_impl, 10, static_argnames=("greedy_only",))
+        args = (eng._cur, jnp.ones((slots,), bool), key, (
+            eng._stemp, eng._stopk, eng._stopp, eng._sminp, row, eng._smin,
+            eng._spres, eng._sfreq, row, eng._sseed,
+            jnp.zeros((slots,), I32), eng._dummy_ctrans, eng._coff,
+            eng._cstate, eng._srem, eng._sdone))
+        kw = {"greedy_only": True}
+    else:
+        prompt = 256  # the largest bucket of prompts of 64-255 tokens
+        fn = eng._jit_cache_program(
+            eng._prefill_impl, 5, static_argnames=("want_plp",))
+        args = (jnp.zeros((1, prompt), I32), jnp.asarray([prompt], I32),
+                jnp.int32(0), key, (jnp.zeros((6,), I32), row, row))
+        kw = {"want_plp": False}
+    compiled = fn.lower(shaped(params), shaped(cache), *shaped(args),
+                        **kw).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held
+    # One copy of k or v is 4.1 GB: the temporaries stay under half that
+    # (1.2 GB of them are wq, wk, wv re-laid each call; PERF.md, PR 33).
+    assert ma.temp_size_in_bytes < pool_bytes / 4, ma.temp_size_in_bytes
+    assert ma.alias_size_in_bytes >= pool_bytes  # donated, returned in place
+    assert ("decode_paged" in text) is (program == "decode")
+    assert "kv.gather" not in text
+    moved = pool_sized_ops(text, [pool])
     assert not moved, "\n".join(moved)
 
 

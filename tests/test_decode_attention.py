@@ -432,6 +432,12 @@ CELLS = {
     # deepseek-v2-lite-batch's: the MLA latent, 576 lanes held at 640,
     # one pool as k and as v (values are its first 512 lanes).
     "latent": (1, 16, 576, 640, 128, 20, 4, True),
+    # ouro-2.6b-batch-reason's: MHA 16/16 (one q head a kv head) over
+    # FIVE 128-row pages a slot. The tile's bound allows two pages a
+    # step, two does not divide five, so this is the one-page kernel
+    # (`decode_paged`); on the chip two pages a step over a table
+    # extended by a dead entry took the same time (PERF.md, PR 33).
+    "mha-odd-table": (16, 1, D, D, 128, 5, 1, False),
 }
 
 

@@ -22,9 +22,10 @@ MAX_LEN = 4 * BLOCK
 N_SLOTS = 4
 
 
-def _compiled_decode(cfg, kv_quant):
-    """The engine's own decode program, compiled for the arguments its
-    first window passes, and the cache those arguments held."""
+def _compiled_decode(cfg, kv_quant, program="_decode_impl"):
+    """The engine's own decode program (or, by name, its whole-prompt
+    prefill), compiled for the arguments its first call passes, and the
+    cache those arguments held."""
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     eng = PagedBatchingEngine(
         cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN, block_size=BLOCK,
@@ -38,7 +39,7 @@ def _compiled_decode(cfg, kv_quant):
 
     def spy(fn, n_tail, **kw):
         jitted = jit_program(fn, n_tail, **kw)
-        if getattr(fn, "__name__", "") != "_decode_impl":
+        if getattr(fn, "__name__", "") != program:
             return jitted
 
         def run(*args, **kwargs):
@@ -63,8 +64,12 @@ def test_decode_program_keeps_the_pool_in_place(paged_stack_cfg, kv_quant,
     # Eight layers: one layer's gathered view (what the reference
     # attention reads, a temporary by design) is an eighth of the pool,
     # and a second pool cannot hide behind it.
+    # The looped layout walks them twice: sixteen cached layers ride
+    # both passes as one carry, and a copy of the pool between passes
+    # would show as a pool-sized temporary.
     cfg = paged_stack_cfg
     assert cfg.cache_kv_heads >= 2 and cfg.n_layers == 8
+    assert cfg.cache_layers == (16 if cfg.loop else 8)
     compiled, cache = _compiled_decode(cfg, kv_quant)
     pools = [getattr(cache, n) for n in kv_field_names(kv_quant)]
     pool_bytes = sum(
@@ -73,5 +78,32 @@ def test_decode_program_keeps_the_pool_in_place(paged_stack_cfg, kv_quant,
     )
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes / 2, (temp, pool_bytes)
+    moved = pool_sized_ops(compiled.as_text(), [s for s, _ in pools])
+    assert not moved, "\n".join(moved)
+
+
+@pytest.mark.parametrize("layout", ["plain", "looped"])
+def test_prefill_program_keeps_the_pool_in_place(layout, pool_sized_ops):
+    """The whole-prompt prefill computes into a dense scratch of the
+    prompt's bucket (every cached layer's: a looped stack's holds a row
+    a pass) and writes it through the slot's table: its temporaries are
+    that scratch, never a pool, and the pool it was handed is the pool
+    it returns."""
+    from conftest import PAGED_STACK_LAYOUTS
+
+    from shellac_tpu import get_model_config
+
+    preset, extra = PAGED_STACK_LAYOUTS[layout]
+    cfg = get_model_config(preset).replace(
+        dtype="float32", n_layers=8, **extra)
+    compiled, cache = _compiled_decode(cfg, None, "_prefill_impl")
+    pools = [getattr(cache, n) for n in kv_field_names(None)]
+    assert pools[0][0][0] == cfg.cache_layers
+    pool_bytes = sum(
+        int(np.prod(shape)) * np.dtype(dtype).itemsize
+        for shape, dtype in pools
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 8, (temp, pool_bytes)
     moved = pool_sized_ops(compiled.as_text(), [s for s, _ in pools])
     assert not moved, "\n".join(moved)
