@@ -209,6 +209,15 @@ class BatchingEngine:
     # engine pins it to 1 (a verify round already emits up to gamma+1
     # tokens per sync) and sets this False so the auto-tuner skips it.
     _decode_ticks_tunable = True
+    # Columns of the host matrix `_samp` (per-slot settings the device
+    # never writes): the first _SAMP_FLOATS are float32 bitcast to
+    # int32, the rest int32. `_unpack_slot_samp` is the one decoder.
+    _SAMP_COLS = ("temperature", "top_p", "min_p", "presence", "frequency",
+                  "top_k", "seed", "coff")
+    _SAMP_FLOATS = 5
+    # Fields of `_patch`, in the order the window carries them:
+    # (_cur, _smin, _srem, _sdone, _cstate).
+    _PATCH_FIELDS = ("cur", "min_rem", "rem", "done", "cstate")
 
     def __init__(
         self,
@@ -444,14 +453,34 @@ class BatchingEngine:
         # logprobs here on completion (keyed by rid), like
         # finished_logprobs.
         self.finished_prompt_logprobs: Dict[Any, List[float]] = {}
-        # Per-slot additive logit biases and remaining min_tokens (EOS
-        # ban countdown, decremented on device inside the decode scan).
-        # The (n_slots, vocab) bias matrix is allocated lazily on the
-        # first biased request — most deployments never pay for it; the
-        # shared zero row keeps prefill jit signatures stable.
+        # ---- per-slot state -------------------------------------------
+        # Where it lives: the HOST. Nothing below is ever written by an
+        # eager device op on the step's path: a change reaches the
+        # device as an argument of a program the step dispatches anyway
+        # (the prefill, the chunk, the decode window), so admission and
+        # release put nothing between two programs and the chip does
+        # not drain around them. Three kinds:
+        #
+        #  * settings the device never writes (temperature, top_p,
+        #    min_p, the penalty coefficients, top_k, the seed, the
+        #    constraint row offset): ONE host int32 matrix `_samp`
+        #    (floats bitcast, columns _SAMP_COLS), uploaded when a
+        #    value changed (`_samp_dev` is None) and otherwise handed
+        #    to the window as the device copy it already saw;
+        #  * vectors the window carries (`_cur`, `_smin`, `_srem`,
+        #    `_sdone`, `_cstate`): device arrays rebound from each
+        #    window's outputs. A new tenant's values (and a freeze, a
+        #    thaw, a cleared DFA state) are written into the host
+        #    `_patch` by `_patch_slot`, ride the next window's one
+        #    host array and are selected in at its entry;
+        #  * the (n_slots, vocab) matrices (`_sbias`, `_scounts`): only
+        #    allocated, and only written, for a request that has a
+        #    logit bias or penalties.
         self._sbias: Optional[jax.Array] = None
         self._zero_bias_row = jnp.zeros((1, cfg.vocab_size), jnp.float32)
         self._slot_bias: List[Optional[Dict[int, float]]] = [None] * n_slots
+        # Remaining min_tokens (EOS ban countdown, decremented on
+        # device inside the decode scan).
         self._smin = jnp.zeros((n_slots,), jnp.int32)
         # Device-side stop/budget decisions: per-slot remaining max_new
         # budget and a sticky done flag, threaded through the decode
@@ -466,25 +495,19 @@ class BatchingEngine:
         self._sdone = jnp.zeros((n_slots,), bool)
         # OpenAI-style repetition penalties over GENERATED tokens:
         # per-slot token-count matrix (lazily allocated, like the bias
-        # matrix) plus presence/frequency coefficient vectors. Counts
-        # update on device inside the decode scan.
+        # matrix); the presence/frequency coefficients are columns of
+        # `_samp`. Counts update on device inside the decode scan.
         self._scounts: Optional[jax.Array] = None
-        self._spres = jnp.zeros((n_slots,), jnp.float32)
-        self._sfreq = jnp.zeros((n_slots,), jnp.float32)
         self._slot_pen: List[bool] = [False] * n_slots
-        # Per-request deterministic sampling: seed (-1 = unseeded, use
-        # the shared stream) + the slot's generated-token count at the
-        # start of each decode window (host-known: len(req.out)).
-        self._sseed = jnp.full((n_slots,), -1, jnp.int32)
         # Structured decoding: active constrained slots' TokenDFA
         # tables stacked into one device table (rows bucketed so the
         # decode trace is reused across request churn), a per-slot row
-        # offset (-1 = unconstrained), and per-slot DFA state that
-        # advances on device inside the decode scan.
+        # offset (-1 = unconstrained; a column of `_samp`), and
+        # per-slot DFA state that advances on device inside the decode
+        # scan.
         self._slot_dfa: List[Optional[Any]] = [None] * n_slots
         self._ctrans: Optional[jax.Array] = None
         self._con_dirty = False
-        self._coff = jnp.full((n_slots,), -1, jnp.int32)
         self._cstate = jnp.zeros((n_slots,), jnp.int32)
         # Shared dummy table for unconstrained decode steps (the hot
         # path): allocated once, like _zero_bias_row.
@@ -492,9 +515,12 @@ class BatchingEngine:
             (1, cfg.vocab_size + 1), -1, jnp.int32
         )
         # Engine-level sampling defaults; submit() can override any of
-        # them per request. Each slot's effective settings live in
-        # device vectors fed to the jitted programs, so one decode tick
-        # serves greedy and sampled requests side by side.
+        # them per request. Each slot's effective settings are a row of
+        # `_samp`, so one decode tick serves greedy and sampled
+        # requests side by side. Per-request deterministic sampling:
+        # seed (-1 = unseeded, use the shared stream) + the slot's
+        # generated-token count at the start of each decode window
+        # (host-known: len(req.out)).
         self._defaults = {
             "temperature": float(temperature),
             # top_k resolves once, here: None (disabled) = full vocab.
@@ -503,14 +529,14 @@ class BatchingEngine:
             "min_p": float(min_p) if min_p is not None else 0.0,
         }
         self._validate_sampling(self._defaults, "engine defaults")
-        self._stemp = jnp.full((n_slots,), self._defaults["temperature"],
-                               jnp.float32)
-        self._stopk = jnp.full((n_slots,), self._defaults["top_k"],
-                               jnp.int32)
-        self._stopp = jnp.full((n_slots,), self._defaults["top_p"],
-                               jnp.float32)
-        self._sminp = jnp.full((n_slots,), self._defaults["min_p"],
-                               jnp.float32)
+        self._samp = np.zeros((n_slots, len(self._SAMP_COLS)), np.int32)
+        self._samp_dev: Optional[jax.Array] = None
+        for slot in range(n_slots):
+            self._write_samp(slot, seed=-1, coff=-1, **self._defaults)
+        # The carried vectors' pending writes: column 0 a bit a field
+        # of _PATCH_FIELDS, then the fields' values.
+        self._patch = np.zeros((n_slots, 1 + len(self._PATCH_FIELDS)),
+                               np.int32)
         # The construction seed is retained (not just consumed into the
         # key) so the multi-host epoch resync can re-key deterministically
         # per (seed, epoch) instead of collapsing every job onto the
@@ -663,6 +689,109 @@ class BatchingEngine:
             jit_kw["out_shardings"] = (self._cache_sh,) + (None,) * n_tail
         return jax.jit(fn, donate_argnums=(1,), **jit_kw)
 
+    # ---- slot state: host writers, program-side readers -------------
+
+    def _write_samp(self, slot: int, **values) -> None:
+        """Set columns (named as _SAMP_COLS) of `slot`'s row of the
+        host settings matrix. The device copy is dropped only if a
+        value changed, so a stream of requests with equal settings
+        uploads nothing."""
+        row = self._samp[slot]
+        for name, v in values.items():
+            col = self._SAMP_COLS.index(name)
+            bits = (np.float32(v).view(np.int32)
+                    if col < self._SAMP_FLOATS else np.int32(v))
+            if row[col] != bits:
+                row[col] = bits
+                self._samp_dev = None
+
+    def _samp_arg(self):
+        """The settings matrix as the window's argument: uploaded (one
+        host-to-device copy) only if a value changed since the last
+        window took it."""
+        if self._samp_dev is None:
+            # A copy: the CPU backend may alias a host buffer.
+            self._samp_dev = jnp.asarray(self._samp.copy())
+            self.obs.steps.count(slot_uploads=1)
+        return self._samp_dev
+
+    def _unpack_slot_samp(self, samp):
+        """Decode the (n_slots, len(_SAMP_COLS)) settings matrix inside
+        a program: a dict of (n_slots,) vectors keyed as _SAMP_COLS,
+        the float columns bit-equal to the float32 the host wrote."""
+        nf = self._SAMP_FLOATS
+        fl = jax.lax.bitcast_convert_type(samp[:, :nf], jnp.float32)
+        cols = [fl[:, i] for i in range(nf)]
+        cols += [samp[:, i] for i in range(nf, len(self._SAMP_COLS))]
+        return dict(zip(self._SAMP_COLS, cols))
+
+    def _patch_slot(self, slot: int, **fields) -> None:
+        """THE writer of a slot's carried vectors (`_cur`, `_smin`,
+        `_srem`, `_sdone`, `_cstate`; named as _PATCH_FIELDS): record
+        the values on the host; the next window selects them in at its
+        entry. Nothing is dispatched. Every caller that arms, freezes,
+        thaws or clears a slot goes through here: the prefill settle,
+        release, preemption, a disaggregated import, the server's
+        thaw."""
+        row = self._patch[slot]
+        for name, v in fields.items():
+            bit = self._PATCH_FIELDS.index(name)
+            row[0] |= 1 << bit
+            row[1 + bit] = int(v)
+
+    @property
+    def _carry(self):
+        """The vectors one window hands the next, in _PATCH_FIELDS
+        order: device arrays, rebound from each window's outputs."""
+        return (self._cur, self._smin, self._srem, self._sdone,
+                self._cstate)
+
+    @_carry.setter
+    def _carry(self, carry) -> None:
+        (self._cur, self._smin, self._srem, self._sdone,
+         self._cstate) = carry
+
+    def _window_arg(self, active_rows, gen0):
+        """The window's ONE per-call host array, (n_slots, 2 +
+        _patch's columns) int32: is the slot active, its generated-
+        token count at the window's start, and the pending patch,
+        which is cleared here (the window about to be dispatched
+        applies it)."""
+        win = np.empty((self.n_slots, 2 + self._patch.shape[1]), np.int32)
+        win[:, 0] = active_rows
+        win[:, 1] = gen0
+        win[:, 2:] = self._patch
+        if self._patch[:, 0].any():
+            self.obs.steps.count(slot_uploads=1)
+            self._patch[:] = 0
+        return jnp.asarray(win)
+
+    @staticmethod
+    def _apply_patch(carry, win):
+        """Inside a window program: the carried vectors, in
+        _PATCH_FIELDS order, with the patched fields of `win` (see
+        _window_arg) selected in."""
+        mask = win[:, 2]
+        return tuple(
+            jnp.where((mask >> bit) & 1 == 1,
+                      win[:, 3 + bit].astype(vec.dtype), vec)
+            for bit, vec in enumerate(carry)
+        )
+
+    @staticmethod
+    def _enter(cache, tables, key):
+        """First thing inside every engine program. -> (the cache with
+        the backend's per-slot indirection as the program received it
+        (`CacheBackend.slot_tables()`: the paged block table, whose
+        rows the host owns; None from a backend without one), the half
+        of the engine's PRNG key the program hands back, the half it
+        draws from): the halves an eager `key, sub = split(key)` gave,
+        so every stream is what it was."""
+        carried, sub = jax.random.split(key)
+        if tables is not None:
+            cache = cache.replace(tables=tables)
+        return cache, carried, sub
+
     # ---- jitted programs --------------------------------------------
 
     def _fresh_mini(self, length: int):
@@ -683,12 +812,16 @@ class BatchingEngine:
         return jnp.zeros((tokens.shape[1],), jnp.float32).at[1:].set(tok_lp)
 
     def _prefill_impl(self, params, cache, tokens, prompt_len, slot, key,
-                      samp, want_plp: bool = False):
+                      samp, tables, want_plp: bool = False):
         """Prefill one request and scatter it into `slot` of `cache`.
+        Like every engine program it starts with _enter: it takes the
+        engine's PRNG key (and hands the carried half back) and the
+        backend's slot tables.
 
         want_plp additionally returns the PROMPT's per-token logprobs
         (token t given tokens[:t]; position 0 has no predictor and
         reports 0.0 — the server renders it as null)."""
+        cache, key_out, key = self._enter(cache, tables, key)
         mini = self._fresh_mini(self.max_len)
         logits, mini = transformer.forward_with_cache(
             self.cfg, params, tokens, mini, new_tokens_len=prompt_len,
@@ -701,10 +834,47 @@ class BatchingEngine:
         plp = (self._plp_within(logits, tokens) if want_plp
                else jnp.zeros((tokens.shape[1],), jnp.float32))
         tlv, tli = self._first_tl(last)
-        return (scatter_slot(cache, mini, slot), first, first_lp, plp,
-                tlv, tli)
+        return (scatter_slot(cache, mini, slot), key_out, first, first_lp,
+                plp, tlv, tli)
 
-    def _decode_impl(self, params, cache, cur, active, key, samp,
+    def _decode_impl(self, params, cache, key, carry, win, samp, tables,
+                     bias, counts, ctrans, greedy_only: bool = False,
+                     use_bias: bool = False, use_pen: bool = False,
+                     use_seed: bool = False, use_con: bool = False):
+        """THE decode-window program: everything a window needs to know
+        about the slots arrives as its arguments, so the step
+        dispatches nothing around it.
+
+        `key`: the engine's PRNG key, split by _enter (the carried half
+        is returned). `carry`: the vectors a window hands the next (cur,
+        min_rem, rem, done, cstate). `win`: the call's one host array
+        (_window_arg: active, gen0, and the pending patch, selected
+        into `carry` first). `samp`: the host settings matrix
+        (_samp_arg). `tables`: the backend's slot tables or None.
+        `bias` / `counts` / `ctrans`: the (n_slots, vocab) matrices and
+        the stacked DFA table, or their shared dummies.
+
+        Returns (cache, key, carry, counts, tokens (K, n_slots),
+        logprobs, top-K values, top-K ids, acts (K, n_slots) validity
+        flags); the scan itself is _decode_scan (or, on a pp mesh,
+        _decode_impl_pp)."""
+        cache, key, sub = self._enter(cache, tables, key)
+        cur, min_rem, rem, done, cstate = self._apply_patch(carry, win)
+        s = self._unpack_slot_samp(samp)
+        scan = self._decode_impl_pp if self.pp_pipeline else self._decode_scan
+        (cache, toks, lps, min_rem, counts, cstate, tlvs, tlis, rem, done,
+         acts) = scan(
+            params, cache, cur, win[:, 0] != 0, sub,
+            (s["temperature"], s["top_k"], s["top_p"], s["min_p"], bias,
+             min_rem, s["presence"], s["frequency"], counts, s["seed"],
+             win[:, 1], ctrans, s["coff"], cstate, rem, done),
+            greedy_only=greedy_only, use_bias=use_bias, use_pen=use_pen,
+            use_seed=use_seed, use_con=use_con,
+        )
+        return (cache, key, (toks[-1], min_rem, rem, done, cstate), counts,
+                toks, lps, tlvs, tlis, acts)
+
+    def _decode_scan(self, params, cache, cur, active, key, samp,
                      greedy_only: bool = False, use_bias: bool = False,
                      use_pen: bool = False, use_seed: bool = False,
                      use_con: bool = False):
@@ -1316,11 +1486,14 @@ class BatchingEngine:
 
     def _release_slot(self, slot: int) -> None:
         """A request left `slot`: release its storage (backend hook;
-        paged frees blocks) and clear the slot's SAMPLING state, which
-        is the engine's own. Clearing the logit bias drops the engine
-        back to the cheap no-bias decode variant — zeroing the row
-        too, or a later unbiased request on this slot would silently
-        inherit the stale biases."""
+        paged frees blocks and zeroes the slot's HOST table row) and
+        clear the slot's SAMPLING state, which is the engine's own.
+        Host writes only, for a request without a bias or penalties:
+        the zeroed row, the cleared coefficients and the cleared DFA
+        state ride the next program. Clearing the logit bias drops the
+        engine back to the cheap no-bias decode variant — zeroing the
+        row too, or a later unbiased request on this slot would
+        silently inherit the stale biases."""
         with self.obs.steps.span("cache.release_slot", slot=slot):
             self.cache_backend.release_slot(slot)
         if self._slot_bias[slot] is not None:
@@ -1329,13 +1502,12 @@ class BatchingEngine:
         if self._slot_pen[slot]:
             # Clear the coefficient AND the counts, or the next request
             # on this slot would inherit a stale repetition history.
-            self._spres = self._spres.at[slot].set(0.0)
-            self._sfreq = self._sfreq.at[slot].set(0.0)
+            self._write_samp(slot, presence=0.0, frequency=0.0)
             self._scounts = self._scounts.at[slot].set(0.0)
             self._slot_pen[slot] = False
         if self._slot_dfa[slot] is not None:
             self._slot_dfa[slot] = None
-            self._cstate = self._cstate.at[slot].set(0)
+            self._patch_slot(slot, cstate=0)
             self._con_dirty = True
 
     def _bias_row(self, req: _Request) -> np.ndarray:
@@ -1377,12 +1549,18 @@ class BatchingEngine:
         return (jnp.asarray(packed), bias, cmask)
 
     def _set_slot_sampling(self, slot: int, req: _Request) -> None:
-        """Write the request's settings into the per-slot vectors the
-        decode program samples with."""
-        self._stemp = self._stemp.at[slot].set(req.temperature)
-        self._stopk = self._stopk.at[slot].set(req.top_k)
-        self._stopp = self._stopp.at[slot].set(req.top_p)
-        self._sminp = self._sminp.at[slot].set(req.min_p)
+        """Write the request's settings into the slot's row of the
+        host settings matrix the decode window samples with (the
+        window uploads the matrix if a value changed: requests with
+        equal settings upload nothing). Only a logit bias or penalties
+        touch the device here, in the (n_slots, vocab) matrices such a
+        request needs."""
+        self._write_samp(
+            slot, temperature=req.temperature, top_k=req.top_k,
+            top_p=req.top_p, min_p=req.min_p,
+            seed=req.seed if req.seed is not None else -1,
+            presence=req.presence_penalty, frequency=req.frequency_penalty,
+        )
         new_bias = req.logit_bias or None
         if new_bias != self._slot_bias[slot]:
             # O(n_slots x vocab) device copy — only when this slot's
@@ -1395,10 +1573,6 @@ class BatchingEngine:
                 jnp.asarray(self._bias_row(req))
             )
             self._slot_bias[slot] = new_bias
-        self._smin = self._smin.at[slot].set(req.min_tokens)
-        self._sseed = self._sseed.at[slot].set(
-            req.seed if req.seed is not None else -1
-        )
         penalized = (req.presence_penalty != 0.0
                      or req.frequency_penalty != 0.0)
         if penalized or self._slot_pen[slot]:
@@ -1406,31 +1580,29 @@ class BatchingEngine:
                 self._scounts = jnp.zeros(
                     (self.n_slots, self.cfg.vocab_size), jnp.float32
                 )
-            self._spres = self._spres.at[slot].set(req.presence_penalty)
-            self._sfreq = self._sfreq.at[slot].set(req.frequency_penalty)
             self._scounts = self._scounts.at[slot].set(0.0)
         self._slot_pen[slot] = penalized
         if req.constraint is not None or self._slot_dfa[slot] is not None:
             self._slot_dfa[slot] = req.constraint
-            self._cstate = self._cstate.at[slot].set(0)
+            self._patch_slot(slot, cstate=0)
             # Lazy: admissions and releases in one engine step coalesce
             # into a single restack right before the next decode.
             self._con_dirty = True
 
     def _rebuild_constraints(self) -> None:
         """Restack active constrained slots' DFA tables into one device
-        table with per-slot row offsets. Rows are bucketed to powers of
-        two so the decode program's trace survives request churn."""
+        table with per-slot row offsets (a column of the host settings
+        matrix). Rows are bucketed to powers of two so the decode
+        program's trace survives request churn."""
         self._con_dirty = False
-        tables, offs, off = [], [], 0
-        for dfa in self._slot_dfa:
+        tables, off = [], 0
+        for slot, dfa in enumerate(self._slot_dfa):
             if dfa is None:
-                offs.append(-1)
+                self._write_samp(slot, coff=-1)
                 continue
-            offs.append(off)
+            self._write_samp(slot, coff=off)
             tables.append(dfa.trans)
             off += dfa.trans.shape[0]
-        self._coff = jnp.asarray(offs, jnp.int32)
         if not tables:
             self._ctrans = None
             return
@@ -1459,18 +1631,20 @@ class BatchingEngine:
         key = (pad, req.prompt_logprobs)
         if key not in self._prefill_jit:
             self._prefill_jit[key] = self._jit_cache_program(
-                self._prefill_impl, 5, static_argnames=("want_plp",)
+                self._prefill_impl, 6, static_argnames=("want_plp",)
             )
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = req.tokens
-        self._key, sub = jax.random.split(self._key)
         self._count_prefill(s, pad, True)
-        cache, first, lp, plp, tlv, tli = self._prefill_jit[key](
-            self.params, self._cache, jnp.asarray(padded),
-            jnp.asarray([s], jnp.int32), slot, sub, self._slot_samp(slot, req),
-            want_plp=req.prompt_logprobs,
+        self._cache, self._key, first, lp, plp, tlv, tli = (
+            self._prefill_jit[key](
+                self.params, self._cache, jnp.asarray(padded),
+                np.array([s], np.int32), slot, self._key,
+                self._slot_samp(slot, req),
+                self.cache_backend.slot_tables(),
+                want_plp=req.prompt_logprobs,
+            )
         )
-        self._cache = cache
         # Prompt scoring no longer pays its own per-admission pull: the
         # device array rides the flight and lands in the ONE batched
         # settle device_get alongside the first token (SH002 history:
@@ -1631,17 +1805,33 @@ class BatchingEngine:
         The slot's prompt KV is now certainly resident, so paged
         prefix caching registers the prompt blocks as matchable here —
         at settle, never at dispatch (an in-flight program's blocks
-        must not be matchable, and a cancelled flight's never are)."""
+        must not be matchable, and a cancelled flight's never are).
+
+        The new tenant's carried vectors (next input token, budget,
+        done flag, EOS-ban countdown, DFA state) are ARMED here by one
+        _patch_slot call: host writes that the next decode window, the
+        first the slot is active in, selects in at its entry. Why the
+        patch and not the prefill program: the values are final only at
+        the settle (the DFA advance and the repetition count need the
+        first token on the host, a cancelled flight must arm nothing,
+        prefill_only freezes), the same writer serves an imported slot,
+        a preemption and a thaw, and a window in between runs the slot
+        inactive, so the values are bit-equal to eager writes made
+        here."""
         self.cache_backend.on_prefill_complete(slot)
         first_tok = int(first)
-        self._cur = self._cur.at[slot].set(first_tok)
-        # Arm the device-side stop decisions: the prefill-sampled token
-        # below is the first of max_new, so the decode window may emit
-        # max_new - 1 more before the budget freeze; done clears in
-        # case the slot's previous tenant froze it.
-        self._srem = self._srem.at[slot].set(req.max_new - 1)
-        self._sdone = self._sdone.at[slot].set(False)
         self._slots[slot] = req
+        # The prefill-sampled token is the first of max_new, so the
+        # decode window may emit max_new - 1 more before the budget
+        # freeze; done clears in case the slot's previous tenant froze
+        # it — unless this is a disaggregated freeze: then the
+        # device-side done flag plus host-side exclusion keep the slot
+        # out of every decode window, the KV-migration exporter ships
+        # it and release_frozen() reclaims the slot. The prefill-sampled
+        # token also consumed one unit of the EOS ban.
+        arm = dict(cur=first_tok, rem=req.max_new - 1,
+                   done=req.prefill_only,
+                   min_rem=max(req.min_tokens - 1, 0))
         if req.constraint is not None:
             # Advance the DFA past the prefill-sampled token (host-side:
             # the token is already a host int here). Decode-time tokens
@@ -1649,15 +1839,12 @@ class BatchingEngine:
             trans = req.constraint.trans
             col = (trans.shape[1] - 1 if first_tok == req.constraint.eos_id
                    else first_tok)
-            nxt = int(trans[0, col])
-            self._cstate = self._cstate.at[slot].set(max(nxt, 0))
+            arm["cstate"] = max(int(trans[0, col]), 0)
+        self._patch_slot(slot, **arm)
         if self._slot_pen[slot]:
             # The prefill-sampled token is generated output: it joins
             # the slot's repetition counts.
             self._scounts = self._scounts.at[slot, first_tok].add(1.0)
-        # The prefill-sampled token consumed one unit of the EOS ban.
-        if req.min_tokens > 0:
-            self._smin = self._smin.at[slot].set(req.min_tokens - 1)
         req.out.append(first_tok)
         self.obs.steps.count(tokens_delivered=1)
         if req.trace is not None:
@@ -1675,11 +1862,6 @@ class BatchingEngine:
         if req.prompt_logprobs and plp is not None:
             req.plp = self._stitch_plp(plp, req.tokens.size)
         if req.prefill_only:
-            # Disaggregated freeze: the device-side done flag (PR 7's
-            # freeze mechanism) plus host-side exclusion keep the slot
-            # out of every decode window; the KV-migration exporter
-            # ships it and release_frozen() reclaims the slot.
-            self._sdone = self._sdone.at[slot].set(True)
             self.frozen_prefills[req.rid] = slot
             if req.trace is not None:
                 req.trace.record("prefill-frozen", src="engine",
@@ -1709,23 +1891,20 @@ class BatchingEngine:
             # settle has its own span, after this one).
             with steps.span("engine.prefill_dispatch", slot=slot,
                             offset=int(off)):
-                self._key, sub = jax.random.split(self._key)
-                boundary = (jnp.asarray(0, jnp.int32) if final
-                            else jnp.asarray(int(req.tokens[off + s]),
-                                             jnp.int32))
+                # (numpy, like every small host argument below: jnp
+                # would dispatch a program to convert a Python scalar)
+                boundary = np.int32(0 if final else req.tokens[off + s])
                 self._count_prefill(s, pad, off == 0)
-                cache, first, lp, plp_w, blp, tlv, tli = \
-                    self._chunk_prefill(
-                        pad, off == 0, jnp.asarray(
-                            np.pad(chunk, (0, pad - s))[None]
-                        ),
-                        jnp.asarray([s], jnp.int32),
-                        jnp.asarray([off], jnp.int32),
-                        slot, sub, self._slot_samp(slot, req),
-                        boundary_next=boundary,
-                        want_plp=req.prompt_logprobs,
-                    )
-                self._cache = cache
+                first, lp, plp_w, blp, tlv, tli = self._chunk_prefill(
+                    pad, off == 0, jnp.asarray(
+                        np.pad(chunk, (0, pad - s))[None]
+                    ),
+                    np.array([s], np.int32),
+                    np.array([off], np.int32),
+                    slot, self._slot_samp(slot, req),
+                    boundary_next=boundary,
+                    want_plp=req.prompt_logprobs,
+                )
                 if req.prompt_logprobs:
                     # Collect DEVICE arrays; the one blocking transfer
                     # happens at the final chunk, so scoring does not
@@ -1755,24 +1934,39 @@ class BatchingEngine:
         return used
 
     def _chunk_prefill(self, pad, fresh, tokens, chunk_len, offset, slot,
-                       key, samp, boundary_next=None, want_plp=False):
-        """Dispatch one (bucketed, jitted) chunk-continuation program."""
+                       samp, boundary_next=None, want_plp=False):
+        """Dispatch one (bucketed, jitted) chunk-continuation program;
+        rebinds the cache and the PRNG key from its outputs and returns
+        the rest (first token, its logprob, in-chunk prompt scores,
+        boundary score, top-K values and ids: device values)."""
         jkey = (pad, fresh, want_plp)
         if jkey not in self._chunk_jit:
             self._chunk_jit[jkey] = self._jit_cache_program(
                 functools.partial(self._chunk_prefill_impl, fresh=fresh,
-                                  want_plp=want_plp), 6
+                                  want_plp=want_plp), 7
             )
-        if boundary_next is None:
-            boundary_next = jnp.zeros((), jnp.int32)
-        return self._chunk_jit[jkey](
-            self.params, self._cache, tokens, chunk_len, offset, slot, key,
-            samp, boundary_next,
+        return self._run_chunk_program(
+            self._chunk_jit[jkey], tokens, chunk_len, offset, slot, samp,
+            boundary_next,
         )
 
+    def _run_chunk_program(self, program, tokens, chunk_len, offset, slot,
+                           samp, boundary_next):
+        """One chunk program's call, the dense and the paged engine's:
+        the engine's key and the backend's slot tables go in as
+        arguments, the cache and the key come back."""
+        if boundary_next is None:
+            boundary_next = np.int32(0)
+        self._cache, self._key, *rest = program(
+            self.params, self._cache, tokens, chunk_len, offset, slot,
+            self._key, samp, boundary_next,
+            self.cache_backend.slot_tables(),
+        )
+        return rest
+
     def _chunk_prefill_impl(self, params, cache, tokens, chunk_len, offset,
-                            slot, key, samp, boundary_next, *, fresh: bool,
-                            want_plp: bool = False):
+                            slot, key, samp, boundary_next, tables, *,
+                            fresh: bool, want_plp: bool = False):
         """Write one prompt chunk at `offset` into `slot`'s cache row.
 
         A batch-1 view of the row continues from `offset` tokens
@@ -1788,6 +1982,7 @@ class BatchingEngine:
         chunk's final position, so the host can stitch the full prompt
         scoring across chunks.
         """
+        cache, key_out, key = self._enter(cache, tables, key)
         view = slot_view(cache, slot, offset)
         logits, view = transformer.forward_with_cache(
             self.cfg, params, tokens, view, new_tokens_len=chunk_len,
@@ -1808,7 +2003,7 @@ class BatchingEngine:
                 last.astype(jnp.float32)
             )[boundary_next]
         tlv, tli = self._first_tl(last)
-        return (scatter_slot(cache, view, slot), first, first_lp,
+        return (scatter_slot(cache, view, slot), key_out, first, first_lp,
                 plp_within, boundary_lp, tlv, tli)
 
     def _finish_check(self, finished):
@@ -2013,24 +2208,25 @@ class BatchingEngine:
     def _dispatch_window(self, active_rows) -> _DecodeWindow:
         """Dispatch ONE jitted decode window asynchronously and record
         it in the flight queue. No host sync happens here — jax returns
-        the outputs as futures, and every per-slot device vector is
-        rebound from them so admissions/releases that run before the
-        sync compose in dispatch order."""
+        the outputs as futures — and no other device call either: the
+        window's program is the one dispatch. What changed about the
+        slots since the last window goes in as its arguments (the
+        active rows, gen0 and the pending patch as one host array; the
+        settings matrix and the block table when a value or a row
+        changed), the PRNG key is split inside it, and the vectors the
+        window carries are rebound from its outputs, so admissions and
+        releases that run before the sync compose in dispatch order."""
         with self.obs.steps.span("engine.dispatch_window",
                                  ticks=self.decode_ticks,
                                  rows=sum(active_rows)):
             if self._decode is None:
-                impl = (self._decode_impl_pp if self.pp_pipeline
-                        else self._decode_impl)
                 self._decode = self._jit_cache_program(
-                    impl, 10,
+                    self._decode_impl, 8,
                     static_argnames=("greedy_only", "use_bias", "use_pen",
                                      "use_seed", "use_con"),
                 )
             adv = self._inflight_advance()
             self._pre_decode(active_rows, adv)
-            active = jnp.asarray(active_rows)
-            self._key, sub = jax.random.split(self._key)
             greedy_only = all(
                 r is None or r.temperature == 0.0 for r in self._slots
             )
@@ -2038,26 +2234,22 @@ class BatchingEngine:
             if self._con_dirty:
                 self._rebuild_constraints()
             use_con = self._ctrans is not None
-            counts = (self._scounts if use_pen else self._zero_bias_row)
             # Generated-token counts at the window's start: host-known
             # len(out), projected past any window still in flight.
-            gen0 = jnp.asarray(
-                [len(r.out) + adv.get(i, 0) if r is not None else 0
-                 for i, r in enumerate(self._slots)],
-                jnp.int32,
-            )
-            # Unconstrained steps pass the shared dummy table so the arg
-            # tree keeps its structure without holding a real table alive.
-            ctrans = self._ctrans if use_con else self._dummy_ctrans
-            (self._cache, toks, lps, self._smin, counts, cstate,
-             tlvs, tlis, self._srem, self._sdone, acts) = self._decode(
-                self.params, self._cache, self._cur, active, sub,
-                (self._stemp, self._stopk, self._stopp, self._sminp,
-                 self._sbias if self._sbias is not None
-                 else self._zero_bias_row, self._smin,
-                 self._spres, self._sfreq, counts,
-                 self._sseed, gen0, ctrans, self._coff, self._cstate,
-                 self._srem, self._sdone),
+            gen0 = [len(r.out) + adv.get(i, 0) if r is not None else 0
+                    for i, r in enumerate(self._slots)]
+            (self._cache, self._key, self._carry, counts, toks, lps, tlvs,
+             tlis, acts) = self._decode(
+                self.params, self._cache, self._key, self._carry,
+                self._window_arg(active_rows, gen0), self._samp_arg(),
+                self.cache_backend.slot_tables(),
+                self._sbias if self._sbias is not None
+                else self._zero_bias_row,
+                self._scounts if use_pen else self._zero_bias_row,
+                # Unconstrained steps pass the shared dummy table so the
+                # arg tree keeps its structure without holding a real
+                # table alive.
+                self._ctrans if use_con else self._dummy_ctrans,
                 greedy_only=greedy_only,
                 use_bias=self._sbias is not None and any(
                     b is not None for b in self._slot_bias
@@ -2070,9 +2262,6 @@ class BatchingEngine:
             )
             if use_pen:
                 self._scounts = counts
-            if use_con:
-                self._cstate = cstate
-            self._cur = toks[-1]
             w = _DecodeWindow(
                 pairs=[(i, self._slots[i])
                        for i in range(self.n_slots) if active_rows[i]],
@@ -2297,7 +2486,7 @@ class BatchingEngine:
             self._settle_window(finished)
         if self._slots[slot] is not req:
             return finished
-        self._sdone = self._sdone.at[slot].set(True)
+        self._patch_slot(slot, done=True)
         req.frozen = True
         self.frozen_decodes[rid] = slot
         self.stats["preemptions"] += 1
@@ -2370,6 +2559,7 @@ class BatchingEngine:
             self._slots[i] = None
             self._release_slot(i)
         self._prefilling.clear()
+        self._patch[:] = 0
         self.frozen_prefills.clear()
         self.frozen_decodes.clear()
         self.finished_logprobs.clear()
@@ -2622,7 +2812,7 @@ class PagedBatchingEngine(BatchingEngine):
 
     # ---- jitted programs --------------------------------------------
     def _chunk_prefill(self, pad, fresh, tokens, chunk_len, offset, slot,
-                       key, samp, boundary_next=None, want_plp=False):
+                       samp, boundary_next=None, want_plp=False):
         """Paged chunks reuse the continuation program (a chunk is a
         'suffix' past `offset` resident tokens; offset 0 included).
         Prompt logprobs ride the same stitching contract as the dense
@@ -2633,13 +2823,11 @@ class PagedBatchingEngine(BatchingEngine):
             self._prefix_prefill_jit[jkey] = self._jit_cache_program(
                 functools.partial(
                     self._prefix_prefill_impl, want_plp=want_plp
-                ), 6,
+                ), 7,
             )
-        if boundary_next is None:
-            boundary_next = jnp.zeros((), jnp.int32)
-        return self._prefix_prefill_jit[jkey](
-            self.params, self._cache, tokens, chunk_len, offset, slot, key,
-            samp, boundary_next,
+        return self._run_chunk_program(
+            self._prefix_prefill_jit[jkey], tokens, chunk_len, offset,
+            slot, samp, boundary_next,
         )
 
     def _run_prefill(self, slot: int, req):
@@ -2658,16 +2846,14 @@ class PagedBatchingEngine(BatchingEngine):
         pad = min(_bucket(s), self.max_len - p)
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = suffix
-        self._key, sub = jax.random.split(self._key)
         self._count_prefill(s, pad, False)
         # One dispatch path: the chunk-continuation program IS the
         # suffix prefill (a suffix is a chunk past `p` resident tokens).
-        cache, first, lp, _, _, tlv, tli = self._chunk_prefill(
+        first, lp, _, _, tlv, tli = self._chunk_prefill(
             pad, False, jnp.asarray(padded),
-            jnp.asarray([s], jnp.int32), jnp.asarray([p], jnp.int32),
-            slot, sub, self._slot_samp(slot, req),
+            np.array([s], np.int32), np.array([p], np.int32),
+            slot, self._slot_samp(slot, req),
         )
-        self._cache = cache
         # No plp payload: submit() refuses prompt_logprobs on
         # prefix-cached engines (the hit skips the scoring passes).
         return (first, lp, ((tlv, tli) if self.top_logprobs else None),
@@ -2675,7 +2861,7 @@ class PagedBatchingEngine(BatchingEngine):
 
     def _prefix_prefill_impl(
         self, params, cache, tokens, suffix_len, prefix_len, slot, key,
-        samp, boundary_next, *, want_plp: bool = False,
+        samp, boundary_next, tables, *, want_plp: bool = False,
     ):
         """Continue from `prefix_len` cached tokens: a batch-1 view of
         the slot's table row over the shared pool, forwarded with
@@ -2693,6 +2879,7 @@ class PagedBatchingEngine(BatchingEngine):
         kernel targets s<=8 steady-state decode and would only fall
         back (warning) on a prefill-sized s.
         """
+        cache, key_out, key = self._enter(cache, tables, key)
         row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)
         if self.kv_quant == "int8":
             view = QuantPagedKVCache(
@@ -2736,17 +2923,22 @@ class PagedBatchingEngine(BatchingEngine):
             fields.update(idx=view.idx)
         cache = cache.replace(**fields)
         tlv, tli = self._first_tl(last)
-        return (cache, first, first_lp, plp_within, boundary_lp,
+        return (cache, key_out, first, first_lp, plp_within, boundary_lp,
                 tlv, tli)
 
     def _prefill_impl(self, params, cache, tokens, prompt_len, slot, key,
-                      samp, want_plp: bool = False):
+                      samp, tables, want_plp: bool = False):
         """Prefill one prompt and leave its state in the slot's pages,
         the backend's way (`prefill_into`: a dense mini cache of the
         pool's kind scattered through the slot's table, or, for EVA
-        state, straight through a view of the slot). want_plp scores
-        the prompt from the prefill's own logits — identical math to
-        the dense engine's whole-prompt scoring."""
+        state, straight through a view of the slot). `tables` is the
+        host's block table as this program's argument: a new tenant's
+        row (and every row released since the last program) reaches
+        the device HERE. want_plp scores the prompt from the prefill's
+        own logits — identical math to the dense engine's whole-prompt
+        scoring."""
+        cache, key_out, key = self._enter(cache, tables, key)
+
         def forward(scratch):
             return transformer.forward_with_cache(
                 self.cfg, params, tokens, scratch,
@@ -2764,7 +2956,7 @@ class PagedBatchingEngine(BatchingEngine):
         plp = (self._plp_within(logits, tokens) if want_plp
                else jnp.zeros((tokens.shape[1],), jnp.float32))
         tlv, tli = self._first_tl(last)
-        return cache, first, first_lp, plp, tlv, tli
+        return cache, key_out, first, first_lp, plp, tlv, tli
 
 
     # ---- beam search over the pool (copy-on-write tables) ------------

@@ -22,10 +22,12 @@ A backend owns two things:
      disaggregated prefill/decode split will ship between hosts.
 
 Backends are bound to exactly one engine (`bind`); the engine keeps
-rebinding `engine._cache` from its jitted programs' donated outputs,
-and the backend reads/writes that attribute for table surgery (paged)
-rather than holding its own copy — one owner for the device tree, one
-for the host policy.
+rebinding `engine._cache` from its jitted programs' donated outputs —
+one owner for the device tree, one for the host policy. The backend
+never writes that tree: what its policy changes about a slot (the
+paged pool's block-table rows) it keeps on the host and hands the
+engine's next program as an argument (`slot_tables`), so admission and
+release dispatch nothing of their own.
 """
 
 from __future__ import annotations
@@ -191,6 +193,13 @@ class CacheBackend:
         """Tokens already resident when prefill starts (paged prefix
         caching returns the matched prefix length)."""
         return 0
+
+    def slot_tables(self):
+        """Per-slot storage indirection the next engine program must
+        see (the paged pool's block table: a host array whose changed
+        rows ride the program as an argument and become its
+        cache.tables), or None where a slot's storage is its own row."""
+        return None
 
     def reset(self) -> None:
         """abort_all: restore the allocator to its canonical pristine

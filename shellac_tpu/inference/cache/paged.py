@@ -3,11 +3,14 @@ grow and return them on completion, so resident KV memory tracks the
 tokens actually alive instead of n_slots x max_len worst case.
 
 All ALLOCATOR state lives here — free list, per-slot block lists, the
-prefix-cache hash registry and refcounts — while the device side only
-ever sees the block tables the backend writes into the engine's cache
-pytree. Block 0 is reserved scratch: unallocated table entries point at
-it, so stray writes/reads through them land harmlessly and are masked
-downstream.
+prefix-cache hash registry and refcounts — and so does the BLOCK TABLE:
+a host (n_slots, max_blocks) int32 array with one writer of a row
+(`_write_row`). The device never sees a table op of its own: the table,
+when a row changed since the last program, rides the next engine
+program as an argument (`slot_tables()`) and becomes that program's
+`cache.tables`, so admission and release dispatch nothing. Block 0 is
+reserved scratch: unallocated table entries point at it, so stray
+writes/reads through them land harmlessly and are masked downstream.
 
 prefix_cache=True adds automatic prefix caching (the public
 PagedAttention/vLLM idea): full prompt blocks are content-hashed with a
@@ -130,6 +133,13 @@ class PagedBackend(CacheBackend):
         ) + 1
         self._free: List[int] = list(range(self.n_blocks - 1, 0, -1))
         self._slot_blocks: List[List[int]] = [[] for _ in range(n_slots)]
+        # The block table, host side: row i is _slot_blocks[i] padded
+        # with scratch block 0. _write_row is its only writer; the
+        # device copy (None: a row changed since it was uploaded) is
+        # what slot_tables() hands the next engine program.
+        self._tables = np.zeros((n_slots, self.max_blocks_per_slot),
+                                np.int32)
+        self._tables_dev: Optional[jax.Array] = None
         # Prefix cache state (all host-side; empty when disabled):
         # hash -> block id, insertion/touch-ordered so the front is
         # LRU; _block_ref counts slots currently attached to a cached
@@ -307,9 +317,39 @@ class PagedBackend(CacheBackend):
         self._prefix_hits.pop(h, None)
         self._prefix_version += 1
 
+    def _write_row(self, slot: int) -> None:
+        """THE writer of a table row: host row `slot` := the slot's
+        block list, scratch block 0 behind it. Nothing is dispatched;
+        the device sees the row when the next engine program takes
+        slot_tables(). A released slot's row therefore reads zeros on
+        the device from the first program dispatched after its release,
+        whichever it is (a prefill, a chunk, the window) — before any
+        later window can write through it and before a new tenant's
+        prefill writes its pages — which is the order the eager
+        zeroing used to give by dispatch order."""
+        blocks = self._slot_blocks[slot]
+        row = self._tables[slot]
+        row[:len(blocks)] = blocks
+        row[len(blocks):] = 0
+        self._tables_dev = None
+
+    def slot_tables(self):
+        """The block table as the next engine program's argument (the
+        program makes it its cache.tables on entry). Uploaded here, one
+        host-to-device copy of the whole table, only if a row changed
+        since the last program took it; otherwise the device copy that
+        program already saw."""
+        if self._tables_dev is None:
+            # A copy: the CPU backend may alias a host buffer, and the
+            # host table goes on changing under programs in flight.
+            self._tables_dev = jnp.asarray(self._tables.copy())
+            self.engine.obs.steps.count(slot_uploads=1)
+        return self._tables_dev
+
     def ensure_blocks(self, slot: int, total_tokens: int) -> bool:
-        """Grow slot's table to cover total_tokens; False if pool
-        empty."""
+        """Grow slot's block list (and its host table row) to cover
+        total_tokens; False if the pool is empty. Host work only: the
+        grown row rides the next engine program (slot_tables)."""
         eng = self.engine
         need = -(-total_tokens // self.block_size)
         have = len(self._slot_blocks[slot])
@@ -321,13 +361,10 @@ class PagedBackend(CacheBackend):
         # pre_window find nothing to do and record nothing.
         with eng.obs.steps.span("cache.ensure_blocks", slot=slot,
                                 pages=need - have):
-            new_ids = [self.alloc_block() for _ in range(need - have)]
-            self._slot_blocks[slot].extend(new_ids)
-            idx = jnp.arange(have, need, dtype=jnp.int32)
-            tables = eng._cache.tables.at[slot, idx].set(
-                jnp.asarray(new_ids, jnp.int32)
+            self._slot_blocks[slot].extend(
+                self.alloc_block() for _ in range(need - have)
             )
-            eng._cache = eng._cache.replace(tables=tables)
+            self._write_row(slot)
         return True
 
     # ---- prefix cache ------------------------------------------------
@@ -383,9 +420,13 @@ class PagedBackend(CacheBackend):
     # ---- slot lifecycle ---------------------------------------------
 
     def prepare_slot(self, slot: int, req, footprint: int) -> None:
-        # Reserve the FULL footprint (prompt + generation budget +
-        # engine slack) at admission: growth mid-decode could exhaust
-        # the pool and there is no good victim to evict at that point.
+        """Reserve the FULL footprint (prompt + generation budget +
+        engine slack) at admission: growth mid-decode could exhaust
+        the pool and there is no good victim to evict at that point.
+        With the prefix cache, the longest cached chain is attached
+        first (rolled back if the rest does not fit). Host work only:
+        the slot's row reaches the device as an argument of its own
+        prefill program."""
         eng = self.engine
         if not self.prefix_cache:
             if not self.ensure_blocks(slot, footprint):
@@ -397,18 +438,12 @@ class PagedBackend(CacheBackend):
         m = len(matched)
         if matched:
             self._slot_blocks[slot] = list(matched)
-            tables = eng._cache.tables.at[
-                slot, jnp.arange(m, dtype=jnp.int32)
-            ].set(jnp.asarray(matched, jnp.int32))
-            eng._cache = eng._cache.replace(tables=tables)
+            self._write_row(slot)
         if not self.ensure_blocks(slot, footprint):
             # Roll back the attach (blocks stay cached) and requeue.
             self.detach_prefix(matched)
             self._slot_blocks[slot] = []
-            row = jnp.zeros((eng._cache.max_blocks,), jnp.int32)
-            eng._cache = eng._cache.replace(
-                tables=eng._cache.tables.at[slot].set(row)
-            )
+            self._write_row(slot)
             raise PoolExhausted()
         # The slot's own full prompt blocks become matchable only once
         # prefill has actually written them — with chunked prefill that
@@ -443,7 +478,9 @@ class PagedBackend(CacheBackend):
             self._prefix_version += 1
 
     def release_slot(self, slot: int) -> None:
-        eng = self.engine
+        """The request left `slot`: its pages go back (free list, or
+        stay cached at refcount 0) and its host row is zeroed. Host
+        work only; see _write_row for when the device reads the zeros."""
         self._pending_reg.pop(slot, None)
         if self.prefix_cache:
             for blk in self._slot_blocks[slot]:
@@ -456,10 +493,7 @@ class PagedBackend(CacheBackend):
             self._free.extend(reversed(self._slot_blocks[slot]))
         self._slot_blocks[slot] = []
         self._slot_prefix_len[slot] = 0
-        row = jnp.zeros((eng._cache.max_blocks,), jnp.int32)
-        eng._cache = eng._cache.replace(
-            tables=eng._cache.tables.at[slot].set(row)
-        )
+        self._write_row(slot)
 
     def pre_window(self, active_rows, advance, span: int) -> None:
         # Backstop only — admission already reserved the full
@@ -511,6 +545,8 @@ class PagedBackend(CacheBackend):
         self._free = list(range(self.n_blocks - 1, 0, -1))
         self._slot_blocks = [[] for _ in range(self.n_slots)]
         self._slot_prefix_len = [0] * self.n_slots
+        for slot in range(self.n_slots):
+            self._write_row(slot)
 
     # ---- fabric: directory manifest + chain export/seed -------------
 

@@ -465,8 +465,6 @@ def _place_slot(engine, backend, blob, header, req, rid, slot,
         nb = int(header["n_blocks"])
         blocks = backend._slot_blocks[slot][:nb]
         idx = jnp.asarray(blocks, jnp.int32)
-        # Re-read after ensure_blocks rebound the tables.
-        cache = engine._cache
         new = {
             f: getattr(cache, f).at[:, idx].set(
                 jnp.asarray(blob.arrays[f])
@@ -496,22 +494,21 @@ def _place_slot(engine, backend, blob, header, req, rid, slot,
     engine._cache = cache.replace(**new)
 
     # ---- host bookkeeping (the _finish_prefill mirror) --------------
+    # The slot's carried vectors go through the engine's one writer
+    # (host values the next decode window selects in); its table row
+    # went through the backend's (ensure_blocks above) and rides the
+    # same window.
     n_out = len(req.out)
-    engine._cur = engine._cur.at[slot].set(int(req.out[-1]))
-    engine._srem = engine._srem.at[slot].set(
-        max(req.max_new - n_out, 0)
+    engine._patch_slot(
+        slot, cur=int(req.out[-1]), rem=max(req.max_new - n_out, 0),
+        done=False, min_rem=max(req.min_tokens - n_out, 0),
     )
-    engine._sdone = engine._sdone.at[slot].set(False)
     engine._set_slot_sampling(slot, req)
     if req.constraint is not None:  # unreachable: submit refuses above
         raise ValueError("constrained requests do not migrate")
     if engine._slot_pen[slot]:
         for t in req.out:
             engine._scounts = engine._scounts.at[slot, int(t)].add(1.0)
-    if req.min_tokens > 0:
-        engine._smin = engine._smin.at[slot].set(
-            max(req.min_tokens - n_out, 0)
-        )
     engine._slots[slot] = req
     engine.stats["kv_imports"] += 1
     if trace is not None:

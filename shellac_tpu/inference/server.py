@@ -1696,7 +1696,7 @@ class InferenceServer:
             # request.
             engine.frozen_decodes.pop(vrid, None)
             req.frozen = False
-            engine._sdone = engine._sdone.at[vslot].set(False)
+            engine._patch_slot(vslot, done=False)
             if p is not None and p.trace is not None:
                 p.trace.record("preempt-failed", src="server", rid=vrid,
                                error=f"{type(e).__name__}: {e}")

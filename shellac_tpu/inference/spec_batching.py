@@ -334,7 +334,7 @@ class _SpecDecodeMixin:
         padded[0, :s] = req.tokens
         self._dcache = self._draft_prefill_jit[pad](
             self.draft_params, self._dcache, jnp.asarray(padded),
-            jnp.asarray([s], jnp.int32), slot,
+            np.array([s], np.int32), slot,
         )
         return first_and_lp
 
@@ -351,7 +351,7 @@ class _SpecDecodeMixin:
     # ---- chunked prefill (draft cache chunks alongside the target) ---
 
     def _chunk_prefill(self, pad, fresh, tokens, chunk_len, offset, slot,
-                       key, samp, boundary_next=None, want_plp=False):
+                       samp, boundary_next=None, want_plp=False):
         """The target chunk program runs via the host engine; the
         draft's cache row is then brought to the SAME coverage, so by
         the final chunk both caches hold the full prompt — identical
@@ -361,7 +361,7 @@ class _SpecDecodeMixin:
         (always 0-origin): a prefix-cache hit starts the target at the
         matched offset, but the draft owns no prefix blocks."""
         out = super()._chunk_prefill(
-            pad, fresh, tokens, chunk_len, offset, slot, key, samp,
+            pad, fresh, tokens, chunk_len, offset, slot, samp,
             boundary_next=boundary_next, want_plp=want_plp,
         )
         if self._spec_skip_draft:
@@ -392,8 +392,8 @@ class _SpecDecodeMixin:
             self._dcache = self._draft_chunk_jit[jkey](
                 self.draft_params, self._dcache,
                 jnp.asarray(np.pad(dchunk, (0, dpad - ds))[None]),
-                jnp.asarray([ds], jnp.int32),
-                jnp.asarray([dstart], jnp.int32), slot,
+                np.array([ds], np.int32),
+                np.array([dstart], np.int32), slot,
             )
         if t_end >= req.tokens.size:
             self._draft_chunk_off.pop(slot, None)
@@ -419,12 +419,18 @@ class _SpecDecodeMixin:
 
     # ---- one verification round over all slots ----------------------
 
-    def _spec_round_impl(self, params, dparams, tcache, dcache, cur,
-                         active, key, samp, use_bias: bool = False,
+    def _spec_round_impl(self, params, dparams, tcache, dcache, key, carry,
+                         win, samp, tables, bias, use_bias: bool = False,
                          use_seed: bool = False):
-        """Returns (tcache, dcache, emitted (B, g+1), counts (B,), cur,
-        lps (B, g+1) — zeros unless self.logprobs, top-K value/id
-        sidecars, min_rem).
+        """The verify round's program. Its slot-state arguments are the
+        decode window's (BatchingEngine._decode_impl): the engine's key
+        (split here), the carried vectors (`carry`: cur and min_rem are
+        this round's; the budget, done flag and DFA state pass through
+        with the patch applied), the call's host array `win` (active,
+        gen0, pending patch), the host settings matrix `samp`, the
+        target backend's slot tables, the bias matrix. Returns (tcache,
+        dcache, key, carry, emitted (B, g+1), counts (B,), lps (B, g+1)
+        — zeros unless self.logprobs, top-K value/id sidecars).
 
         counts[b] tokens of emitted[b] are real (0 for inactive rows).
         Per-row temperature: greedy rows use the exact-match degenerate
@@ -446,8 +452,14 @@ class _SpecDecodeMixin:
         different number of variates than a token-by-token sampler.)
         """
         g = self.gamma
+        tcache, key_out, key = self._enter(tcache, tables, key)
+        cur, min_rem0, rem, done, cstate = self._apply_patch(carry, win)
+        active, gen0 = win[:, 0] != 0, win[:, 1]
         b = cur.shape[0]
-        temp, topk, topp, minp, bias, min_rem0, seed_vec, gen0 = samp
+        st = self._unpack_slot_samp(samp)
+        temp, topk, topp, minp = (st["temperature"], st["top_k"],
+                                  st["top_p"], st["min_p"])
+        seed_vec = st["seed"]
         key, kd, kacc, kres, kbonus = jax.random.split(key, 5)
         greedy = temp <= 0.0
         t = jnp.where(greedy, 1.0, temp)[:, None]
@@ -627,8 +639,8 @@ class _SpecDecodeMixin:
             lps = jnp.zeros(emitted.shape, jnp.float32)
             tlv = jnp.zeros((*emitted.shape, 0), jnp.float32)
             tli = jnp.zeros((*emitted.shape, 0), jnp.int32)
-        return (tcache, dcache, emitted, counts, cur, lps, tlv, tli,
-                min_rem)
+        return (tcache, dcache, key_out, (cur, min_rem, rem, done, cstate),
+                emitted, counts, lps, tlv, tli)
 
     def _decode_tokens(self, active_rows):
         steps = self.obs.steps
@@ -640,18 +652,13 @@ class _SpecDecodeMixin:
             # reserved the full slack footprint, so this is the same
             # no-op-in-steady-state check the dense window performs).
             self._pre_decode(active_rows)
-            active = jnp.asarray(active_rows)
-            self._key, sub = jax.random.split(self._key)
             use_bias = self._sbias is not None and any(
                 bb is not None for bb in self._slot_bias
             )
             use_seed = any(
                 r is not None and r.seed is not None for r in self._slots
             )
-            gen0 = jnp.asarray(
-                [len(r.out) if r is not None else 0 for r in self._slots],
-                jnp.int32,
-            )
+            gen0 = [len(r.out) if r is not None else 0 for r in self._slots]
             if self._spec_round is None:
                 round_kw = (
                     {"out_shardings": ((self._cache_sh, self._dcache_sh)
@@ -662,13 +669,14 @@ class _SpecDecodeMixin:
                     self._spec_round_impl,
                     static_argnames=("use_bias", "use_seed"), **round_kw,
                 )
-            (self._cache, self._dcache, emitted, counts, self._cur,
-             lps, tlv, tli, self._smin) = self._spec_round(
+            (self._cache, self._dcache, self._key, self._carry, emitted,
+             counts, lps, tlv, tli) = self._spec_round(
                 self.params, self.draft_params, self._cache, self._dcache,
-                self._cur, active, sub,
-                (self._stemp, self._stopk, self._stopp, self._sminp,
-                 self._sbias if self._sbias is not None
-                 else self._zero_bias_row, self._smin, self._sseed, gen0),
+                self._key, self._carry,
+                self._window_arg(active_rows, gen0), self._samp_arg(),
+                self.cache_backend.slot_tables(),
+                self._sbias if self._sbias is not None
+                else self._zero_bias_row,
                 use_bias=use_bias, use_seed=use_seed,
             )
         # The one host sync. The base engine's window instruments live

@@ -100,14 +100,19 @@ SPAN_PHASE = {
 #: the rows kept, whichever is less); and, for a looped stack (cfg.loop;
 #: 0 elsewhere), over the same slot-ticks the stack passes run (`steps`
 #: each) and the cached rows read over all passes (`steps` x the
-#: context).
+#: context); `slot_uploads`: the host arrays of slot state the step
+#: handed to its programs (the block table, the settings matrix, the
+#: carried vectors' patch; each only when it changed: 0 on a step that
+#: neither admits, settles, releases nor grows a slot, nor follows one
+#: that released after its window went out). With `compiles` it says
+#: that admission dispatches nothing of its own.
 STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
                "prefill_padded_tokens", "prefill_sorted_tokens",
                "compiles", "compile_s",
                "eva_window_rows", "eva_summary_rows",
                "dsa_index_rows", "dsa_selected_rows",
-               "loop_passes", "loop_kv_rows")
+               "loop_passes", "loop_kv_rows", "slot_uploads")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:
@@ -908,6 +913,15 @@ class EngineMetrics:
                 "Cached rows those slot-ticks read over all their "
                 "passes (steps x the query's context; 0 on a model "
                 "without a looped stack)",
+            ),
+            "slot_uploads": c(
+                "shellac_engine_slot_uploads_total",
+                "Host arrays of slot state handed to the step's programs "
+                "as arguments: the block table (a row changed), the "
+                "settings matrix (a value changed), the carried "
+                "vectors' patch (a slot was armed, frozen or cleared); "
+                "0 on a step with no admission, settle, release or "
+                "growth in it or just before it",
             ),
         }
         self.compiles = c(

@@ -498,6 +498,29 @@ def test_latent_pool_is_relaid_for_the_kernel_on_v5e(chip, heads, hkv, d,
         assert temp >= pool_bytes, (temp, pool_bytes)
 
 
+def _engine_program(eng, program, prompt):
+    """The engine's decode window ("decode") or whole-prompt prefill of
+    `prompt` padded tokens, jitted as the engine jits it, with the
+    arguments its own call passes (the slot state as the engine's
+    accessors hand it to a program) and the static flags of a greedy
+    run."""
+    key = jax.random.PRNGKey(0)
+    row = eng._zero_bias_row
+    tables = eng.cache_backend.slot_tables()
+    if program == "decode":
+        fn = eng._jit_cache_program(
+            eng._decode_impl, 8, static_argnames=("greedy_only",))
+        args = (key, eng._carry,
+                eng._window_arg([True] * eng.n_slots, [0] * eng.n_slots),
+                eng._samp_arg(), tables, row, row, eng._dummy_ctrans)
+        return fn, args, {"greedy_only": True}
+    fn = eng._jit_cache_program(
+        eng._prefill_impl, 6, static_argnames=("want_plp",))
+    args = (jnp.zeros((1, prompt), I32), jnp.asarray([prompt], I32),
+            jnp.int32(0), key, (jnp.zeros((6,), I32), row, row), tables)
+    return fn, args, {"want_plp": False}
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_mla_engine_programs_keep_the_latent_pool_in_place_on_v5e(
         chip, pool_sized_ops, monkeypatch, program):
@@ -528,23 +551,7 @@ def test_mla_engine_programs_keep_the_latent_pool_in_place_on_v5e(
     assert eng.stats["decode_attn"] == "paged_kernel"
     cache = eng._cache
     assert cache.k.shape == (8, slots * 20 + 1, 1, 128, 640)
-    key = jax.random.PRNGKey(0)
-    row = eng._zero_bias_row
-    if program == "decode":
-        fn = eng._jit_cache_program(
-            eng._decode_impl, 10, static_argnames=("greedy_only",))
-        args = (eng._cur, jnp.ones((slots,), bool), key, (
-            eng._stemp, eng._stopk, eng._stopp, eng._sminp, row, eng._smin,
-            eng._spres, eng._sfreq, row, eng._sseed,
-            jnp.zeros((slots,), I32), eng._dummy_ctrans, eng._coff,
-            eng._cstate, eng._srem, eng._sdone))
-        kw = {"greedy_only": True}
-    else:
-        fn = eng._jit_cache_program(
-            eng._prefill_impl, 5, static_argnames=("want_plp",))
-        args = (jnp.zeros((1, prompt), I32), jnp.asarray([prompt], I32),
-                jnp.int32(0), key, (jnp.zeros((6,), I32), row, row))
-        kw = {"want_plp": False}
+    fn, args, kw = _engine_program(eng, program, prompt)
     compiled = fn.lower(shaped(params), shaped(cache), *shaped(args),
                         **kw).compile()
     text = compiled.as_text()
@@ -607,24 +614,8 @@ def test_looped_engine_programs_fit_one_chip_on_v5e(
         k=jax.ShapeDtypeStruct(pool, BF16), v=jax.ShapeDtypeStruct(pool, BF16))
     pool_bytes = 2 * 2 * math.prod(pool)  # k and v, bfloat16
     assert round(pool_bytes / 1e9, 2) == 8.25  # 8.05 GB + the scratch page
-    key = jax.random.PRNGKey(0)
-    row = eng._zero_bias_row
-    if program == "decode":
-        fn = eng._jit_cache_program(
-            eng._decode_impl, 10, static_argnames=("greedy_only",))
-        args = (eng._cur, jnp.ones((slots,), bool), key, (
-            eng._stemp, eng._stopk, eng._stopp, eng._sminp, row, eng._smin,
-            eng._spres, eng._sfreq, row, eng._sseed,
-            jnp.zeros((slots,), I32), eng._dummy_ctrans, eng._coff,
-            eng._cstate, eng._srem, eng._sdone))
-        kw = {"greedy_only": True}
-    else:
-        prompt = 256  # the largest bucket of prompts of 64-255 tokens
-        fn = eng._jit_cache_program(
-            eng._prefill_impl, 5, static_argnames=("want_plp",))
-        args = (jnp.zeros((1, prompt), I32), jnp.asarray([prompt], I32),
-                jnp.int32(0), key, (jnp.zeros((6,), I32), row, row))
-        kw = {"want_plp": False}
+    # (256: the largest bucket of prompts of 64-255 tokens)
+    fn, args, kw = _engine_program(eng, program, 256)
     compiled = fn.lower(shaped(params), shaped(cache), *shaped(args),
                         **kw).compile()
     text = compiled.as_text()

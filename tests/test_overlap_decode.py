@@ -436,3 +436,67 @@ class TestStatsSurface:
                 cfg, params, dcfg, transformer_params(dcfg),
                 overlap_decode=True, n_slots=2, max_len=64,
             )
+
+
+# Token ids the PARENT of the change that moved the PRNG split into the
+# engine's programs (commit 8f675d6) served for _pinned_scenario below,
+# written down from one run of it: the dense and the paged engine read
+# the same, strict ordering and both overlaps apart only in the two
+# requests that draw from the engine's shared stream (the docstring of
+# BatchingEngine.overlap_decode says why).
+PARENT_STREAMS = {
+    "greedy0": [180, 76, 31],
+    "seeded1": [4, 194, 206, 174],
+    "shared2": [149, 34, 84],
+    "greedy3": [64, 49, 114, 169, 169, 27, 76, 165],
+    "seeded4": [52, 134, 141, 227, 194, 74],
+    "shared5": {False: [249, 182, 136, 232, 144],
+                True: [241, 79, 144, 182, 130]},
+    "greedy6": [148, 246, 246, 246, 246, 210, 252],
+    "seeded7": [192, 76, 127, 58, 77, 244, 148, 155],
+}
+
+
+def _pinned_scenario(backend, overlap):
+    from shellac_tpu.inference.cache import engine_class
+
+    cfg = _tiny(param_dtype="float32")
+    params = transformer_params(cfg)
+    kw = {"block_size": 16} if backend == "paged" else {}
+    eng = engine_class(backend)(
+        cfg, params, n_slots=3, max_len=96, decode_ticks=2, seed=5,
+        overlap_decode=overlap, overlap_prefill=overlap,
+        cache_backend=backend, **kw)
+    rng = np.random.default_rng(34)
+    for i in range(8):
+        prompt = rng.integers(0, cfg.vocab_size, size=int(rng.integers(3, 40)))
+        kind = ("greedy", "seeded", "shared")[i % 3]
+        eng.submit(f"{kind}{i}", prompt, int(rng.integers(3, 12)), **{
+            "greedy": {},
+            "seeded": {"temperature": 0.9, "top_k": 40, "seed": 1000 + i},
+            "shared": {"temperature": 0.8, "top_p": 0.95},
+        }[kind])
+    return _drain(eng)
+
+
+class TestParentStreams:
+    """The engine's key is split inside its programs, and a slot's
+    settings, first token, budget and done flag reach them as arguments:
+    greedy streams, per-request seeded streams AND the shared stream's
+    draws are bit for bit what the eager split and the eager per-slot
+    writes gave, in every ordering."""
+
+    @pytest.mark.parametrize("overlap", [False, True],
+                             ids=["strict", "overlap"])
+    @pytest.mark.parametrize("backend", ["dense", "paged"])
+    def test_streams_are_the_parents(self, backend, overlap):
+        got = _pinned_scenario(backend, overlap)
+        want = {rid: toks[overlap] if isinstance(toks, dict) else toks
+                for rid, toks in PARENT_STREAMS.items()}
+        assert got == want
+
+    def test_greedy_and_seeded_streams_ignore_the_ordering(self):
+        keep = lambda d: {r: t for r, t in d.items()  # noqa: E731
+                          if not r.startswith("shared")}
+        assert keep(_pinned_scenario("paged", False)) == \
+            keep(_pinned_scenario("paged", True))

@@ -158,8 +158,12 @@ class TestOverlapPrefillParity:
                 eng.step()  # dispatch only: flight in the pipeline
                 assert eng._pflights, "prefill never went in flight"
                 slot = eng._pflights[0].slot
-                # Pre-settle: the device DFA state is still state 0.
-                assert int(np.asarray(eng._cstate)[slot]) == 0
+                # Pre-settle: the slot's DFA state is state 0, armed
+                # at admission (host side: the next window selects the
+                # pending patch in; the device vector has not moved).
+                bit = eng._PATCH_FIELDS.index("cstate")
+                assert eng._patch[slot, 0] >> bit & 1
+                assert eng._patch[slot, 1 + bit] == 0
                 # Settle exactly (white-box: the next step() would
                 # also dispatch a window and advance the state past
                 # the first token before returning).
@@ -167,7 +171,10 @@ class TestOverlapPrefillParity:
                 req = next(r for r in eng._slots if r is not None)
                 assert req.out, "settle deposited no first token"
                 want = max(int(dfa.trans[0, req.out[0]]), 0)
-                assert int(np.asarray(eng._cstate)[slot]) == want
+                # The settle re-armed it past the first token.
+                assert eng._patch[slot, 0] >> bit & 1
+                assert eng._patch[slot, 1 + bit] == want
+                assert int(np.asarray(eng._cstate)[slot]) == 0
             outs.append(_drain(eng))
         assert outs[0] == outs[1]
         text = bytes(outs[0]["c"][:3]).decode()
@@ -477,3 +484,60 @@ class TestStatsSurface:
             assert eng.prefill_chunk_source == "fixed"
         finally:
             srv.close()
+
+
+class TestSlotReuseWithinOneStep:
+    """A slot's settings, bias, counts and DFA state are its tenant's
+    alone: with one slot, a tenant with a logit bias, penalties, a
+    constraint or min_tokens finishes in a step's window settle and a
+    plain tenant takes the slot in the same step's fill, and serves
+    exactly what a fresh engine serves it: nothing of the last tenant is
+    left in the host matrix, the pending patch or the device vectors."""
+
+    @pytest.mark.parametrize("overlap", [False, True],
+                             ids=["strict", "overlap"])
+    @pytest.mark.parametrize("backend", ["dense", "paged"])
+    @pytest.mark.parametrize("last", ["bias", "penalties", "constraint",
+                                      "min_tokens", "sampled"])
+    def test_plain_tenant_after(self, setup, last, backend, overlap):
+        from shellac_tpu.inference.constraints import compile_token_dfa
+        from shellac_tpu.training.tokenizer import ByteTokenizer
+
+        cfg, params = setup
+        eos = cfg.vocab_size - 2
+        rng = np.random.default_rng(3)
+        plain = ("plain", rng.integers(0, cfg.vocab_size, 9), 12)
+        kw = dict(backend=backend, n_slots=1, max_len=64, eos_id=eos,
+                  decode_ticks=2, logprobs=True,
+                  overlap_prefill=overlap, overlap_decode=overlap)
+        fresh = _build(cfg, params, **kw)
+        want = _drain_after_submit(fresh, plain)
+        want_lps = fresh.finished_logprobs.pop("plain")
+        greedy = want["plain"]
+        special = {
+            "bias": dict(logit_bias={int(greedy[0]): -50.0,
+                                     int(greedy[1]): -50.0, 7: 9.0}),
+            "penalties": dict(presence_penalty=1.5, frequency_penalty=0.7),
+            "constraint": dict(constraint=compile_token_dfa(
+                "(cat|dog)", ByteTokenizer(), cfg.vocab_size, eos_id=eos)),
+            "min_tokens": dict(min_tokens=40,
+                               logit_bias={eos: 50.0}),
+            "sampled": dict(temperature=1.3, top_k=5, top_p=0.8,
+                            min_p=0.05, seed=11),
+        }[last]
+        eng = _build(cfg, params, **kw)
+        eng.submit("last", rng.integers(0, cfg.vocab_size, 6), 5, **special)
+        eng.submit(*plain)
+        holders, out = [], {}
+        while eng.pending:
+            out.update({rid: list(t) for rid, t in eng.step()})
+            holders.append(getattr(eng._slots[0], "rid", None))
+        # Overlapped, the window settles before the fill: the slot
+        # changes hands inside one step. In the strict ordering the
+        # window is the step's last act, so the release ends one step
+        # and the admission opens the next, with no program between.
+        handover = holders[holders.index("plain") - 1]
+        assert handover == ("last" if overlap else None), holders
+        assert out["plain"] == greedy
+        assert eng.finished_logprobs.pop("plain") == want_lps
+        assert len(out["last"]) >= 1

@@ -238,3 +238,226 @@ class TestPagedEngine:
                                   block_size=16)
         pool_positions = srv._cache.k.shape[1] * srv._cache.k.shape[3]
         assert pool_positions < dense_tokens * 0.6
+
+
+# ---- the block table lives on the host -------------------------------------
+
+def _watch_tables(eng):
+    """From here on, every engine program `eng` dispatches is checked as
+    it returns: the device table it leaves equals the host table (the
+    truth) as it stood at the dispatch, host rows are the slots' block
+    lists, and no page sits in two rows unless the prefix cache shares
+    it. So whichever program comes first after a row changed carries
+    the change, and a program that writes a new tenant's pages runs
+    with their last tenant's row already zero. Returns the list the
+    checked programs' names are appended to."""
+    be = eng.cache_backend
+    seen = []
+    jit_program = eng._jit_cache_program
+
+    def check(name, cache):
+        host = be._tables.copy()
+        for slot, blocks in enumerate(be._slot_blocks):
+            assert host[slot, :len(blocks)].tolist() == blocks
+            assert not host[slot, len(blocks):].any()
+        ids, counts = np.unique(host[host > 0], return_counts=True)
+        for blk in ids[counts > 1]:
+            assert int(blk) in be._block_ref, f"page {blk} in two rows"
+        np.testing.assert_array_equal(np.asarray(cache.tables), host)
+        seen.append(name)
+
+    def spy(fn, n_tail, **kw):
+        jitted = jit_program(fn, n_tail, **kw)
+        name = getattr(fn, "__name__", None) or fn.func.__name__
+
+        def run(*args, **kwargs):
+            out = jitted(*args, **kwargs)
+            check(name, out[0])
+            return out
+
+        return run
+
+    eng._jit_cache_program = spy
+    return seen
+
+
+def _paged(cfg, params, **kw):
+    kw = dict(dict(n_slots=2, max_len=64, block_size=8, decode_ticks=2,
+                   overlap_decode=True, overlap_prefill=True), **kw)
+    return PagedBatchingEngine(cfg, params, **kw)
+
+
+def _drain(eng):
+    out = {}
+    while eng.pending:
+        out.update({rid: list(t) for rid, t in eng.step()})
+    return out
+
+
+def _prompt(cfg, seed, n):
+    return np.random.default_rng(seed).integers(0, cfg.vocab_size, n)
+
+
+def _event_admission(cfg, params):
+    eng = _paged(cfg, params)
+    seen = _watch_tables(eng)
+    eng.submit("a", _prompt(cfg, 0, 11), 9)
+    eng.step()
+    assert seen == ["_prefill_impl"]
+    assert np.asarray(eng._cache.tables)[0, :3].all()  # 21 tokens: 3 pages
+    return eng, seen, {"a": (_prompt(cfg, 0, 11), 9)}
+
+
+def _event_release(cfg, params):
+    eng, seen, want = _event_admission(cfg, params)
+    out = _drain(eng)
+    pages = set(range(1, eng.cache_backend.n_blocks)) - set(eng._free)
+    assert not pages and not eng.cache_backend._tables.any()
+    # the release followed the step's last program: the device row goes
+    # to zero with the next one, here a new tenant's prefill, which
+    # takes the very pages "a" gave back
+    assert np.asarray(eng._cache.tables).any()
+    eng.submit("b", _prompt(cfg, 1, 20), 5)
+    eng.step()
+    assert seen[-1] == "_prefill_impl"
+    want["b"] = (_prompt(cfg, 1, 20), 5)
+    return eng, seen, want, out
+
+
+def _event_prefix_attach(cfg, params):
+    eng = _paged(cfg, params, prefix_cache=True)
+    seen = _watch_tables(eng)
+    shared = _prompt(cfg, 2, 24)
+    first = np.concatenate([shared, _prompt(cfg, 3, 3)])
+    second = np.concatenate([shared, _prompt(cfg, 4, 5)])
+    eng.submit("a", first, 4)
+    out = _drain(eng)
+    cached = [eng._hash_to_block[h]
+              for h in eng.cache_backend.chain_hashes(shared)]
+    eng.submit("b", second, 4)
+    eng.step()
+    assert eng.cache_backend._tables[0, :3].tolist() == cached
+    assert seen[-1] == "_prefix_prefill_impl"
+    return eng, seen, {"a": (first, 4), "b": (second, 4)}, out
+
+
+def _event_pool_exhausted_rollback(cfg, params):
+    # 9 usable pages. "a" leaves three cached; "c" then holds five of
+    # the six free, so "b", which matches the cached three and needs
+    # two more, attaches, fails to grow, and is rolled back and put
+    # back in the queue until "c" is done.
+    eng = _paged(cfg, params, prefix_cache=True, pool_tokens=72)
+    seen = _watch_tables(eng)
+    shared = _prompt(cfg, 5, 24)
+    first = np.concatenate([shared, _prompt(cfg, 6, 2)])
+    second = np.concatenate([shared, _prompt(cfg, 7, 6)])
+    other = _prompt(cfg, 8, 30)
+    eng.submit("a", first, 3)
+    out = _drain(eng)
+    eng.submit("c", other, 9)
+    eng.submit("b", second, 9)
+    eng.step()
+    be = eng.cache_backend
+    assert [len(b) for b in be._slot_blocks] == [5, 0]
+    assert not be._tables[1].any() and eng._slots[1] is None
+    assert all(r == 0 for r in be._block_ref.values())  # detached again
+    return (eng, seen,
+            {"a": (first, 3), "b": (second, 9), "c": (other, 9)}, out)
+
+
+def _event_cancel_in_flight(cfg, params):
+    eng, seen, want = _event_admission(cfg, params)
+    eng.submit("b", _prompt(cfg, 9, 7), 12)
+    eng.step()
+    eng.step()
+    assert eng._windows, "no window in flight to cancel under"
+    slot = next(i for i, r in enumerate(eng._slots) if r.rid == "a")
+    row = np.asarray(eng._cache.tables)[slot].copy()
+    assert eng.cancel("a")
+    assert not eng.cache_backend._tables[slot].any()
+    # no program since: the device still holds the row, which the window
+    # in flight may write through; the next program zeroes it
+    np.testing.assert_array_equal(np.asarray(eng._cache.tables)[slot], row)
+    eng.step()
+    assert not np.asarray(eng._cache.tables)[slot].any()
+    return eng, seen, {"b": (_prompt(cfg, 9, 7), 12)}
+
+
+def _event_preempt(cfg, params):
+    eng, seen, want = _event_admission(cfg, params)
+    eng.step()
+    eng.step()
+    finished = dict(eng.preempt("a"))
+    assert not finished and eng._slots[0].frozen
+    bit = eng._PATCH_FIELDS.index("done")  # armed for the next window
+    assert eng._patch[0, 0] >> bit & 1 and eng._patch[0, 1 + bit] == 1
+    held = eng.cache_backend._tables[0].copy()
+    assert held.any()  # frozen in place: the row stays until the release
+    eng.submit("b", _prompt(cfg, 10, 9), 6)
+    eng.step()
+    np.testing.assert_array_equal(np.asarray(eng._cache.tables)[0], held)
+    assert eng.release_frozen("a") is not None
+    assert not eng.cache_backend._tables[0].any()
+    eng.step()
+    assert not np.asarray(eng._cache.tables)[0].any()
+    return eng, seen, {"b": (_prompt(cfg, 10, 9), 6)}
+
+
+def _event_abort_all(cfg, params):
+    eng, seen, _ = _event_admission(cfg, params)
+    eng.submit("b", _prompt(cfg, 11, 30), 6)
+    eng.step()
+    assert sorted(eng.abort_all()) == ["a", "b"]
+    assert not eng.cache_backend._tables.any()
+    assert np.asarray(eng._cache.tables).any()  # until the next program
+    eng.submit("c", _prompt(cfg, 12, 13), 7)
+    eng.step()
+    assert not np.asarray(eng._cache.tables)[1].any()
+    return eng, seen, {"c": (_prompt(cfg, 12, 13), 7)}
+
+
+def _event_disagg_import(cfg, params):
+    from shellac_tpu.inference import disagg
+
+    src = _paged(cfg, params)
+    src.submit("m", _prompt(cfg, 13, 19), 8, prefill_only=True)
+    while not src.frozen_prefills:
+        src.step()
+    slot = src.frozen_prefills["m"]
+    blob = disagg.export_slot(src, slot, src._slots[slot])
+    assert src.release_frozen("m") is not None
+    eng = _paged(cfg, params)
+    seen = _watch_tables(eng)
+    eng.submit("other", _prompt(cfg, 14, 5), 5)
+    eng.step()
+    got = disagg.import_blob(eng, blob, rid="m")
+    assert eng.cache_backend._tables[got].any()
+    assert not np.asarray(eng._cache.tables)[got].any()
+    eng.step()  # the window: the imported row and its armed vectors
+    assert np.asarray(eng._cache.tables)[got].any()
+    return eng, seen, {"m": (_prompt(cfg, 13, 19), 8),
+                       "other": (_prompt(cfg, 14, 5), 5)}
+
+
+@pytest.mark.parametrize("event", [
+    "admission", "prefix_attach", "pool_exhausted_rollback", "release",
+    "cancel_in_flight", "preempt", "abort_all", "disagg_import",
+])
+def test_the_device_table_follows_the_host_table(setup, event):
+    """The block table is a host array with one writer of a row; the
+    device copy is written only by engine programs, from the argument
+    they are handed. After each kind of change the next program, be it
+    a prefill, a continuation or the window, leaves the device table
+    equal to the host's (checked at every program by _watch_tables), a
+    released slot's row is zero there before its pages are written for
+    another, and every request still reads as the one-request engine's."""
+    cfg, params = setup
+    eng, seen, want, *rest = globals()[f"_event_{event}"](cfg, params)
+    out = dict(rest[0]) if rest else {}
+    out.update(_drain(eng))
+    for rid, (toks, max_new) in want.items():
+        assert out[rid] == _ref(cfg, params, toks, max_new), rid
+    assert "_decode_impl" in seen and len(seen) > 2
+    # drained: every row is released on the host (the device's last rows
+    # wait for a next program that an idle engine never dispatches)
+    assert not eng.cache_backend._tables.any()

@@ -385,3 +385,156 @@ def test_the_sampler_s_scope():
         jax.random.PRNGKey(0), jnp.zeros((2, 8)), o,
         jnp.full((2,), 8, jnp.int32), o, z).as_text(debug_info=True)
     assert "/sample/" in text and "sample" in tracereport.DEVICE_SCOPES
+
+
+# ---- slot state reaches the device as arguments (slot_uploads) -------------
+
+FLAVOURS = ("dense", "paged", "eva", "spec-paged")
+_EVA_HF = dict(
+    attention_class="eva", chunk_size=4, window_size=32, hidden_size=64,
+    intermediate_size=128, num_attention_heads=4, num_key_value_heads=4,
+    num_hidden_layers=2, num_pred_heads=3, vocab_size=256,
+    rms_norm_eps=1e-5, rope_theta=100000, model_type="evabyte",
+    norm_add_unit_offset=True, fp32_skip_add=True,
+    max_position_embeddings=512, tie_word_embeddings=False,
+)
+
+
+def _flavour(model, flavour, reg, **kw):
+    """-> (engine, tokens a page or None, pages a slot). Two slots, so
+    that requests churn, over a pool that holds both at their largest
+    (no admission is put back); every overlap on, as the benchmark's
+    cells."""
+    cfg, params = model
+    kw = dict(dict(n_slots=2, decode_ticks=TICKS, registry=reg,
+                   overlap_decode=True, overlap_prefill=True), **kw)
+    if flavour == "dense":
+        return engine_class("dense")(cfg, params, max_len=96, **kw), None, 6
+    if flavour == "paged":
+        return engine_class("paged")(
+            cfg, params, max_len=96, block_size=16, pool_tokens=192,
+            cache_backend="paged", **kw), 16, 6
+    if flavour == "eva":
+        import types
+
+        from shellac_tpu.models.convert import config_from_hf
+
+        ecfg = config_from_hf(types.SimpleNamespace(**_EVA_HF)).replace(
+            dtype="float32", param_dtype="float32", remat=False)
+        eparams = transformer.init_params(ecfg, jax.random.PRNGKey(0))
+        return engine_class("eva")(
+            ecfg, eparams, max_len=160, pool_tokens=320,
+            cache_backend="eva", **kw), 32, 5
+    # The speculative engine refuses both overlaps (its round has no
+    # sync to defer): it runs the strict ordering.
+    kw.update(overlap_decode=False, overlap_prefill=False, decode_ticks=1)
+    return engine_class("paged", speculative=True)(
+        cfg, params, cfg, params, gamma=2, max_len=96, block_size=16,
+        pool_tokens=192, cache_backend="paged", **kw), 16, 6
+
+
+def _quiet(rec):
+    """A step that neither admitted, settled, released nor grew a slot."""
+    return not {sp[0] for sp in rec.spans} & {
+        "engine.admit", "engine.settle_prefills", "cache.release_slot",
+        "cache.ensure_blocks"}
+
+
+@pytest.mark.parametrize("flavour", FLAVOURS)
+def test_admission_compiles_and_dispatches_nothing_of_its_own(model, flavour):
+    """Past the first admission and the first window a step compiles
+    NOTHING, whatever page count a request reserves (1 to the most a
+    slot can hold): a slot's table row, settings and carried vectors
+    are arguments of the prefill and the window, not programs of their
+    own (one scatter shape a page count, before). And a step hands its
+    programs a host array of slot state only around an admission, a
+    settle, a release or growth: a release made after the step's window
+    went out rides the next step's first program, so the step after
+    counts too."""
+    reg = Registry()
+    eng, page, pages = _flavour(model, flavour, reg)
+    vocab, prompt = eng.cfg.vocab_size, 5
+    slack = 1 + eng._footprint_slack
+    rng = np.random.default_rng(0)
+
+    def request(rid, n_pages):
+        # a footprint of exactly n_pages pages (16-token pages where
+        # the backend has none)
+        max_new = n_pages * (page or 16) - prompt - slack
+        return rid, rng.integers(0, vocab, size=prompt), max_new
+
+    _drive(eng, [request("warm", 1)])
+    warm = len(reg.step_records)
+    outs = _drive(eng, [request(p, p) for p in range(1, pages + 1)]
+                  + [request(("again", p), p) for p in (pages, 1, 2)])
+    assert len(outs) == pages + 3
+    recs = list(reg.step_records)[warm:]
+    assert len(recs) > pages
+    assert [r.counts["compiles"] for r in recs] == [0] * len(recs)
+    if page is not None:
+        grown = {sp[4]["pages"] for r in recs for sp in r.spans
+                 if sp[0] == "cache.ensure_blocks"}
+        assert grown == set(range(1, pages + 1))
+    quiet = [r.counts["slot_uploads"] for prev, r in zip(recs, recs[1:])
+             if _quiet(prev) and _quiet(r)]
+    assert quiet and not any(quiet)
+    busy = sum(r.counts["slot_uploads"] for r in recs)
+    # an admission arms its slot (the patch: one upload for all a window
+    # finds pending) and, on a paged pool, its row rides its prefill and
+    # its release's the next program; greedy requests of equal settings
+    # never touch the settings matrix
+    admits = sum(sp[0] == "engine.admit" for r in recs for sp in r.spans)
+    assert admits == pages + 3
+    assert 0 < busy <= admits * (3 if page is not None else 1)
+    if page is not None:
+        assert busy > admits
+    assert reg.value("shellac_engine_slot_uploads_total") == sum(
+        r.counts["slot_uploads"] for r in reg.step_records)
+
+
+def test_the_settings_matrix_goes_up_only_when_a_value_changed(model):
+    """Requests of the engine's own settings upload no settings; one
+    with another temperature uploads the matrix once, and so does the
+    plain request that takes its slot back."""
+    reg = Registry()
+    eng, _, _ = _flavour(model, "dense", reg, n_slots=1)
+    rng = np.random.default_rng(1)
+    prompt = lambda: rng.integers(0, eng.cfg.vocab_size, size=5)  # noqa: E731
+    seen = []
+    for kw in ({}, {}, {"temperature": 0.7, "seed": 3}, {}, {}):
+        eng._samp_dev is None and eng._samp_arg()  # the first upload
+        before = reg.value("shellac_engine_slot_uploads_total")
+        changed = []
+        real = eng._samp_arg
+
+        def spy():
+            changed.append(eng._samp_dev is None)
+            return real()
+
+        eng._samp_arg = spy
+        eng.submit(len(seen), prompt(), 4, **kw)
+        while eng.pending:
+            eng.step()
+        eng._samp_arg = real
+        seen.append(sum(changed))
+        assert reg.value("shellac_engine_slot_uploads_total") > before
+    assert seen == [0, 0, 1, 1, 0]
+
+
+@pytest.mark.parametrize("flavour", ["dense", "paged"])
+def test_the_only_programs_a_step_builds_are_the_engine_s(model, flavour,
+                                                          caplog):
+    """Admission, release and the window's dispatch run no eager device
+    op: once the engine is built, the only executables its steps ever
+    build are its own prefill, chunk and window programs (an
+    `x.at[i].set(v)`, a `random.split`, a `jnp.asarray` of a Python
+    scalar each build, and then dispatch every time, a tiny program of
+    their own)."""
+    eng, _, _ = _flavour(model, flavour, Registry(), prefill_chunk=8)
+    rng = np.random.default_rng(2)
+    with jax.log_compiles():
+        _drive(eng, [(i, rng.integers(0, eng.cfg.vocab_size, size=n), 5)
+                     for i, n in enumerate((5, 21, 9))])
+    built = set(re.findall(r"Compiling jit\((.*?)\)", caplog.text))
+    assert "_decode_impl" in built
+    assert built <= {"_decode_impl", "_prefill_impl", "<unknown>"}, built
