@@ -130,6 +130,20 @@ class _Request:
         return None
 
 
+def named_program(fn):
+    """`fn` with a `__name__` for jax.jit to name the program after:
+    its own, or, for a functools.partial (which has none, and would
+    compile as `jit__unknown`), that of the method it wraps."""
+    if not hasattr(fn, "__name__"):
+        fn.__name__ = fn.func.__name__
+    return fn
+
+
+def program_name(jitted) -> str:
+    """The name a device trace shows for a jitted program."""
+    return "jit_" + jitted.__name__
+
+
 def _bucket(n: int, lo: int = 16) -> int:
     b = lo
     while b < n:
@@ -151,13 +165,12 @@ class _PrefillFlight:
     array or the chunked path's list of (in-chunk scores, size,
     boundary score) pieces — both ride the ONE batched settle pull."""
 
-    __slots__ = ("slot", "req", "arrays", "t_dispatch")
+    __slots__ = ("slot", "req", "arrays")
 
     def __init__(self, slot, req, arrays):
         self.slot = slot
         self.req = req
         self.arrays = arrays  # (first, lp, tl, plp) futures
-        self.t_dispatch = time.perf_counter()
 
 
 class _DecodeWindow:
@@ -172,13 +185,12 @@ class _DecodeWindow:
     for stale generations). `ticks` is the window's decode_ticks at
     dispatch (the auto-tuner may retune between windows)."""
 
-    __slots__ = ("pairs", "ticks", "arrays", "t_dispatch")
+    __slots__ = ("pairs", "ticks", "arrays")
 
     def __init__(self, pairs, ticks, arrays):
         self.pairs = pairs      # [(slot, _Request)] active at dispatch
         self.ticks = ticks
         self.arrays = arrays    # (toks, lps, tlvs, tlis, acts) futures
-        self.t_dispatch = time.perf_counter()
 
 
 class BatchingEngine:
@@ -684,10 +696,26 @@ class BatchingEngine:
         every writer goes through kvcache.paged_write, and
         tests/test_paged_inplace.py (with test_aot_compile.py, for the
         TPU's compiler) holds the compiled decode window to no
-        pool-sized temporary, copy, transpose or per-layer slice."""
+        pool-sized temporary, copy, transpose or per-layer slice.
+
+        The program is named after the method it runs, a
+        functools.partial of one included (jax calls that `_unknown`):
+        `jit_<name>` is what a device trace shows and what the
+        program's launch rows say (program_name)."""
         if self._cache_sh is not None:
             jit_kw["out_shardings"] = (self._cache_sh,) + (None,) * n_tail
-        return jax.jit(fn, donate_argnums=(1,), **jit_kw)
+        return jax.jit(named_program(fn), donate_argnums=(1,), **jit_kw)
+
+    def _launch_prompt(self, kind: str, program, first, **attrs) -> None:
+        """A whole-prompt (`prefill`) or continuation (`chunk`) program
+        was just dispatched; `first` is the token it samples, the
+        output its flight keeps. `stalled_rows` is taken here: the
+        slots that hold a decoding request and sit out while the
+        program runs."""
+        if self.obs.registry.enabled:
+            self.obs.steps.launch(
+                kind, program_name(program), first,
+                stalled_rows=sum(self._active_rows()), **attrs)
 
     # ---- slot state: host writers, program-side readers -------------
 
@@ -1636,15 +1664,16 @@ class BatchingEngine:
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = req.tokens
         self._count_prefill(s, pad, True)
-        self._cache, self._key, first, lp, plp, tlv, tli = (
-            self._prefill_jit[key](
-                self.params, self._cache, jnp.asarray(padded),
-                np.array([s], np.int32), slot, self._key,
-                self._slot_samp(slot, req),
-                self.cache_backend.slot_tables(),
-                want_plp=req.prompt_logprobs,
-            )
+        program = self._prefill_jit[key]
+        self._cache, self._key, first, lp, plp, tlv, tli = program(
+            self.params, self._cache, jnp.asarray(padded),
+            np.array([s], np.int32), slot, self._key,
+            self._slot_samp(slot, req),
+            self.cache_backend.slot_tables(),
+            want_plp=req.prompt_logprobs,
         )
+        self._launch_prompt("prefill", program, first, slot=slot,
+                            bucket=pad, tokens=int(s), offset=0)
         # Prompt scoring no longer pays its own per-admission pull: the
         # device array rides the flight and lands in the ONE batched
         # settle device_get alongside the first token (SH002 history:
@@ -1720,7 +1749,8 @@ class BatchingEngine:
                 # surrounding admission bookkeeping (the settle sync
                 # has its own span — immediately below without
                 # overlap, at the next step boundary with it).
-                with steps.span("engine.prefill_dispatch") as pf:
+                with steps.span("engine.prefill_dispatch",
+                                launch=steps.next_launch) as pf:
                     arrays = self._run_prefill(i, req)
                     self._dispatch_prefill(i, req, arrays)
                 adm.set(padded_tokens=pf.get("bucket"))
@@ -1773,7 +1803,11 @@ class BatchingEngine:
             with steps.span("engine.wait_prefill"):
                 if self._prefill_hooks is not None:
                     self._prefill_hooks.before_prefill_sync(flights)
-                host = jax.device_get([fl.arrays for fl in flights])  # shellac: ignore[SH002] — THE prefill settle: one batched pull for every in-flight prefill's first token / logprob / top-K / prompt scores (the per-admission pulls this replaces each paid their own round trip); the first tokens MUST reach the host here — settle is the TTFT point and the finish check needs them
+                arrays = [fl.arrays for fl in flights]
+                # The newest flight's first token: everything
+                # dispatched before it lands with it.
+                steps.land(arrays[-1][0], arrays)
+                host = jax.device_get(arrays)  # shellac: ignore[SH002] — THE prefill settle: one batched pull for every in-flight prefill's first token / logprob / top-K / prompt scores (the per-admission pulls this replaces each paid their own round trip); the first tokens MUST reach the host here — settle is the TTFT point and the finish check needs them
             for fl, (first, lp, tl, plp) in zip(flights, host):
                 if self._slots[fl.slot] is not fl.req:
                     continue
@@ -1890,7 +1924,7 @@ class BatchingEngine:
             # One chunk program's dispatch (a final chunk's inline
             # settle has its own span, after this one).
             with steps.span("engine.prefill_dispatch", slot=slot,
-                            offset=int(off)):
+                            offset=int(off), launch=steps.next_launch):
                 # (numpy, like every small host argument below: jnp
                 # would dispatch a program to convert a Python scalar)
                 boundary = np.int32(0 if final else req.tokens[off + s])
@@ -1962,6 +1996,9 @@ class BatchingEngine:
             self._key, samp, boundary_next,
             self.cache_backend.slot_tables(),
         )
+        self._launch_prompt("chunk", program, rest[0], slot=slot,
+                            bucket=tokens.shape[1],
+                            tokens=int(chunk_len[0]), offset=int(offset[0]))
         return rest
 
     def _chunk_prefill_impl(self, params, cache, tokens, chunk_len, offset,
@@ -2216,9 +2253,10 @@ class BatchingEngine:
         changed), the PRNG key is split inside it, and the vectors the
         window carries are rebound from its outputs, so admissions and
         releases that run before the sync compose in dispatch order."""
-        with self.obs.steps.span("engine.dispatch_window",
-                                 ticks=self.decode_ticks,
-                                 rows=sum(active_rows)):
+        steps = self.obs.steps
+        n_rows = sum(active_rows)
+        with steps.span("engine.dispatch_window", ticks=self.decode_ticks,
+                        rows=n_rows, launch=steps.next_launch):
             if self._decode is None:
                 self._decode = self._jit_cache_program(
                     self._decode_impl, 8,
@@ -2262,6 +2300,8 @@ class BatchingEngine:
             )
             if use_pen:
                 self._scounts = counts
+            steps.launch("window", program_name(self._decode), acts,
+                         ticks=self.decode_ticks, rows=n_rows)
             w = _DecodeWindow(
                 pairs=[(i, self._slots[i])
                        for i in range(self.n_slots) if active_rows[i]],
@@ -2290,15 +2330,10 @@ class BatchingEngine:
         with steps.span("engine.wait_window"):
             if self._window_hooks is not None:
                 self._window_hooks.before_sync(w)
+            steps.land(w.arrays[4], w.arrays)
             host_toks, host_lps, host_tlv, host_tli, host_acts = (
                 jax.device_get(w.arrays)  # shellac: ignore[SH002] — the decode window's ONE packed sync; everything the host needs arrives in this single transfer
             )
-        # Window wall time, dispatch to results-on-host: under
-        # overlapped dispatch this spans the host work interleaved with
-        # the window — the overlapped reality, not the serial span.
-        self.obs.decode_window_seconds.observe(
-            time.perf_counter() - w.t_dispatch
-        )
         # Device-side stop decisions arrive as per-tick validity flags;
         # valid ticks are a prefix (done is sticky), so each slot's
         # token list is a slice, not a scan.
@@ -2544,6 +2579,7 @@ class BatchingEngine:
             jax.device_get(self._windows.popleft().arrays)
         while self._pflights:
             jax.device_get(self._pflights.pop().arrays)
+        self.obs.steps.drop_launches()
         dropped = []
         for req in self._queue:
             dropped.append(req.rid)
@@ -3094,7 +3130,7 @@ class PagedBatchingEngine(BatchingEngine):
                               for f in pool_fields),
                         None, None, None,
                     )
-                fn = jax.jit(impl, **jit_kw)
+                fn = jax.jit(named_program(impl), **jit_kw)
                 self._beam_jit[jit_key] = fn
             pools, out, norm, lens = fn(
                 self.params,

@@ -53,7 +53,6 @@ no upstream speculative serving engine to cite.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict
 
 import jax
@@ -65,6 +64,8 @@ from shellac_tpu.inference.batching import (
     BatchingEngine,
     PagedBatchingEngine,
     _bucket,
+    named_program,
+    program_name,
 )
 from shellac_tpu.inference.cache import CacheBackend, DenseBackend
 from shellac_tpu.models import transformer
@@ -385,8 +386,8 @@ class _SpecDecodeMixin:
                 # Same donation contract as the draft prefill:
                 # self._dcache is rebound from the result right below.
                 self._draft_chunk_jit[jkey] = jax.jit(
-                    functools.partial(self._draft_chunk_impl,
-                                      fresh=dfresh),
+                    named_program(functools.partial(
+                        self._draft_chunk_impl, fresh=dfresh)),
                     donate_argnums=(1,), **jit_kw,
                 )
             self._dcache = self._draft_chunk_jit[jkey](
@@ -644,9 +645,9 @@ class _SpecDecodeMixin:
 
     def _decode_tokens(self, active_rows):
         steps = self.obs.steps
-        t0 = time.perf_counter()
+        n_rows = sum(active_rows)
         with steps.span("engine.dispatch_window", ticks=self.gamma + 1,
-                        rows=sum(active_rows)):
+                        rows=n_rows, launch=steps.next_launch):
             # Backend backstop for the round's write span (paged: grow
             # tables to cover cur + gamma positions; admission already
             # reserved the full slack footprint, so this is the same
@@ -679,14 +680,17 @@ class _SpecDecodeMixin:
                 else self._zero_bias_row,
                 use_bias=use_bias, use_seed=use_seed,
             )
+            # The whole round (draft steps and the verify pass) is one
+            # program, so one launch.
+            steps.launch("window", program_name(self._spec_round), counts,
+                         ticks=self.gamma + 1, rows=n_rows)
         # The one host sync. The base engine's window instruments live
         # in _sync_window, which this override replaces: report the
         # verify round as the decode window it is.
         with steps.span("engine.wait_window"):
-            em, cnt, host_lps, host_tlv, host_tli = jax.device_get(  # shellac: ignore[SH002] — the verify round's ONE packed sync (acceptance counts must reach the host before the next round)
-                (emitted, counts, lps, tlv, tli)
-            )
-        self.obs.decode_window_seconds.observe(time.perf_counter() - t0)
+            arrays = (emitted, counts, lps, tlv, tli)
+            steps.land(counts, arrays)
+            em, cnt, host_lps, host_tlv, host_tli = jax.device_get(arrays)  # shellac: ignore[SH002] — the verify round's ONE packed sync (acceptance counts must reach the host before the next round)
         # A round computes gamma + 1 positions a slot and keeps
         # `counts` of them.
         steps.count(decode_slot_ticks=(self.gamma + 1) * self.n_slots,

@@ -84,6 +84,7 @@ from shellac_tpu.obs.spool import (
     spool_path,
 )
 from shellac_tpu.obs.trace import (
+    LAUNCH_KINDS,
     SPAN_PHASE,
     STEP_COUNTS,
     STEP_PHASES,
@@ -124,6 +125,7 @@ __all__ = [
     "STEP_PHASES",
     "SPAN_PHASE",
     "STEP_COUNTS",
+    "LAUNCH_KINDS",
     "StepRecord",
     "StepTrace",
     "ParsedMetrics",

@@ -15,8 +15,13 @@ step that did work as a tree of spans (`engine.step` down to the
 `time.perf_counter_ns()`, mirrors each span as a
 `jax.profiler.TraceAnnotation` so a profiler capture shows it beside
 the device operations, and derives `shellac_step_phase_seconds` from
-the closed spans. Finished records land in a bounded ring on the
-registry (`Registry.step_records`), which outlives the engine.
+the closed spans. It also keeps the engine's own device timeline:
+every jitted program a step dispatches is a *launch*, a numbered row
+stamped when the program is found finished at a pull the step makes
+anyway (`StepTrace.launch` / `land`), which gives each program's
+device time without a profiler. Finished records land in a bounded
+ring on the registry (`Registry.step_records`), which outlives the
+engine.
 
 `ServeMetrics` / `EngineMetrics` bundle the instruments each layer
 writes so the metric names and bucket layouts are defined exactly once;
@@ -26,6 +31,7 @@ both are cheap to construct repeatedly over the same registry
 
 from __future__ import annotations
 
+import collections
 import time
 import weakref
 from typing import Any, Dict, List, Optional
@@ -113,6 +119,14 @@ STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "eva_window_rows", "eva_summary_rows",
                "dsa_index_rows", "dsa_selected_rows",
                "loop_passes", "loop_kv_rows", "slot_uploads")
+
+#: What a launch row calls the program it stands for (the `kind` label
+#: of shellac_launch_device_seconds and shellac_engine_launches_total):
+#: `prefill`, the whole-prompt program (a fresh scratch scattered into
+#: a slot); `chunk`, the continuation program (a chunk of a long
+#: prompt, or a suffix behind a matched prefix: it writes at an
+#: offset); `window`, the decode window (a speculative round is one).
+LAUNCH_KINDS = ("prefill", "chunk", "window")
 
 #: Request outcomes (the `outcome` label of shellac_requests_total).
 #: ok: completed; shed: deadline expired before prefill; cancelled:
@@ -665,16 +679,22 @@ class StepRecord:
     children) and its work counts (`STEP_COUNTS`). `root` indexes the
     `engine.step` span; spans before it, and any with parent -1, were
     recorded outside the step's tree (`engine.submit`, a cancel's
-    `cache.release_slot`)."""
+    `cache.release_slot`). `launches` are the programs the step
+    dispatched, in dispatch order: rows `[seq, kind, program,
+    dispatched_ns, busy_from_ns, done_ns, late, attrs]` on the same
+    clock (see StepTrace.launch). A row is stamped in place when its
+    program lands, which is usually a step later: until then
+    `done_ns` is 0."""
 
-    __slots__ = ("step", "root", "spans", "counts")
+    __slots__ = ("step", "root", "spans", "counts", "launches")
 
     def __init__(self, step: int, root: int, spans: List[list],
-                 counts: Dict[str, float]):
+                 counts: Dict[str, float], launches: List[list]):
         self.step = step
         self.root = root
         self.spans = spans
         self.counts = counts
+        self.launches = launches
 
     @property
     def start_ns(self) -> int:
@@ -719,15 +739,20 @@ class StepTrace:
     commits a StepRecord to the registry's ring, observing the step's
     phases and counts into the histograms and counters, or, for a step
     that did nothing, drops its spans. Spans closed outside a step
-    wait for the next record. With the registry disabled every method
-    returns after one attribute check and nothing is recorded."""
+    wait for the next record, as do the launch rows of a dropped step.
+    `launch` numbers a program just dispatched and queues one of its
+    outputs; `land` stamps the queued programs a pull is about to wait
+    for. With the registry disabled every method returns after one
+    attribute check, nothing is recorded and nothing is queued."""
 
     def __init__(self, metrics: "EngineMetrics"):
         from jax.profiler import TraceAnnotation
+        from jax.tree_util import tree_leaves
 
         self._m = metrics
         self._reg = metrics.registry
         self._annotation = TraceAnnotation
+        self._tree_leaves = tree_leaves
         self._spans: List[list] = []
         self._stack: List[int] = []
         self._counts = dict.fromkeys(STEP_COUNTS, 0)
@@ -735,6 +760,14 @@ class StepTrace:
         self._n_steps = 0
         self._compiles_seen = (metrics.compiles.value,
                                metrics.compile_seconds.value)
+        # Launches: the rows of the step being recorded, the (row,
+        # handle) pairs not yet landed in dispatch order, and what the
+        # last landing left for the next (its done_ns, whether it was
+        # late; None: no predecessor this recorder knows of).
+        self._launches: List[list] = []
+        self._inflight: collections.deque = collections.deque()
+        self._n_launches = 0
+        self._landed: Optional[tuple] = None
 
     # ---- spans -------------------------------------------------------
 
@@ -753,6 +786,97 @@ class StepTrace:
             return
         for k, v in amounts.items():
             self._counts[k] += v
+
+    # ---- launches ----------------------------------------------------
+
+    @property
+    def next_launch(self) -> int:
+        """The number the next launch will get. A dispatch span is
+        opened with `launch=` this: an annotation's attributes are
+        fixed when it is entered, and so the number is on the
+        profiler's host plane as well as in the ring."""
+        return self._n_launches + 1
+
+    def launch(self, kind: str, program: str, handle, **attrs) -> None:
+        """A jitted engine program was just dispatched (called inside
+        its dispatch span, right after the call returned). `kind` is
+        one of LAUNCH_KINDS, `program` the name a device trace shows
+        for it, `handle` ONE small output of it that the engine keeps
+        anyway (the first token, the window's validity flags; never the
+        cache) and `attrs` what the host knows of the work (`bucket`,
+        `tokens`, `offset`, `slot`, `stalled_rows`; `ticks`, `rows`).
+
+        The launch gets the engine's next sequence number (the one its
+        dispatch span was opened with: `next_launch`) and a row `[seq,
+        kind, program, dispatched_ns, busy_from_ns, done_ns, late,
+        attrs]` in the step's record; `busy_from_ns`, `done_ns` and
+        `late` are stamped by `land`."""
+        if not self._reg.enabled:
+            return
+        self._n_launches += 1
+        row = [self._n_launches, kind, program, time.perf_counter_ns(),
+               0, 0, False, attrs]
+        self._launches.append(row)
+        self._inflight.append((row, handle))
+        self._m.launches.labels(kind=kind).inc()
+
+    def land(self, handle, pull) -> None:
+        """The engine is about to pull `pull` (the arrays of its one
+        device_get), the results of the launch whose handle this is
+        (called inside `engine.wait_prefill` / `engine.wait_window`).
+        Their copies to the host are started first, as device_get
+        itself starts them before it waits: waiting here first would
+        put a transfer's round trip behind the program's end. Then
+        every queued launch up to and including that one is landed,
+        oldest first: `late` = its handle was ready when the host first
+        looked (the program finished at some earlier moment: `done_ns`
+        is then an upper bound), wait for the handle, `done_ns` = now,
+        `busy_from_ns` = the later of its predecessor's `done_ns` and
+        its own `dispatched_ns`. One chip runs the engine's programs in
+        dispatch order, so all of these precede the pulled program on
+        the device: nothing is waited for that the pull would not have
+        waited for. A launch is SOUND when neither it nor its
+        predecessor was late; only then is `done_ns - busy_from_ns` its
+        device time. A handle not in the queue (recorded under another
+        registry, or landed by an earlier pull) lands nothing."""
+        q = self._inflight
+        if not q or not any(h is handle for _, h in q):
+            return
+        for leaf in self._tree_leaves(pull):
+            if hasattr(leaf, "copy_to_host_async"):
+                leaf.copy_to_host_async()
+        m = self._m
+        while True:
+            row, h = q.popleft()
+            late = bool(h.is_ready())
+            h.block_until_ready()
+            done = time.perf_counter_ns()
+            busy_from, sound = row[3], False
+            if self._landed is not None:
+                prev_done, prev_late = self._landed
+                sound = not (late or prev_late)
+                if prev_done >= busy_from:
+                    busy_from = prev_done
+                else:
+                    # The device had nothing queued from the moment the
+                    # predecessor finished until this dispatch.
+                    m.device_drained.inc((busy_from - prev_done) * 1e-9)
+            row[4], row[5], row[6] = busy_from, done, late
+            self._landed = (done, late)
+            if late:
+                m.launches_late.inc()
+            if sound:
+                m.launch_device.labels(kind=row[1]).observe(
+                    (done - busy_from) * 1e-9)
+            if h is handle:
+                return
+
+    def drop_launches(self) -> None:
+        """Forget the queued launches (abort_all: their results are
+        discarded unseen). Their rows keep `done_ns` 0, and the next
+        launch to land has no known predecessor."""
+        self._inflight.clear()
+        self._landed = None
 
     # ---- step boundaries ---------------------------------------------
 
@@ -779,7 +903,8 @@ class StepTrace:
         counts["compile_s"] = seen[1] - self._compiles_seen[1]
         self._compiles_seen = seen
         spans, self._spans = self._spans, []
-        rec = StepRecord(self._n_steps, i, spans, counts)
+        launches, self._launches = self._launches, []
+        rec = StepRecord(self._n_steps, i, spans, counts, launches)
         self._reg.step_records.append(rec)
         for phase, v in rec.phases().items():
             m.step_phase.labels(phase=phase).observe(v)
@@ -789,11 +914,6 @@ class StepTrace:
             # and overlap exists to hide.
             wall = (rec.end_ns - rec.start_ns) * 1e-9
             m.host_overhead.observe(max(0.0, wall - rec.blocked_s()))
-        if "engine.prefill_dispatch" in names:
-            # A step that ran a prefill or chunk program: its whole
-            # fill section, inline syncs included.
-            fill = next(sp for sp in spans[i:] if sp[0] == "engine.fill")
-            m.prefill_seconds.observe((fill[2] - fill[1]) * 1e-9)
         for k, c in m.step_counters.items():
             if counts[k]:
                 c.inc(counts[k])
@@ -809,19 +929,21 @@ class EngineMetrics:
     def __init__(self, registry: Registry):
         self.registry = registry
         h, g = registry.histogram, registry.gauge
-        self.prefill_seconds = h(
-            "shellac_prefill_seconds",
-            "Wall time of one engine step's prefill section (all "
-            "prefill/chunk programs it ran)",
+        self.launch_device = h(
+            "shellac_launch_device_seconds",
+            "Device time of one engine program (kind: prefill | chunk "
+            "| window), from the engine's own timeline: the program "
+            "was found finished at a pull the step makes anyway, less "
+            "the moment its predecessor was (or its own dispatch, if "
+            "later). Sound launches only: neither it nor its "
+            "predecessor had already finished when the host looked",
+            labels=("kind",),
             buckets=LATENCY_BUCKETS,
         )
-        self.decode_window_seconds = h(
-            "shellac_decode_window_seconds",
-            "Wall time of one decode window, dispatch to results-on-"
-            "host (under overlapped dispatch this spans the host work "
-            "interleaved with the window — the overlapped reality)",
-            buckets=LATENCY_BUCKETS,
-        )
+        for kind in LAUNCH_KINDS:
+            # Every kind's series exists from the start: an engine that
+            # has timed no chunk yet exposes a count of 0, not a gap.
+            self.launch_device.labels(kind=kind)
         self.host_overhead = h(
             "shellac_decode_host_overhead_seconds",
             "Per engine step that synced a decode window: step wall "
@@ -924,6 +1046,25 @@ class EngineMetrics:
                 "growth in it or just before it",
             ),
         }
+        self.launches = c(
+            "shellac_engine_launches_total",
+            "Engine programs dispatched, by kind (prefill | chunk | "
+            "window)",
+            labels=("kind",),
+        )
+        self.launches_late = c(
+            "shellac_engine_launches_late_total",
+            "Launches whose program had already finished when the host "
+            "first looked: the host is behind the device there, and "
+            "that launch's time (and its successor's) is an upper "
+            "bound, left out of shellac_launch_device_seconds",
+        )
+        self.device_drained = c(
+            "shellac_device_drained_seconds_total",
+            "Seconds the device had nothing of the engine's queued: "
+            "from the moment a program finished to the dispatch of the "
+            "next, where the dispatch came later",
+        )
         self.compiles = c(
             "shellac_compile_events_total",
             "Executables built by this process (XLA backend compiles, "

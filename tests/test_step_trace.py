@@ -18,9 +18,11 @@ from shellac_tpu.inference.cache import engine_class
 from shellac_tpu.models import transformer
 from shellac_tpu.models.registry import get_model_config
 from shellac_tpu.obs import (
+    LAUNCH_KINDS,
     SPAN_PHASE,
     STEP_COUNTS,
     STEP_PHASES,
+    EngineMetrics,
     Registry,
     ServeMetrics,
 )
@@ -343,6 +345,9 @@ def test_span_names_reach_the_profiler_s_host_plane(model, tmp_path):
             "cache.release_slot"} <= set(seen)
     assert "slot" in seen["engine.admit"] and "ticks" in \
         seen["engine.dispatch_window"]
+    # a dispatch span carries the number of the launch it makes
+    assert int(seen["engine.dispatch_window"]["launch"]) >= 1
+    assert int(seen["engine.prefill_dispatch"]["launch"]) >= 1
 
 
 @pytest.mark.parametrize("preset,expect", [
@@ -529,12 +534,290 @@ def test_the_only_programs_a_step_builds_are_the_engine_s(model, flavour,
     build are its own prefill, chunk and window programs (an
     `x.at[i].set(v)`, a `random.split`, a `jnp.asarray` of a Python
     scalar each build, and then dispatch every time, a tiny program of
-    their own)."""
+    their own), and each has a name of its own: a chunk program jitted
+    through functools.partial is not `<unknown>`."""
     eng, _, _ = _flavour(model, flavour, Registry(), prefill_chunk=8)
     rng = np.random.default_rng(2)
     with jax.log_compiles():
         _drive(eng, [(i, rng.integers(0, eng.cfg.vocab_size, size=n), 5)
                      for i, n in enumerate((5, 21, 9))])
     built = set(re.findall(r"Compiling jit\((.*?)\)", caplog.text))
-    assert "_decode_impl" in built
-    assert built <= {"_decode_impl", "_prefill_impl", "<unknown>"}, built
+    chunk = {"dense": "_chunk_prefill_impl",
+             "paged": "_prefix_prefill_impl"}[flavour]
+    assert {"_decode_impl", chunk} <= built
+    assert built <= {"_decode_impl", "_prefill_impl", chunk}, built
+
+
+# ---- the engine's own device timeline: launch rows ------------------
+# a row: [seq, kind, program, dispatched_ns, busy_from_ns, done_ns,
+#         late, attrs]
+DISPATCH_SPANS = ("engine.prefill_dispatch", "engine.dispatch_window")
+
+
+def _rows(reg):
+    return [row for r in reg.step_records for row in r.launches]
+
+
+@pytest.mark.parametrize("combo", COMBOS, ids=IDS)
+def test_every_program_dispatched_is_a_numbered_row(runs, combo):
+    """Rows are in dispatch order, numbered from 1 without a gap, one
+    for each dispatch span, which carries the row's number and whose
+    attributes the row repeats."""
+    reg, reqs, _ = runs(combo)
+    rows = _rows(reg)
+    assert [row[0] for row in rows] == list(range(1, len(rows) + 1))
+    assert all(a[3] <= b[3] for a, b in zip(rows, rows[1:]))
+    spans = [sp for r in reg.step_records for sp in r.spans
+             if sp[0] in DISPATCH_SPANS]
+    assert [sp[4]["launch"] for sp in spans] == [row[0] for row in rows]
+    for sp, (seq, kind, program, disp, busy, done, late, attrs) in zip(spans,
+                                                                        rows):
+        assert kind in LAUNCH_KINDS and program.startswith("jit__")
+        assert sp[1] <= disp <= sp[2]          # stamped inside its span
+        if kind == "window":
+            assert sp[0] == "engine.dispatch_window"
+            assert attrs == {"ticks": TICKS, "rows": sp[4]["rows"]}
+            assert "decode" in program
+        else:
+            assert "prefill" in program
+            assert attrs["bucket"] == sp[4]["bucket"] >= attrs["tokens"] \
+                == sp[4]["tokens"]
+            assert attrs["offset"] == 0 and 0 <= attrs["slot"] < N_SLOTS
+            assert 0 <= attrs["stalled_rows"] < N_SLOTS
+    # every prompt went through one whole-prompt program
+    assert sum(row[1] == "prefill" for row in rows) == len(reqs)
+    assert reg.value("shellac_engine_launches_total",
+                     kind="prefill") == len(reqs)
+    assert reg.value("shellac_engine_launches_total", kind="window") == \
+        sum(row[1] == "window" for row in rows)
+
+
+@pytest.mark.parametrize("combo", COMBOS, ids=IDS)
+def test_a_launch_has_landed_by_the_time_its_result_is_applied(runs, combo):
+    """Landing happens inside the pulls the step makes anyway, oldest
+    first; what is never pulled (the last window of a drained run,
+    dispatched ahead and discarded) never lands."""
+    reg, _, _ = runs(combo)
+    rows = _rows(reg)
+    waits = [sp for r in reg.step_records for sp in r.spans
+             if sp[0] in ("engine.wait_window", "engine.wait_prefill")]
+    landed = [row for row in rows if row[5]]
+    assert all(row[1] == "window" for row in rows if not row[5])
+    assert len(rows) - len(landed) <= (1 if combo[0] else 0)
+    for seq, kind, _, disp, busy, done, late, _ in landed:
+        assert disp <= busy <= done
+        assert any(w[1] <= done <= w[2] for w in waits)
+    # in dispatch order: each starts where its predecessor ended, or
+    # at its own dispatch if that came later
+    for a, b in zip(landed, landed[1:]):
+        assert a[5] <= b[5] and b[4] == max(a[5], b[3])
+    # a window's tokens are applied after it landed
+    applies = [sp for r in reg.step_records for sp in r.spans
+               if sp[0] == "engine.apply_window"]
+    windows = [row for row in landed if row[1] == "window"]
+    assert len(applies) == len(windows)
+    assert all(row[5] <= sp[1] for row, sp in zip(windows, applies))
+    timed = sum(reg.value("shellac_launch_device_seconds", kind=k)
+                for k in LAUNCH_KINDS)
+    assert timed <= len(landed) - 1
+    assert reg.value("shellac_engine_launches_late_total") == \
+        sum(row[6] for row in rows)
+
+
+class _Handle:
+    """What the recorder asks of a program's output."""
+
+    def __init__(self, ready):
+        self.ready, self.waited = ready, 0
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.waited += 1
+
+
+def test_a_launch_found_ready_is_late_and_its_successor_is_not_timed():
+    reg = Registry()
+    steps = EngineMetrics(reg).steps
+    a, b, c, d, e = (_Handle(r) for r in (False, True, False, False, False))
+    steps.begin_step()
+    with steps.span("engine.dispatch_window", launch=steps.next_launch) as sp:
+        steps.launch("window", "jit__decode_impl", a, ticks=2, rows=1)
+    assert sp.get("launch") == 1 and steps.next_launch == 2
+    for h in (b, c):
+        steps.launch("chunk", "jit__chunk_prefill_impl", h, tokens=8)
+    steps.launch("window", "jit__decode_impl", d, ticks=2, rows=1)
+    steps.launch("window", "jit__decode_impl", e, ticks=2, rows=1)
+    steps.land(_Handle(False), ())      # not a queued one: nothing lands
+    assert not any(h.waited for h in (a, b, c, d, e))
+    steps.land(d, (d,))                 # everything up to d, not e
+    assert [h.waited for h in (a, b, c, d, e)] == [1, 1, 1, 1, 0]
+    steps.end_step(True)
+    rows = reg.step_records[-1].launches
+    assert [row[0] for row in rows] == [1, 2, 3, 4, 5]
+    assert [row[6] for row in rows] == [False, True, False, False, False]
+    assert all(row[3] <= row[4] <= row[5] for row in rows[:4])
+    assert rows[4][4] == rows[4][5] == 0        # e: not landed yet
+    # a has no predecessor, b was found ready, c follows b: only d is
+    # timed
+    assert reg.value("shellac_launch_device_seconds", kind="window") == 1
+    assert reg.value("shellac_launch_device_seconds", kind="chunk") == 0
+    assert reg.value("shellac_engine_launches_late_total") == 1
+    assert reg.value("shellac_engine_launches_total", kind="window") == 3
+    steps.land(e, (e,))                 # stamped in the ring, in place
+    assert rows[4][5] >= rows[4][4] == rows[3][5]
+    assert reg.value("shellac_launch_device_seconds", kind="window") == 2
+    # the rows of a step that is dropped ride in the next record, and
+    # dropping the queue leaves the next launch without a predecessor
+    steps.begin_step()
+    steps.launch("window", "jit__decode_impl", _Handle(False), ticks=2, rows=1)
+    steps.end_step(False)
+    f = _Handle(False)
+    steps.drop_launches()
+    steps.begin_step()
+    steps.launch("window", "jit__decode_impl", f, ticks=2, rows=1)
+    steps.land(f, (f,))
+    steps.end_step(True)
+    assert [row[0] for row in reg.step_records[-1].launches] == [6, 7]
+    assert reg.step_records[-1].launches[0][5] == 0
+    assert reg.value("shellac_launch_device_seconds", kind="window") == 2
+
+
+def test_drained_time_is_what_lies_between_a_landing_and_a_later_dispatch(
+        model):
+    """While a window is always in flight the device is never drained
+    (the next is dispatched before the last is found finished); across
+    an idle engine it is, for as long as the host stayed away."""
+    import time
+
+    cfg = model[0]
+    reg = Registry()
+    eng = _engine(model, "dense", reg, overlap_decode=True,
+                  overlap_prefill=True)
+    eng.submit("a", np.arange(9) % cfg.vocab_size, 30)
+    for _ in range(3):
+        eng.step()
+    before = reg.value("shellac_device_drained_seconds_total")
+    n0 = len(_rows(reg))
+    for _ in range(6):
+        eng.step()
+    rows = _rows(reg)[n0:]
+    assert len(rows) == 6 and all(row[1] == "window" for row in rows)
+    assert reg.value("shellac_device_drained_seconds_total") == before
+    while eng.pending:
+        eng.step()
+    eng.step()                          # nothing to do
+    drained = reg.value("shellac_device_drained_seconds_total")
+    time.sleep(0.05)
+    eng.submit("b", np.arange(7) % cfg.vocab_size, 3)
+    while eng.pending:
+        eng.step()
+    assert reg.value("shellac_device_drained_seconds_total") >= \
+        drained + 0.05
+
+
+@pytest.mark.parametrize("backend,program", [
+    ("dense", "jit__chunk_prefill_impl"),
+    ("paged", "jit__prefix_prefill_impl"),
+])
+def test_a_chunked_prompt_is_one_row_a_chunk(model, backend, program):
+    cfg = model[0]
+    reg = Registry()
+    eng = _engine(model, backend, reg, prefill_chunk=8, overlap_decode=True,
+                  overlap_prefill=True)
+    eng.submit("short", np.arange(5) % cfg.vocab_size, 12)
+    eng.step()
+    eng.step()
+    eng.submit("long", np.arange(21) % cfg.vocab_size, 3)
+    while eng.pending:
+        eng.step()
+    chunks = [row for row in _rows(reg) if row[1] == "chunk"]
+    assert [(row[7]["offset"], row[7]["tokens"], row[7]["bucket"])
+            for row in chunks] == [(0, 8, 16), (8, 8, 16), (16, 5, 16)]
+    assert {row[2] for row in chunks} == {program}
+    assert {row[7]["slot"] for row in chunks} == {1}
+    # the short request was decoding and sat the chunks out
+    assert all(row[7]["stalled_rows"] == 1 for row in chunks)
+    assert all(row[5] for row in chunks)        # all landed at the settle
+    spans = [sp for r in reg.step_records for sp in r.spans
+             if sp[0] == "engine.prefill_dispatch" and "offset" in sp[4]]
+    assert [sp[4]["launch"] for sp in spans] == [row[0] for row in chunks]
+
+
+def test_the_speculative_round_is_one_row(model):
+    from shellac_tpu.inference.spec_batching import SpeculativeBatchingEngine
+
+    cfg, params = model
+    reg = Registry()
+    eng = SpeculativeBatchingEngine(cfg, params, cfg, params, gamma=2,
+                                    n_slots=2, max_len=64, registry=reg)
+    _drive(eng, _requests(cfg, n=3, seed=1))
+    rows = _rows(reg)
+    rounds = [row for row in rows if row[1] == "window"]
+    n_waits = sum(sp[0] == "engine.wait_window"
+                  for r in reg.step_records for sp in r.spans)
+    assert len(rounds) == n_waits > 0
+    assert all(row[2] == "jit__spec_round_impl" and row[7]["ticks"] == 3
+               and row[5] for row in rounds)
+    assert sum(row[1] == "prefill" for row in rows) == 3
+
+
+def test_a_disabled_registry_keeps_no_queue_and_never_waits(model,
+                                                           monkeypatch):
+    """With the recorder off the engine makes no device call of the
+    recorder's: no `is_ready`, no `block_until_ready`, nothing queued."""
+    calls = collections.Counter()
+    array = type(jnp.zeros(()))
+    for name in ("is_ready", "block_until_ready"):
+        real = getattr(array, name)
+
+        def counting(self, _real=real, _name=name):
+            calls[_name] += 1
+            return _real(self)
+
+        monkeypatch.setattr(array, name, counting)
+    off = Registry(enabled=False)
+    eng = _engine(model, "paged", off, overlap_decode=True,
+                  overlap_prefill=True, prefill_chunk=16)
+    assert len(_drive(eng, _requests(model[0]))) == 7
+    assert not calls and not eng.obs.steps._inflight
+    assert not eng.obs.steps._launches and eng.obs.steps.next_launch == 1
+    on = Registry()
+    eng = _engine(model, "paged", on, overlap_decode=True,
+                  overlap_prefill=True, prefill_chunk=16)
+    _drive(eng, _requests(model[0]))
+    landed = sum(1 for row in _rows(on) if row[5])
+    assert calls["block_until_ready"] == calls["is_ready"] == landed > 0
+
+
+def test_abort_all_forgets_the_queued_launches(model):
+    cfg = model[0]
+    reg = Registry()
+    eng = _engine(model, "dense", reg, overlap_decode=True,
+                  overlap_prefill=True)
+    eng.submit("a", np.arange(9) % cfg.vocab_size, 30)
+    for _ in range(3):
+        eng.step()
+    assert eng.obs.steps._inflight
+    eng.abort_all()
+    assert not eng.obs.steps._inflight
+    unlanded = [row[0] for row in _rows(reg) if not row[5]]
+    assert unlanded
+    eng.submit("b", np.arange(7) % cfg.vocab_size, 4)
+    while eng.pending:
+        eng.step()
+    rows = _rows(reg)
+    # what was dropped stays unstamped; what came after landed
+    assert [row[0] for row in rows if not row[5]][:len(unlanded)] == unlanded
+    assert any(row[5] for row in rows if row[0] > unlanded[-1])
+
+
+@pytest.mark.parametrize("combo", [c for c in COMBOS if c[0] == c[1]],
+                         ids=[i for c, i in zip(COMBOS, IDS) if c[0] == c[1]])
+def test_streams_are_the_same_with_the_recorder_off(model, runs, combo):
+    _, reqs, outs = runs(combo)
+    od, op, backend = combo
+    eng = _engine(model, backend, Registry(enabled=False), overlap_decode=od,
+                  overlap_prefill=op)
+    assert _drive(eng, reqs) == outs
