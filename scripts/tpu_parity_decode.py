@@ -485,6 +485,83 @@ def sink_cases(checks):
         check(f"flash bwd sinks {name}", a, b, atol=1.5e-1, checks=checks)
 
 
+def eva_decode_cases(checks):
+    """ops/eva_attention.py::eva_decode_kernel, compiled, against the
+    XLA form: tests/test_eva.py's cases at the evabyte cell's sizes (W
+    2048 in blocks of 128, 32 heads x 128, pages of 128 pooled rows,
+    two layers of stacks, layer 1 read). Every ring row at or past a
+    slot's count, every page no slot owns and all of layer 0 hold NaN
+    on the kernel's side: the copies are the kernel's own, across grid
+    steps, which interpret mode cannot fault."""
+    from shellac_tpu.ops.eva_attention import (
+        eva_decode_attention,
+        eva_decode_kernel,
+        eva_ring_block,
+    )
+
+    L, W, S, H, D, P, R = 2, 2048, 4, 32, 128, 9, 128
+    blk = eva_ring_block(W)
+    cases = [
+        ("one row, no page", (1,), (0,), [[1, 2, 3, 4]], (0,)),
+        ("a block's last row", (blk,), (1,), [[1, 2, 3, 4]], (1,)),
+        ("a block's first row", (blk + 1,), (1,), [[1, 2, 3, 4]], (2,)),
+        ("the whole ring, no page", (W,), (0,), [[1, 2, 3, 4]], (3,)),
+        ("every page of its row", (37,), (4,), [[8, 6, 4, 2]], (0,)),
+        ("tables interleave", (70, 1500), (2, 3),
+         [[1, 3, 5, 7], [2, 4, 6, 8]], (1, 0)),
+        ("stale entries", (900, 3), (1, 2), [[5, 6, 8, 8], [6, 7, 5, 1]],
+         (2, 3)),
+        ("four slots", (W, 1, 1025, blk), (0, 4, 2, 1),
+         [[1, 1, 1, 1], [2, 3, 4, 5], [6, 7, 1, 1], [8, 2, 2, 2]],
+         (3, 1, 0, 2)),
+    ]
+    rng = np.random.default_rng(36)
+    ring = [rng.standard_normal((L, W, S, H, D), np.float32) for _ in range(2)]
+    pool = [rng.standard_normal((L, H, P, R, D), np.float32) for _ in range(2)]
+    layer = 1
+
+    def inputs(dtype, n_exact, n_pages, tables, cols):
+        """(kernel's arguments, the XLA form's, the slots' columns): the
+        kernel's stacks hold NaN wherever no slot may read."""
+        b = len(n_exact)
+        q = jnp.asarray(rng.standard_normal((b, H, D), np.float32), dtype)
+        tables = np.array(tables, np.int32)
+        n_exact, n_pages = np.array(n_exact, np.int32), np.array(n_pages, np.int32)
+        owned = np.zeros((b, P), bool)
+        live = np.zeros((L, W, S), bool)
+        for i in range(b):
+            owned[i, tables[i, :n_pages[i]]] = True
+            live[layer, :n_exact[i], cols[i]] = True
+        held = np.zeros((L, P), bool)
+        held[layer] = owned.any(axis=0)
+        bad = ([np.where(live[..., None, None], a, np.nan) for a in ring]
+               + [np.where(held[:, None, :, None, None], a, np.nan)
+                  for a in pool])
+        rk, rv, pk, pv = (jnp.asarray(a, dtype) for a in bad)
+        clean = ([jnp.asarray(a[layer][:, list(cols)], dtype) for a in ring]
+                 + [jnp.asarray(a[layer], dtype) for a in pool])
+        return ((q, rk, rv, n_exact, pk, pv, tables, n_pages),
+                (q, clean[0], clean[1], n_exact, clean[2], clean[3], owned),
+                np.array(cols, np.int32))
+
+    kernel = _jitted(eva_decode_kernel)
+
+    def exact(*a, **k):  # float32 rows multiply as float32 on this side too
+        with jax.default_matmul_precision("highest"):
+            return eva_decode_attention(*a, **k)
+
+    ref = _jitted(exact)
+    for dtype, atol in ((jnp.bfloat16, 2e-2), (jnp.float32, 1e-4)):
+        for label, n_exact, n_pages, tables, cols in cases:
+            ours, theirs, cols = inputs(dtype, n_exact, n_pages, tables, cols)
+            out = kernel(*ours, layer=np.int32(layer), cols=cols,
+                         scale=D ** -0.5, interpret=False)
+            want = ref(*theirs, scale=D ** -0.5)
+            assert np.isfinite(_host(out)).all(), label
+            check(f"eva decode {jnp.dtype(dtype).name} {label}", out, want,
+                  atol=atol, checks=checks)
+
+
 def run_all():
     """Every compiled parity check; returns the list of check names.
     Raises on the first mismatch. The caller has made sure a TPU is
@@ -498,6 +575,7 @@ def run_all():
     head_dim_64_cases(checks)
     mla_shape_cases(checks)
     sink_cases(checks)
+    eva_decode_cases(checks)
     return checks
 
 

@@ -120,10 +120,27 @@ class EvaBackend(PagedBackend):
             self.refuse("mesh")
         super().bind(engine)
 
+    def decode_attn(self) -> str:
+        """How the engine's decode program attends: through the kernel
+        that moves a slot's valid ring rows and own pages only
+        ("eva_kernel"), or through the XLA form that reads every ring
+        row and every page and masks ("xla"). The model's own rule,
+        asked with the shapes built here."""
+        from shellac_tpu.ops.eva_attention import eva_decode_path
+
+        cfg, e = self.cfg, self.cfg.eva
+        h, d, layers = cfg.n_heads, cfg.dim_per_head, cfg.cache_layers
+        return eva_decode_path(
+            (self.n_slots, h, d),
+            (layers, e.window, self.n_slots, h, d),
+            (layers, h, self.n_blocks, e.window // e.chunk, d),
+            cfg.compute_dtype, self.engine.attn_impl,
+        )
+
     def initial_stats(self) -> Dict[str, int]:
-        # No "decode_attn": eva_decode_attention reads rings and pool
-        # itself, never through paged_decode_attention.
-        return {}
+        # Non-numeric, as on the paged backend: /metrics skips it.
+        self._decode_attn = self.decode_attn()
+        return {"decode_attn": self._decode_attn}
 
     # ---- device cache construction ----------------------------------
 
@@ -210,16 +227,29 @@ class EvaBackend(PagedBackend):
     def window_counts(self, pairs, n_valid) -> Dict[str, int]:
         """Work of one synced decode window, from lengths the host
         already has: over every (slot, tick) that produced a token, the
-        exact rows and the pooled rows its query attended. A request
-        with n outputs settled has its prompt plus n - 1 positions
-        written, so the window's tick t sits at position
-        prompt + n - 1 + t."""
+        exact rows and the pooled rows its query attended, and the rows
+        the program's read path MOVED for them (`eva_read_rows`;
+        ops/eva_attention.py: the kernel a slot's valid rows in whole
+        blocks and its own pages; the XLA form every ring row a
+        slot-tick and the whole pool once a tick). A request with n
+        outputs settled has its prompt plus n - 1 positions written, so
+        the window's tick t sits at position prompt + n - 1 + t."""
+        from shellac_tpu.ops.eva_attention import eva_ring_block
+
         e = self.cfg.eva
         per_page = e.window // e.chunk
-        exact = pooled = 0
+        kernel = self._decode_attn == "eva_kernel"
+        block = eva_ring_block(e.window)
+        exact = pooled = read = ticks = 0
         for slot, req in pairs:
             first = req.tokens.size + len(req.out) - 1
             p = first + np.arange(int(n_valid[slot]))
-            exact += int((p % e.window + 1).sum())
+            n_exact = p % e.window + 1
+            exact += int(n_exact.sum())
             pooled += int((p // e.window).sum()) * per_page
-        return {"eva_window_rows": exact, "eva_summary_rows": pooled}
+            ticks = max(ticks, p.size)
+            read += (int((-(-n_exact // block) * block).sum()) if kernel
+                     else p.size * e.window)
+        read += pooled if kernel else ticks * self.n_blocks * per_page
+        return {"eva_window_rows": exact, "eva_summary_rows": pooled,
+                "eva_read_rows": read}

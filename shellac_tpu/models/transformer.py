@@ -1262,6 +1262,8 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
     from shellac_tpu.ops.eva_attention import (
         eva_attention,
         eva_decode_attention,
+        eva_decode_kernel,
+        eva_decode_path,
         eva_pool,
         eva_pool_sequence,
     )
@@ -1336,7 +1338,6 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
             Layout(major_to_minor=tuple(range(a.ndim - 1))),
         )
 
-    rk, rv = of_layer(ring[0]), of_layer(ring[1])  # (W, B, H, D)
     with jax.named_scope("eva.pool"):
         # The chunk's rows, (B, C, H, D): a gather of B x C slabs out of
         # the stack itself. (A vmapped slice of the layer's rings moved
@@ -1355,10 +1356,21 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
             only=(index % e.chunk) == e.chunk - 1,
         )
     with jax.named_scope("eva.attend"):
-        o = eva_decode_attention(
-            q[:, 0], rk, rv, at + 1, of_layer(pool[0]), of_layer(pool[1]),
-            eva_tables["owned"], scale=scale,
-        )
+        if eva_decode_path(q[:, 0].shape, ring[0].shape, pool[0].shape,
+                           ring[0].dtype, attn_impl) == "eva_kernel":
+            # The kernel takes the whole stacks and the layer's number,
+            # and copies a slot's valid rows and own pages itself.
+            o = eva_decode_kernel(
+                q[:, 0], *ring, at + 1, *pool, eva_tables["tables"],
+                eva_tables["done"], layer=layer, cols=eva_tables["slots"],
+                scale=scale,
+            )
+        else:
+            o = eva_decode_attention(
+                q[:, 0], of_layer(ring[0]), of_layer(ring[1]), at + 1,
+                of_layer(pool[0]), of_layer(pool[1]), eva_tables["owned"],
+                scale=scale,
+            )
     return o.reshape(b, 1, h * dh), (ring, pool)
 
 
@@ -2056,7 +2068,8 @@ def forward_with_cache(
             x, (ring, pool), _ = block(
                 x, lp, (ring, pool), moe_layer, attn_kind,
                 page_tables={"layer": li, "slots": cache.slots,
-                             "tables": cache.tables, "owned": owned},
+                             "tables": cache.tables, "owned": owned,
+                             "done": done},
             )
             return (x, ring, pool), None
 

@@ -95,11 +95,13 @@ SPAN_PHASE = {
 #: ran over the sorted routed rows, by ops/moe.py::moe_ffn_path; 0 on
 #: a model without experts); `compiles`/`compile_s` are what the
 #: process-wide compile listener
-#: added to the registry since the previous record; the last two are
-#: the cache backend's (`CacheBackend.window_counts`; 0 but on the 'eva'
-#: backend): over every slot-tick of a synced window that produced a
-#: token, the exact rows of its own window and the pooled rows of
-#: earlier windows that its query attended, from lengths the host has;
+#: added to the registry since the previous record; the three `eva_*`
+#: are the cache backend's (`CacheBackend.window_counts`; 0 but on the
+#: 'eva' backend): over every slot-tick of a synced window that
+#: produced a token, the exact rows of its own window and the pooled
+#: rows of earlier windows that its query attended, and the rows the
+#: decode program's read path MOVED for them (attended / read is its
+#: read efficiency), from lengths the host has;
 #: and, for a model with an indexer (cfg.dsa, the 'paged' backend; 0
 #: elsewhere), over the same slot-ticks the rows the indexer scored
 #: (the context) and the rows the query then attended (the context or
@@ -116,7 +118,7 @@ STEP_COUNTS = ("tokens_delivered", "decode_slot_ticks",
                "decode_valid_ticks", "prefill_tokens",
                "prefill_padded_tokens", "prefill_sorted_tokens",
                "compiles", "compile_s",
-               "eva_window_rows", "eva_summary_rows",
+               "eva_window_rows", "eva_summary_rows", "eva_read_rows",
                "dsa_index_rows", "dsa_selected_rows",
                "loop_passes", "loop_kv_rows", "slot_uploads")
 
@@ -1023,6 +1025,15 @@ class EngineMetrics:
                 "Padded prompt rows whose expert FFN ran as grouped "
                 "GEMMs over the sorted routed rows (0 on a model "
                 "without experts, and where the buckets run)",
+            ),
+            "eva_read_rows": c(
+                "shellac_engine_eva_read_rows_total",
+                "Ring and pooled rows the decode program's attention "
+                "moved for the slot-ticks that produced a token (the "
+                "'eva' backend; 0 elsewhere): on the kernel a slot's "
+                "valid ring rows in whole blocks and its own pages, on "
+                "the XLA form every ring row and the whole pool once a "
+                "tick",
             ),
             "loop_passes": c(
                 "shellac_engine_loop_passes_total",

@@ -140,6 +140,27 @@ def _paged_latent_decode():
     return fn, shapes
 
 
+def _eva_decode():
+    """evabyte-batch-bytes' tick: 24 slots of 32 heads x 128 against the
+    WHOLE stacks of 8 layers: rings of 2048 rows, 217 pages of 128
+    pooled rows, tables of 9 entries; the layer a scalar."""
+    from shellac_tpu.ops.eva_attention import eva_decode_kernel
+
+    layers, w, slots, heads, pages, rows, mb = 8, 2048, 24, 32, 217, 128, 9
+    ring = ((layers, w, slots, heads, D), BF16)
+    pool = ((layers, heads, pages, rows, D), BF16)
+    shapes = [((slots, heads, D), BF16), ring, ring, ((slots,), I32), pool,
+              pool, ((slots, mb), I32), ((slots,), I32), ((), I32)]
+
+    def fn(q, rk, rv, n_exact, pk, pv, tables, n_pages, layer):
+        return eva_decode_kernel(
+            q, rk, rv, n_exact, pk, pv, tables, n_pages, layer=layer,
+            cols=jnp.arange(slots, dtype=I32), scale=D ** -0.5,
+            interpret=False)
+
+    return fn, shapes
+
+
 def _rmsnorm(grad):
     from shellac_tpu.ops.norms import rms_norm_pallas
 
@@ -210,6 +231,8 @@ CASES = [
       for bs in _int8_page_sizes()],
     ("dsa-index-scores-chunk4096", lambda: _dsa_chunk("scores"), True),
     ("dsa-masked-flash-chunk4096", lambda: _dsa_chunk("attend"), True),
+    # evabyte-batch-bytes' decode attention, whole stacks in HBM.
+    ("eva-decode-w2048-page128", _eva_decode, True),
     ("rmsnorm-fwd", lambda: _rmsnorm(False), True),
     # The backward is plain XLA (vjp of the reference); it only has to
     # compile.
@@ -631,6 +654,90 @@ def test_looped_engine_programs_fit_one_chip_on_v5e(
     assert "kv.gather" not in text
     moved = pool_sized_ops(text, [pool])
     assert not moved, "\n".join(moved)
+
+
+def test_eva_engine_decode_window_moves_live_rows_only_on_v5e(
+        chip, pool_sized_ops, monkeypatch):
+    """The engine's REAL decode window for EvaByte at its published
+    widths (benchmark/configs/evabyte.json: 8 of its layers) and the
+    cell's serving numbers: 24 slots, rings of 24 x 256 MiB, 217 pages
+    of 16 MiB, described and never allocated. The tick attends through
+    the kernel, which takes the WHOLE ring and pool stacks where the
+    layer walk carries them: the program holds no temporary, copy or
+    slice of a layer's rings or pages and none of a stack (a layer of
+    rings copied in front of the call was half the tick before PR 27
+    found it in a trace), its arguments and temporaries fit the chip,
+    and its temporaries are under those of the XLA form, which holds
+    the float32 scores of every slot against every page."""
+    import re
+    import types
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from shellac_tpu.inference.cache import eva as eva_backend
+    from shellac_tpu.inference.cache import engine_class
+    from shellac_tpu.models import transformer
+    from shellac_tpu.models.convert import config_from_hf
+
+    with open(os.path.join(REPO, "benchmark", "configs", "evabyte.json")) as f:
+        hf = json.load(f)
+    serving = hf["serving"]
+    cfg = config_from_hf(types.SimpleNamespace(**hf)).replace(
+        dtype="bfloat16", param_dtype="bfloat16").validate()
+    slots, window, max_len = serving["n_slots"], cfg.eva.window, 9 * 2048
+    assert (slots, window, serving["block_size"]) == (24, 2048, 2048)
+    described = eva_backend.init_eva_cache
+
+    def describe(*a, **k):  # 9.4 GiB of zeros otherwise
+        return jax.eval_shape(lambda: described(*a, **k))
+
+    monkeypatch.setattr(eva_backend, "init_eva_cache", describe)
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    ring = (8, window, slots, 32, 128)
+    pool = (8, 32, slots * 9 + 1, window // cfg.eva.chunk, 128)
+    stack_bytes = 2 * 2 * (math.prod(ring) + math.prod(pool))
+    assert round(stack_bytes / 2 ** 30, 2) == 9.39
+    temps = {}
+    for impl, path in (("auto", "eva_kernel"), ("ref", "xla")):
+        eng = engine_class("eva")(
+            cfg, params, n_slots=slots, max_len=max_len, block_size=window,
+            pool_tokens=slots * max_len, cache_backend="eva", attn_impl=impl,
+            decode_ticks=int(serving["decode_ticks"]),
+        )
+        assert eng.stats["decode_attn"] == path
+        assert (eng._cache.k.shape, eng._cache.pk.shape) == (ring, pool)
+        fn, args, kw = _engine_program(eng, "decode", None)
+        compiled = fn.lower(shaped(params), shaped(eng._cache),
+                            *shaped(args), **kw).compile()
+        ma = compiled.memory_analysis()
+        temps[path] = ma.temp_size_in_bytes
+        if path == "xla":
+            continue
+        text = compiled.as_text()
+        assert re.search(r'custom_call_target="tpu_custom_call".*eva_decode',
+                         text)
+        held = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        assert held < 15.75 * 2 ** 30, held
+        assert ma.alias_size_in_bytes >= stack_bytes  # donated, in place
+        # What a fusion computes inside itself is never held (the
+        # summary writer reads its one chunk through a re-laid view of
+        # the pool, as on the XLA form): the instructions that hold
+        # their results are those outside the fused computations.
+        text = re.sub(r"(?ms)^%fused_computation\S* \(.*?^}\n", "", text)
+        moved = pool_sized_ops(text, [ring, pool])
+        # A materialised slice is a fusion, not a copy: nothing may
+        # have the shape of one layer's rings or pages at all.
+        layer = "|".join(",".join(map(str, s[1:])) for s in (ring, pool))
+        moved += [ln.strip()[:160] for ln in text.splitlines() if re.search(
+            rf"= \(?\w+\[(?:1,)?(?:{layer})\]\S* (?!bitcast|parameter"
+            rf"|get-tuple-element)\w", ln)]
+        assert not moved, "\n".join(moved)
+    assert temps["eva_kernel"] < temps["xla"], temps
 
 
 @pytest.mark.parametrize("build", [

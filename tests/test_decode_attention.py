@@ -383,19 +383,46 @@ def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
     # pool, which the gather reads.
     ("paged", 576, 128, "paged_kernel"),
     ("paged-int8", 576, 128, "gather"),
+    # An EVA model's ring and pool (its page is its window, 32 rows):
+    # the kernel that moves live rows only where a head fills the lanes,
+    # the XLA form over every row elsewhere.
+    ("eva", 128, 32, "eva_kernel"),
+    ("eva", 16, 32, "xla"),
 ])
 def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
                                              page, want):
     """engine.stats["decode_attn"] is the dispatcher's own verdict for
     the decode program's shapes, recorded once at construction beside
     "cache_backend" (non-numeric: /stats shows it, the /metrics mirror
-    skips it). Off the TPU every pool reads through the gather."""
+    skips it). Off the TPU every pool reads through the gather, and an
+    EVA model's state through the XLA form."""
     import shellac_tpu.ops.decode_attention as da
+    import shellac_tpu.ops.eva_attention as ea
     from shellac_tpu import get_model_config
     from shellac_tpu.config import MLAConfig
     from shellac_tpu.inference.batching import PagedBatchingEngine
     from shellac_tpu.models import transformer
 
+    if backend == "eva":
+        # 8 heads of float32: whole sublane tiles, as the kernel asks.
+        cfg = get_model_config("tiny-eva").replace(
+            n_heads=8, head_dim=head_dim, n_layers=1, dtype="float32",
+            param_dtype="float32").validate()
+        params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+
+        def build_eva():
+            return PagedBatchingEngine(cfg, params, n_slots=2, max_len=256,
+                                       cache_backend="eva")
+
+        assert build_eva().stats["decode_attn"] == "xla"
+        monkeypatch.setattr(ea, "pallas_supported", lambda: True)
+        eng = build_eva()
+        assert eng.stats["decode_attn"] == want
+        assert eng.stats["cache_backend"] == "eva"
+        c = eng._cache
+        assert ea.eva_decode_path((2, 8, head_dim), c.k.shape, c.pk.shape,
+                                  c.k.dtype) == want
+        return
     if head_dim == 576:
         cfg = get_model_config("tiny-mla").replace(
             n_layers=1, max_seq_len=512,

@@ -350,6 +350,192 @@ def test_model_and_backend_must_match(model):
             cfg, params, jnp.zeros((1, 1), jnp.int32), init_cache(cfg, 1, 64))
 
 
+# ---- (e2) the decode kernel against the XLA form -----------------------------
+#
+# ops/eva_attention.py::eva_decode_kernel, interpreted on the CPU, against
+# eva_decode_attention over the same rows. Small shapes with the kernel's
+# structure whole: 2 layers of stacks (the kernel reads layer 1), rings of
+# 64 rows copied in blocks of 16, 4 heads of 128, pages of 16 pooled rows,
+# tables of 4 entries over a pool of 9 pages (page 0 is scratch).
+
+KL, KW, KS, KH, KD, KP, KR, KMB, KBLOCK = 2, 64, 4, 4, 128, 9, 16, 4, 16
+
+# (id, valid ring rows a slot, pages a slot, table rows, ring column a slot)
+KERNEL_CASES = [
+    # One row and no page: the softmax of one score.
+    ("one-row-no-page", (1,), (0,), [[1, 2, 3, 4]], (0,)),
+    ("a-blocks-last-row", (KBLOCK,), (1,), [[1, 2, 3, 4]], (1,)),
+    ("a-blocks-first-row", (KBLOCK + 1,), (1,), [[1, 2, 3, 4]], (2,)),
+    ("the-whole-ring-no-page", (KW,), (0,), [[1, 2, 3, 4]], (3,)),
+    ("one-page", (5,), (1,), [[7, 2, 3, 4]], (0,)),
+    ("every-page-of-its-row", (5,), (KMB,), [[8, 6, 4, 2]], (0,)),
+    # Two slots whose pages alternate through the pool.
+    ("tables-interleave", (9, 40), (2, 3), [[1, 3, 5, 7], [2, 4, 6, 8]],
+     (1, 0)),
+    # Entries past a slot's count name pages it does not own (another
+    # slot's, and pages nobody owns, which hold NaN): never read.
+    ("stale-entries", (30, 3), (1, 2), [[5, 6, 8, 8], [6, 7, 5, 1]], (2, 3)),
+    # Every slot of the stacks, in another order than its columns.
+    ("four-slots", (64, 1, 33, 16), (0, 4, 2, 1),
+     [[1, 1, 1, 1], [2, 3, 4, 5], [6, 7, 1, 1], [8, 2, 2, 2]], (3, 1, 0, 2)),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n_exact,n_pages,tables,cols",
+                         [pytest.param(*c[1:], id=c[0]) for c in KERNEL_CASES])
+def test_decode_kernel_matches_the_xla_form(dtype, n_exact, n_pages, tables,
+                                            cols):
+    """The kernel computes eva_decode_attention's equations from the
+    WHOLE stacks, a layer's number, the block table and the counts; and
+    it reads nothing else: every ring row at or past a slot's count,
+    every page no slot owns, every other layer and every column no slot
+    names hold NaN on the kernel's side, and no output may see one."""
+    from shellac_tpu.ops import eva_attention as ea
+
+    rng = np.random.default_rng(len(n_exact) * 1000 + sum(n_exact))
+    b, layer, scale = len(n_exact), 1, KD ** -0.5
+    draw = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    q = draw(b, KH, KD)
+    ring = [draw(KL, KW, KS, KH, KD) for _ in range(2)]
+    pool = [draw(KL, KH, KP, KR, KD) for _ in range(2)]
+    tables = np.asarray(tables, np.int32)
+    owned = np.zeros((b, KP), bool)
+    for i in range(b):
+        owned[i, tables[i, :n_pages[i]]] = True
+    live_ring = np.zeros((KL, KW, KS), bool)
+    for i in range(b):
+        live_ring[layer, :n_exact[i], cols[i]] = True
+    live_pool = np.zeros((KL, KP), bool)
+    live_pool[layer] = owned.any(axis=0)
+    cast = lambda a: jnp.asarray(a, dtype)  # noqa: E731
+    want = ea.eva_decode_attention(
+        cast(q), *(cast(a[layer][:, list(cols)]) for a in ring),
+        jnp.asarray(n_exact, jnp.int32), *(cast(a[layer]) for a in pool),
+        jnp.asarray(owned), scale=scale)
+    poisoned = (
+        [np.where(live_ring[..., None, None], a, np.nan) for a in ring]
+        + [np.where(live_pool[:, None, :, None, None], a, np.nan)
+           for a in pool])
+    got = ea.eva_decode_kernel(
+        cast(q), cast(poisoned[0]), cast(poisoned[1]), n_exact,
+        cast(poisoned[2]), cast(poisoned[3]), tables, n_pages, layer=layer,
+        cols=cols, scale=scale, block=KBLOCK, interpret=True)
+    assert got.dtype == want.dtype and got.shape == (b, KH, KD)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    # float32: both sides hold float32 scores and sums and differ in the
+    # order they add them (the kernel 8 rows at a time with a running
+    # max, the XLA form one softmax over the row): 6e-7 at outputs up to
+    # |3|; 5e-6 leaves 8 x. bfloat16: the same sums rounded once to
+    # bfloat16, and a weight rounded to bfloat16 before its value on
+    # both sides, relative to maxima that differ by float32 rounding:
+    # one unit of bfloat16 at |2| is 2^-7 = 0.0078; two units.
+    tol = 5e-6 if dtype == jnp.float32 else 2 * 2.0 ** -7
+    np.testing.assert_allclose(got, want, atol=tol, rtol=0)
+
+
+# The cell's shapes: 24 slots, 32 heads of 128, rings of 2048, 217 pages of
+# 128 pooled rows, 8 layers.
+_Q, _RING, _POOL = (24, 32, 128), (8, 2048, 24, 32, 128), (8, 32, 217, 128, 128)
+
+# (id, q, ring, pool, dtype, what the refusal says; None: the kernel runs)
+PATH_RULE = [
+    ("the-cell-bf16", _Q, _RING, _POOL, jnp.bfloat16, None),
+    ("the-cell-float32", _Q, _RING, _POOL, jnp.float32, None),
+    ("a-short-ring-is-one-block", _Q, (8, 128, 24, 32, 128), _POOL,
+     jnp.bfloat16, None),
+    ("head-dim-64", (24, 32, 64), (8, 2048, 24, 32, 64),
+     (8, 32, 217, 128, 64), jnp.bfloat16, "128-lane"),
+    ("8-heads-of-bf16", (24, 8, 128), (8, 2048, 24, 8, 128),
+     (8, 8, 217, 128, 128), jnp.bfloat16, "8 heads"),
+    ("pages-of-8-rows-of-bf16", _Q, _RING, (8, 32, 217, 8, 128),
+     jnp.bfloat16, "8 pooled rows"),
+    ("ring-not-whole-blocks", _Q, (8, 2048 + 64, 24, 32, 128), _POOL,
+     jnp.bfloat16, "whole blocks"),
+    ("float16-rows", _Q, _RING, _POOL, jnp.float16, "float16"),
+    ("tiles-over-the-vmem-bound", (24, 128, 128), (8, 2048, 24, 128, 128),
+     (8, 128, 217, 128, 128), jnp.bfloat16, "VMEM"),
+    ("tiny-eva", (2, 4, 16), (2, 32, 2, 4, 16), (2, 4, 9, 8, 16),
+     jnp.float32, "128-lane"),
+]
+
+
+@pytest.mark.parametrize("q,ring,pool,dtype,refusal",
+                         [pytest.param(*c[1:], id=c[0]) for c in PATH_RULE])
+def test_decode_path_rule(monkeypatch, q, ring, pool, dtype, refusal):
+    """One rule says how a decode tick attends, from shapes, dtype,
+    `impl` and the platform: under "auto" the kernel on a TPU where its
+    constraints hold and the XLA form silently everywhere else; "ref"
+    the XLA form; "flash" the kernel or its refusal."""
+    from shellac_tpu.ops import eva_attention as ea
+
+    said = ea.eva_kernel_refusal(q, ring, pool, dtype)
+    assert (said is None) if refusal is None else (refusal in said), said
+    assert ea.eva_decode_path(q, ring, pool, dtype) == "xla"  # the CPU
+    assert ea.eva_decode_path(q, ring, pool, dtype, "ref") == "xla"
+    if refusal is None:
+        assert ea.eva_decode_path(q, ring, pool, dtype, "flash") == "eva_kernel"
+    else:
+        with pytest.raises(ValueError, match="refuses"):
+            ea.eva_decode_path(q, ring, pool, dtype, "flash")
+    monkeypatch.setattr(ea, "pallas_supported", lambda: True)
+    want = "eva_kernel" if refusal is None else "xla"
+    assert ea.eva_decode_path(q, ring, pool, dtype) == want
+    assert ea.eva_decode_path(q, ring, pool, dtype, "ref") == "xla"
+
+
+def _wide_model(arch):
+    """The test model at the kernel's widths: 8 heads of 128, float32
+    (eight rows a sublane tile), W 32, C 4: pages of 8 pooled rows."""
+    hf = dict(HF, hidden_size=1024, num_attention_heads=8,
+              num_key_value_heads=8, intermediate_size=256)
+    cfg = config_from_hf(types.SimpleNamespace(**hf)).replace(
+        dtype="float32", param_dtype="float32", remat=False)
+    return cfg, arch.to_program(arch.make_weights(hf, 5, dtype=jnp.float32))
+
+
+def test_cached_decode_through_the_kernel_matches_the_xla_form(arch):
+    """The model's own decode branch, handing the kernel the whole
+    stacks, the engine's table, the slots' columns and the windows each
+    has completed: prefill two prompts of different lengths, then tick
+    across a window's end (a ring that wraps to one row, a page that
+    becomes a slot's own), every tick's logits against
+    attn_impl="ref"'s."""
+    cfg, params = _wide_model(arch)
+    assert cfg.dim_per_head == 128
+    caches = {}
+    for impl in ("flash", "ref"):
+        cache = init_cache_for(cfg, 2, 128)
+        for slot, n in enumerate((29, 61)):
+            view = cache.replace(
+                tables=cache.tables[slot:slot + 1],
+                lengths=jnp.zeros((1,), jnp.int32),
+                slots=jnp.asarray([slot], jnp.int32))
+            with jax.default_matmul_precision("highest"):
+                _, view = transformer.forward_with_cache(
+                    cfg, params, jnp.asarray(_tokens(n, seed=slot))[None],
+                    view, fresh_cache=True, attn_impl="ref")
+            cache = cache.replace(
+                k=view.k, v=view.v, pk=view.pk, pv=view.pv,
+                lengths=cache.lengths.at[slot].set(n))
+        caches[impl] = cache
+    step = jax.jit(transformer.forward_with_cache,
+                   static_argnames=("cfg", "attn_impl"))
+    for t in range(5):
+        toks = jnp.asarray(_tokens(2, seed=50 + t))[:, None]
+        out = {}
+        for impl in ("flash", "ref"):
+            with jax.default_matmul_precision("highest"):
+                out[impl], caches[impl] = step(
+                    cfg=cfg, params=params, tokens=toks, cache=caches[impl],
+                    attn_impl=impl)
+        np.testing.assert_allclose(np.asarray(out["flash"]),
+                                   np.asarray(out["ref"]), atol=TOL, rtol=0)
+    assert caches["flash"].lengths.tolist() == [34, 66]
+
+
 # ---- (f) the step records' counts -------------------------------------------
 
 def test_step_records_count_the_rows_attended(model):
@@ -368,11 +554,64 @@ def test_step_records_count_the_rows_attended(model):
     recs = list(reg.step_records)
     assert sum(r.counts["eva_window_rows"] for r in recs) == exact
     assert sum(r.counts["eva_summary_rows"] for r in recs) == pooled
+    # On the CPU the tick attends through the XLA form, which reads more
+    # than it attends: every row of a slot's ring, the whole pool.
+    assert eng.stats["decode_attn"] == "xla"
+    assert sum(r.counts["eva_read_rows"] for r in recs) > exact + pooled
     assert sum(r.counts["decode_valid_ticks"] for r in recs) == sum(
         m - 1 for _, m in REQS)
     names = {sp[0] for r in recs for sp in r.spans}
     assert {"cache.prepare_slot", "cache.ensure_blocks",
             "cache.release_slot"} <= names
+
+
+@pytest.mark.parametrize("path", ["eva_kernel", "xla"])
+def test_window_counts_the_rows_the_read_path_moves(path):
+    """`eva_read_rows` against a brute count, slot-tick by slot-tick: the
+    kernel copies a slot's valid ring rows in whole blocks and the pages
+    of its completed windows; the XLA form reads every ring row a
+    slot-tick and the whole pool (scratch page and all) once a tick."""
+    from shellac_tpu import get_model_config
+    from shellac_tpu.config import EvaConfig
+    from shellac_tpu.ops.eva_attention import eva_ring_block
+
+    window, chunk, slots = 512, 16, 3
+    cfg = get_model_config("tiny-eva").replace(
+        eva=EvaConfig(window=window, chunk=chunk), max_seq_len=4096)
+    backend = make_backend("eva", cfg, slots, 2048)
+    backend._decode_attn = path
+    block, per_page = eva_ring_block(window), window // chunk
+    assert block == 128 and window % block == 0
+    # (prompt tokens, outputs settled, valid ticks of this window): a
+    # window's first row, a block's last row then the next block's
+    # first, the ring's last row then a wrap to one row with a page more.
+    reqs = [(700, 1, 4), (512 + 126, 1, 3), (1022, 3, 2)]
+    pairs = [(i, types.SimpleNamespace(tokens=np.zeros(n, np.int32),
+                                        out=[0] * m))
+             for i, (n, m, _) in enumerate(reqs)]
+    n_valid = np.asarray([t for _, _, t in reqs])
+    exact = pooled = read = 0
+    for n, m, ticks in reqs:
+        for t in range(ticks):
+            p = n + m - 1 + t
+            exact += p % window + 1
+            pooled += (p // window) * per_page
+            if path == "eva_kernel":
+                read += (-(-(p % window + 1) // block) * block
+                         + (p // window) * per_page)
+            else:
+                read += window
+    if path == "xla":
+        read += max(t for _, _, t in reqs) * backend.n_blocks * per_page
+    got = backend.window_counts(pairs, n_valid)
+    assert got == {"eva_window_rows": exact, "eva_summary_rows": pooled,
+                   "eva_read_rows": read}
+    # What the rows attended are of the rows moved (these positions sit
+    # at blocks' edges: the worst a block's rounding gets).
+    if path == "eva_kernel":
+        assert 0.6 < (exact + pooled) / read <= 1.0
+    else:
+        assert (exact + pooled) / read < 0.6
 
 
 def test_the_compiled_programs_carry_the_eva_scopes(model):
