@@ -1652,10 +1652,9 @@ class BatchingEngine:
         overlap, batched at the next step boundary with it) pulls
         everything in one device_get."""
         s = req.tokens.size
-        # Cap the bucket at max_len: a pad larger than the cache
-        # (dense) or the block table (paged) would write out of
-        # range — loudly for dense, silently-clamped for paged.
-        pad = min(_bucket(s), self.max_len)
+        # The backend pads (a bucket capped at max_len; EVA state one
+        # buffer of whole windows) and says what the program runs.
+        pad, run = self.cache_backend.prefill_rows(s)
         key = (pad, req.prompt_logprobs)
         if key not in self._prefill_jit:
             self._prefill_jit[key] = self._jit_cache_program(
@@ -1663,7 +1662,7 @@ class BatchingEngine:
             )
         padded = np.zeros((1, pad), np.int32)
         padded[0, :s] = req.tokens
-        self._count_prefill(s, pad, True)
+        self._count_prefill(s, run, True)
         program = self._prefill_jit[key]
         self._cache, self._key, first, lp, plp, tlv, tli = program(
             self.params, self._cache, jnp.asarray(padded),
@@ -1673,7 +1672,7 @@ class BatchingEngine:
             want_plp=req.prompt_logprobs,
         )
         self._launch_prompt("prefill", program, first, slot=slot,
-                            bucket=pad, tokens=int(s), offset=0)
+                            bucket=run, tokens=int(s), offset=0)
         # Prompt scoring no longer pays its own per-admission pull: the
         # device array rides the flight and lands in the ONE batched
         # settle device_get alongside the first token (SH002 history:
@@ -1683,8 +1682,8 @@ class BatchingEngine:
 
     def _count_prefill(self, tokens: int, padded: int,
                        fresh: bool) -> None:
-        """A prefill or chunk program of `tokens` real and `padded`
-        bucketed prompt tokens is about to be dispatched (called
+        """A prefill or chunk program of `tokens` real prompt tokens
+        that runs `padded` rows is about to be dispatched (called
         inside its engine.prefill_dispatch span), into an empty cache
         (`fresh`: a whole prompt, a first chunk) or behind resident
         tokens. The model's own rule says whether its expert FFN runs
@@ -2967,27 +2966,28 @@ class PagedBatchingEngine(BatchingEngine):
         """Prefill one prompt and leave its state in the slot's pages,
         the backend's way (`prefill_into`: a dense mini cache of the
         pool's kind scattered through the slot's table, or, for EVA
-        state, straight through a view of the slot). `tables` is the
-        host's block table as this program's argument: a new tenant's
-        row (and every row released since the last program) reaches
-        the device HERE. want_plp scores the prompt from the prefill's
-        own logits — identical math to the dense engine's whole-prompt
-        scoring."""
+        state, straight through a view of the slot, a window at a
+        time). `tables` is the host's block table as this program's
+        argument: a new tenant's row (and every row released since the
+        last program) reaches the device HERE. want_plp scores the
+        prompt from the prefill's own logits — identical math to the
+        dense engine's whole-prompt scoring."""
         cache, key_out, key = self._enter(cache, tables, key)
 
-        def forward(scratch):
+        def forward(scratch, toks, n, fresh_cache=True, logits_at=None):
             return transformer.forward_with_cache(
-                self.cfg, params, tokens, scratch,
-                new_tokens_len=prompt_len, fresh_cache=True,
+                self.cfg, params, toks, scratch,
+                new_tokens_len=n, fresh_cache=fresh_cache,
                 attn_impl=self.attn_impl, mesh=self.mesh,
+                logits_at=logits_at,
             )
 
         logits, cache = self.cache_backend.prefill_into(
-            cache, slot, tokens.shape[1], forward
+            cache, slot, tokens, prompt_len, forward, all_rows=want_plp
         )
-        last = jnp.take_along_axis(
+        last = (jnp.take_along_axis(
             logits, (prompt_len - 1)[:, None, None].astype(jnp.int32), axis=1
-        )[0, 0]
+        ) if want_plp else logits)[0, 0]
         first, first_lp = self._sample_first(key, last, samp)
         plp = (self._plp_within(logits, tokens) if want_plp
                else jnp.zeros((tokens.shape[1],), jnp.float32))

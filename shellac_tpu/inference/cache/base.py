@@ -32,7 +32,7 @@ release dispatch nothing of their own.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from shellac_tpu.config import ModelConfig
 
@@ -193,6 +193,18 @@ class CacheBackend:
         """Tokens already resident when prefill starts (paged prefix
         caching returns the matched prefix length)."""
         return 0
+
+    def prefill_rows(self, tokens: int) -> Tuple[int, int]:
+        """(pad, run) for a whole prompt of `tokens` tokens: the length
+        its token buffer is padded to, one prefill program per length,
+        and the rows that program runs through the model. Here both are
+        the power-of-two bucket, capped at max_len: a pad larger than
+        the cache (dense) or the block table (paged) would write out of
+        range."""
+        from shellac_tpu.inference.batching import _bucket
+
+        pad = min(_bucket(tokens), self.max_len)
+        return pad, pad
 
     def slot_tables(self):
         """Per-slot storage indirection the next engine program must

@@ -157,17 +157,53 @@ class EvaBackend(PagedBackend):
     def logical_axes(self):
         return eva_cache_logical_axes(self.cfg)
 
-    def prefill_into(self, cache, slot, length: int, forward):
-        """Prefill one prompt straight into `slot`: `forward` runs on a
-        batch-1 view that shares the rings and the pool and names the
-        slot's ring row and table row, so the prompt's last window and
-        its pooled rows land where decode will read them."""
+    def prefill_rows(self, tokens: int):
+        """One buffer for every prompt, `max_len` in whole windows (one
+        prefill program, whatever the length); the program runs the
+        prompt's windows alone."""
+        w = self.cfg.eva.window
+        return -(-self.max_len // w) * w, -(-tokens // w) * w
+
+    def prefill_into(self, cache, slot, tokens, prompt_len, forward, *,
+                     all_rows: bool):
+        """Prefill one prompt straight into `slot`, a window at a time:
+        a loop over the prompt's windows alone, ceil(prompt_len / W)
+        trips whatever the buffer's length, runs `forward` on W rows of
+        `tokens` at a time, on a batch-1 view that shares the rings and
+        the pool and names the slot's ring row and table row. Each window
+        attends its own rows and the pooled rows its predecessors left
+        in the slot's pages, writes its rows over the ring and its pooled
+        rows to its page; the last window's rows stay in the ring, where
+        decode will read them. Logits as `PagedBackend.prefill_into`'s:
+        every row's (rows of windows not run are zeros, and no score
+        reads them), or the last token's, unembedded from its window
+        alone."""
+        w = self.cfg.eva.window
+        n = prompt_len.astype(jnp.int32)
         view = cache.replace(
             tables=jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0),
             lengths=jnp.zeros((1,), jnp.int32),
             slots=jnp.reshape(slot, (1,)).astype(jnp.int32),
         )
-        logits, view = forward(view)
+
+        def window(i, carry):
+            view, logits = carry
+            start = i * w
+            real = jnp.minimum(n - start, w)
+            lg, view = forward(
+                view, jax.lax.dynamic_slice_in_dim(tokens, start, w, axis=1),
+                real, fresh_cache=False, logits_at=None if all_rows else real - 1,
+            )
+            if all_rows:
+                lg = jax.lax.dynamic_update_slice_in_dim(logits, lg, start,
+                                                         axis=1)
+            return view, lg
+
+        logits = jnp.zeros(
+            (1, tokens.shape[1] if all_rows else 1, self.cfg.vocab_size),
+            jnp.float32)
+        view, logits = jax.lax.fori_loop(
+            0, (n[0] + w - 1) // w, window, (view, logits))
         return logits, cache.replace(
             k=view.k, v=view.v, pk=view.pk, pv=view.pv,
             lengths=jax.lax.dynamic_update_slice(

@@ -212,14 +212,24 @@ class PagedBackend(CacheBackend):
         """Tokens a page when the engine is given no block_size."""
         return 16
 
-    def prefill_into(self, cache, slot, length: int, forward):
-        """Inside the engine's prefill program: run `forward(scratch) ->
-        (logits, scratch)` for one prompt padded to `length` and leave
-        its rows in `slot`'s pages. Here: a dense mini cache of the
-        pool's kind (bf16, or int8 + scales: the quant mini already
-        quantized at write, K post-rope, and its scales go through the
-        same pages as its values), then paged_write_prompt through the
-        slot's table row. Returns (logits, cache)."""
+    def prefill_into(self, cache, slot, tokens, prompt_len, forward, *,
+                     all_rows: bool):
+        """Inside the engine's prefill program: run `forward(scratch,
+        tokens, new_len, fresh_cache=, logits_at=) -> (logits, scratch)`
+        for one prompt, `tokens` (1, pad) holding `prompt_len` (1,) real
+        ones, and leave its rows in `slot`'s pages. Returns (logits,
+        cache): logits (1, pad, V) of every row if `all_rows`, else (1,
+        1, V), the row of the prompt's last token.
+
+        Here: a dense mini cache of the pool's kind (bf16, or int8 +
+        scales: the quant mini already quantized at write, K post-rope,
+        and its scales go through the same pages as its values), then
+        paged_write_prompt through the slot's table row."""
+        def rows(logits):
+            return logits if all_rows else jnp.take_along_axis(
+                logits, (prompt_len - 1)[:, None, None].astype(jnp.int32),
+                axis=1)
+
         if self.cfg.dsa is not None:
             # Three pools and no dense mini of their kind: the prompt
             # goes straight through a batch-1 view of the slot's table
@@ -228,14 +238,16 @@ class PagedBackend(CacheBackend):
                 tables=jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0),
                 lengths=jnp.zeros((1,), jnp.int32),
             )
-            logits, view = forward(view)
-            return logits, cache.replace(
+            logits, view = forward(view, tokens, prompt_len)
+            cache = cache.replace(
                 k=view.k, v=view.v, idx=view.idx,
                 lengths=jax.lax.dynamic_update_slice(
                     cache.lengths, view.lengths, (slot,)
                 ),
             )
-        logits, mini = forward(self.init_mini(length))
+            return rows(logits), cache
+        logits, mini = forward(self.init_mini(tokens.shape[1]), tokens,
+                               prompt_len)
         table_row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, 0)[0]
         names = kv_field_names(self.kv_quant)
         fields = dict(zip(names, paged_write_prompt(
@@ -245,7 +257,7 @@ class PagedBackend(CacheBackend):
         fields["lengths"] = jax.lax.dynamic_update_slice(
             cache.lengths, mini.lengths, (slot,)
         )
-        return logits, cache.replace(**fields)
+        return rows(logits), cache.replace(**fields)
 
     def decode_attn(self) -> str:
         """How the engine's decode program reads this pool: through the

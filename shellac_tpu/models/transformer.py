@@ -1254,7 +1254,13 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
       chunk that row belongs to from the ring, and writes that pooled
       row through the table if the row completed the chunk, then
       attends its ring rows 0 .. p % W
-      and the pages of its completed windows in one softmax.
+      and the pages of its completed windows in one softmax;
+    * a block of exactly W rows that starts at a window's first position
+      (a served prompt's prefill, one window at a time) writes its rows
+      over the ring and its chunks' pooled rows to the window's page,
+      then attends its own rows, causal, and the pooled rows of the pages
+      of the windows before it (`eva_window_attention`). It reads no
+      ring row: the window before it has closed, and is pooled.
     """
     from jax.experimental.layout import Layout, with_layout_constraint
 
@@ -1266,6 +1272,8 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
         eva_decode_path,
         eva_pool,
         eva_pool_sequence,
+        eva_pooled_widths,
+        eva_window_attention,
     )
 
     e = cfg.eva
@@ -1315,15 +1323,58 @@ def _eva_attention(cfg, attn_impl, hx, lp, cos, sin, cache, fresh_cache,
             )
         return o.reshape(b, s, h * dh), (ring, pool)
 
-    if s != 1:
-        raise NotImplementedError(
-            "EVA state continues one row at a time: a cached chunk of "
-            f"{s} rows (chunked prefill, a speculative verify window) "
-            "would need the pooled rows of chunks that complete inside "
-            "it"
-        )
     ring, pool, index, _ = cache
     layer = eva_tables["layer"]
+    if s != 1:  # one whole window at its first position (forward_with_cache)
+        zero = jnp.zeros((b,), jnp.int32)
+        with jax.named_scope("eva.pool"):
+            kp, vp = eva_pool_sequence(k, v, phi, mu, e.chunk, scale)
+        with jax.named_scope("kv.write"):
+            ring = eva_ring_write(ring, (k, v), layer, eva_tables["slots"],
+                                  zero)
+        with jax.named_scope("eva.summary_write"):
+            pool = paged_write(
+                pool,
+                (kp.astype(pool[0].dtype).transpose(0, 2, 1, 3),
+                 vp.astype(pool[1].dtype).transpose(0, 2, 1, 3)),
+                index // e.chunk, eva_tables["tables"], layer=layer,
+            )
+        with jax.named_scope("eva.attend"):
+            # The pooled rows of the table's first n pages, in table
+            # order: a window's index is at most the table's last entry,
+            # so the pages before it are among all but that one (and
+            # the scratch page where a width holds more). Row c is chunk
+            # c; the block sees it iff its window has closed. One branch
+            # a width: the smallest that holds the pages of the windows
+            # before the block (the stacks stay out of it).
+            r = e.window // e.chunk
+            pages = eva_tables["tables"][:, :-1]
+            widths = eva_pooled_widths(pages.shape[1], r, e.window)
+            pages = jnp.pad(pages, ((0, 0), (0, widths[-1] - pages.shape[1])))
+
+            def earlier(a):  # (L, H, N, R, D) -> (B, pages x R, H, D)
+                return a[layer, :, pages].transpose(0, 1, 3, 2, 4).reshape(
+                    b, -1, h, dh)
+
+            def over(n):
+                def attend(q, k, v, kp, vp):
+                    if n == 0:
+                        return attention(q, k, v, causal=True, scale=scale,
+                                         impl=attn_impl)
+                    seen = (jnp.arange(n * r, dtype=jnp.int32)[None, :]
+                            < (index // e.chunk)[:, None])
+                    return eva_window_attention(
+                        q, k, v, kp[:, :n * r], vp[:, :n * r], seen,
+                        scale=scale, impl=attn_impl,
+                    )
+                return attend
+
+            which = jnp.searchsorted(jnp.asarray(widths, jnp.int32),
+                                     jnp.max(index // e.window))
+            o = jax.lax.switch(which, [over(n) for n in widths], q, k, v,
+                               earlier(pool[0]), earlier(pool[1]))
+        return o.reshape(b, s, h * dh), (ring, pool)
+
     at = index % e.window  # the new row's place in the ring
     with jax.named_scope("kv.write"):
         ring = eva_ring_write(ring, (k, v), layer, eva_tables["slots"], at)
@@ -1984,6 +2035,19 @@ def forward_with_cache(
     cdt = cfg.compute_dtype
     b, s = tokens.shape
     index = cache.lengths  # (B,)
+    if eva and not fresh_cache and s != 1:
+        # A traced index is the caller's to align (the 'eva' backend's
+        # prefill loop); a concrete one is checked here.
+        w = cfg.eva.window
+        if s != w or (not isinstance(index, jax.core.Tracer)
+                      and bool(jnp.any(index % w))):
+            raise NotImplementedError(
+                "EVA state continues one row at a time, or one whole "
+                f"window of {w} rows at a window's first position: a "
+                f"cached chunk of {s} rows (chunked prefill, a speculative "
+                "verify window) would need the pooled rows of chunks that "
+                "complete inside it"
+            )
     positions = index[:, None] + jnp.broadcast_to(
         jnp.arange(s, dtype=jnp.int32), (b, s)
     )

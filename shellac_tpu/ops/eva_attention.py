@@ -12,11 +12,14 @@ already rotated, phi and mu the head's two learned vectors:
       the pooled rows  { c : (c + 1) C <= wW }       (every chunk of
                                                       every earlier window)
 
-Three functions: `eva_pool` (chunks -> pooled rows), `eva_attention` (a
-whole sequence from position 0: training, scoring, prefill; through
-`ops.attention`, so the flash kernel where there is one) and
-`eva_decode_attention` (one new row a slot against a ring of exact rows
-and a pool of pooled rows; plain jnp: scores and the softmax in
+Four functions: `eva_pool` (chunks -> pooled rows), `eva_attention` (a
+whole sequence from position 0: training, scoring, the single-request
+prefill), `eva_window_attention` (windows that start at a window's
+first position, each beside the pooled rows of earlier windows: the
+core of `eva_attention`, and a served prompt's prefill one window at a
+time; through `ops.attention`, so the flash kernel where there is one)
+and `eva_decode_attention` (one new row a slot against a ring of exact
+rows and a pool of pooled rows; plain jnp: scores and the softmax in
 float32, the value sums accumulated in float32, rows kept in the dtype
 they arrive in). benchmark/arch/evabyte.py is the independent float32
 reference the tests hold these to.
@@ -74,16 +77,10 @@ def eva_attention(q, k, v, kp, vp, *, window: int, chunk: int,
     the pooled rows of `eva_pool_sequence`. Returns (B, S, H, D) in q's
     dtype.
 
-    Each window becomes one row of a batch: its keys are the pooled
-    rows of the whole sequence followed by the window's own exact rows,
-    and `ops.attention` runs over that, causal, with a segment row that
-    hides the pooled rows of the window itself and of later ones. A
-    window's queries stand after every pooled row, so causality shows
-    them all the pooled rows their segment admits and the exact rows up
-    to themselves: one softmax over both kinds, in the flash kernel
-    where the dispatcher has one (the pooled rows' own query positions
-    are padding, computed and dropped). A sequence inside one window
-    is plain causal attention.
+    Each window becomes one row of a batch for `eva_window_attention`,
+    beside the pooled rows of the whole sequence, of which it sees
+    those of earlier windows. A sequence inside one window is plain
+    causal attention.
     """
     from shellac_tpu.ops.attention import attention
 
@@ -94,33 +91,84 @@ def eva_attention(q, k, v, kp, vp, *, window: int, chunk: int,
     r = window // chunk
     n_p = n_w * r
 
-    def windows(a):  # (B, S, H, D) -> (B, n_w, W, H, D)
+    def windows(a):  # (B, S, H, D) -> (B * n_w, W, H, D)
         a = jnp.pad(a, ((0, 0), (0, n_w * window - s), (0, 0), (0, 0)))
-        return a.reshape(b, n_w, window, h, d)
+        return a.reshape(b * n_w, window, h, d)
 
-    def pooled(a):  # (B, N, H, D) -> (B, n_w, n_p, H, D)
+    def pooled(a):  # (B, N, H, D) -> (B * n_w, n_p, H, D)
         a = jnp.pad(a[:, :n_p], ((0, 0), (0, max(0, n_p - a.shape[1])),
                                  (0, 0), (0, 0)))
-        return jnp.broadcast_to(a[:, None], (b, n_w, n_p, h, d))
+        return jnp.broadcast_to(a[:, None], (b, n_w, n_p, h, d)).reshape(
+            b * n_w, n_p, h, d)
 
-    def rows(front, back):
-        return jnp.concatenate([front, back], axis=2).reshape(
-            b * n_w, n_p + window, h, d)
-
-    # Segment 1: what a window's queries may see. Pooled row c belongs
-    # to window c // r; window w sees it iff c // r < w.
+    # Pooled row c belongs to window c // r; window w sees it iff
+    # c // r < w.
     seen = (jnp.arange(n_p, dtype=jnp.int32)[None, :] // r
             < jnp.arange(n_w, dtype=jnp.int32)[:, None])
+    out = eva_window_attention(
+        windows(q), windows(k), windows(v), pooled(kp), pooled(vp),
+        jnp.broadcast_to(seen[None], (b, n_w, n_p)).reshape(b * n_w, n_p),
+        scale=scale, impl=impl,
+    )
+    return out.reshape(b, n_w * window, h, d)[:, :s]
+
+
+def eva_pooled_widths(pages: int, per_page: int, window: int):
+    """The pooled page counts a window's attention is compiled for,
+    ascending: none; the last, the fewest that hold `pages` (every page
+    a window can attend); and the halvings of the last. Where the window
+    is whole 512-row tiles, so is every width's pooled rows beside it
+    (the flash kernel steps by a divisor of its rows, and its segment
+    rows need whole 128-lane tiles: one page of 128 rows beside 2048 is
+    refused), so the last may hold more than `pages`. A window attends
+    the smallest that holds its completed windows' pages."""
+    def whole(n):
+        return window % 512 or (n * per_page + window) % 512 == 0
+
+    top = pages
+    while not whole(top):
+        top += 1
+    out, n = {0, top}, top
+    while n > 1:
+        n //= 2
+        if whole(n):
+            out.add(n)
+    return tuple(sorted(out))
+
+
+def eva_window_attention(q, k, v, kp, vp, seen, *, scale: float,
+                         impl: str = "auto"):
+    """EVA attention of windows that each start at a window's first
+    position, beside pooled rows of earlier windows.
+
+    q, k, v: (N, W, H, D), a window a row; kp, vp: (N, P, H, D) pooled
+    rows; seen: (N, P) bool, the pooled rows a window's queries attend.
+    Returns (N, W, H, D) in q's dtype.
+
+    A row's keys are its pooled rows followed by the window's own exact
+    rows, and `ops.attention` runs over that, causal, with a segment row
+    that hides the pooled rows not seen. The window's queries stand
+    after every pooled row, so causality shows them all the pooled rows
+    their segment admits and the exact rows up to themselves: one
+    softmax over both kinds, in the flash kernel where the dispatcher
+    has one (the pooled rows' own query positions are padding, computed
+    and dropped: the kernel takes as many queries as keys).
+    """
+    from shellac_tpu.ops.attention import attention
+
+    n, w, h, d = q.shape
+    p = seen.shape[1]
     seg = jnp.concatenate(
-        [seen.astype(jnp.int32), jnp.ones((n_w, window), jnp.int32)], axis=1)
-    seg = jnp.broadcast_to(seg[None], (b, n_w, n_p + window)).reshape(
-        b * n_w, n_p + window)
+        [seen.astype(jnp.int32), jnp.ones((n, w), jnp.int32)], axis=1)
+
+    def rows(front, back):
+        return jnp.concatenate([front, back], axis=1)
+
     out = attention(
-        rows(jnp.zeros((b, n_w, n_p, h, d), q.dtype), windows(q)),
-        rows(pooled(kp), windows(k)), rows(pooled(vp), windows(v)),
+        rows(jnp.zeros((n, p, h, d), q.dtype), q), rows(kp, k), rows(vp, v),
         causal=True, scale=scale, q_segments=seg, kv_segments=seg, impl=impl,
     )
-    return out[:, n_p:].reshape(b, n_w * window, h, d)[:, :s]
+    return out[:, p:]
 
 
 def eva_decode_attention(q, ring_k, ring_v, n_exact, pool_k, pool_v, owned,

@@ -740,6 +740,63 @@ def test_eva_engine_decode_window_moves_live_rows_only_on_v5e(
     assert temps["eva_kernel"] < temps["xla"], temps
 
 
+def test_eva_engine_prefill_holds_one_window_on_v5e(chip, pool_sized_ops,
+                                                    monkeypatch):
+    """The engine's REAL prefill program for EvaByte at the cell's
+    shapes (8 layers at the published widths, 24 slots, 217 pages, the
+    one buffer of 18,432 bytes every prompt is padded to), the cache
+    described: a loop over the prompt's windows, whose temporaries are
+    one window's (the whole-sequence form held 1.67 GiB for a prompt of
+    16,384), whose arguments still alias the donated rings and pool, and
+    which moves no ring or pool stack."""
+    import re
+    import types
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from shellac_tpu.inference.cache import eva as eva_backend
+    from shellac_tpu.inference.cache import engine_class
+    from shellac_tpu.models import transformer
+    from shellac_tpu.models.convert import config_from_hf
+
+    with open(os.path.join(REPO, "benchmark", "configs", "evabyte.json")) as f:
+        hf = json.load(f)
+    serving = hf["serving"]
+    cfg = config_from_hf(types.SimpleNamespace(**hf)).replace(
+        dtype="bfloat16", param_dtype="bfloat16").validate()
+    slots, window, max_len = serving["n_slots"], cfg.eva.window, 9 * 2048
+    described = eva_backend.init_eva_cache
+    monkeypatch.setattr(eva_backend, "init_eva_cache", lambda *a, **k:
+                        jax.eval_shape(lambda: described(*a, **k)))
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree,
+    )
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = engine_class("eva")(
+        cfg, params, n_slots=slots, max_len=max_len, block_size=window,
+        pool_tokens=slots * max_len, cache_backend="eva",
+        decode_ticks=int(serving["decode_ticks"]),
+    )
+    pad, run = eng.cache_backend.prefill_rows(16384)
+    assert (pad, run) == (max_len, 16384)
+    ring, pool = eng._cache.k.shape, eng._cache.pk.shape
+    stack_bytes = 2 * 2 * (math.prod(ring) + math.prod(pool))
+    fn, args, kw = _engine_program(eng, "prefill", pad)
+    compiled = fn.lower(shaped(params), shaped(eng._cache), *shaped(args),
+                        **kw).compile()
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held
+    assert ma.temp_size_in_bytes < 1.67 * 2 ** 30 / 2, ma.temp_size_in_bytes
+    assert ma.alias_size_in_bytes >= stack_bytes  # donated, in place
+    text = re.sub(r"(?ms)^%fused_computation\S* \(.*?^}\n", "",
+                  compiled.as_text())
+    moved = pool_sized_ops(text, [ring, pool])
+    assert not moved, "\n".join(moved)
+
+
 @pytest.mark.parametrize("build", [
     pytest.param(_mesh_rmsnorm, id="rmsnorm"),
     pytest.param(_mesh_flash_grad, id="flash-fwd-bwd"),

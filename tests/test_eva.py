@@ -132,11 +132,137 @@ def test_prefill_then_decode_match_full_forward(model, cached, n):
     assert worst < TOL
 
 
-def test_cached_continuation_of_several_rows_refuses(model):
+@pytest.mark.parametrize("rows,at", [
+    (4, 0), (W - 1, 0), (W + 1, 0), (2 * W, 0), (W, 5), (W, W - 1),
+], ids=["part-of-a-window", "W-1", "W+1", "two-windows", "W-at-5",
+        "W-at-W-1"])
+def test_cached_continuation_of_several_rows_refuses(model, rows, at):
+    """A cached call of several rows is one whole window at a window's
+    first position, or it is refused."""
     cfg, _, params = model
+    cache = init_cache_for(cfg, 1, 128)
+    cache = cache.replace(lengths=jnp.full_like(cache.lengths, at))
     with pytest.raises(NotImplementedError, match="one row at a time"):
         transformer.forward_with_cache(
-            cfg, params, jnp.zeros((1, 4), jnp.int32), init_cache_for(cfg, 1, 64))
+            cfg, params, jnp.zeros((1, rows), jnp.int32), cache)
+
+
+# ---- (b2) a served prompt's prefill, one window at a time -----------------
+
+LOOP_LEN = 160  # max_len: five windows, the buffer every prompt is padded to
+
+
+@pytest.fixture(scope="module")
+def window_loop(model):
+    """The 'eva' backend's prefill of one prompt, a loop over its
+    windows, and the whole-sequence fresh prefill the single-request
+    Engine runs, each jitted once over a slot cache whose row owns its
+    pages."""
+    cfg, _, params = model
+    backend = make_backend("eva", cfg, 1, LOOP_LEN)
+
+    def forward(p, scratch, toks, n, fresh_cache=True, logits_at=None):
+        return transformer.forward_with_cache(
+            cfg, p, toks, scratch, new_tokens_len=n, fresh_cache=fresh_cache,
+            logits_at=logits_at)
+
+    @jax.jit
+    def loop(p, cache, toks, n):
+        return backend.prefill_into(
+            cache, jnp.int32(0), toks, n, lambda *a, **k: forward(p, *a, **k),
+            all_rows=True)
+
+    @jax.jit
+    def last_only(p, cache, toks, n):
+        return backend.prefill_into(
+            cache, jnp.int32(0), toks, n, lambda *a, **k: forward(p, *a, **k),
+            all_rows=False)
+
+    fresh = jax.jit(forward)
+    decode = jax.jit(lambda p, t, c: transformer.forward_with_cache(cfg, p, t, c))
+    return backend, loop, last_only, fresh, decode
+
+
+@pytest.mark.parametrize("n", [1, W - 1, W, W + 1, 2 * W, 3 * W - 1, 3 * W,
+                               3 * W + 1])
+def test_window_loop_leaves_what_the_fresh_prefill_leaves(model, window_loop, n):
+    """A prompt of n tokens through the window loop against the
+    whole-sequence fresh prefill of its power-of-two bucket: the last
+    row's logits, every real row's (prompt scoring reads them), the
+    ring rows decode will read (the last window's, up to the prompt's
+    end) and the pooled rows of every chunk the prompt completed; then
+    greedy decode continued from either state gives the same stream,
+    across the roll-overs that follow."""
+    cfg, _, params = model
+    backend, loop, last_only, fresh, decode = window_loop
+    toks = _tokens(n, seed=300 + n)
+    pad, run = backend.prefill_rows(n)
+    assert (pad, run) == (LOOP_LEN, -(-n // W) * W)
+    buf = np.zeros((1, pad), np.int32)
+    buf[0, :n] = toks
+    bucket = 16
+    while bucket < n:
+        bucket *= 2
+    length = jnp.asarray([n], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got, cache_l = loop(params, init_cache_for(cfg, 1, LOOP_LEN),
+                            jnp.asarray(buf), length)
+        last, _ = last_only(params, init_cache_for(cfg, 1, LOOP_LEN),
+                            jnp.asarray(buf), length)
+        want, cache_f = fresh(params, init_cache_for(cfg, 1, LOOP_LEN),
+                              jnp.asarray(buf[:, :bucket]), length)
+    assert got.shape == (1, pad, 256) and last.shape == (1, 1, 256)
+    np.testing.assert_allclose(np.asarray(got[0, :n]), np.asarray(want[0, :n]),
+                               atol=TOL, rtol=0)
+    np.testing.assert_allclose(np.asarray(last[0, 0]), np.asarray(want[0, n - 1]),
+                               atol=TOL, rtol=0)
+    assert int(cache_l.lengths[0]) == int(cache_f.lengths[0]) == n
+    live = n % W  # ring rows decode reads at position n
+    for a, b in ((cache_l.k, cache_f.k), (cache_l.v, cache_f.v)):
+        np.testing.assert_allclose(np.asarray(a[:, :live]),
+                                   np.asarray(b[:, :live]), atol=TOL, rtol=0)
+    chunks = np.arange(n // C)
+    pages = np.asarray(cache_l.tables)[0, chunks // (W // C)]
+    for a, b in ((cache_l.pk, cache_f.pk), (cache_l.pv, cache_f.pv)):
+        np.testing.assert_allclose(
+            np.asarray(a)[:, :, pages, chunks % (W // C)],
+            np.asarray(b)[:, :, pages, chunks % (W // C)], atol=TOL, rtol=0)
+    streams = []
+    for lg, cache in ((got[0, n - 1], cache_l), (want[0, n - 1], cache_f)):
+        tok, out = int(jnp.argmax(lg)), []
+        with jax.default_matmul_precision("highest"):
+            for _ in range(3 * W + 8 - n if n < 2 * W else W + 4):
+                out.append(tok)
+                lg2, cache = decode(params, jnp.asarray([[tok]], jnp.int32),
+                                    cache)
+                tok = int(jnp.argmax(lg2[0, 0]))
+        streams.append(out)
+    assert streams[0] == streams[1]
+
+
+def test_window_loop_runs_the_prompts_windows_alone(model):
+    """The loop's trip count is the prompt's windows, not the buffer's:
+    every call of the model it makes at run time is counted (a host
+    callback in the traced body, the window's first position), for
+    prompts whose windows number 1 to 4 in a buffer of 5."""
+    cfg, _, params = model
+    calls = []
+
+    def forward(p, scratch, toks, n, **kw):
+        jax.debug.callback(lambda at: calls.append(int(at[0])), scratch.lengths)
+        return transformer.forward_with_cache(cfg, p, toks, scratch,
+                                              new_tokens_len=n, **kw)
+
+    backend = make_backend("eva", cfg, 1, LOOP_LEN)
+    run = jax.jit(lambda p, c, t, n: backend.prefill_into(
+        c, jnp.int32(0), t, n, lambda *a, **k: forward(p, *a, **k),
+        all_rows=False))
+    for n in (1, W, W + 1, 4 * W):
+        calls.clear()
+        buf = jnp.zeros((1, LOOP_LEN), jnp.int32)
+        jax.block_until_ready(run(params, init_cache_for(cfg, 1, LOOP_LEN), buf,
+                                  jnp.asarray([n], jnp.int32)))
+        assert calls == [i * W for i in range(-(-n // W))], (n, calls)
 
 
 # ---- (c) the paged engine on the 'eva' backend ------------------------------
@@ -486,6 +612,29 @@ def test_decode_path_rule(monkeypatch, q, ring, pool, dtype, refusal):
     assert ea.eva_decode_path(q, ring, pool, dtype, "ref") == "xla"
 
 
+@pytest.mark.parametrize("pages,per_page,window,want", [
+    (8, 128, 2048, (0, 4, 8)),       # the cell: 2048, 2560, 3072 rows
+    (1, 128, 2048, (0, 4)),          # 2176 rows would be refused
+    (3, 128, 2048, (0, 4)),
+    (16, 128, 2048, (0, 4, 8, 16)),
+    (0, 128, 2048, (0,)),
+    (4, W // C, W, (0, 1, 2, 4)),    # tiny-eva: no tile to keep whole
+], ids=["the-cell", "one-page", "three-pages", "sixteen-pages", "no-page",
+        "tiny-eva"])
+def test_pooled_widths(pages, per_page, window, want):
+    """The pooled page counts a window's attention is compiled for: none,
+    the fewest that hold every page a window can attend, and its
+    halvings, each beside the window whole 512-row tiles where the window
+    is (the flash kernel's segment rows need whole lane tiles)."""
+    from shellac_tpu.ops.eva_attention import eva_pooled_widths
+
+    got = eva_pooled_widths(pages, per_page, window)
+    assert got == want
+    assert got[-1] >= pages
+    if window % 512 == 0:
+        assert all((n * per_page + window) % 512 == 0 for n in got)
+
+
 def _wide_model(arch):
     """The test model at the kernel's widths: 8 heads of 128, float32
     (eight rows a sublane tile), W 32, C 4: pages of 8 pooled rows."""
@@ -563,6 +712,60 @@ def test_step_records_count_the_rows_attended(model):
     names = {sp[0] for r in recs for sp in r.spans}
     assert {"cache.prepare_slot", "cache.ensure_blocks",
             "cache.release_slot"} <= names
+
+
+@pytest.mark.parametrize("backend", ["eva", "paged"])
+def test_prefill_counts_the_rows_its_program_runs(model, backend):
+    """`prefill_padded_tokens` (and the launch row's `bucket`) count the
+    rows the prefill program runs: on 'eva' the prompt's whole windows,
+    elsewhere the power-of-two bucket the prompt is padded to, capped at
+    max_len."""
+    from shellac_tpu.inference.batching import _bucket
+    from shellac_tpu.models.registry import get_model_config
+
+    reg = Registry()
+    lens = [1, W - 1, W, W + 1, 2 * W, 3 * W - 1, 3 * W, 3 * W + 1]
+    if backend == "eva":
+        eng = _engine(model, reg)
+        want = [-(-n // W) * W for n in lens]
+    else:
+        cfg = get_model_config("tiny").replace(dtype="float32")
+        params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+        eng = engine_class("paged")(
+            cfg, params, n_slots=3, max_len=128, temperature=0.0,
+            decode_ticks=2, cache_backend="paged", registry=reg)
+        want = [min(_bucket(n), 128) for n in lens]
+    eng.run([(i, _tokens(n, seed=i), 2) for i, n in enumerate(lens)])
+    recs = list(reg.step_records)
+    assert sum(r.counts["prefill_tokens"] for r in recs) == sum(lens)
+    assert sum(r.counts["prefill_padded_tokens"] for r in recs) == sum(want)
+    buckets = sorted(row[7]["bucket"] for r in recs for row in r.launches
+                     if row[1] == "prefill")
+    assert buckets == sorted(want)
+
+
+def test_every_prompt_length_of_a_mix_compiles_nothing_after_its_warmup(model):
+    """The benchmark warms an engine up with the prompt lengths of
+    `warmup_prompt_lengths` (powers of two over the mix's bounds); on
+    the 'eva' backend one prefill program takes every length, so
+    prompts of EVERY length in the mix's range then compile nothing."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_traffic", os.path.join(ROOT, "benchmark", "harness", "traffic.py"))
+    traffic = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(traffic)
+    lo, hi = 2, 150
+    warm = traffic.warmup_prompt_lengths(
+        {"prompt_tokens": {"min": lo, "max": hi}}, None)
+    assert warm == [16, 32, 64, 128, 150]
+    reg = Registry()
+    eng = _engine(model, reg, decode_ticks=2)
+    eng.run([(("warm", n), _tokens(n, seed=n), 6) for n in warm])
+    done = len(reg.step_records)
+    out = eng.run([(n, _tokens(n, seed=1000 + n), 2) for n in range(lo, hi + 1)])
+    assert len(out) == hi - lo + 1
+    recs = list(reg.step_records)[done:]
+    assert sum(r.counts["compiles"] for r in recs) == 0
+    assert len(eng._prefill_jit) == 1
 
 
 @pytest.mark.parametrize("path", ["eva_kernel", "xla"])
