@@ -8,7 +8,8 @@ parity assertions with interpret=False on the real chip:
 
   - dense decode: GQA, sliding window, ragged lengths (incl. 0 and
     max_len-s), s=1 and s=4
-  - paged decode: shuffled block table, window, ragged lengths
+  - paged decode: shuffled block table, window, ragged lengths; the
+    MHA kernel with NaN in every row no slot may read
   - training flash attention: forward + backward grads vs reference
 
 Exits 0 and prints one JSON line {"ok": true, ...} on success; any
@@ -119,8 +120,9 @@ def paged_decode_cases(checks):
     # to the XLA ref on — BENCH_DECODE.json); bs=256 is the page "auto"
     # sends to the kernel (2 pages a step, 2 groups: the double buffer
     # crosses groups and slots). The last two are ouro-2.6b-batch-
-    # reason's read: MHA 16/16 over FIVE 128-row pages a slot, which two
-    # pages a step do not divide: the one-page kernel.
+    # reason's pool: MHA 16/16 over FIVE 128-row pages a slot, one query
+    # row a head at s=1 (the MHA kernel; paged_mha_cases has more), and
+    # at s=3, which two pages a step do not divide, the one-page kernel.
     for s, window, bs, L, HKV in [
         (1, None, 64, 1024, 8), (1, 200, 64, 1024, 8), (2, None, 64, 1024, 8),
         (1, None, 16, 1024, 8), (1, 200, 16, 1024, 8),
@@ -485,6 +487,66 @@ def sink_cases(checks):
         check(f"flash bwd sinks {name}", a, b, atol=1.5e-1, checks=checks)
 
 
+def paged_mha_cases(checks):
+    """The MHA paged kernel (`decode_paged_mha`), compiled: ouro-2.6b-
+    batch-reason's read, 16 q heads = 16 kv heads x 128 over five
+    128-row pages a slot, one query row a head. Every row no slot may
+    read holds NaN (past a slot's last row, before its window, and the
+    pages no slot owns): the kernel copies a last page only up to the
+    row block that holds the slot's last row, and its copies run ahead
+    across slots, which interpret mode cannot fault."""
+    from shellac_tpu.ops.decode_attention import (
+        _decode_ref,
+        paged_decode_attention,
+        paged_kernel_name,
+    )
+
+    B, H, D, bs, mb = 8, 16, 128, 128, 5
+    L = bs * mb
+    index = np.array([0, 31, 32, 127, 128, 300, 511, L - 1], np.int32)
+    rng = np.random.default_rng(42)
+    tables = (rng.permutation(B * mb) + 1).reshape(B, mb).astype(np.int32)
+    dense = [rng.standard_normal((B, H, L, D), np.float32) for _ in range(2)]
+    sinks = rng.standard_normal((H,), np.float32)
+
+    def exact(*a, **k):  # float32 rows multiply as float32 on this side too
+        with jax.default_matmul_precision("highest"):
+            return _decode_ref(*a, **k)
+
+    assert paged_kernel_name((B, 1, H, D), (1, H, bs, D), jnp.bfloat16, mb,
+                             D) == "decode_paged_mha"
+    for dtype, atol in ((jnp.bfloat16, 2e-2), (jnp.float32, 1e-4)):
+        for window, extra in ((None, {}), (200, {}),
+                              (None, {"softcap": 30.0, "sinks": sinks})):
+            pools = []
+            for a in dense:
+                pool = np.full((B * mb + 1, H, bs, D), np.nan, np.float32)
+                pool[0] = 0.0
+                for b in range(B):
+                    pos = np.arange(L)
+                    keep = pos <= index[b]
+                    if window is not None:
+                        keep &= index[b] - pos < window
+                    rows = np.where(keep[None, :, None], a[b], np.nan)
+                    for j in range(mb):
+                        pool[tables[b, j]] = rows[:, j * bs:(j + 1) * bs]
+                pools.append(jnp.asarray(pool, dtype))
+            kw = {k: (jnp.asarray(v) if k == "sinks" else v)
+                  for k, v in extra.items()}
+            q = _normal(jax.random.PRNGKey(7), (B, 1, H, D), dtype)
+            out = _jitted(paged_decode_attention)(
+                q, pools[0], pools[1], jnp.asarray(tables),
+                jnp.asarray(index), window=window, impl="flash",
+                interpret=False, **kw)
+            ref = _jitted(exact)(
+                q, jnp.asarray(dense[0], dtype), jnp.asarray(dense[1], dtype),
+                jnp.asarray(index), window, D ** -0.5, **kw)
+            assert np.isfinite(_host(out)).all()
+            check(f"paged mha {jnp.dtype(dtype).name} window={window} "
+                  f"{'+'.join(extra) or 'plain'} NaN past the rows",
+                  out, ref, atol=atol, checks=checks)
+
+
 def eva_decode_cases(checks):
     """ops/eva_attention.py::eva_decode_kernel, compiled, against the
     XLA form: tests/test_eva.py's cases at the evabyte cell's sizes (W
@@ -575,6 +637,7 @@ def run_all():
     head_dim_64_cases(checks)
     mla_shape_cases(checks)
     sink_cases(checks)
+    paged_mha_cases(checks)
     eva_decode_cases(checks)
     return checks
 

@@ -30,7 +30,11 @@ Two entry points:
   - `paged_decode_attention`: block-pool cache (n_blocks, Hkv, bs, D)
     with per-slot tables. Same kernel body; the kv DMA indirects
     through the scalar-prefetched block table, so the dense (B,
-    view, H, D) gather the ref path materializes never exists.
+    view, H, D) gather the ref path materializes never exists. One
+    query row a kv head (MHA decode) has a kernel of its own,
+    `decode_paged_mha`: at group size 1 there is no matmul row to
+    batch, so every head of a page is one vector pass, over a slot's
+    live pages only (paged_kernel_name says which kernel runs).
 
 Both positions contracts follow forward_with_cache: q row si of batch b
 sits at position lengths[b] + si, kv slot p is valid iff p <= that
@@ -844,12 +848,22 @@ def _paged_group(tables, pool_k, pool_v) -> int:
     lands each page at sublane offset g*bs of the VMEM tile, so bs
     must be a multiple of the dtype's sublane tile (fp32 8, bf16 16,
     int8 32) or Mosaic rejects the slice."""
-    num_kv = tables.shape[1]
-    hkv, bs, dk = pool_k.shape[1:]
-    sublane = 8 * max(1, 4 // jnp.dtype(pool_k.dtype).itemsize)
-    if bs % sublane:
+    return _pages_a_step(tables.shape[1], pool_k.shape, pool_k.dtype,
+                         0 if pool_v is None else pool_v.shape[3])
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows of one sublane tile of `dtype` (fp32 8, bf16 16, int8 32)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _pages_a_step(num_kv, pool_shape, pool_dtype, v_width) -> int:
+    """_paged_group from shapes: `v_width` lanes of a v row, 0 where v
+    is read out of the k tile."""
+    hkv, bs, dk = pool_shape[1:]
+    if bs % _sublane_rows(pool_dtype):
         return 1
-    page = hkv * bs * (dk + (0 if pool_v is None else pool_v.shape[3]))
+    page = hkv * bs * (dk + v_width)
     g = min(max(512 // bs, 1), max(PAGED_TILE_ELEMS // page, 1), num_kv)
     while g > 1 and num_kv % g:
         g -= 1
@@ -934,6 +948,228 @@ def _paged_flash(q, pool_k, pool_v, tables, index, scale, window, interpret,
     return _unflatten_o(out, b, s, h, d)
 
 
+# Pages of the MHA kernel in VMEM at once: one in use, the rest in
+# flight. Rows of a slot's last live page are copied in blocks of this
+# many, up to its last row (rows past a slot's length are never needed).
+# On a v5e at ouro-2.6b-batch-reason's shapes a tick's attention took
+# 6.05 ms at 3 pages and 32-row blocks, 6.58 with whole last pages, 6.87
+# at 2 pages (docs/decode_performance.md, "The MHA paged kernel").
+PAGED_MHA_DEPTH = 3
+PAGED_MHA_TAIL_ROWS = 32
+
+
+def _mha_page(q, k, v, acc_ref, m_ref, l_ref, valid, softcap):
+    """One online-softmax step of every head over one page.
+
+    q: (H, 1, D) float32, the scale folded in; k, v: (H, bs, D) tiles;
+    valid: None (every row attended) or a (1, bs, 1) mask of the rows
+    the query attends. A score is a multiply and a lane reduction a
+    (head, row), on the VPU, in float32; (max, sum, accumulator) are
+    float32, and the weight is rounded to the pool's dtype before its
+    value product, as _decode_tile's dot has it.
+    """
+    s = jnp.sum(k.astype(jnp.float32) * q, axis=-1, keepdims=True)
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    vf = v.astype(jnp.float32)
+    if valid is not None:
+        s = jnp.where(valid, s, NEG_INF)
+        # A row nobody attends may never have been copied: its weight is
+        # 0, and 0 * NaN would still poison the sum.
+        vf = jnp.where(valid, vf, 0.0)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    pv = p.astype(v.dtype).astype(jnp.float32) * vf
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(pv, axis=1, keepdims=True)
+    m_ref[...] = m_new
+
+
+def _paged_mha_kernel(
+    len_ref, tab_ref, q_ref, k_hbm, v_hbm, *rest,
+    scale, bs, rb, window, num_kv, softcap=None, has_sinks=False,
+):
+    """MHA paged decode (one query row a kv head): one slot a grid step,
+    over its live pages only.
+
+    The pool stays in HBM (memory space ANY); the kernel copies the
+    slot's live pages, tables[b, first..last] (_live_range), into VMEM
+    itself, the last one only up to the row block (`rb` rows) that holds
+    the slot's last row, and attends every head of a page in one vector
+    pass (_mha_page). The tile holds PAGED_MHA_DEPTH pages: one in use,
+    the next ones in flight. `cur_ref` is a cursor over every slot's
+    live pages in order, (pages consumed, slot and page of the next copy
+    to start, copies started); a page starts the copy at the cursor
+    before it waits for its own, so the copies run ahead across slots.
+    Grid steps run in order on one core.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    sink_ref, rest = _split_sink_rest(rest, has_sinks)
+    o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sems, cur_ref = rest
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    depth = k_buf.shape[0]
+    blocks = bs // rb
+
+    def live_pages(slot):
+        return _live_range(len_ref[slot], 1, bs, window, num_kv)
+
+    def each_copy(slot, pg, buf, act):
+        """Start (or wait for) the copies of page `pg` of `slot` into
+        tile `buf`: the whole page, or the last one's live row blocks."""
+        page = tab_ref[slot, pg]
+        n = jnp.where(pg == live_pages(slot)[1],
+                      (len_ref[slot] - pg * bs) // rb + 1, blocks)
+
+        def copy(j, rows):
+            for i, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(src.at[page, :, rows],
+                                          dst.at[buf, :, rows],
+                                          sems.at[buf, i, j]))
+
+        @pl.when(n == blocks)
+        def _whole():
+            copy(0, slice(None))
+
+        if blocks > 1:
+            @pl.when(n < blocks)
+            def _tail():
+                for j in range(blocks - 1):
+                    pl.when(j < n)(functools.partial(
+                        copy, j, pl.ds(j * rb, rb)))
+
+    def start_next():
+        slot = cur_ref[1]
+
+        @pl.when(slot < n_slots)
+        def _():
+            pg = cur_ref[2]
+            each_copy(slot, pg, cur_ref[3] % depth, lambda c: c.start())
+            cur_ref[3] = cur_ref[3] + 1
+            more = pg < live_pages(slot)[1]
+            cur_ref[1] = jnp.where(more, slot, slot + 1)
+            cur_ref[2] = jnp.where(
+                more, pg + 1, live_pages(jnp.minimum(slot + 1, n_slots - 1))[0])
+
+    @pl.when(b == 0)
+    def _prime():
+        cur_ref[0] = 0
+        cur_ref[1] = 0
+        cur_ref[2] = live_pages(0)[0]
+        cur_ref[3] = 0
+        for _ in range(depth - 1):
+            start_next()
+
+    idx = len_ref[b]
+    first, last = live_pages(b)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0].astype(jnp.float32) * scale  # (H, 1, D)
+
+    def page(pg, carry):
+        buf = cur_ref[0] % depth
+        start_next()
+        each_copy(b, pg, buf, lambda c: c.wait())
+        # Rows past the slot's length (its last page) and, under a
+        # window, before it (its first) are masked; every other page is
+        # attended whole.
+        edge = pg == last
+        if window is not None:
+            edge |= pg == first
+
+        @pl.when(edge)
+        def _masked():
+            kpos = pg * bs + jax.lax.broadcasted_iota(
+                jnp.int32, (bs, 1), 0)[None]
+            valid = kpos <= idx
+            if window is not None:
+                valid &= idx - kpos < window
+            _mha_page(q, k_buf[buf], v_buf[buf], acc_ref, m_ref, l_ref,
+                      valid, softcap)
+
+        @pl.when(~edge)
+        def _whole():
+            _mha_page(q, k_buf[buf], v_buf[buf], acc_ref, m_ref, l_ref,
+                      None, softcap)
+
+        cur_ref[0] = cur_ref[0] + 1
+        return carry
+
+    jax.lax.fori_loop(first, last + 1, page, 0)
+    l = l_ref[...]
+    if sink_ref is not None:
+        r, l2, _ = sink_rebase(m_ref[...], l, sink_ref[...][..., :1])
+        o_ref[0] = (acc_ref[...] * r / l2).astype(o_ref.dtype)
+    else:
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _mha_vmem_bytes(h, bs, d, dtype) -> int:
+    """VMEM the MHA kernel asks for: PAGED_MHA_DEPTH k and v pages, and
+    as much again for the arithmetic, with room for the compiler's own."""
+    tiles = PAGED_MHA_DEPTH * 2 * h * bs * d * jnp.dtype(dtype).itemsize
+    return 2 * tiles + (4 << 20)
+
+
+# The most VMEM the MHA kernel may ask for: under the v5e's 128 MiB with
+# room for the compiler's own.
+PAGED_MHA_VMEM_BOUND = 64 << 20
+
+
+def _paged_mha_flash(q, pool_k, pool_v, tables, index, scale, window,
+                     interpret, softcap=None, sinks=None):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, h, d = q.shape
+    bs = pool_k.shape[2]
+    rb = PAGED_MHA_TAIL_ROWS
+    while bs % rb:
+        rb //= 2
+    row = pl.BlockSpec((1, h, 1, d), lambda bi, lr, tr: (bi, 0, 0, 0))
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row, any_, any_]
+    operands = [q.reshape(b, h, 1, d), pool_k, pool_v]
+    has_sinks = sinks is not None
+    if has_sinks:
+        in_specs.append(
+            pl.BlockSpec((h, 1, 128), lambda bi, lr, tr: (0, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32)[:, None, None], (h, 1, 128)))
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_mha_kernel, scale=scale, bs=bs, rb=rb, window=window,
+            num_kv=tables.shape[1], softcap=softcap, has_sinks=has_sinks,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=in_specs,
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((PAGED_MHA_DEPTH, h, bs, d), pool_k.dtype),
+                pltpu.VMEM((PAGED_MHA_DEPTH, h, bs, d), pool_v.dtype),
+                pltpu.VMEM((h, 1, d), jnp.float32),
+                pltpu.VMEM((h, 1, 1), jnp.float32),
+                pltpu.VMEM((h, 1, 1), jnp.float32),
+                pltpu.SemaphoreType.DMA((PAGED_MHA_DEPTH, 2, bs // rb)),
+                pltpu.SMEM((4,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_mha_vmem_bytes(h, bs, d, pool_k.dtype),
+        ),
+        interpret=interpret,
+        name="decode_paged_mha",
+    )(index.astype(jnp.int32), tables.astype(jnp.int32), *operands)
+    return out.reshape(b, 1, h, d)
+
+
 def paged_decode_supported(q, pool_k, *, quant: bool = False) -> bool:
     """Can a compiled paged kernel handle these arrays?"""
     return _paged_shapes_supported(q.shape, pool_k.shape, quant)
@@ -989,6 +1225,39 @@ def paged_kernel_under_auto(q_shape, pool_shape, pool_dtype) -> bool:
     return (_paged_shapes_supported(q_shape, pool_shape, quant=False)
             and pool_shape[3] % 128 == 0
             and pool_shape[2] >= PAGED_KERNEL_MIN_PAGE)
+
+
+def paged_kernel_name(q_shape, pool_shape, pool_dtype, num_kv: int,
+                      v_width: int) -> str:
+    """Which kernel reads a pool through its table, as its pallas_call is
+    named (a trace shows that name): a pure function of q's and the
+    pool's shapes, the pool's dtype, the table's pages a slot and the
+    lanes of a v row (0 where v is read out of the k tile). The shapes
+    are ones the kernels support (_paged_shapes_supported).
+
+    "decode_paged_mha": one query row a kv head (H == Hkv, s == 1) of a
+    bf16/fp32 pool with full-lane heads, whole sublane tiles a page and
+    its tiles under PAGED_MHA_VMEM_BOUND. Every head of a page is one
+    vector pass, over a slot's live pages only: at group size 1 there is
+    no matmul row to batch.
+    "decode_paged_group": an int8 pool (the one-page kernel has no scale
+    plumbing), or full-lane heads where more than one page a grid step
+    divides the table (_paged_group): its tile body is the ref-slicing
+    fast path and grouping amortizes the step.
+    "decode_paged": the one-page kernel, for everything else.
+    """
+    _, s, h, d = q_shape
+    hkv, bs, dk = pool_shape[1:]
+    quant = jnp.dtype(pool_dtype) == jnp.int8
+    if (not quant and h == hkv and s == 1 and d % 128 == 0
+            and v_width in (0, dk)
+            and _paged_shapes_supported(q_shape, pool_shape, False)
+            and bs % _sublane_rows(pool_dtype) == 0
+            and _mha_vmem_bytes(h, bs, d, pool_dtype) <= PAGED_MHA_VMEM_BOUND):
+        return "decode_paged_mha"
+    group = (_pages_a_step(num_kv, pool_shape, pool_dtype, v_width)
+             if d % 128 == 0 else 1)
+    return "decode_paged_group" if group > 1 or quant else "decode_paged"
 
 
 def paged_decode_path(q_shape, pool_shape, pool_dtype,
@@ -1098,16 +1367,19 @@ def paged_decode_attention(
         kernel_v = None if pool_v is pool_k and not quant else pool_v
 
         def kernel(q, pool_k, pool_v, tables, index, k_scale, v_scale, sinks):
-            # Grouped gather kernel when the head dim keeps full-lane
-            # tiles (its tile body is the ref-slicing fast path) and
-            # grouping actually amortizes anything; one-page kernel
-            # otherwise. Int8 pools always take the grouped kernel (the
-            # support gate guarantees its constraints): the one-page
-            # kernel's BlockSpec body has no scale plumbing.
-            group = (_paged_group(tables, pool_k, pool_v)
-                     if q.shape[-1] % 128 == 0 else 1)
             sc = None if softcap is None else float(softcap)
-            if group > 1 or quant:
+            name = paged_kernel_name(
+                q.shape, pool_k.shape, pool_k.dtype, tables.shape[1],
+                0 if pool_v is None else pool_v.shape[3])
+            if name == "decode_paged_mha":
+                return _paged_mha_flash(
+                    q, pool_k, pool_k if pool_v is None else pool_v, tables,
+                    index, float(scale), window, interpret, softcap=sc,
+                    sinks=sinks,
+                )
+            if name == "decode_paged_group":
+                group = (_paged_group(tables, pool_k, pool_v)
+                         if q.shape[-1] % 128 == 0 else 1)
                 return _paged_group_flash(
                     q, pool_k, pool_v, tables, index, float(scale), window,
                     max(group, 1), interpret, softcap=sc, sinks=sinks,

@@ -121,6 +121,24 @@ def _paged_decode(bs, *, quant=False):
     return fn, shapes
 
 
+def _paged_mha_decode(window=None):
+    """ouro-2.6b-batch-reason's read: 16 q heads = 16 kv heads x 128
+    (one query row a kv head), five 128-row pages a slot, 8 slots; the
+    MHA kernel."""
+    from shellac_tpu.ops.decode_attention import paged_decode_attention
+
+    slots, pages, heads = 8, 5, 16
+    pool = ((slots * pages + 1, heads, 128, D), BF16)
+    shapes = [((slots, 1, heads, D), BF16), pool, pool,
+              ((slots, pages), I32), ((slots,), I32)]
+
+    def fn(q, pk, pv, tables, index):
+        return paged_decode_attention(q, pk, pv, tables, index, impl="flash",
+                                      interpret=False, window=window)
+
+    return fn, shapes
+
+
 def _paged_latent_decode():
     """deepseek-v2-lite-batch's read: 16 q heads on the one shared
     latent row, held at 640 lanes (kvcache.held_width(576)), 128-row
@@ -226,6 +244,9 @@ CASES = [
     ("paged-bf16-page256", lambda: _paged_decode(256), True),
     # And the latent pool it sends there (deepseek-v2-lite-batch's).
     ("paged-latent-page128", _paged_latent_decode, True),
+    # And ouro-2.6b-batch-reason's, one query row a kv head.
+    ("paged-mha-page128", _paged_mha_decode, True),
+    ("paged-mha-page128-window", lambda: _paged_mha_decode(200), True),
     *[(f"paged-int8-page{bs}",
        lambda bs=bs: _paged_decode(bs, quant=True), True)
       for bs in _int8_page_sizes()],

@@ -222,6 +222,39 @@ def test_paged_group_is_sized_from_the_tile(hkv, bs, d, pages, shared, want):
     assert _paged_group(tables, pool_k, None if shared else pool_k) == want
 
 
+@pytest.mark.parametrize("s,h,hkv,bs,d,pages,dtype,shared,want", [
+    # ouro-2.6b-batch-reason's read: one query row a kv head.
+    pytest.param(1, 16, 16, 128, 128, 5, jnp.bfloat16, False,
+                 "decode_paged_mha", id="mha-bf16-s1"),
+    pytest.param(1, 4, 4, 16, 128, 8, jnp.float32, False,
+                 "decode_paged_mha", id="mha-f32-s1"),
+    # The same pool, three query rows a head: the one-page kernel (two
+    # pages a step do not divide five).
+    pytest.param(3, 16, 16, 128, 128, 5, jnp.bfloat16, False,
+                 "decode_paged", id="mha-bf16-s3"),
+    pytest.param(1, 32, 8, 256, 128, 10, jnp.bfloat16, False,
+                 "decode_paged_group", id="gqa-mistral-7b-batch"),
+    pytest.param(1, 16, 1, 128, 640, 20, jnp.bfloat16, True,
+                 "decode_paged_group", id="latent-deepseek-v2-lite-batch"),
+    pytest.param(1, 16, 16, 128, 128, 5, jnp.int8, False,
+                 "decode_paged_group", id="mha-int8-s1"),
+    # Eight rows of bf16 are half a sublane tile.
+    pytest.param(1, 4, 4, 8, 128, 8, jnp.bfloat16, False,
+                 "decode_paged", id="mha-bf16-page8"),
+])
+def test_paged_kernel_is_chosen_from_the_shapes(s, h, hkv, bs, d, pages,
+                                                dtype, shared, want):
+    """Which kernel reads a pool through its table is a pure function of
+    the shapes and the pool's dtype: the MHA kernel for one query row a
+    kv head of a bf16/fp32 pool with full-lane heads and whole sublane
+    tiles a page; the grouped kernel for int8 and where pages group; the
+    one-page kernel for the rest."""
+    from shellac_tpu.ops.decode_attention import paged_kernel_name
+
+    assert paged_kernel_name((8, s, h, d), (3, hkv, bs, d), dtype, pages,
+                             0 if shared else d) == want
+
+
 def test_auto_falls_back_to_ref_off_tpu():
     """impl='auto' off-TPU must take the ref path bit-for-bit."""
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -351,7 +384,7 @@ def test_paged_auto_rule(monkeypatch, h, hkv, d, bs, dtype, kernel):
                                 "ref") == "gather"
 
     took = []
-    for name in ("_paged_group_flash", "_paged_flash"):
+    for name in ("_paged_group_flash", "_paged_flash", "_paged_mha_flash"):
         real = getattr(da, name)
         monkeypatch.setattr(
             da, name,
@@ -452,19 +485,23 @@ def test_engine_records_its_decode_read_path(monkeypatch, backend, head_dim,
 
 
 # (kv heads, q heads a kv head, row, held row, page rows, pages a slot,
-#  pages a grid step, k served as v)
+#  pages a grid step, k served as v, attention's keywords)
 CELLS = {
     # mistral-7b-batch's pool.
-    "gqa": (8, 4, D, D, 256, 4, 2, False),
+    "gqa": (8, 4, D, D, 256, 4, 2, False, {}),
     # deepseek-v2-lite-batch's: the MLA latent, 576 lanes held at 640,
     # one pool as k and as v (values are its first 512 lanes).
-    "latent": (1, 16, 576, 640, 128, 20, 4, True),
+    "latent": (1, 16, 576, 640, 128, 20, 4, True, {}),
     # ouro-2.6b-batch-reason's: MHA 16/16 (one q head a kv head) over
-    # FIVE 128-row pages a slot. The tile's bound allows two pages a
-    # step, two does not divide five, so this is the one-page kernel
-    # (`decode_paged`); on the chip two pages a step over a table
-    # extended by a dead entry took the same time (PERF.md, PR 33).
-    "mha-odd-table": (16, 1, D, D, 128, 5, 1, False),
+    # FIVE 128-row pages a slot. One query row a head is the MHA kernel
+    # (`decode_paged_mha`); at s=3 the tile's bound allows two pages a
+    # step, two does not divide five, so it is the one-page kernel
+    # (`decode_paged`).
+    "mha-odd-table": (16, 1, D, D, 128, 5, 1, False, {}),
+    # The same pool under a window (pages wholly before it are dead),
+    # Gemma-2's logit cap and GPT-OSS's sinks (one a q head).
+    "mha-window": (16, 1, D, D, 128, 5, 1, False,
+                   {"window": 200, "softcap": 30.0, "sinks": True}),
 }
 
 
@@ -472,21 +509,34 @@ CELLS = {
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("s", [1, 3])
 @pytest.mark.parametrize("cell", list(CELLS))
-def test_paged_kernel_at_serving_shape_matches_ref(cell, s, dtype):
-    """The grouped kernel at a benchmark cell's pool (8 kv heads x 128,
-    256-row pages, 2 pages a grid step, 4 query heads a kv head; and
-    the latent pool, one 640-lane row a token under 16 heads, 4
-    128-row pages a step, the k tile read as v): lengths that end
-    mid-page, on a page's last row, on a page's first row, at one row
-    and at the view's end, and a slot whose table row was never
-    allocated (every entry the scratch page 0). Every live row of
-    every slot is attended, no dead one: the pool's unowned pages hold
-    NaN, which any read past a slot's length would carry into its
-    output. The latent's q arrives 576 wide against rows whose pad
+def test_paged_kernel_at_serving_shape_matches_ref(monkeypatch, cell, s,
+                                                   dtype):
+    """The kernels at a benchmark cell's pool (the grouped kernel over 8
+    kv heads x 128, 256-row pages, 2 pages a grid step, 4 query heads a
+    kv head, and over the latent pool, one 640-lane row a token under 16
+    heads, 4 128-row pages a step, the k tile read as v; the MHA kernel
+    over 16 heads = 16 kv heads, five 128-row pages a slot, one query row
+    a head): lengths that end mid-page, on a page's last row, on a page's
+    first row, at one row and at the view's end, and a slot whose table
+    row was never allocated (every entry the scratch page 0). Every live
+    row of every slot is attended, no dead one: the pool's unowned pages
+    hold NaN (and, where the MHA kernel reads, so does every row past a
+    slot's last), which any read past a slot's length would carry into
+    its output. The latent's q arrives 576 wide against rows whose pad
     lanes hold zeros, as the model's does."""
-    from shellac_tpu.ops.decode_attention import _paged_group
+    import shellac_tpu.ops.decode_attention as da
 
-    hkv, per_kv, d, held, page, pages, group, k_as_v = CELLS[cell]
+    hkv, per_kv, d, held, page, pages, group, k_as_v, kw = CELLS[cell]
+    window = kw.get("window")
+    mha = per_kv == 1 and s == 1
+    want = ("_paged_mha_flash" if mha else
+            "_paged_group_flash" if group > 1 else "_paged_flash")
+    took = []
+    for name in ("_paged_group_flash", "_paged_flash", "_paged_mha_flash"):
+        real = getattr(da, name)
+        monkeypatch.setattr(
+            da, name, lambda *a, _real=real, _name=name, **k: (
+                took.append(_name), _real(*a, **k))[1])
     view = page * pages
     index = jnp.asarray(
         [page + 44, page - s, page, 0, view - s, 0], jnp.int32)
@@ -498,24 +548,36 @@ def test_paged_kernel_at_serving_shape_matches_ref(cell, s, dtype):
     dense_v = (dense_k if k_as_v
                else _rand(ks[2], (n, hkv, view, d)).astype(dtype))
     pool_k, pool_v, tables = _scatter_pool(dense_k, dense_v, page)
-    assert _paged_group(tables, pool_k, None if k_as_v else pool_v) == group
-    # Pages wholly past a slot's length: NaN in the pool (never to be
-    # read), zero in the reference's dense view (masked there). Rows
-    # past the length inside a live page keep their finite garbage in
-    # both, as a served pool's do.
-    live_pages = (np.arange(pages)[None, :] * page
-                  < np.asarray(index)[:, None] + s)  # (n, pages)
+    assert da._paged_group(tables, pool_k,
+                           None if k_as_v else pool_v) == group
+    # Pages wholly past a slot's length, or before its window: NaN in
+    # the pool (never to be read), zero in the reference's dense view
+    # (masked there). Rows past the length inside a live page keep their
+    # finite garbage in both, as a served pool's do, but where the MHA
+    # kernel reads: it copies a last page only up to the row block that
+    # holds the slot's last row, so the rest may be anything.
+    at = np.asarray(index)[:, None]
+    live_pages = np.arange(pages)[None, :] * page < at + s  # (n, pages)
+    if window is not None:
+        live_pages &= (np.arange(pages)[None, :] + 1) * page > (
+            at - window + 1)
     keep = jnp.asarray(np.repeat(live_pages, page, axis=1))
     dead = np.ones(pool_k.shape[0], bool)
     dead[0] = False
     dead[np.asarray(tables)[live_pages]] = False
+    past = np.zeros(pool_k.shape[:1] + pool_k.shape[2:3], bool)  # (N, page)
+    if mha:
+        for b, row in enumerate(np.asarray(tables)):
+            rows = np.arange(pages * page).reshape(pages, page) > at[b]
+            past[row] |= rows
     # The last slot was never allocated: its table row names page 0,
     # which holds finite scratch (the allocator's convention: zeros in
     # a held row's pad lanes there too).
     tables = tables.at[n - 1].set(0)
 
     def served(pool, dense):
-        pool = jnp.where(jnp.asarray(dead)[:, None, None, None], jnp.nan,
+        pool = jnp.where(jnp.asarray(dead)[:, None, None, None]
+                         | jnp.asarray(past)[:, None, :, None], jnp.nan,
                          pool).astype(dtype)
         dense = jnp.where(keep[:, None, :, None], dense, 0)
         return (pool.at[0, ..., :d].set(1.0),
@@ -525,13 +587,17 @@ def test_paged_kernel_at_serving_shape_matches_ref(cell, s, dtype):
     pool_v, dense_v = (pool_k, dense_k) if k_as_v else served(pool_v, dense_v)
 
     scale = (192 if k_as_v else d) ** -0.5
+    if kw.get("sinks"):
+        kw = {**kw, "sinks": _rand(ks[2], (per_kv * hkv,)) * 2.0}
     ref = _decode_ref(jnp.pad(q, ((0, 0),) * 3 + ((0, held - d),)),
-                      dense_k, dense_v, index, None, scale)
+                      dense_k, dense_v, index, window, scale,
+                      softcap=kw.get("softcap"), sinks=kw.get("sinks"))
     out = paged_decode_attention(
         q, pool_k, pool_v, tables, index, impl="flash", interpret=True,
-        scale=scale,
+        scale=scale, **kw,
     )
     assert out.shape == q.shape
+    assert took == [want], took
     # What the model reads of the latent's output: its first 512 lanes.
     lanes = 512 if k_as_v else d
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
